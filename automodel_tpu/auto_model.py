@@ -79,21 +79,33 @@ def from_config(
     mesh_ctx: Optional[MeshContext] = None,
     backend: BackendConfig | dict | None = None,
     seed: int = 0,
+    abstract: bool = False,
 ) -> AutoModel:
     """Random-init (pretraining) constructor (reference: from_config,
-    auto_model.py:479). Params materialize directly sharded via jit+out_shardings."""
+    auto_model.py:479). Params materialize directly sharded via jit+out_shardings.
+
+    ``abstract``: params are ``ShapeDtypeStruct``s carrying their shardings
+    and nothing touches a device — what the compile-only pre-check
+    (tools/compile_check.py) lowers against a TPU topology from a host
+    that has no chip."""
     backend = _as_backend(backend, mesh_ctx)
     builder = resolve_architecture(hf_config)
     model, adapter = builder(hf_config, backend)
+    _check_kernel_mesh(model.config, mesh_ctx, backend)
     model = _maybe_pp(model, mesh_ctx, backend)
     key = jax.random.key(seed)
     if mesh_ctx is None:
-        params = model.init(key)
+        params = jax.eval_shape(model.init, key) if abstract else model.init(key)
     else:
-        shardings = make_param_shardings(
-            mesh_ctx, jax.eval_shape(model.init, key), model.sharding_rules
-        )
-        params = jax.jit(model.init, out_shardings=shardings)(key)
+        shapes = jax.eval_shape(model.init, key)
+        shardings = make_param_shardings(mesh_ctx, shapes, model.sharding_rules)
+        if abstract:
+            params = jax.tree.map(
+                lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+                shapes, shardings,
+            )
+        else:
+            params = jax.jit(model.init, out_shardings=shardings)(key)
     return AutoModel(
         model=model, params=params, adapter=adapter, mesh_ctx=mesh_ctx,
         hf_config=hf_config if isinstance(hf_config, dict) else None,
@@ -120,6 +132,7 @@ def from_pretrained(
         hf_config = {**hf_config, **dict(hf_config_overrides)}
     builder = resolve_architecture(hf_config)
     model, adapter = builder(hf_config, backend)
+    _check_kernel_mesh(model.config, mesh_ctx, backend)
     model = _maybe_pp(model, mesh_ctx, backend)
     shardings = None
     if mesh_ctx is not None:
@@ -151,10 +164,12 @@ def _as_backend(
         backend = BackendConfig()
     elif not isinstance(backend, BackendConfig):
         backend = BackendConfig(**dict(backend))
-    if backend.platform is None and mesh_ctx is not None:
-        import dataclasses
-
-        backend = dataclasses.replace(backend, platform=mesh_ctx.platform)
+    if mesh_ctx is not None:
+        backend = dataclasses.replace(
+            backend,
+            platform=backend.platform or mesh_ctx.platform,
+            mesh_ctx=mesh_ctx,
+        )
     if backend.attn == "ring":
         if mesh_ctx is None:
             raise ValueError("attn='ring' (context parallel) requires a mesh")
@@ -162,6 +177,44 @@ def _as_backend(
 
         install_ring_backend(mesh_ctx, zigzag=backend.cp_zigzag)
     return backend
+
+
+def _check_kernel_mesh(
+    cfg: Any, mesh_ctx: Optional[MeshContext], backend: BackendConfig
+) -> None:
+    """Refuse at setup what the Pallas kernels cannot run on this mesh.
+    GSPMD cannot partition a Mosaic call, so on several devices each kernel
+    runs per device inside a shard_map (ops/platform_check.kernel_axes) and
+    needs whole shards; left alone, a bad combination surfaces as a
+    lowering error at the first step. Only where a kernel would really run:
+    off-TPU the same configs take XLA paths that GSPMD partitions freely."""
+    if mesh_ctx is None or mesh_ctx.mesh.size == 1:
+        return
+    from automodel_tpu.ops.attention import _flash_eligible, flash_head_axes
+    from automodel_tpu.ops.platform_check import is_tpu_platform
+
+    heads = getattr(cfg, "num_heads", None)
+    if (
+        backend.attn == "flash"
+        and heads is not None
+        and _flash_eligible(backend.platform)
+    ):
+        flash_head_axes(mesh_ctx, heads, getattr(cfg, "num_kv_heads", heads))
+    if (
+        mesh_ctx.pp_size > 1
+        and getattr(cfg, "moe", None) is not None
+        and backend.experts in ("ragged", "ragged_fused", "a2a", "a2a_fused")
+        and is_tpu_platform(backend.platform)
+    ):
+        # a pipeline stage is manual over pp (and ep, for the a2a exchange)
+        # only, and the expert block inside it is handed no mesh to make
+        # the remaining axes manual with. (Interpreted kernels are plain
+        # XLA ops, so the CPU suite runs this combination.)
+        raise ValueError(
+            f"pp={mesh_ctx.pp_size} with experts: {backend.experts}: the "
+            "Pallas grouped matmul cannot run inside a pipeline stage on "
+            "TPU yet — use experts: gspmd with pp, or ep/dp/tp without pp"
+        )
 
 
 def _maybe_pp(model: Any, mesh_ctx: Optional[MeshContext], backend: BackendConfig):

@@ -56,7 +56,47 @@ def _crash_is_preemption_collateral(cfg) -> bool:
     )
 
 
+def _device_runtime() -> None:
+    """What every command that can touch a device does first: point JAX at
+    the one persistent compile cache (utils/compile_cache.py) and join the
+    multi-host job when the launcher declared one."""
+    from automodel_tpu.parallel.mesh import initialize_distributed
+    from automodel_tpu.telemetry.compile_events import CompileEventBridge
+    from automodel_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    CompileEventBridge()  # registers the process-wide compile listeners
+    initialize_distributed()
+
+
+def _compile_report() -> None:
+    """One JSON line on stderr when a device command ends: what the process
+    compiled and what the persistent cache saved it — how a second run
+    shows that the cache was found again."""
+    compile_events = sys.modules.get("automodel_tpu.telemetry.compile_events")
+    totals = compile_events.compile_totals() if compile_events else None
+    if totals is not None:
+        import json
+
+        import jax
+
+        print(
+            json.dumps({
+                "event": "compile_report", **totals,
+                "cache_dir": jax.config.jax_compilation_cache_dir,
+            }),
+            file=sys.stderr, flush=True,
+        )
+
+
 def main(argv: list[str] | None = None) -> int:
+    try:
+        return _run(argv)
+    finally:
+        _compile_report()
+
+
+def _run(argv: list[str] | None = None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
     # `report` takes a JSONL path, not a domain: validate + summarize a
     # metrics file (telemetry/report.py — same linter bench.py uses)
@@ -89,20 +129,18 @@ def main(argv: list[str] | None = None) -> int:
     # section for sampling/lengths, `--prompt` via the dotted overrides
     if argv and argv[0] == "generate":
         from automodel_tpu.generation.engine import main as generate_main
-        from automodel_tpu.parallel.mesh import initialize_distributed
 
         cfg = parse_args_and_load_config(argv[1:])
-        initialize_distributed()
+        _device_runtime()
         return generate_main(cfg)
     # `serve` runs the continuous-batching serving engine (serving/):
     # stdin-JSONL by default, a local HTTP front when serving.http.port is
     # set; model/mesh from the same YAML sections as `generate`
     if argv and argv[0] == "serve":
-        from automodel_tpu.parallel.mesh import initialize_distributed
         from automodel_tpu.serving.server import main as serve_main
 
         cfg = parse_args_and_load_config(argv[1:])
-        initialize_distributed()
+        _device_runtime()
         return serve_main(cfg)
     # `route` runs the fleet router (serving/fleet/router.py): spreads
     # requests over N `serve` replicas with prefix-affinity placement,
@@ -125,11 +163,10 @@ def main(argv: list[str] | None = None) -> int:
     # configured workload and GENERATES the PROFILE artifacts (structured
     # report.json + PROFILE.md) — telemetry/profiling/runner.py
     if argv and argv[0] == "profile":
-        from automodel_tpu.parallel.mesh import initialize_distributed
         from automodel_tpu.telemetry.profiling.runner import main as profile_main
 
         cfg = parse_args_and_load_config(argv[1:])
-        initialize_distributed()
+        _device_runtime()
         return profile_main(cfg)
     if len(argv) < 2 or argv[0] in ("-h", "--help"):
         print(_usage())
@@ -176,9 +213,7 @@ def main(argv: list[str] | None = None) -> int:
             print(f"submitted {submitted}")
             return 0
 
-    from automodel_tpu.parallel.mesh import initialize_distributed
-
-    initialize_distributed()
+    _device_runtime()
 
     recipe_modules = {
         ("finetune", "llm"): "automodel_tpu.recipes.train_ft",

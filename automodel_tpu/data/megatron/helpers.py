@@ -2,14 +2,20 @@
 
 Parity: the reference compiles its pybind11 helpers at runtime via Makefile
 with a pure-Python fallback (components/datasets/llm/megatron/helpers.py:20,
-Makefile). Same pattern: g++ -O3 -shared -fPIC at first use, cached next to
-the source; `numpy` fallbacks keep everything working without a toolchain.
+Makefile). Same pattern: g++ -O3 -shared -fPIC at first use, cached in the
+``__pycache__`` beside the source (Python's own build cache: git-ignored,
+never copied to the chip machine) under a name that carries the SOURCE'S
+HASH — so what is loaded was built from the helpers.cpp git would commit,
+never an older or foreign object that happens to sit in the tree with a
+newer mtime; `numpy` fallbacks keep everything working without a toolchain.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
+import os
 import subprocess
 from pathlib import Path
 from typing import Optional
@@ -19,9 +25,6 @@ import numpy as np
 logger = logging.getLogger(__name__)
 
 _HERE = Path(__file__).parent
-# NOTE: not "helpers.so" — an extension-named .so next to helpers.py would
-# shadow this module in import resolution
-_SO = _HERE / "libmegatron_helpers.so"
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
@@ -33,13 +36,20 @@ def _load() -> Optional[ctypes.CDLL]:
     _tried = True
     try:
         src = _HERE / "helpers.cpp"
-        if not _SO.exists() or _SO.stat().st_mtime < src.stat().st_mtime:
+        digest = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+        so = _HERE / "__pycache__" / f"libmegatron_helpers_{digest}.so"
+        if not so.exists():
+            so.parent.mkdir(exist_ok=True)
+            # build beside the target, then rename: concurrent first users
+            # (xdist workers) must never load a half-written object
+            tmp = so.with_suffix(f".{os.getpid()}.tmp")
             subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", str(src), "-o", str(_SO)],
+                ["g++", "-O3", "-shared", "-fPIC", str(src), "-o", str(tmp)],
                 check=True,
                 capture_output=True,
             )
-        lib = ctypes.CDLL(str(_SO))
+            os.replace(tmp, so)
+        lib = ctypes.CDLL(str(so))
         lib.build_sample_idx.restype = ctypes.c_int64
         lib.build_sample_idx.argtypes = [
             ctypes.POINTER(ctypes.c_int32),

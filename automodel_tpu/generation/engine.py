@@ -346,13 +346,9 @@ def build_auto_from_cfg(cfg: Any) -> Any:
     from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
 
     dist = cfg.get("distributed", ConfigNode())
-    degrees = {
-        k: dist.get(k, -1 if k == "dp_shard" else 1)
-        for k in ("dp_replicate", "dp_shard", "tp", "cp", "pp", "ep")
-    }
     platform = dist.get("platform", None)
     devices = jax.devices(platform) if platform else None
-    mesh_ctx = build_mesh(MeshConfig(**degrees), devices=devices)
+    mesh_ctx = build_mesh(MeshConfig.from_section(dist), devices=devices)
     return build_auto_from_model_section(
         cfg.model, mesh_ctx, seed=cfg.get("seed", 0)
     )

@@ -241,13 +241,16 @@ class CacheContext:
         sliding_window: Optional[int] = None,
         scale: Optional[float] = None,
         logits_soft_cap: Optional[float] = None,
+        mesh_ctx=None,
     ) -> jnp.ndarray:
         """Cache-attending attention for this mode — the single dispatch
         point the model attention blocks call when ``attends_cache``.
         ``layer_kv`` is the layer's just-written cache pair from ``write``.
         Decode/chunk: ``sdpa_decode`` over the (gathered) cache under the
         position-tag mask. Paged: the fused Pallas kernel indexes the block
-        pool in place through the tables (ops/paged_attention.py)."""
+        pool in place through the tables (ops/paged_attention.py), inside a
+        shard_map when ``mesh_ctx`` (BackendConfig.mesh_ctx) spans several
+        devices."""
         if self.mode == "paged":
             from automodel_tpu.ops import paged_attention as _pa
 
@@ -258,7 +261,7 @@ class CacheContext:
                 q, kq, vq, self.tables, self.q_pos, ks, vs,
                 scale=scale, sliding_window=sliding_window,
                 logits_soft_cap=logits_soft_cap,
-                interpret=self.paged_interpret,
+                interpret=self.paged_interpret, mesh_ctx=mesh_ctx,
             )
         from automodel_tpu.ops.attention import sdpa_decode
 

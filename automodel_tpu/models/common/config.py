@@ -42,6 +42,11 @@ class BackendConfig:
     # point at a different backend than the mesh (e.g. CPU mesh + visible
     # TPU). None → fall back to the default-device heuristic.
     platform: Optional[str] = None
+    # the MeshContext the model runs on, resolved beside ``platform``: a
+    # Pallas kernel on a mesh of several devices must sit in a shard_map
+    # (ops/platform_check.kernel_axes), so the attention call sites hand it
+    # down. Not a user setting and not part of the config's identity.
+    mesh_ctx: Any = dataclasses.field(default=None, compare=False, repr=False)
     experts: str = "gspmd"  # gspmd | ragged | ragged_fused | dense | a2a | a2a_fused
     fake_balanced_gate: bool = False  # deterministic routing for benchmarks
     param_dtype: str = "float32"

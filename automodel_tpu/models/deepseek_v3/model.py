@@ -172,6 +172,7 @@ def mla_block(
         v,
         backend=backend.attn,
         platform=backend.platform,
+        mesh_ctx=backend.mesh_ctx,
         causal=True,
         scale=cfg.mla_attn_scale,
         segment_ids=segment_ids,

@@ -170,6 +170,7 @@ def _layer(
         v,
         backend=backend.attn,
         platform=backend.platform,
+        mesh_ctx=backend.mesh_ctx,
         is_sliding=flags["is_sliding"],
         window=cfg.sliding_window,
         dynamic_window=flags["window"],  # dynamic bound; S for full layers

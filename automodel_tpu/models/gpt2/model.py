@@ -161,12 +161,15 @@ def decoder_layer(
     if cache is not None and cache_ctx.attends_cache:
         # ctx-dispatched cache attend: sdpa_decode over the (gathered)
         # cache, or the fused paged kernel over the block pool (serving/)
-        attn_out = cache_ctx.attend(q, new_layer_kv)
+        attn_out = cache_ctx.attend(
+            q, new_layer_kv, mesh_ctx=backend.mesh_ctx
+        )
     else:
         attn_out = attention(
             q, k, v,
             backend=backend.attn,
             platform=backend.platform,
+            mesh_ctx=backend.mesh_ctx,
             causal=True,
             segment_ids=segment_ids,
         )

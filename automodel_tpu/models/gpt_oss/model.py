@@ -120,6 +120,7 @@ def _layer(cfg, backend, h, lp, flags, cos, sin, segment_ids, constrain):
         v,
         backend=backend.attn,
         platform=backend.platform,
+        mesh_ctx=backend.mesh_ctx,
         is_sliding=flags["is_sliding"],
         window=cfg.sliding_window,
         dynamic_window=flags["window"],

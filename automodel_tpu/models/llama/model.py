@@ -200,6 +200,7 @@ def attention_block(
                 sliding_window=sliding_window,
                 scale=cfg.attn_scale,
                 logits_soft_cap=cfg.attn_soft_cap,
+                mesh_ctx=backend.mesh_ctx,
             )
             h = h + _proj(
                 attn_out.reshape(B, S, cfg.q_dim), lp["attn"]["o_proj"], backend.fp8
@@ -211,6 +212,7 @@ def attention_block(
         v,
         backend=backend.attn,
         platform=backend.platform,
+        mesh_ctx=backend.mesh_ctx,
         causal=cfg.causal,
         scale=cfg.attn_scale,
         segment_ids=segment_ids,

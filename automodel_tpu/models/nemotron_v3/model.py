@@ -254,6 +254,7 @@ def _attn_mixer(cfg, backend, x, ap, segment_ids):
     v = proj("v_proj", cfg.num_kv_heads)
     out = attention(
         q, k, v, backend=backend.attn, platform=backend.platform,
+        mesh_ctx=backend.mesh_ctx,
         causal=True, segment_ids=segment_ids,
         **(
             {"block_q": backend.attn_block_q, "block_kv": backend.attn_block_kv}

@@ -164,6 +164,7 @@ def _full_attn_layer(cfg, backend, x, ap, cos, sin, segment_ids):
     out = attention(
         q, k, v,
         backend=backend.attn, platform=backend.platform,
+        mesh_ctx=backend.mesh_ctx,
         causal=True, segment_ids=segment_ids,
         **(
             {"block_q": backend.attn_block_q, "block_kv": backend.attn_block_kv}

@@ -240,6 +240,7 @@ def _attn_layer(cfg, backend, x, ap, cos_sin, nh, nkv, window, segment_ids):
         q, k = apply_rope(q, k, *cos_sin)
     out = attention(
         q, k, v, backend=backend.attn, platform=backend.platform,
+        mesh_ctx=backend.mesh_ctx,
         causal=True, segment_ids=segment_ids, sliding_window=window,
         **(
             {"block_q": backend.attn_block_q, "block_kv": backend.attn_block_kv}

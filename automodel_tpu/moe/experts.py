@@ -13,7 +13,9 @@ backends:
   Tokens over capacity are dropped (capacity_factor; the aux-free bias and
   aux loss keep loads balanced so drops stay rare).
 - ``ragged`` — dropless sort + `jax.lax.ragged_dot` grouped matmul
-  (megablocks-style). Best single-slice path.
+  (megablocks-style). Best single-slice path; on a mesh of several devices
+  it runs per device inside the ``a2a`` backend's shard_map (GSPMD cannot
+  partition the Pallas grouped matmul).
 - ``a2a``    — the DeepEP-equivalent token-exchange dispatcher (reference
   token_dispatcher.py:339, fused_a2a.py:102,201): explicit shard_map over the
   ``ep`` mesh axis with `lax.all_to_all` dispatch/combine around a local
@@ -41,6 +43,7 @@ from automodel_tpu.moe.config import MoEConfig
 from automodel_tpu.moe.gate import GateOutput
 from automodel_tpu.ops.fp8 import fp8_qdq_blockwise, fp8_qdq_tensor
 from automodel_tpu.ops.grouped_matmul import ragged_dot
+from automodel_tpu.ops.platform_check import kernel_axes
 
 Act = Callable[[jnp.ndarray], jnp.ndarray]
 
@@ -154,7 +157,7 @@ def _dispatch_take(x, order, inv, K):
 
     Autodiff's VJP of this gather is a scatter-add onto [T, D] — the single
     most expensive op in the old MoE step (XLA scatter runs ~4x slower than
-    a gather at bench shape, PROFILE_MOE_r04.md). Because ``order`` is a
+    a gather at bench shape in a round-4 profile). Because ``order`` is a
     bijection over the T·K picks, dx[t] = Σ_k dxs[inv[t·K+k]] is a pure
     gather + K-fold dense sum instead. order/inv are explicit args (not a
     closure) so the function stays remat/checkpoint-safe."""
@@ -286,7 +289,7 @@ def ragged_experts(
     """Dropless sort + ragged_dot grouped matmul (single-slice hot path).
 
     Dispatch and combine are expressed as permutation GATHERS with custom
-    VJPs (no XLA scatter anywhere in fwd or bwd — see PROFILE_MOE_r04.md for
+    VJPs (no XLA scatter anywhere in fwd or bwd — `_dispatch_take` says
     why); group sizes reuse the gate's expert_counts (an exact bincount of
     topk_idx, moe/gate.py).
 
@@ -370,12 +373,21 @@ def a2a_experts(
     implemented by XLA:CPU (where the multichip tests run); the padded
     formulation is numerically identical and XLA lowers the all_to_all onto
     ICI either way.
+
+    This is also how EVERY ragged backend meets a mesh of several devices:
+    GSPMD cannot partition the grouped-matmul Mosaic calls, so `ragged` /
+    `ragged_fused` route here too and the block runs inside the shard_map
+    below — with ``ep == 1`` the exchange drops out and what is left is the
+    single-slice ragged path on each device's own tokens.
     """
     B, S, D = x.shape
     if ctx is not None:
         platform = ctx.platform
-    if ctx is None or ctx.ep_size == 1:
-        # single-slice: the ragged path is already dropless
+    axes = kernel_axes(ctx)
+    if axes is None or len(axes) < len(ctx.mesh.axis_names):
+        # one device, or already inside a manual region (pipeline stage):
+        # the operands are this device's block and the ragged path is
+        # already dropless
         if fused_act is not None:
             return ragged_fused_experts(
                 x.reshape(-1, D), gate_out, weights, cfg, act2,
@@ -433,9 +445,7 @@ def a2a_experts(
     # carry mixed vma (jax limitation), and custom-VJP cotangent psums are
     # then placed by the spec-based shard_map transpose. The in-kernel
     # _match_vma/_out_sds plumbing stays for vma-checked callers (pp).
-    from automodel_tpu.utils.compat import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(tok_spec, tok_spec, tok_spec, {k: w_specs[k] for k in wd}),
@@ -486,35 +496,41 @@ def _a2a_body(xb, idxb, cwb, wd, *, ep, ep_axis, E, E_loc, C, D, K, act2,
     xs = _dispatch_take(xt, order, inv_order, K)
 
     counts = jnp.bincount(flat, length=E).astype(jnp.int32)
-    peer_counts = counts.reshape(ep, E_loc).sum(-1)
-    peer_off = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(peer_counts)[:-1]]
-    )
-    peer_of = sorted_e // E_loc
-    pos_in_peer = jnp.arange(T * K, dtype=jnp.int32) - peer_off[peer_of]
-    keep = pos_in_peer < C  # over-capacity picks drop (zero contribution)
-    dst = jnp.where(keep, peer_of * C + pos_in_peer, ep * C)
-    # slot r of peer p holds pick peer_off[p] + r%C (picks are sorted, hence
-    # peer-contiguous) — the send buffer is a gather, not an .at[].set
-    slot = jnp.arange(ep * C, dtype=jnp.int32)
-    slot_c = slot % C
-    slot_valid = slot_c < peer_counts[slot // C]
-    src = jnp.minimum(peer_off[slot // C] + slot_c, T * K - 1)
+    if ep == 1:
+        # one expert shard: the sorted picks are already grouped by local
+        # expert and nothing is dropped — no exchange, no second sort
+        xs2, sid, gsz = xs, sorted_e, counts
+    else:
+        peer_counts = counts.reshape(ep, E_loc).sum(-1)
+        peer_off = jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32), jnp.cumsum(peer_counts)[:-1]]
+        )
+        peer_of = sorted_e // E_loc
+        pos_in_peer = jnp.arange(T * K, dtype=jnp.int32) - peer_off[peer_of]
+        keep = pos_in_peer < C  # over-capacity picks drop (zero contribution)
+        dst = jnp.where(keep, peer_of * C + pos_in_peer, ep * C)
+        # slot r of peer p holds pick peer_off[p] + r%C (picks are sorted,
+        # hence peer-contiguous) — the send buffer is a gather, not an
+        # .at[].set
+        slot = jnp.arange(ep * C, dtype=jnp.int32)
+        slot_c = slot % C
+        slot_valid = slot_c < peer_counts[slot // C]
+        src = jnp.minimum(peer_off[slot // C] + slot_c, T * K - 1)
 
-    send_x = _slot_pack(xs, src, dst, slot_valid)
-    send_id = jnp.where(slot_valid, sorted_e[src] % E_loc, E_loc)
-    a2a = lambda a: jax.lax.all_to_all(
-        a, ep_axis, split_axis=0, concat_axis=0, tiled=True
-    )
-    recv_x, recv_id = a2a(send_x), a2a(send_id)  # [ep*C, ...] by sender
+        send_x = _slot_pack(xs, src, dst, slot_valid)
+        send_id = jnp.where(slot_valid, sorted_e[src] % E_loc, E_loc)
+        a2a = lambda a: jax.lax.all_to_all(
+            a, ep_axis, split_axis=0, concat_axis=0, tiled=True
+        )
+        recv_x, recv_id = a2a(send_x), a2a(send_id)  # [ep*C, ...] by sender
 
-    order2 = _name_ckpt(
-        jnp.argsort(recv_id, stable=True), "moe_sort_inv"
-    )  # sentinel E_loc sorts last
-    inv_order2 = _name_ckpt(jnp.argsort(order2), "moe_sort_inv2")
-    xs2 = _perm_take(recv_x, order2, inv_order2)
-    sid = jnp.minimum(recv_id[order2], E_loc - 1)
-    gsz = jnp.bincount(recv_id, length=E_loc).astype(jnp.int32)  # sentinel drops
+        order2 = _name_ckpt(
+            jnp.argsort(recv_id, stable=True), "moe_sort_inv"
+        )  # sentinel E_loc sorts last
+        inv_order2 = _name_ckpt(jnp.argsort(order2), "moe_sort_inv2")
+        xs2 = _perm_take(recv_x, order2, inv_order2)
+        sid = jnp.minimum(recv_id[order2], E_loc - 1)
+        gsz = jnp.bincount(recv_id, length=E_loc).astype(jnp.int32)  # sentinel drops
 
     w_g = wd["gw"].astype(xs2.dtype)
     w_d = wd["dw"].astype(xs2.dtype)
@@ -575,9 +591,10 @@ def _a2a_body(xb, idxb, cwb, wd, *, ep, ep_axis, E, E_loc, C, D, K, act2,
     # — the EP backward contains no XLA scatter (VERDICT r4 weak #3; jax
     # 0.9's shard_map infers vma through custom_vjp cleanly, which blocked
     # this in r4).
-    y = _perm_take(y, inv_order2, order2)  # back to recv order
-    y = a2a(y)  # [ep*C, D] back in my send layout
-    y = _slot_unpack(y, dst, src, slot_valid)  # picks; dropped → 0
+    if ep > 1:
+        y = _perm_take(y, inv_order2, order2)  # back to recv order
+        y = a2a(y)  # [ep*C, D] back in my send layout
+        y = _slot_unpack(y, dst, src, slot_valid)  # picks; dropped → 0
     y = _perm_take(y, inv_order, order)  # original pick order
 
     # picks of token t are rows [t*K, t*K+K) → combine is a dense reshape
@@ -671,15 +688,6 @@ def _run_gspmd(x, gate_out, weights, cfg, act2, *, ctx=None,
     return gspmd_experts(x, gate_out, weights, cfg, act2, constrain=constrain)
 
 
-def _run_ragged(x, gate_out, weights, cfg, act2, *, ctx=None,
-                constrain=_noop_constrain, platform=None, fp8=False,
-                act_name="silu"):
-    B, S, D = x.shape
-    return ragged_experts(
-        x.reshape(-1, D), gate_out, weights, cfg, act2, platform=platform, fp8=fp8
-    ).reshape(B, S, D)
-
-
 def _run_a2a(x, gate_out, weights, cfg, act2, *, ctx=None,
              constrain=_noop_constrain, platform=None, fp8=False,
              act_name="silu"):
@@ -740,25 +748,16 @@ def ragged_fused_experts(
     return out.astype(x.dtype)
 
 
-def _run_ragged_fused(x, gate_out, weights, cfg, act2, *, ctx=None,
-                      constrain=_noop_constrain, platform=None, fp8=False,
-                      act_name="silu"):
-    # validate the full envelope incl. fp8 (raise, matching a2a_fused — a
-    # config must not abort on one mesh topology and silently drop
-    # quantization on another)
-    _fused_act_of(cfg, act_name, fp8)
-    B, S, D = x.shape
-    return ragged_fused_experts(
-        x.reshape(-1, D), gate_out, weights, cfg, act2, platform=platform,
-        act_name=act_name,
-    ).reshape(B, S, D)
-
-
+# `ragged` / `ragged_fused` name the same code as `a2a` / `a2a_fused`:
+# a2a_experts IS the single-slice ragged path wherever there is no mesh to
+# exchange over (no ctx, one device, a pipeline stage), and the shard_map'd
+# block — which the Pallas grouped matmul needs on any mesh of several
+# devices — wherever there is. The names stay because configs use them.
 EXPERT_BACKENDS = {
-    "ragged_fused": _run_ragged_fused,
+    "ragged_fused": _run_a2a_fused,
     "dense": _run_dense,
     "gspmd": _run_gspmd,
-    "ragged": _run_ragged,
+    "ragged": _run_a2a,
     "a2a": _run_a2a,
     "a2a_fused": _run_a2a_fused,
 }

@@ -24,6 +24,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from automodel_tpu.ops.platform_check import (
+    is_tpu_platform,
+    kernel_axes,
+    kernel_shard_map,
+    sharded_axes,
+)
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -176,26 +184,6 @@ def _autotune_entry(head_dim: int, window: Optional[int], causal: bool):
     return out
 
 
-_SPLASH_SINKS_SUPPORTED: Optional[bool] = None
-
-
-def _splash_supports_sinks() -> bool:
-    """Whether this jax build's splash kernel takes a ``sinks`` argument
-    (one signature inspection, cached)."""
-    global _SPLASH_SINKS_SUPPORTED
-    if _SPLASH_SINKS_SUPPORTED is None:
-        import inspect
-
-        from jax.experimental.pallas.ops.tpu.splash_attention import (
-            splash_attention_kernel as sak,
-        )
-
-        _SPLASH_SINKS_SUPPORTED = "sinks" in inspect.signature(
-            sak._splash_attention
-        ).parameters
-    return _SPLASH_SINKS_SUPPORTED
-
-
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -268,18 +256,8 @@ def _splash_flash(
         if segment_ids is not None
         else None
     )
-    # older jax builds ship a splash kernel without the `sinks` parameter
-    # (_splash_attention has no such arg): passing it positionally breaks
-    # EVERY splash call, sinks or not. Omit the argument when it is None so
-    # sink-less models keep the fused kernel on those builds; an actual
-    # sinks tensor on such a build still fails loudly below (the capability
-    # is genuinely missing — silently dropping the sinks would mis-compute).
-    call = (qt, kt, vt, seg)
-    axes: tuple = (0, 0, 0, 0 if seg is not None else None)
-    if sinks is not None or _splash_supports_sinks():
-        call += (sinks,)
-        axes += (None,)
-    out = jax.vmap(kernel, in_axes=axes)(*call)
+    axes = (0, 0, 0, 0 if seg is not None else None, None)
+    out = jax.vmap(kernel, in_axes=axes)(qt, kt, vt, seg, sinks)
     out = out.transpose(0, 2, 1, 3).astype(q.dtype)
     return out[:, :S] if pad else out
 
@@ -312,12 +290,18 @@ def flash(
     block_q: int = 512,
     block_kv: int = 512,
     platform: Optional[str] = None,
+    mesh_ctx=None,
 ) -> jnp.ndarray:
     """Pallas TPU flash (splash) attention: causal/sliding-window/soft-cap/
     segments/sinks all stay on the fused kernel; sequences are padded to 128
     internally. Falls back to sdpa ONLY off-TPU or for ANY non-causal
     attention (splash's LocalMask enforces causality, so even non-causal
-    windowed must not route there), and logs loudly when it does."""
+    windowed must not route there), and logs loudly when it does.
+
+    ``mesh_ctx`` (parallel.mesh.MeshContext, from BackendConfig.mesh_ctx):
+    on a mesh of several devices the kernel runs inside a ``shard_map`` —
+    batch over the data axes, heads and KV heads over ``tp`` — because
+    GSPMD cannot partition a Mosaic call."""
     h = q.shape[-1]
     if q.shape[1] == 1:
         # single-query decode: the splash MXU tiling pads the query to a
@@ -366,22 +350,69 @@ def flash(
         # static defaults rather than the other kernel's measured blocks
         block_q = entry.get("block_q", block_q)
         block_kv = entry.get("block_kv", block_kv)
-    if take_block_path:
-        from automodel_tpu.ops import ring_flash
+    interpret = _interpret_requested()
 
-        return ring_flash.flash_attention(
-            q, k, v,
-            causal=causal, scale=scale, segment_ids=segment_ids,
-            sliding_window=sliding_window, sinks=sinks,
-            block_q=block_q, block_kv=block_kv,
-            interpret=_interpret_requested(),
+    def kernel(q, k, v, segment_ids, sinks):
+        if take_block_path:
+            from automodel_tpu.ops import ring_flash
+
+            return ring_flash.flash_attention(
+                q, k, v,
+                causal=causal, scale=scale, segment_ids=segment_ids,
+                sliding_window=sliding_window, sinks=sinks,
+                block_q=block_q, block_kv=block_kv, interpret=interpret,
+            )
+        return _splash_flash(
+            q, k, v, segment_ids, sinks,
+            causal=causal, scale=scale, logits_soft_cap=logits_soft_cap,
+            sliding_window=sliding_window, block_q=block_q, block_kv=block_kv,
+            interpret=interpret,
         )
-    return _splash_flash(
-        q, k, v, segment_ids, sinks,
-        causal=causal, scale=scale, logits_soft_cap=logits_soft_cap,
-        sliding_window=sliding_window, block_q=block_q, block_kv=block_kv,
-        interpret=_interpret_requested(),
+
+    if kernel_axes(mesh_ctx) is None:
+        return kernel(q, k, v, segment_ids, sinks)
+    return _flash_shard_map(kernel, mesh_ctx, q, k, v, segment_ids, sinks)
+
+
+def flash_head_axes(mesh_ctx, num_heads: int, num_kv_heads: int):
+    """The mesh axes the flash kernel's heads shard over, or a refusal of a
+    mesh it cannot run on — asked when the model is built
+    (auto_model._check_kernel_mesh) and again when the call is traced."""
+    if mesh_ctx.cp_size > 1:
+        raise ValueError(
+            f"attn: flash on a mesh with cp={mesh_ctx.cp_size}: the flash "
+            "kernel attends a whole sequence per device — use attn: ring "
+            "for context parallelism"
+        )
+    return sharded_axes(
+        mesh_ctx, "tensor", (num_heads, num_kv_heads),
+        "attn: flash — num_attention_heads / num_key_value_heads",
     )
+
+
+def _flash_shard_map(kernel, mesh_ctx, q, k, v, segment_ids, sinks):
+    """Run ``kernel`` per device block: batch over the data axes, heads and
+    KV heads over ``tp`` (GQA groups stay whole because both divide), the
+    sequence whole — the layout the projections already produce, so no data
+    moves at the region's edge."""
+    batch = sharded_axes(mesh_ctx, "batch", (q.shape[0],), "attention batch")
+    heads = flash_head_axes(mesh_ctx, q.shape[2], k.shape[2])
+    qkv = P(batch, None, heads, None)
+    args, specs = [q, k, v], [qkv, qkv, qkv]
+    if segment_ids is not None:
+        args.append(segment_ids)
+        specs.append(P(batch, None))
+    if sinks is not None:
+        args.append(sinks)
+        specs.append(P(heads))  # per-head logits follow the head shard
+
+    def block(q, k, v, *rest):
+        rest = list(rest)
+        seg = rest.pop(0) if segment_ids is not None else None
+        snk = rest.pop(0) if sinks is not None else None
+        return kernel(q, k, v, seg, snk)
+
+    return kernel_shard_map(mesh_ctx, block, tuple(specs), qkv)(*args)
 
 
 def _ring_not_installed(*args, **kwargs):
@@ -405,6 +436,7 @@ def attention(
     v: jnp.ndarray,
     backend: str = "sdpa",
     platform: Optional[str] = None,
+    mesh_ctx=None,
     **kwargs,
 ) -> jnp.ndarray:
     try:
@@ -415,6 +447,7 @@ def attention(
         )
     if backend == "flash":
         kwargs["platform"] = platform
+        kwargs["mesh_ctx"] = mesh_ctx
     return fn(q, k, v, **kwargs)
 
 
@@ -436,6 +469,7 @@ def windowed_attention(
     block_q: int = 512,
     block_kv: int = 512,
     platform: Optional[str] = None,
+    mesh_ctx=None,
 ) -> jnp.ndarray:
     """Attention for scanned layer stacks that mix full and sliding-window
     layers (Gemma-2/3, GPT-OSS). The per-layer layer type rides the scan as
@@ -463,6 +497,7 @@ def windowed_attention(
             causal=causal, scale=scale, segment_ids=segment_ids,
             logits_soft_cap=logits_soft_cap, sinks=sinks,
             block_q=block_q, block_kv=block_kv, platform=platform,
+            mesh_ctx=mesh_ctx,
         )
         if not isinstance(is_sliding, jax.core.Tracer):
             # static flag (unrolled layer loop): compile exactly one kernel
@@ -478,6 +513,7 @@ def windowed_attention(
             causal=causal, scale=scale, segment_ids=segment_ids,
             logits_soft_cap=logits_soft_cap, sinks=sinks,
             block_q=block_q, block_kv=block_kv, platform=platform,
+            mesh_ctx=mesh_ctx,
         )
     if backend == "ring":
         return ATTENTION_BACKENDS["ring"](
@@ -505,6 +541,4 @@ def _interpret_requested() -> bool:
 
 
 def _flash_eligible(platform: Optional[str] = None) -> bool:
-    from automodel_tpu.ops.platform_check import is_tpu_platform
-
     return _interpret_requested() or is_tpu_platform(platform)

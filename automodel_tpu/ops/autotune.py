@@ -103,14 +103,13 @@ def _dt(dtype: Any) -> str:
 
 def chip_key() -> str:
     """``jax.Device.device_kind`` of the first device ("TPU v5 lite", "cpu",
-    ...); "unknown" when the backend cannot initialize. Matching against the
-    table is exact-then-prefix, same scheme as utils.flops_utils."""
-    try:
-        import jax
+    ...). A backend that cannot start raises here as it would one line
+    later in the kernel: there is no device to pick tiles for. Matching
+    against the table is exact-then-prefix, same scheme as
+    utils.flops_utils."""
+    import jax
 
-        return getattr(jax.devices()[0], "device_kind", "") or "unknown"
-    except Exception:
-        return "unknown"
+    return jax.devices()[0].device_kind
 
 
 def _match_chip(chips: dict, chip: str) -> Optional[dict]:

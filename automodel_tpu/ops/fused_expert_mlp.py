@@ -1,7 +1,7 @@
 """Fused MoE expert MLP: gate_up matmul + gated activation + down matmul in
 ONE Pallas kernel, with a purpose-tiled Pallas manual backward.
 
-Forward motivation (PROFILE_MOE_r04.md): the two-kernel expert path writes
+Forward motivation (a round-4 profile of the MoE step): the two-kernel expert path writes
 the [T·K, 2I] gate_up output and the [T·K, I] activation to HBM and reads
 them back (~600MB per layer at bench shape). Here both stay in VMEM: per
 work unit (m-tile × group) the kernel loops I-chunks on the grid, computing
@@ -42,10 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from automodel_tpu.utils.compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
 
 from automodel_tpu.ops.grouped_matmul import (
     _interpret_requested,
@@ -228,10 +224,11 @@ def _fwd(lhs, gate, up, down, group_sizes, gb, ub, db, act_kind, limit,
             scratch_shapes=[pltpu.VMEM((tm, Dp), jnp.float32)],
         ),
         out_shape=out_sds,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="fused_expert_mlp_fwd",
     )(wg, wt, ws, we, *operands)
     return out[:M, :D]
 
@@ -512,10 +509,11 @@ def _bwd_gu(lhs, g, u, dmid, group_sizes, act_kind, limit, interpret,
             out_specs=out_specs,
         ),
         out_shape=out_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="fused_expert_mlp_bwd_gu",
     )(wg, wt, ws, we, lhs, g, u, dmid)
     nz = (group_sizes > 0)
     dwg = jnp.where(nz[:, None, None], outs[0][:, :D, :I], 0.0)
@@ -601,10 +599,11 @@ def _bwd_dwd(g, u, dy, group_sizes, act_kind, limit, interpret, want_db):
             out_specs=out_specs,
         ),
         out_shape=out_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="fused_expert_mlp_bwd_dwd",
     )(wg, wt, ws, we, g, u, dy)
     nz = (group_sizes > 0)
     dwd = jnp.where(nz[:, None, None], outs[0][:, :I, :D], 0.0)
@@ -684,10 +683,11 @@ def _bwd_dx(g, u, dmid, gate, up, group_sizes, interpret, act_kind, limit):
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         ),
         out_shape=_out_sds((Mp, Np), g.dtype, g, u, dmid, gate, up),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="fused_expert_mlp_bwd_dx",
     )(wg, wt, ws, we, g, u, dmid, gate, up)
     return out[:M, :D]
 

@@ -35,10 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from automodel_tpu.utils.compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
-
 
 def _interpret_requested() -> bool:
     return os.environ.get("AUTOMODEL_GMM_INTERPRET", "0") == "1"
@@ -58,9 +54,7 @@ def _out_sds(shape, dtype, *operands):
     """ShapeDtypeStruct carrying the union of the operands' vma — inside a
     check_vma shard_map region (the a2a/a2a_fused EP paths) a pallas_call
     must state how its output varies over the manual axes."""
-    from automodel_tpu.utils.compat import vma_of
-
-    vmas = [vma_of(o) for o in operands]
+    vmas = [jax.typeof(o).vma for o in operands]
     if any(vmas):
         vma = frozenset().union(*[v for v in vmas if v])
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
@@ -219,10 +213,11 @@ def _gmm(lhs: jnp.ndarray, rhs: jnp.ndarray, group_sizes: jnp.ndarray,
             out_specs=pl.BlockSpec((tm, tn), lambda n, w, wg, wt, ws, we: (wt[w], n)),
         ),
         out_shape=_out_sds((Mp, Np), out_dtype, lhs, rhs),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="gmm",
     )(wg, wt, ws, we, lhs, rhs)
     return out[:M, :N]
 
@@ -275,10 +270,11 @@ def _tgmm(lhs: jnp.ndarray, dout: jnp.ndarray, group_sizes: jnp.ndarray,
             ),
         ),
         out_shape=_out_sds((G, Kp, Np), jnp.float32, lhs, dout),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="tgmm",
     )(wg, wt, ws, we, lhs, dout)
     # empty groups are never visited → force their slabs to zero
     out = jnp.where((group_sizes > 0)[:, None, None], out[:, :K, :N], 0.0)
@@ -306,11 +302,9 @@ def _match_vma(ct, primal):
     primal does not vary over means the primal was (conceptually) broadcast
     there — whose AD transpose is the psum this inserts (the replicated-
     weight gradient reduction shard_map's own transpose would have done)."""
-    from automodel_tpu.utils.compat import vma_of
-
-    want = vma_of(primal)
-    have = vma_of(ct)
-    if want is not None and have is not None and have - want:
+    want = jax.typeof(primal).vma
+    have = jax.typeof(ct).vma
+    if have - want:
         ct = jax.lax.psum(ct, tuple(sorted(have - want)))
     return ct
 

@@ -152,7 +152,7 @@ def vocab_parallel_cross_entropy(
     hidden [..., D] (replicated over tensor), lm_head_kernel [D, V] sharded
     on V, labels [...]. Returns (loss_sum fp32, n_valid) replicated.
     """
-    from automodel_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = mesh_ctx.mesh

@@ -45,6 +45,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from automodel_tpu.ops.platform_check import (
+    kernel_axes,
+    kernel_shard_map,
+    sharded_axes,
+)
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 INT8_MAX = 127.0
@@ -166,13 +173,52 @@ def _paged_kernel(
         o_ref[0] = jnp.where(l > 0, acc_scr[...] / safe, 0.0).astype(o_ref.dtype)
 
 
+def paged_attend(
+    q: jnp.ndarray,
+    k_pool: jnp.ndarray,
+    v_pool: jnp.ndarray,
+    tables: jnp.ndarray,
+    lengths: jnp.ndarray,
+    k_scale: Optional[jnp.ndarray] = None,
+    v_scale: Optional[jnp.ndarray] = None,
+    *,
+    mesh_ctx=None,
+    **kw,
+) -> jnp.ndarray:
+    """``_paged_attend`` on whatever mesh the pool lives on. On a mesh of
+    several devices the kernel runs inside a ``shard_map`` over the axes
+    the pool is sharded on — KV heads over ``tp`` (serving/paged.place_pool)
+    and nothing else, since every sequence's table may point anywhere in
+    the pool: each TP shard attends its own heads over its own pool slice
+    and no cache collective runs. The data axes hold pool replicas, so the
+    slots are computed whole on each."""
+    if kernel_axes(mesh_ctx) is None:
+        return _paged_attend(
+            q, k_pool, v_pool, tables, lengths, k_scale, v_scale, **kw
+        )
+    heads = sharded_axes(
+        mesh_ctx, "tensor", (q.shape[2], k_pool.shape[2]),
+        "paged-attention heads / KV heads",
+    )
+    qo = P(None, None, heads, None)
+    pool = P(None, None, heads, None)
+    args = [q, k_pool, v_pool, tables, lengths]
+    specs = [qo, pool, pool, P(), P()]
+    if k_scale is not None:
+        args += [k_scale, v_scale]
+        specs += [P(None, None, heads)] * 2
+    return kernel_shard_map(
+        mesh_ctx, functools.partial(_paged_attend, **kw), tuple(specs), qo
+    )(*args)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
         "scale", "sliding_window", "logits_soft_cap", "interpret",
     ),
 )
-def paged_attend(
+def _paged_attend(
     q: jnp.ndarray,
     k_pool: jnp.ndarray,
     v_pool: jnp.ndarray,
@@ -261,16 +307,15 @@ def paged_attend(
             pltpu.VMEM((Nkv * SR, H), jnp.float32),
         ],
     )
-    from automodel_tpu.utils.compat import pallas_tpu_compiler_params
-
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Nkv * SR, H), q.dtype),
-        compiler_params=pallas_tpu_compiler_params()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_attention",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *args)
     return (
         out.reshape(B, Nkv, Sq, rep, H).transpose(0, 2, 1, 3, 4).reshape(B, Sq, N, H)

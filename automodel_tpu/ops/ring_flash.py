@@ -29,10 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from automodel_tpu.utils.compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
-
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
@@ -244,10 +240,11 @@ def flash_block_fwd(q, k, v, q_pos, kv_pos, seg_q, seg_kv, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, H), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="block_flash_fwd",
     )(qp, kp, sq, sk, qf, kf, vf)
     return (
         out.reshape(B, N, Sq, H).transpose(0, 2, 1, 3),
@@ -280,10 +277,11 @@ def flash_block_bwd(q, k, v, do, lse, delta, q_pos, kv_pos, seg_q, seg_kv, *,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((B * N, Sq, H), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, H), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="block_flash_dq",
     )(*args)
 
     # dkv: kv tile outer, q tiles inner (accumulate over queries)
@@ -306,10 +304,11 @@ def flash_block_bwd(q, k, v, do, lse, delta, q_pos, kv_pos, seg_q, seg_kv, *,
             pltpu.VMEM((bkv, H), jnp.float32),
             pltpu.VMEM((bkv, H), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="block_flash_dkv",
     )(*args)
     # GQA: per-q-head dk/dv reduce onto their kv head
     dk = dk.reshape(B, Nkv, rep, Sk, H).sum(axis=2).transpose(0, 2, 1, 3)

@@ -163,3 +163,48 @@ def build_optimizer(
         parts.append(optax.add_decayed_weights(weight_decay))
     parts.append(optax.scale_by_learning_rate(schedule))
     return optax.chain(*parts)
+
+
+def opt_state_shardings(
+    optimizer: optax.GradientTransformation, params: Any, mesh_ctx: Any
+) -> Any:
+    """Where ``optimizer.init(params)`` belongs on the mesh: every state leaf
+    that mirrors a param (Adam's mu/nu: same path suffix, same shape) on
+    that param's shards; everything else (step counts, factored statistics)
+    replicated. ``params`` may be arrays or sharded ShapeDtypeStructs."""
+    from automodel_tpu.parallel.plans import path_str
+
+    by_path = {
+        path_str(path): leaf
+        for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]
+    }
+
+    def sharding(path, leaf):
+        parts = path_str(path).split("/")
+        for i in range(len(parts)):
+            p = by_path.get("/".join(parts[i:]))
+            if p is not None and p.shape == leaf.shape:
+                return p.sharding
+        return mesh_ctx.replicated()
+
+    return jax.tree_util.tree_map_with_path(
+        sharding, jax.eval_shape(optimizer.init, params)
+    )
+
+
+def init_opt_state(
+    optimizer: optax.GradientTransformation, params: Any, mesh_ctx: Any = None
+) -> Any:
+    """``optimizer.init(params)`` created on the shards
+    ``opt_state_shardings`` names. A bare ``jax.jit(optimizer.init)(params)``
+    does not do this: the moments are ``zeros_like`` — no data dependence on
+    the params — so XLA materializes all of them unsharded on device 0 (2x
+    the model in fp32 on one chip of the host), the first train step moves
+    them, and its outputs come back laid out differently, so the step
+    compiles twice."""
+    if mesh_ctx is None:
+        return jax.jit(optimizer.init)(params)
+    return jax.jit(
+        optimizer.init,
+        out_shardings=opt_state_shardings(optimizer, params, mesh_ctx),
+    )(params)

@@ -37,7 +37,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from automodel_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from automodel_tpu.ops.attention import repeat_kv

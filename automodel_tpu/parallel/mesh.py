@@ -108,6 +108,18 @@ class MeshConfig:
     pp_schedule: str = "gpipe"
     pp_zb_queue: Optional[int] = None
 
+    @classmethod
+    def from_section(cls, dist: Any) -> "MeshConfig":
+        """The ``distributed:`` YAML section (a ConfigNode or dict; absent
+        keys take the defaults above) — the one reading the train recipes,
+        the generate/serve CLIs and tools/compile_check.py share."""
+        dist = dist or {}
+        return cls(**{
+            f.name: dist.get(f.name)
+            for f in dataclasses.fields(cls)
+            if f.name != "dcn" and dist.get(f.name) is not None
+        })
+
     def validate(self, world_size: int) -> "MeshConfig":
         cfg = dataclasses.replace(self)
         known = cfg.dp_replicate * cfg.tp * cfg.cp * cfg.pp
@@ -324,13 +336,12 @@ def build_mesh(
     else:
         try:
             dev_array = jmu.create_device_mesh(shape, devices=devices)
-        except (ValueError, NotImplementedError, AssertionError) as e:
-            # CPU/host platforms without torus assignment. On real TPU this
-            # fallback loses topology-aware placement — make it loud.
-            logger.warning(
-                "create_device_mesh failed (%s); falling back to flat device "
-                "order. On TPU hardware this loses ICI-aware placement.", e
-            )
+        except (ValueError, NotImplementedError, AssertionError):
+            if devices[0].platform == "tpu":
+                # flat device order on a torus would put tp/cp neighbours
+                # on far chips: a mesh the topology cannot hold is an error
+                raise
+            # host platforms have no torus to assign
             dev_array = np.array(devices).reshape(shape)
     mesh = Mesh(dev_array.reshape(shape), MeshAxisName.ALL)
     logger.info("Built mesh %s", dict(mesh.shape))

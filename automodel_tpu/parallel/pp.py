@@ -61,7 +61,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from automodel_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from automodel_tpu.parallel.mesh import MeshContext

@@ -54,7 +54,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from automodel_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 # -- B/W split matmul ---------------------------------------------------------

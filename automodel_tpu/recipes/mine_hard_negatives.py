@@ -66,12 +66,7 @@ class MineHardNegativesRecipe:
 
     def setup(self) -> None:
         cfg = self.cfg
-        dist = cfg.get("distributed", ConfigNode())
-        degrees = {
-            k: dist.get(k, -1 if k == "dp_shard" else 1)
-            for k in ("dp_replicate", "dp_shard", "tp", "cp", "pp", "ep")
-        }
-        self.mesh_ctx = build_mesh(MeshConfig(**degrees))
+        self.mesh_ctx = build_mesh(MeshConfig.from_section(cfg.get("distributed")))
 
         mcfg = cfg.model
         backend = dict(mcfg.get("backend", {}) or {})

@@ -39,7 +39,7 @@ from automodel_tpu.data.prefetch import (
 )
 from automodel_tpu.loggers.log_utils import setup_logging
 from automodel_tpu.loggers.metric_logger import MetricLogger
-from automodel_tpu.optim.builders import build_optimizer
+from automodel_tpu.optim.builders import build_optimizer, init_opt_state
 from automodel_tpu.optim.scheduler import build_lr_schedule
 from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
 from automodel_tpu.resilience import NonFiniteError, Resilience, TrainingPreempted
@@ -80,20 +80,12 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         self.rng = StatefulRNG(seed=cfg.get("seed", 42))
 
         dist = cfg.get("distributed", ConfigNode())
-        mesh_degrees = {
-            k: dist.get(k, -1 if k == "dp_shard" else 1)
-            for k in ("dp_replicate", "dp_shard", "tp", "cp", "pp", "ep")
-        }
-        # pipeline schedule knobs ride MeshConfig (distributed.pp_schedule:
-        # gpipe|zero_bubble, distributed.pp_zb_queue: int|null)
-        mesh_degrees["pp_schedule"] = dist.get("pp_schedule", "gpipe")
-        mesh_degrees["pp_zb_queue"] = dist.get("pp_zb_queue", None)
         # distributed.platform pins the device platform — e.g. `cpu` to run
         # SPMD recipes on virtual host devices (the reference's gloo-backend
         # CPU test path, init_utils.py:136-140)
         platform = dist.get("platform", None)
         devices = jax.devices(platform) if platform else None
-        self.mesh_ctx = build_mesh(MeshConfig(**mesh_degrees), devices=devices)
+        self.mesh_ctx = build_mesh(MeshConfig.from_section(dist), devices=devices)
         logger.info("mesh: %s", dict(self.mesh_ctx.mesh.shape))
 
         # model
@@ -146,7 +138,7 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         sched_cfg = dict(ocfg.get("lr_schedule") or {})
         self.lr_schedule = build_lr_schedule(lr=ocfg.get("lr", 1e-4), **sched_cfg)
         self.optimizer = self._wrap_optimizer(build_optimizer(**ocfg), trainable)
-        opt_state = jax.jit(self.optimizer.init)(trainable)
+        opt_state = init_opt_state(self.optimizer, trainable, self.mesh_ctx)
         self.state = TrainState.create(trainable, opt_state)
 
         # loss + steps; a family may declare its own default loss (reference
@@ -373,7 +365,16 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         # this facade adds the step-time split, compile-event stamps, the
         # periodic memory census, and the crash flight recorder. On by
         # default — no `telemetry:` section required.
-        from automodel_tpu.telemetry import Telemetry, build_fingerprint
+        from automodel_tpu.telemetry import (
+            Telemetry,
+            build_fingerprint,
+            device_report,
+        )
+
+        # first record of the JSONL, once per run
+        report = device_report(self.mesh_ctx, getattr(self.model, "backend", None))
+        logger.info("device report: %s", report)
+        self.metric_logger.log(report)
 
         fingerprint = build_fingerprint(cfg.to_dict(), self.mesh_ctx)
         if self.ledger.enabled:
@@ -650,6 +651,10 @@ class TrainFinetuneRecipeForNextTokenPrediction:
             **{k: v for k, v in self._step_cost.items() if v is not None},
         }
         self.telemetry.record_step({**rec, "ts": time.time()})
+        logger.info(
+            "train_step traced: pallas_kernels=%s mosaic_calls=%s",
+            cost.pallas_kernels, cost.mosaic_calls,
+        )
         try:
             self.metric_logger.log(rec)
         except Exception:

@@ -21,6 +21,7 @@ from automodel_tpu.models.llama.seq_cls import (
     LlamaForSequenceClassification,
     make_seq_cls_loss,
 )
+from automodel_tpu.optim.builders import init_opt_state
 from automodel_tpu.recipes.train_ft import TrainFinetuneRecipeForNextTokenPrediction
 from automodel_tpu.training.train_state import TrainState
 from automodel_tpu.training.train_step import build_eval_step
@@ -52,7 +53,7 @@ class TrainSeqClsRecipe(TrainFinetuneRecipeForNextTokenPrediction):
 
         params = shard_params(self.mesh_ctx, params, model.sharding_rules)
         self.model = model
-        opt_state = jax.jit(self.optimizer.init)(params)
+        opt_state = init_opt_state(self.optimizer, params, self.mesh_ctx)
         self.state = TrainState.create(params, opt_state)
         self.loss_fn = make_seq_cls_loss(model)
         self.train_step = self._make_train_step(self.loss_fn)

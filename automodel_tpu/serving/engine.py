@@ -688,6 +688,19 @@ class ServingEngine:
 
         self._interpret = _interpret_requested()
         self.decode_backend = self._resolve_decode_backend()
+        if self.decode_backend == "fused" and auto.mesh_ctx is not None:
+            # the paged kernel runs per TP shard on whole heads
+            # (ops/paged_attention.paged_attend); refuse here, not at the
+            # first decode step — where step() would catch the error, fail
+            # the wave, rebuild, and the front would still exit 0
+            from automodel_tpu.ops.platform_check import sharded_axes
+
+            sharded_axes(
+                auto.mesh_ctx, "tensor",
+                (int(mcfg.num_heads), int(mcfg.num_kv_heads)),
+                "serving.decode_kernel: fused (or serve with decode_kernel: "
+                "gather) — num_attention_heads / num_key_value_heads",
+            )
         spec = self.config.speculative
         self._spec_enabled = bool(spec.enabled)
         sp = self.config.kv_spill
@@ -2564,6 +2577,15 @@ class ServingEngine:
         from automodel_tpu.telemetry.profiling import record_program_cost
 
         record_program_cost(self.program_costs, name, jit_fn, *args)
+        cost = self.program_costs[name]
+        logger.info(
+            "%s traced: pallas_kernels=%s mosaic_calls=%s",
+            name, cost.get("pallas_kernels"), cost.get("mosaic_calls"),
+        )
+        if self.on_record is not None:
+            self.on_record(
+                {"event": "cost_attribution", "program": name, **cost}
+            )
 
     # -- workload driver (bench leg + sustained-throughput tests) -------------
     def run_workload(

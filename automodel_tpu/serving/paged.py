@@ -114,30 +114,29 @@ def init_pool(
     return PagedKV(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
-def place_pool(pool: PagedKV, mesh_ctx) -> PagedKV:
-    """Shard the pool: KV heads over the tensor axes (each TP shard owns its
-    heads' blocks — the same no-cache-collective decode layout as
+def pool_shardings(mesh_ctx, num_kv_heads: int, quantized: bool) -> PagedKV:
+    """Where the pool lives: KV heads over the tensor axes (each TP shard
+    owns its heads' blocks — the same no-cache-collective decode layout as
     generation.kv_cache.place_cache); blocks are NOT batch-sharded (every
-    sequence's table may point anywhere in the pool). Non-divisible axes are
-    dropped (replicated). Int8 scales shard on the same kv-head axis."""
-    if mesh_ctx is None:
-        return pool
+    sequence's table may point anywhere in the pool). Non-divisible axes
+    are dropped (replicated). Int8 scales shard on the same kv-head axis.
+    → a PagedKV of NamedShardings, leaf for leaf the pool's shape."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    nkv = pool.values_shape[3]
-    names = kv_cache.usable_axes(mesh_ctx, nkv, "tensor")
+    names = kv_cache.usable_axes(mesh_ctx, num_kv_heads, "tensor")
     val_s = NamedSharding(mesh_ctx.mesh, P(None, None, None, names, None))
     scale_s = NamedSharding(mesh_ctx.mesh, P(None, None, None, names))
+    side = (val_s, scale_s) if quantized else val_s
+    return PagedKV(k=side, v=side)
 
-    def place_side(side):
-        if isinstance(side, tuple):
-            return (
-                jax.device_put(side[0], val_s),
-                jax.device_put(side[1], scale_s),
-            )
-        return jax.device_put(side, val_s)
 
-    return PagedKV(k=place_side(pool.k), v=place_side(pool.v))
+def place_pool(pool: PagedKV, mesh_ctx) -> PagedKV:
+    """Shard the pool onto the mesh as ``pool_shardings`` says."""
+    if mesh_ctx is None:
+        return pool
+    return jax.device_put(
+        pool, pool_shardings(mesh_ctx, pool.values_shape[3], pool.quantized)
+    )
 
 
 # -- gather / scatter (the XLA fallback path + chunk prefill) ----------------

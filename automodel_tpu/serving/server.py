@@ -1041,6 +1041,21 @@ def main(cfg: Any) -> int:
     engine.boot_t = t_boot
     engine.boot_source = boot_source
 
+    # once per boot: the device report; each program's `cost_attribution`
+    # record follows the first time it runs (profiling section, on by
+    # default like the training recipe's)
+    from automodel_tpu.telemetry import device_report
+    from automodel_tpu.telemetry.profiling import ProfilingConfig
+
+    prof = ProfilingConfig.from_dict(dict(cfg.get("profiling") or {}))
+    engine.collect_program_costs = prof.enabled and prof.cost_attribution
+    report = device_report(
+        auto.mesh_ctx, auto.model.backend, decode_backend=engine.decode_backend
+    )
+    logger.info("device report: %s", report)
+    if on_record is not None:
+        on_record(report)
+
     # fleet KV listener: a decode-role replica listens for prefill→decode
     # handoffs, and a spill-enabled replica listens for peer /kv_fetch
     # (serving.kv_transfer.enabled: null = auto-on for either role); the

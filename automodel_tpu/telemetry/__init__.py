@@ -42,7 +42,12 @@ from automodel_tpu.telemetry.anomaly import (  # noqa: F401  (re-export)
     nonfinite_count,
 )
 from automodel_tpu.telemetry.compile_events import CompileEventBridge
-from automodel_tpu.telemetry.flight_recorder import FlightRecorder, build_fingerprint
+from automodel_tpu.telemetry.flight_recorder import (
+    FlightRecorder,
+    build_fingerprint,
+    device_info,
+    device_report,
+)
 from automodel_tpu.training.timers import Timers
 from automodel_tpu.utils.profiler import ProfilerConfig, StepProfiler
 
@@ -250,6 +255,8 @@ __all__ = [
     "CompileEventBridge",
     "FlightRecorder",
     "build_fingerprint",
+    "device_info",
+    "device_report",
     "memory_snapshot",
     "anomaly_metrics",
     "group_grad_norms",

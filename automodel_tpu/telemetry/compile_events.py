@@ -27,8 +27,13 @@ _EVENT_SUFFIXES = (
     "jaxpr_to_mlir_module_duration",
 )
 
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+
 _lock = threading.Lock()
-_totals = {"count": 0, "secs": 0.0}
+_totals = {"count": 0, "secs": 0.0, "cache_hits": 0, "cache_misses": 0}
 _registered = False
 
 
@@ -43,6 +48,13 @@ def _listener(event: str, duration_secs: float, **kwargs) -> None:
         _totals["secs"] += float(duration_secs)
 
 
+def _cache_listener(event: str, **kwargs) -> None:
+    key = _CACHE_EVENTS.get(event)
+    if key is not None:
+        with _lock:
+            _totals[key] += 1
+
+
 def _ensure_registered() -> None:
     global _registered
     with _lock:
@@ -51,7 +63,24 @@ def _ensure_registered() -> None:
         import jax.monitoring
 
         jax.monitoring.register_event_duration_secs_listener(_listener)
+        jax.monitoring.register_event_listener(_cache_listener)
         _registered = True
+
+
+def compile_totals() -> dict[str, float] | None:
+    """Process totals since the listeners were registered — compiles, the
+    seconds they took (a persistent-cache hit costs its retrieval only) and
+    how many were hits or misses of that cache. None when nothing in this
+    process registered them (no device command ran)."""
+    with _lock:
+        if not _registered:
+            return None
+        return {
+            "compiles": _totals["count"],
+            "compile_secs": round(_totals["secs"], 3),
+            "cache_hits": _totals["cache_hits"],
+            "cache_misses": _totals["cache_misses"],
+        }
 
 
 class CompileEventBridge:

@@ -7,7 +7,7 @@ CPU backend) and `jax.live_arrays()` (every array the client still holds a
 reference to). Grouping live arrays by (dtype, shape) gives a top-K census
 that names *what* filled the chip — stacked expert grads vs optimizer
 moments vs activations read very differently — which is exactly the
-information the all-zero BENCH_r05 legs were missing.
+information a leg that dies of RESOURCE_EXHAUSTED otherwise lacks.
 
 Everything here is host-side and allocation-free on device; callers control
 the cadence (TelemetryConfig.memory_every_steps) and the forced dump on

@@ -47,10 +47,15 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
-from automodel_tpu.utils.flops_utils import TPU_PEAK_BF16_TFLOPS, device_peak_tflops
+from automodel_tpu.utils.compile_only import mosaic_calls
+from automodel_tpu.utils.flops_utils import (
+    TPU_PEAK_BF16_TFLOPS,
+    device_peak_tflops,
+    device_table_lookup,
+)
 
 # HBM bandwidth per chip, GB/s (public TPU spec sheets; same key scheme as
-# the peak-FLOPs table). Unknown kinds → NaN, never a silent wrong basis.
+# the peak-FLOPs table, and the same rule: an unknown TPU is an error).
 TPU_HBM_GBPS: dict[str, float] = {
     "TPU v4": 1228.0,
     "TPU v5": 2765.0,  # v5p
@@ -62,27 +67,18 @@ TPU_HBM_GBPS: dict[str, float] = {
     "TPU7x": 7370.0,  # ironwood
 }
 
-# explicit-collective primitive names; matched with trailing digits
-# stripped (jax renames across versions: psum → psum2)
+# explicit-collective primitives as this jax names them. Under a
+# vma-checked shard_map (the default) ``lax.psum`` traces to
+# ``psum_invariant`` and ``all_gather`` to ``all_gather_invariant``.
 _COLLECTIVES = {
-    "psum", "all_gather", "all_to_all", "ppermute", "psum_scatter",
-    "reduce_scatter", "pmax", "pmin", "pbroadcast",
+    "psum", "psum_invariant", "all_gather", "all_gather_invariant",
+    "all_to_all", "ragged_all_to_all", "ppermute", "reduce_scatter",
+    "pmax", "pmin",
 }
 
 
-def _is_collective(name: str) -> bool:
-    return name.rstrip("0123456789") in _COLLECTIVES
-
-
 def device_hbm_gbps(device: Optional[jax.Device] = None) -> float:
-    d = device or jax.devices()[0]
-    kind = getattr(d, "device_kind", "")
-    if kind in TPU_HBM_GBPS:
-        return TPU_HBM_GBPS[kind]
-    for k, v in TPU_HBM_GBPS.items():
-        if kind.lower().startswith(k.lower()):
-            return v
-    return float("nan")
+    return device_table_lookup(TPU_HBM_GBPS, "HBM GB/s", device)
 
 
 @dataclasses.dataclass
@@ -100,9 +96,16 @@ class ProgramCost:
     dot_ops: int = 0
     eqns: int = 0
     while_loops: int = 0  # bodies counted once (per-iteration cost)
+    # Pallas call sites by kernel name (a site inside a scan counts once):
+    # which kernel families the program really traced, whatever the
+    # backend config asked for
+    pallas_kernels: dict = dataclasses.field(default_factory=dict)
     # XLA's own numbers (Lowered.cost_analysis; scan/while bodies once)
     hlo_flops: Optional[float] = None
     hlo_bytes: Optional[float] = None
+    # Mosaic custom calls in the lowered module: the Pallas sites that
+    # lowered FOR THE TPU (0 for interpreted kernels and XLA fallbacks)
+    mosaic_calls: Optional[int] = None
 
     def to_dict(self) -> dict:
         d = dataclasses.asdict(self)
@@ -181,12 +184,15 @@ def _walk(jaxpr, cost: ProgramCost, mult: float) -> None:
             cost.conv_flops += f
             cost.flops += f
             cost.bytes_est += sum(map(_aval_bytes, (*eqn.invars, *eqn.outvars))) * mult
-        elif _is_collective(name):
+        elif name in _COLLECTIVES:
             b = sum(map(_aval_bytes, eqn.outvars)) * mult
             cost.collective_bytes += b
             cost.bytes_est += b
             cost.collective_ops += 1
         else:
+            if name == "pallas_call":
+                kernel = eqn.params["name"]
+                cost.pallas_kernels[kernel] = cost.pallas_kernels.get(kernel, 0) + 1
             subs = list(_sub_jaxprs(eqn.params))
             if subs:
                 if name == "scan":
@@ -217,6 +223,8 @@ def _walk(jaxpr, cost: ProgramCost, mult: float) -> None:
                         cost.collective_ops += best.collective_ops
                         cost.eqns += best.eqns
                         cost.while_loops += best.while_loops
+                        for k, n in best.pallas_kernels.items():
+                            cost.pallas_kernels[k] = cost.pallas_kernels.get(k, 0) + n
                 else:
                     for _, sub in subs:
                         _walk(getattr(sub, "jaxpr", sub), cost, mult)
@@ -264,7 +272,9 @@ def program_cost(
     cost = ProgramCost(program=program)
     _walk(traced.jaxpr.jaxpr, cost, 1.0)
     try:
-        cost.hlo_flops, cost.hlo_bytes = lowered_cost(traced.lower())
+        lowered = traced.lower()
+        cost.hlo_flops, cost.hlo_bytes = lowered_cost(lowered)
+        cost.mosaic_calls = mosaic_calls(lowered)
     except Exception:
         pass
     return cost

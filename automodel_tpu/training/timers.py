@@ -3,8 +3,8 @@
 Parity: Megatron-style `Timers` (reference: components/training/timers.py:
 257-346 — barriered start/stop with min/max across ranks). Single-controller
 JAX needs no cross-rank reduction: one process observes the whole step. The
-device sync happens by blocking on a data transfer (`jax.device_get`), which
-is the only true barrier on tunneled/remote backends.
+device sync happens by blocking on a data transfer (`jax.device_get`): the
+value cannot arrive before the step that produces it has run.
 """
 
 from __future__ import annotations

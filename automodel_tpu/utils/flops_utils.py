@@ -26,17 +26,33 @@ TPU_PEAK_BF16_TFLOPS: dict[str, float] = {
 _H100_PEAK_TFLOPS = 989.0  # the reference's MFU basis (performance-summary.md:70)
 
 
-def device_peak_tflops(device: Optional[jax.Device] = None) -> float:
-    """Peak BF16 TFLOPs of `device` (default: first local device).
-    Unknown kinds return float('nan') rather than a silent wrong basis."""
+def device_table_lookup(
+    table: dict[str, float], what: str, device: Optional[jax.Device] = None
+) -> float:
+    """``table[device_kind]`` of `device` (default: first local device),
+    exact or by prefix. A TPU whose kind is not in the table is an ERROR —
+    a rate over a guessed peak is worse than none — while a host CPU, which
+    has no entry by design, gets NaN so the CPU suite runs with its roofline
+    class `unknown`."""
     d = device or jax.devices()[0]
     kind = getattr(d, "device_kind", "")
-    if kind in TPU_PEAK_BF16_TFLOPS:
-        return TPU_PEAK_BF16_TFLOPS[kind]
-    for k, v in TPU_PEAK_BF16_TFLOPS.items():
+    if kind in table:
+        return table[kind]
+    for k, v in table.items():
         if kind.lower().startswith(k.lower()):
             return v
+    if d.platform == "tpu":
+        raise ValueError(
+            f"no {what} on record for TPU device_kind {kind!r}; add it, with "
+            "its source, to the table it belongs in (utils/flops_utils.py "
+            "TPU_PEAK_BF16_TFLOPS, telemetry/profiling/cost.py TPU_HBM_GBPS)"
+        )
     return float("nan")
+
+
+def device_peak_tflops(device: Optional[jax.Device] = None) -> float:
+    """Peak BF16 TFLOPs of `device` (see ``device_table_lookup``)."""
+    return device_table_lookup(TPU_PEAK_BF16_TFLOPS, "peak bf16 TFLOP/s", device)
 
 
 def dense_transformer_flops_per_token(

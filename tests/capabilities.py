@@ -1,11 +1,9 @@
 """Capability probes for environment-dependent tier-1 tests.
 
-Some tests exercise functionality this container's jax/jaxlib/optax build
-cannot run (old splash kernel, partial-auto shard_map lowering that emits
-GSPMD-rejected PartitionId ops, no multiprocess CPU backend, no
-optax.contrib.muon). Letting them FAIL buries real regressions in a wall
-of known noise; skipping them wholesale would mask a real regression the
-day the environment gains the capability.
+Some tests exercise functionality a jaxlib/optax build may lack (a
+multiprocess CPU backend, optax.contrib.muon). Letting them FAIL buries
+real regressions in a wall of known noise; skipping them wholesale would
+mask a real regression the day the environment gains the capability.
 
 The contract here: each probe reproduces the SPECIFIC minimal operation
 the gated tests depend on, once per session (cached), and the skip fires
@@ -16,8 +14,8 @@ and a regression in the feature fails loudly again.
 Usage::
 
     from capabilities import skip_unless
-    @skip_unless("splash_attention")
-    def test_flash_kernel_taken_...():
+    @skip_unless("muon")
+    def test_muon_...():
 """
 
 from __future__ import annotations
@@ -30,10 +28,7 @@ import subprocess
 import sys
 import textwrap
 
-import numpy as np
 import pytest
-
-import jax
 
 
 @functools.lru_cache(maxsize=None)
@@ -47,8 +42,8 @@ def skip_unless(name: str):
 
     The probe runs LAZILY at test call time (cached per session), not at
     decoration: collection (`--collect-only`, `-k something_else`) must not
-    pay for the 2-subprocess multiprocess probe or the pallas-interpret
-    splash probe when the gated tests never run."""
+    pay for the 2-subprocess multiprocess probe when the gated tests never
+    run."""
 
     def deco(fn):
         @functools.wraps(fn)
@@ -68,66 +63,11 @@ def skip_unless(name: str):
 # ---------------------------------------------------------------------------
 
 
-def _splash_attention() -> tuple[bool, str]:
-    """The exact splash invocation the suite's shapes need: GQA, head_dim
-    64, seq 128, interpret mode. This build's kernel lacks the ``sinks``
-    parameter AND requires head_dim % 128 == 0 — either one breaks every
-    flash test, and a future jax upgrade clears both at once."""
-    try:
-        import jax.numpy as jnp
-
-        from automodel_tpu.ops import attention as attn_mod
-
-        rng = np.random.default_rng(0)
-        q = jnp.asarray(rng.standard_normal((1, 128, 2, 64)), jnp.float32)
-        k = jnp.asarray(rng.standard_normal((1, 128, 1, 64)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((1, 128, 1, 64)), jnp.float32)
-        out = attn_mod._splash_flash(
-            q, k, v, None, None, causal=True, scale=0.125,
-            logits_soft_cap=None, sliding_window=None,
-            block_q=128, block_kv=128, interpret=True,
-        )
-        assert np.isfinite(np.asarray(out)).all()
-    except Exception as e:
-        return False, f"{type(e).__name__}: {str(e)[:160]}"
-    return True, ""
-
-
-def _partial_auto_shard_map() -> tuple[bool, str]:
-    """The pipeline lowering shape: a shard_map region manual over ``pp``
-    with a >1 ``tp`` axis left auto, using ``axis_index`` inside. On 0.4.x
-    jaxlib this emits a PartitionId instruction GSPMD refuses
-    (UNIMPLEMENTED) — the exact failure of the pp/a2a pipeline tests."""
-    try:
-        import jax.numpy as jnp
-        from jax.sharding import Mesh, PartitionSpec as P
-
-        from automodel_tpu.utils.compat import shard_map
-
-        devs = jax.devices("cpu")
-        if len(devs) < 4:
-            return False, "needs 4 CPU devices"
-        mesh = Mesh(np.array(devs[:4]).reshape(2, 2), ("pp", "tp"))
-
-        def body(x):
-            return x + jax.lax.axis_index("pp")
-
-        out = jax.jit(shard_map(
-            body, mesh=mesh, in_specs=(P("pp"),), out_specs=P("pp"),
-            axis_names={"pp"}, check_vma=False,
-        ))(jnp.arange(4.0))
-        assert np.asarray(out).shape == (4,)
-    except Exception as e:
-        return False, f"{type(e).__name__}: {str(e)[:160]}"
-    return True, ""
-
-
 _MP_PROBE_SCRIPT = textwrap.dedent("""\
     import os, sys
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-    os.environ["JAX_PLATFORMS"] = ""
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     jax.distributed.initialize(
         coordinator_address=sys.argv[1], num_processes=2,
         process_id=int(sys.argv[2]),
@@ -195,8 +135,6 @@ def _muon() -> tuple[bool, str]:
 
 
 _PROBES = {
-    "splash_attention": _splash_attention,
-    "partial_auto_shard_map": _partial_auto_shard_map,
     "multiprocess_cpu": _multiprocess_cpu,
     "muon": _muon,
 }

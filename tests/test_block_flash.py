@@ -2,10 +2,7 @@
 (ops/ring_flash.flash_attention) vs sdpa, and the per-shape autotune
 routing in ops/attention.flash.
 
-Interpret mode executes the REAL kernel code on CPU. Unlike the library
-splash kernel (which on this jax build requires head_dim % 128 == 0 and
-lacks the sinks parameter — tests/capabilities.py), the in-tree kernels run
-head_dim 64 and sinks as-is, so these parity tests are tier-1 everywhere.
+Interpret mode executes the REAL kernel code on CPU.
 """
 
 import json

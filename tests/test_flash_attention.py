@@ -12,7 +12,6 @@ benchmark recipe on the real chip.
 import numpy as np
 import pytest
 
-from capabilities import skip_unless
 
 import jax
 import jax.numpy as jnp
@@ -50,14 +49,12 @@ def _close(a, b, tol=2e-2):
     assert rel < tol, f"rel err {rel}"
 
 
-@skip_unless("splash_attention")
 def test_flash_kernel_taken_causal_gqa(no_fallback):
     q, k, v = _mk()
     out = attn_mod.flash(q, k, v)
     _close(out, sdpa(q, k, v))
 
 
-@skip_unless("splash_attention")
 def test_flash_kernel_taken_gemma2_shape(no_fallback):
     """Sliding window + logit soft cap + non-1/sqrt(h) scale — the exact
     combination that previously forced O(S^2) sdpa on TPU."""
@@ -68,7 +65,6 @@ def test_flash_kernel_taken_gemma2_shape(no_fallback):
     _close(out, sdpa(q, k, v, sliding_window=64, logits_soft_cap=50.0, scale=0.0884))
 
 
-@skip_unless("splash_attention")
 def test_flash_kernel_taken_gpt_oss_sinks(no_fallback):
     """Sliding window + attention sinks (gpt-oss)."""
     q, k, v = _mk(n=2, nkv=1, h=64)
@@ -77,7 +73,6 @@ def test_flash_kernel_taken_gpt_oss_sinks(no_fallback):
     _close(out, sdpa(q, k, v, sliding_window=64, sinks=sinks))
 
 
-@skip_unless("splash_attention")
 def test_flash_kernel_taken_unaligned_seq(no_fallback):
     """S not a multiple of 128 pads inside the wrapper instead of falling
     back (a 4097-token sequence must not lose the fused kernel)."""
@@ -87,7 +82,6 @@ def test_flash_kernel_taken_unaligned_seq(no_fallback):
     _close(out, sdpa(q, k, v))
 
 
-@skip_unless("splash_attention")
 def test_flash_kernel_taken_segments_padded(no_fallback):
     """Packed segments + internal padding compose."""
     q, k, v = _mk(s=200)
@@ -96,7 +90,6 @@ def test_flash_kernel_taken_segments_padded(no_fallback):
     _close(out, sdpa(q, k, v, segment_ids=seg))
 
 
-@skip_unless("splash_attention")
 def test_windowed_attention_cond_branches(no_fallback):
     """The scanned mixed-layer helper picks the right static mask per branch
     while staying on the kernel."""
@@ -126,7 +119,6 @@ def test_windowed_attention_cond_branches(no_fallback):
     _close(jitted(jnp.asarray(False)), sdpa(q, k, v))
 
 
-@skip_unless("splash_attention")
 def test_flash_grads_match_sdpa():
     q, k, v = _mk()
     ct = jnp.asarray(np.random.default_rng(2).standard_normal(q.shape), jnp.float32)

@@ -8,7 +8,6 @@ adapter, and require logits to match torch within fp32 tolerance.
 import numpy as np
 import pytest
 
-from capabilities import skip_unless
 
 import jax
 import jax.numpy as jnp
@@ -180,7 +179,6 @@ def test_hf_roundtrip_to_hf():
         np.testing.assert_array_equal(out_sd[k], sd[k])
 
 
-@skip_unless("partial_auto_shard_map")
 def test_vocab_parallel_ce_matches_masked(devices8):
     """TP loss-parallel CE (reference TEParallelCrossEntropy) == plain CE."""
     from automodel_tpu.ops import losses as L

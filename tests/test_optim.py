@@ -88,3 +88,45 @@ def test_build_optimizer_end_to_end_bf16_loss_decreases():
         )
         losses.append(float(l))
     assert losses[-1] < 0.5 * losses[0], (losses[0], losses[-1])
+
+
+def test_train_state_starts_laid_out_as_the_step_returns_it(devices8):
+    """Adam's moments are born on their params' shards (a bare
+    ``jit(optimizer.init)`` puts all of them on device 0) and the step
+    counter on the mesh — so the state going into step 1 is laid out like
+    the state coming out, and the train step compiles exactly once."""
+    from automodel_tpu import auto_model
+    from automodel_tpu.data.loader import place_batch
+    from automodel_tpu.optim.builders import init_opt_state
+    from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
+    from automodel_tpu.training.train_state import TrainState
+    from automodel_tpu.training.train_step import (
+        build_train_step,
+        make_causal_lm_loss,
+    )
+
+    hf = {
+        "architectures": ["LlamaForCausalLM"], "model_type": "llama",
+        "vocab_size": 128, "hidden_size": 64, "intermediate_size": 128,
+        "num_hidden_layers": 2, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": 16,
+    }
+    ctx = build_mesh(MeshConfig(dp_shard=4, tp=2), devices=devices8)
+    auto = auto_model.from_config(hf, ctx, {"attn": "sdpa"}, seed=0)
+    opt = build_optimizer(name="adamw", lr=1e-3, grad_clip_norm=1.0)
+    state = TrainState.create(auto.params, init_opt_state(opt, auto.params, ctx))
+
+    mu = state.opt_state[1].mu
+    for p, m in zip(jax.tree.leaves(auto.params), jax.tree.leaves(mu)):
+        assert m.sharding == p.sharding
+    assert state.step.sharding == ctx.replicated()
+
+    step = build_train_step(
+        make_causal_lm_loss(auto.model, loss="masked_ce", constrain=auto.constrain),
+        opt,
+    )
+    ids = np.random.default_rng(0).integers(0, 128, (1, 8, 32)).astype(np.int32)
+    batch = place_batch(ctx, {"input_ids": ids, "labels": ids})
+    for _ in range(2):
+        state, _ = step(state, batch)
+    assert step._cache_size() == 1

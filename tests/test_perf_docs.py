@@ -5,9 +5,9 @@ ROADMAP item 3's drift guard: round 5 shipped a doc whose MoE headline
 "every number in this table is quoted VERBATIM from the committed artifact"
 — is now enforced: every numeric value in BENCH_chip.json (recursively,
 incl. the per-backend MoE map) must appear as the same decimal string in
-docs/performance.md, so prose and artifact can never drift again. When a
-new chip round regenerates BENCH_chip.json (tools/chip_suite.sh), this
-test fails until the doc table is updated from the artifact.
+docs/performance.md, so prose and artifact can never drift again. If
+BENCH_chip.json is ever regenerated, this test fails until the doc table
+is updated from the artifact.
 """
 
 import json

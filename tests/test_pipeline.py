@@ -9,7 +9,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from capabilities import skip_unless
 
 from automodel_tpu import auto_model
 from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -36,7 +35,6 @@ def pp_setup(devices8):
     return ctx, auto_pp, auto_ref
 
 
-@skip_unless("partial_auto_shard_map")
 def test_pp_forward_matches_unpipelined(pp_setup):
     ctx, auto_pp, auto_ref = pp_setup
     ids = jnp.asarray(
@@ -47,7 +45,6 @@ def test_pp_forward_matches_unpipelined(pp_setup):
     np.testing.assert_allclose(out_pp, out_ref, atol=2e-4, rtol=2e-3)
 
 
-@skip_unless("partial_auto_shard_map")
 def test_pp_grads_match_unpipelined(pp_setup):
     ctx, auto_pp, auto_ref = pp_setup
     ids = jnp.asarray(
@@ -71,7 +68,6 @@ def test_pp_grads_match_unpipelined(pp_setup):
     )
 
 
-@skip_unless("partial_auto_shard_map")
 def test_pp_train_step_learns(pp_setup):
     from automodel_tpu.data.loader import place_batch
     from automodel_tpu.optim.builders import build_optimizer
@@ -130,7 +126,6 @@ def moe_pp_setup(devices8):
     return ctx, auto_pp, auto_ref
 
 
-@skip_unless("partial_auto_shard_map")
 def test_moe_pp_forward_and_aux_match(moe_pp_setup):
     ctx, auto_pp, auto_ref = moe_pp_setup
     ids = jnp.asarray(
@@ -152,7 +147,6 @@ def test_moe_pp_forward_and_aux_match(moe_pp_setup):
     )
 
 
-@skip_unless("partial_auto_shard_map")
 def test_moe_pp_grads_match(moe_pp_setup):
     ctx, auto_pp, auto_ref = moe_pp_setup
     ids = jnp.asarray(
@@ -177,7 +171,6 @@ def test_moe_pp_grads_match(moe_pp_setup):
     )
 
 
-@skip_unless("partial_auto_shard_map")
 def test_pp4_forward_matches(devices8):
     ctx = build_mesh(MeshConfig(pp=4, dp_shard=2), devices=devices8)
     auto_pp = auto_model.from_config(HF, ctx, {**FP32, "pp_microbatches": 8}, seed=0)
@@ -190,7 +183,6 @@ def test_pp4_forward_matches(devices8):
     np.testing.assert_allclose(out_pp, out_ref, atol=2e-4, rtol=2e-3)
 
 
-@skip_unless("partial_auto_shard_map")
 def test_pp_no_full_activation_psum(pp_setup):
     """The pipeline output leaves the shard_map sharded on pp and is sliced —
     the compiled HLO must not contain an all-reduce over full [B,S,D]
@@ -212,7 +204,6 @@ def test_pp_no_full_activation_psum(pp_setup):
     assert not bad, bad
 
 
-@skip_unless("partial_auto_shard_map")
 def test_moe_pp_a2a_manual_matches(devices8):
     """PP x EP with experts='a2a' runs the token-exchange body with ep
     MANUAL inside the pipeline region (VERDICT r2 #5) — no silent ragged
@@ -261,7 +252,6 @@ def test_moe_pp_a2a_manual_matches(devices8):
         )
 
 
-@skip_unless("partial_auto_shard_map")
 def test_moe_pp_a2a_fused_matches_unfused(devices8, monkeypatch):
     """experts='a2a_fused' inside the pp x ep manual region (the fused
     local expert MLP on the token-exchange path) matches the unfused a2a
@@ -291,8 +281,7 @@ def test_moe_pp_a2a_fused_matches_unfused(devices8, monkeypatch):
 
 # ---- zero-bubble schedule (B/W split, parallel/zero_bubble.py) --------------
 # These meshes keep every non-pp axis at size 1: the zero-bubble region is
-# manual over pp only, and trivial auto axes also keep the suite runnable on
-# jaxlibs whose partial-auto shard_map lowering is broken (utils/compat.py).
+# manual over pp only.
 
 ZB_TOL = dict(atol=2e-3, rtol=2e-3)  # fp32-accum reordering tolerance
 

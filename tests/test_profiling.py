@@ -106,7 +106,7 @@ def test_cost_walker_sees_explicit_collectives(devices8):
     classify as collective bytes; GSPMD-inserted ones do not (documented)."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from automodel_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(devices8[:4]), ("x",))
 

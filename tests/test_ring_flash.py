@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from automodel_tpu.utils.compat import shard_map
+from jax import shard_map
 from automodel_tpu.ops.attention import sdpa
 from automodel_tpu.parallel import cp as cpm
 

@@ -501,6 +501,21 @@ def test_decode_backend_resolution(monkeypatch, tmp_path):
         autotune.clear_cache()
 
 
+def test_fused_decode_refuses_kv_heads_the_mesh_does_not_divide(devices8):
+    """The paged kernel runs per TP shard on whole KV heads. A mesh that
+    does not divide them is refused when the engine is BUILT: at the first
+    decode step the error would be caught by step(), the wave failed, the
+    pool rebuilt — and the stdin front would still exit 0."""
+    from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    model, params = _tiny_llama()  # 2 KV heads
+    ctx = build_mesh(MeshConfig(dp_shard=1, tp=4), devices=devices8[:4])
+    auto = AutoModel(model=model, params=params, adapter=None, mesh_ctx=ctx)
+    with pytest.raises(ValueError, match="decode_kernel: gather"):
+        _serve(auto, decode_kernel="fused")
+    assert _serve(auto, decode_kernel="gather").decode_backend == "gather"
+
+
 # -- bench leg + CLI wiring ---------------------------------------------------
 
 

@@ -1,0 +1,267 @@
+"""Every Pallas family lowers for the TPU — on one device and on a 2x2 mesh.
+
+No chip: a compile-only TPU client (utils/compile_only.py) runs the real
+XLA:TPU + Mosaic compile on ShapeDtypeStructs sharded over the devices of a
+``v5e:2x2`` topology. GSPMD refuses a Mosaic call it is asked to partition,
+so on a mesh each kernel must sit in the shard_map its wrapper builds
+(ops/platform_check.kernel_axes); the 8-device CPU tests cannot see a miss
+because off-TPU ``flash`` routes to ``sdpa`` and the grouped matmul to
+``lax.ragged_dot``. The second half runs the same wrappers with the kernels
+interpreted on the 8 CPU devices and compares with sdpa / a gathered view /
+``lax.ragged_dot``.
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from automodel_tpu.moe.config import MoEConfig
+from automodel_tpu.moe.layer import init_moe_params, moe_block
+from automodel_tpu.ops.attention import flash, sdpa, sdpa_decode
+from automodel_tpu.ops.paged_attention import paged_attend, quantize_kv_rows
+from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
+from automodel_tpu.parallel.plans import make_constrain
+from automodel_tpu.utils.compile_only import mosaic_calls, topology_devices
+
+
+@functools.lru_cache(maxsize=None)
+def _tpu_ctx(n: int, **degrees):
+    return build_mesh(MeshConfig(**degrees), devices=topology_devices()[:n])
+
+
+def _sds(ctx, shape, dtype, *logical):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=ctx.sharding(*logical))
+
+
+def _compile(fn, *args):
+    # conftest pins matmul precision to "highest" for CPU parity tests; on
+    # the chip nothing sets it, and Mosaic refuses a bf16 matmul asked for
+    # fp32 precision
+    with jax.default_matmul_precision("default"):
+        return mosaic_calls(jax.jit(fn).lower(*args).compile())
+
+
+# -- lowering: one device and the 2x2 mesh ------------------------------------
+
+
+@pytest.mark.parametrize(
+    "n,degrees", [(1, {}), (4, {"dp_shard": 2, "tp": 2})], ids=["1chip", "dp2xtp2"]
+)
+def test_flash_fwd_bwd_lowers(n, degrees):
+    ctx = _tpu_ctx(n, **degrees)
+    q = _sds(ctx, (2, 256, 4, 128), jnp.bfloat16, "batch", None, "tensor", None)
+    kv = _sds(ctx, (2, 256, 2, 128), jnp.bfloat16, "batch", None, "tensor", None)
+
+    def loss(q, k, v):
+        out = flash(q, k, v, platform="tpu", mesh_ctx=ctx)
+        return out.astype(jnp.float32).sum()
+
+    # splash forward + its dq and dkv kernels
+    assert _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 3
+
+
+def test_flash_refuses_heads_the_mesh_does_not_divide():
+    ctx = _tpu_ctx(4, dp_shard=1, tp=4)
+    q = _sds(ctx, (2, 256, 4, 128), jnp.bfloat16)
+    kv = _sds(ctx, (2, 256, 2, 128), jnp.bfloat16)  # 2 KV heads over tp=4
+    with pytest.raises(ValueError, match="not divisible by mesh axes"):
+        jax.jit(
+            lambda q, k, v: flash(q, k, v, platform="tpu", mesh_ctx=ctx)
+        ).lower(q, kv, kv)
+
+
+@pytest.mark.parametrize(
+    "n,degrees,sq,int8",
+    [(1, {}, 1, False), (4, {"dp_shard": 1, "tp": 4}, 5, True)],
+    ids=["1chip-bf16-decode", "tp4-int8-verify"],
+)
+def test_paged_decode_lowers(n, degrees, sq, int8):
+    ctx = _tpu_ctx(n, **degrees)
+    B, N, Nkv, H, NB, BS, NBseq = 4, 8, 4, 128, 64, 16, 8
+    q = _sds(ctx, (B, sq, N, H), jnp.bfloat16, None, None, "tensor", None)
+    pool = _sds(
+        ctx, (NB, BS, Nkv, H), jnp.int8 if int8 else jnp.bfloat16,
+        None, None, "tensor", None,
+    )
+    args = [q, pool, pool, _sds(ctx, (B, NBseq), jnp.int32), _sds(ctx, (B,), jnp.int32)]
+    if int8:
+        scale = _sds(ctx, (NB, BS, Nkv), jnp.float32, None, None, "tensor")
+        args += [scale, scale]
+    assert _compile(functools.partial(paged_attend, mesh_ctx=ctx), *args) == 1
+
+
+@pytest.mark.parametrize(
+    "n,degrees,backend",
+    [
+        (1, {}, "ragged"),
+        (4, {"dp_shard": 4}, "ragged"),
+        (4, {"dp_shard": 4, "ep": 4}, "a2a_fused"),
+    ],
+    ids=["1chip-ragged", "dp4-ragged", "ep4-a2a_fused"],
+)
+def test_expert_kernels_fwd_bwd_lower(n, degrees, backend):
+    ctx = _tpu_ctx(n, **degrees)
+    cfg = MoEConfig(num_experts=8, num_experts_per_tok=2, moe_intermediate_size=256)
+    D = 256
+    mp = jax.eval_shape(
+        lambda: init_moe_params(jax.random.key(0), cfg, D, jnp.bfloat16)
+    )
+    spec = {
+        "router": {"weight": (None, None)},
+        "experts": {
+            "gate_up": ("expert", "expert_fsdp", "tensor"),
+            "down": ("expert", "tensor", "expert_fsdp"),
+        },
+    }
+    mp = jax.tree.map(
+        lambda a, s: _sds(ctx, a.shape, a.dtype, *s), mp, spec,
+        is_leaf=lambda x: isinstance(x, tuple),
+    )
+    x = _sds(ctx, (4, 128, D), jnp.bfloat16, "batch", None, None)
+    constrain = make_constrain(ctx)
+
+    def loss(x, mp):
+        out, _ = moe_block(
+            x, mp, cfg, jax.nn.silu, experts_backend=backend,
+            constrain=constrain, platform="tpu",
+        )
+        return out.astype(jnp.float32).sum()
+
+    # ragged: 2 gmm forward, then gmm (dlhs) and tgmm (drhs) backward;
+    # fused: one forward kernel and the three purpose-tiled backward ones.
+    # How many survive depends on what XLA finds dead, so: forward AND
+    # backward kernels present, not an exact count
+    assert _compile(jax.grad(loss, argnums=1), x, mp) >= 4
+
+
+# -- numerics: the wrappers with interpreted kernels on the 8 CPU devices -----
+
+
+def test_flash_wrapper_matches_sdpa(devices8, monkeypatch):
+    monkeypatch.setenv("AUTOMODEL_FLASH_INTERPRET", "1")
+    ctx = build_mesh(MeshConfig(dp_shard=2, tp=4), devices=devices8)
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.standard_normal((2, 128, 8, 128)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, 128, 4, 128)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, 128, 4, 128)), jnp.float32)
+    seg = jnp.asarray(np.repeat([[0, 1], [0, 0]], 64, axis=1), jnp.int32)
+    out = jax.jit(
+        lambda q, k, v, s: flash(
+            q, k, v, segment_ids=s, platform="cpu", mesh_ctx=ctx
+        )
+    )(q, k, v, seg)
+    assert out.sharding.spec == P("dp_shard", None, "tp")
+    # fp32 online softmax vs one-shot softmax: accumulation order only
+    np.testing.assert_allclose(
+        out, sdpa(q, k, v, segment_ids=seg), atol=2e-5, rtol=2e-5
+    )
+
+
+def test_paged_wrapper_matches_gathered_view(devices8):
+    ctx = build_mesh(MeshConfig(dp_shard=2, tp=4), devices=devices8)
+    rng = np.random.default_rng(0)
+    B, Sq, N, Nkv, H, NB, BS, NBseq = 4, 3, 8, 4, 128, 32, 16, 4
+    q = jnp.asarray(rng.standard_normal((B, Sq, N, H)), jnp.float32)
+    kq, ks = quantize_kv_rows(
+        jnp.asarray(rng.standard_normal((NB, BS, Nkv, H)), jnp.float32)
+    )
+    vq, vs = quantize_kv_rows(
+        jnp.asarray(rng.standard_normal((NB, BS, Nkv, H)), jnp.float32)
+    )
+    tables = jnp.asarray(rng.integers(1, NB, (B, NBseq)), jnp.int32)
+    lengths = jnp.asarray([0, 17, 40, 61], jnp.int32)
+    out = jax.jit(
+        functools.partial(paged_attend, interpret=True, mesh_ctx=ctx)
+    )(q, kq, vq, tables, lengths, ks, vs)
+    assert out.sharding.spec == P(None, None, "tp")
+    # reference: gather the (dequantized) blocks into a contiguous view and
+    # attend it under the per-query causal mask
+    deq = lambda x, s: (x.astype(jnp.float32) * s[..., None])[tables].reshape(
+        B, NBseq * BS, Nkv, H
+    )
+    pos = jnp.arange(NBseq * BS)[None, None, :]
+    mask = pos <= (lengths[:, None] + jnp.arange(Sq)[None, :])[:, :, None]
+    ref = sdpa_decode(q, deq(kq, ks), deq(vq, vs), kv_mask=mask)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("backend", ["ragged", "ragged_fused"])
+def test_expert_wrapper_matches_lax_ragged_dot(devices8, monkeypatch, backend):
+    cfg = MoEConfig(num_experts=8, num_experts_per_tok=2, moe_intermediate_size=128)
+    D = 128
+    mp = init_moe_params(jax.random.key(0), cfg, D, jnp.float32)
+    x = jnp.asarray(
+        np.random.default_rng(0).standard_normal((4, 32, D)), jnp.float32
+    )
+
+    def loss(x, mp, **kw):
+        out, _ = moe_block(x, mp, cfg, jax.nn.silu, platform="cpu", **kw)
+        return (out * out).sum()
+
+    # reference first, kernels off: sort + lax.ragged_dot on one device
+    ref = jax.grad(loss, argnums=(0, 1))(x, mp, experts_backend="ragged")
+    monkeypatch.setenv("AUTOMODEL_GMM_INTERPRET", "1")
+    ctx = build_mesh(MeshConfig(dp_shard=4, tp=2), devices=devices8)
+    got = jax.jit(
+        jax.grad(
+            functools.partial(
+                loss, experts_backend=backend, constrain=make_constrain(ctx)
+            ),
+            argnums=(0, 1),
+        )
+    )(x, mp)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+
+
+# -- refused at setup, never a lowering error at the first step ---------------
+
+_TINY_MOE = {
+    "architectures": ["Qwen3MoeForCausalLM"], "model_type": "qwen3_moe",
+    "vocab_size": 256, "hidden_size": 128, "intermediate_size": 256,
+    "moe_intermediate_size": 128, "num_hidden_layers": 2,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 128,
+    "num_experts": 8, "num_experts_per_tok": 2,
+}
+
+
+@pytest.mark.parametrize(
+    "degrees,backend,match",
+    [
+        ({"dp_shard": 1, "tp": 4}, {"attn": "flash"}, "not divisible by mesh axes"),
+        ({"dp_shard": 2, "cp": 2}, {"attn": "flash"}, "use attn: ring"),
+        ({"dp_shard": 2, "pp": 2}, {"attn": "sdpa", "experts": "ragged"},
+         "use experts: gspmd with pp"),
+    ],
+    ids=["kv-heads-vs-tp", "flash-under-cp", "expert-kernels-under-pp"],
+)
+def test_unsupported_kernel_mesh_is_refused_when_the_model_is_built(
+    degrees, backend, match
+):
+    from automodel_tpu import auto_model
+
+    ctx = _tpu_ctx(4, **degrees)
+    with pytest.raises(ValueError, match=match):
+        auto_model.from_config(_TINY_MOE, ctx, backend, abstract=True)
+
+
+def test_flash_inside_a_pipeline_stage_lowers():
+    """A stage is manual over pp only; the flash wrapper nests a shard_map
+    over the axes still auto (GSPMD refuses the Mosaic call otherwise, even
+    when those axes have size 1)."""
+    from automodel_tpu import auto_model
+
+    hf = dict(_TINY_MOE, architectures=["LlamaForCausalLM"], model_type="llama")
+    ctx = _tpu_ctx(4, pp=2, dp_shard=2)
+    auto = auto_model.from_config(
+        hf, ctx,
+        {"attn": "flash", "param_dtype": "bfloat16", "pp_microbatches": 2},
+        abstract=True,
+    )
+    ids = _sds(ctx, (4, 256), jnp.int32, "batch", None)
+    assert _compile(lambda p, i: auto(p, i), auto.params, ids) >= 1

@@ -19,9 +19,14 @@ import bench
 
 
 def main() -> None:
-    if not bench._wait_for_tpu():
-        print("[bench-moe] no TPU; aborting", file=sys.stderr)
+    status, detail = bench._probe_tpu()
+    if status != "tpu":
+        print(f"[bench-moe] no TPU found; aborting\n{detail[-500:]}",
+              file=sys.stderr)
         sys.exit(1)
+    from automodel_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
     from automodel_tpu.utils.flops_utils import calculate_mfu, device_peak_tflops
