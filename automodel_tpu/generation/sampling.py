@@ -81,12 +81,13 @@ def sample(
     logits: jnp.ndarray, key: jax.Array, config: SamplingConfig
 ) -> jnp.ndarray:
     """logits [B, V] → token ids [B] int32."""
-    logits = logits.astype(jnp.float32)
-    if config.greedy:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jax.random.categorical(
-        key, _filter_logits(logits, config), axis=-1
-    ).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        logits = logits.astype(jnp.float32)
+        if config.greedy:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return jax.random.categorical(
+            key, _filter_logits(logits, config), axis=-1
+        ).astype(jnp.int32)
 
 
 def sample_with_logprobs(
@@ -100,12 +101,13 @@ def sample_with_logprobs(
     what a full-forward recompute reproduces exactly. Filtering/temperature
     shape WHICH token is drawn (identical stream to ``sample`` for the same
     key), not the reported probability."""
-    logits = logits.astype(jnp.float32)
-    ids = sample(logits, key, config)
-    logp = jnp.take_along_axis(
-        jax.nn.log_softmax(logits, axis=-1), ids[:, None].astype(jnp.int32), axis=-1
-    )[:, 0]
-    return ids, logp
+    with jax.named_scope("sample"):
+        logits = logits.astype(jnp.float32)
+        ids = sample(logits, key, config)
+        logp = jnp.take_along_axis(
+            jax.nn.log_softmax(logits, axis=-1), ids[:, None].astype(jnp.int32), axis=-1
+        )[:, 0]
+        return ids, logp
 
 
 def speculative_verify(

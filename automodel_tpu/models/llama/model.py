@@ -172,7 +172,8 @@ def attention_block(
     cache under the position-tag mask. With a cache the return value is
     ``(h, (new_k, new_v))`` instead of ``h``."""
     B, S, D = h.shape
-    x = rms_norm(h, lp["input_norm"]["scale"], cfg.rms_eps)
+    with jax.named_scope("norm"):
+        x = rms_norm(h, lp["input_norm"]["scale"], cfg.rms_eps)
     q = _proj(x, lp["attn"]["q_proj"], backend.fp8)
     k = _proj(x, lp["attn"]["k_proj"], backend.fp8)
     v = _proj(x, lp["attn"]["v_proj"], backend.fp8).reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
@@ -189,7 +190,8 @@ def attention_block(
     new_layer_kv = None
     if cache is not None:
         ck, cv = cache
-        new_layer_kv = cache_ctx.write(ck, cv, k, v)
+        with jax.named_scope("kv_write"):
+            new_layer_kv = cache_ctx.write(ck, cv, k, v)
         if cache_ctx.attends_cache:
             # decode (single query) and chunked prefill (serving/): attend
             # over the cache — sdpa_decode under the position-tag mask, or

@@ -213,13 +213,15 @@ def forward_hidden(
         # (dp_shard, ep, cp) doesn't match the batch-sharded gather output
         # and XLA otherwise emits an "involuntary full rematerialization"
         # (VERDICT r2 weak #6) — same data movement, chosen deliberately
-        h = constrain(params["embed"]["embedding"], (None, None)).astype(cd)[input_ids]
+        with jax.named_scope("embed"):
+            h = constrain(params["embed"]["embedding"], (None, None)).astype(cd)[input_ids]
     else:
         h = inputs_embeds.astype(cd)
     h = constrain(h, ("batch", "seq", None))
-    cos, sin = rope_cos_sin if rope_cos_sin is not None else rope_table(
-        position_ids, rope_dim or cfg.rope_dim or cfg.head_dim, cfg.rope
-    )
+    with jax.named_scope("attn"):  # the rope table every layer's attention reads
+        cos, sin = rope_cos_sin if rope_cos_sin is not None else rope_table(
+            position_ids, rope_dim or cfg.rope_dim or cfg.head_dim, cfg.rope
+        )
 
     def maybe_remat(fn):
         from automodel_tpu.models.common.stacking import remat_wrap
@@ -231,42 +233,47 @@ def forward_hidden(
     new_v_parts: list = []
 
     def attn_and_kv(carry, lp, layer_kv):
-        if layer_kv is None:
+        with jax.named_scope("attn"):
+            if layer_kv is None:
+                return attn_block(
+                    cfg, backend, carry, lp, cos, sin, segment_ids, constrain
+                ), None
             return attn_block(
-                cfg, backend, carry, lp, cos, sin, segment_ids, constrain
-            ), None
-        return attn_block(
-            cfg, backend, carry, lp, cos, sin, segment_ids, constrain,
-            cache=layer_kv, cache_ctx=ctx,
-        )
+                cfg, backend, carry, lp, cos, sin, segment_ids, constrain,
+                cache=layer_kv, cache_ctx=ctx,
+            )
 
     if "dense_layers" in params:
         def dense_fn(carry, xs):
             lp, layer_kv = xs if cache is not None else (xs, None)
             hh, new_kv = attn_and_kv(carry, lp, layer_kv)
-            x = rms_norm(hh, lp["post_attn_norm"]["scale"], cfg.rms_eps)
+            with jax.named_scope("norm"):
+                x = rms_norm(hh, lp["post_attn_norm"]["scale"], cfg.rms_eps)
             act = ACT_FNS[cfg.act]
-            mlp = (
-                act(x @ lp["mlp"]["gate_proj"]["kernel"].astype(x.dtype))
-                * (x @ lp["mlp"]["up_proj"]["kernel"].astype(x.dtype))
-            ) @ lp["mlp"]["down_proj"]["kernel"].astype(x.dtype)
-            out = constrain(hh + mlp, ("batch", "seq", None))
+            with jax.named_scope("mlp"):
+                mlp = (
+                    act(x @ lp["mlp"]["gate_proj"]["kernel"].astype(x.dtype))
+                    * (x @ lp["mlp"]["up_proj"]["kernel"].astype(x.dtype))
+                ) @ lp["mlp"]["down_proj"]["kernel"].astype(x.dtype)
+                out = constrain(hh + mlp, ("batch", "seq", None))
             return out, (None if cache is None else new_kv)
 
-        dxs = (
-            params["dense_layers"]
-            if cache is None
-            else (
-                params["dense_layers"],
-                (
-                    kv_cache_mod.layer_range(kvc.k, 0, nd),
-                    kv_cache_mod.layer_range(kvc.v, 0, nd),
-                ),
+        with jax.named_scope("attn"):  # this stack's slice of the cache
+            dxs = (
+                params["dense_layers"]
+                if cache is None
+                else (
+                    params["dense_layers"],
+                    (
+                        kv_cache_mod.layer_range(kvc.k, 0, nd),
+                        kv_cache_mod.layer_range(kvc.v, 0, nd),
+                    ),
+                )
             )
-        )
-        h, dys = jax.lax.scan(
-            dense_fn if cache is not None else maybe_remat(dense_fn), h, dxs
-        )
+        with jax.named_scope("layers"):
+            h, dys = jax.lax.scan(
+                dense_fn if cache is not None else maybe_remat(dense_fn), h, dxs
+            )
         if cache is not None:
             new_k_parts.append(dys[0])
             new_v_parts.append(dys[1])
@@ -274,7 +281,8 @@ def forward_hidden(
     def moe_fn(carry, xs):
         lp, layer_kv = xs if cache is not None else (xs, None)
         hh, new_kv = attn_and_kv(carry, lp, layer_kv)
-        x = rms_norm(hh, lp["post_attn_norm"]["scale"], cfg.rms_eps)
+        with jax.named_scope("norm"):
+            x = rms_norm(hh, lp["post_attn_norm"]["scale"], cfg.rms_eps)
         out, aux = moe_block(
             x,
             lp["moe"],
@@ -314,24 +322,29 @@ def forward_hidden(
             counts_l.append(aux.expert_counts)
             aux_l.append(aux.aux_loss)
         rest = jax.tree.map(lambda x: x[nd:], params["moe_layers"])
-        h, auxs = jax.lax.scan(maybe_remat(moe_fn), h, rest)
+        with jax.named_scope("layers"):
+            h, auxs = jax.lax.scan(maybe_remat(moe_fn), h, rest)
         counts = jnp.concatenate([jnp.stack(counts_l), auxs.expert_counts])
         aux_losses = jnp.concatenate([jnp.stack(aux_l), auxs.aux_loss])
     elif backend.scan_layers:
-        mxs = (
-            params["moe_layers"]
-            if cache is None
-            else (
-                params["moe_layers"],
-                (
-                    kv_cache_mod.layer_range(kvc.k, nd),
-                    kv_cache_mod.layer_range(kvc.v, nd),
-                ),
+        with jax.named_scope("attn"):  # this stack's slice of the cache
+            mxs = (
+                params["moe_layers"]
+                if cache is None
+                else (
+                    params["moe_layers"],
+                    (
+                        kv_cache_mod.layer_range(kvc.k, nd),
+                        kv_cache_mod.layer_range(kvc.v, nd),
+                    ),
+                )
             )
-        )
-        h, ys = jax.lax.scan(
-            moe_fn if cache is not None else maybe_remat(moe_fn), h, mxs
-        )
+        # the scan's own carried and stacked buffers show as `layers` with
+        # no inner scope (the benchmark's layer_scan_overhead_ms)
+        with jax.named_scope("layers"):
+            h, ys = jax.lax.scan(
+                moe_fn if cache is not None else maybe_remat(moe_fn), h, mxs
+            )
         if cache is not None:
             auxs, (mk, mv) = ys
             new_k_parts.append(mk)
@@ -367,14 +380,16 @@ def forward_hidden(
             new_k_parts.append(kv_cache_mod.stack_layer_sides(mk_l))
             new_v_parts.append(kv_cache_mod.stack_layer_sides(mv_l))
 
-    h = rms_norm(h, params["final_norm"]["scale"], cfg.rms_eps)
+    with jax.named_scope("final_norm"):
+        h = rms_norm(h, params["final_norm"]["scale"], cfg.rms_eps)
     out = (h, MoEModelAux(counts, aux_losses.sum()))
     if cache is None:
         return out
-    new_cache = kvc.replace(
-        k=kv_cache_mod.concat_layer_sides(new_k_parts),
-        v=kv_cache_mod.concat_layer_sides(new_v_parts),
-    )
+    with jax.named_scope("kv_write"):
+        new_cache = kvc.replace(
+            k=kv_cache_mod.concat_layer_sides(new_k_parts),
+            v=kv_cache_mod.concat_layer_sides(new_v_parts),
+        )
     return out, new_cache
 
 
@@ -398,9 +413,10 @@ def forward(
         if cfg.tie_embeddings
         else params["lm_head"]["kernel"]
     )
-    logits = h @ kernel.astype(h.dtype)
-    if cfg.logits_soft_cap is not None:
-        logits = cfg.logits_soft_cap * jnp.tanh(logits / cfg.logits_soft_cap)
+    with jax.named_scope("lm_head"):
+        logits = h @ kernel.astype(h.dtype)
+        if cfg.logits_soft_cap is not None:
+            logits = cfg.logits_soft_cap * jnp.tanh(logits / cfg.logits_soft_cap)
     return (logits, aux) if cache is None else ((logits, aux), new_cache)
 
 

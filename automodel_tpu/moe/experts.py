@@ -28,6 +28,11 @@ backends:
 
 All backends take fused gate_up weights [E, D, 2I] and down [E, I, D];
 SwiGLU-family activation.
+
+Every backend runs inside moe_block's ``moe`` scope and names its three
+parts (utils/profiler.SCOPES): ``dispatch`` (sort, permute, exchange, the
+gate/up weight split and casts), ``experts`` (the grouped matmuls or the
+fused kernel), ``combine`` (unpermute, weighted sum).
 """
 
 from __future__ import annotations
@@ -83,15 +88,17 @@ def dense_experts(
     act2: Act,
 ) -> jnp.ndarray:
     E = cfg.num_experts
-    # combine weights [T, E]
-    cw = jnp.zeros((x.shape[0], E), x.dtype)
-    cw = cw.at[
-        jnp.arange(x.shape[0])[:, None], gate_out.topk_idx
-    ].add(gate_out.topk_weights)
-    ys = jax.vmap(
-        lambda w: _ffn(x, w, act2, cfg.interleaved_gate_up, cfg.gated), in_axes=0, out_axes=0
-    )(weights)  # [E, T, D]
-    return jnp.einsum("etd,te->td", ys, cw)
+    with jax.named_scope("experts"):
+        # combine weights [T, E]
+        cw = jnp.zeros((x.shape[0], E), x.dtype)
+        cw = cw.at[
+            jnp.arange(x.shape[0])[:, None], gate_out.topk_idx
+        ].add(gate_out.topk_weights)
+        ys = jax.vmap(
+            lambda w: _ffn(x, w, act2, cfg.interleaved_gate_up, cfg.gated),
+            in_axes=0, out_axes=0,
+        )(weights)  # [E, T, D]
+        return jnp.einsum("etd,te->td", ys, cw)
 
 
 def gspmd_experts(
@@ -107,33 +114,36 @@ def gspmd_experts(
     E, K = cfg.num_experts, cfg.num_experts_per_tok
     cap = max(K, int(math.ceil(S * K / E * cfg.capacity_factor)))
 
-    idx = gate_out.topk_idx.reshape(B, S, K)
-    w = gate_out.topk_weights.reshape(B, S, K).astype(jnp.float32)
+    with jax.named_scope("dispatch"):
+        idx = gate_out.topk_idx.reshape(B, S, K)
+        w = gate_out.topk_weights.reshape(B, S, K).astype(jnp.float32)
 
-    # position of each (token, k) pick inside its expert's buffer, in
-    # token-major priority order (reference dispatch order)
-    onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)  # [B,S,K,E]
-    flat = onehot.reshape(B, S * K, E)
-    pos = (jnp.cumsum(flat, axis=1) - flat).reshape(B, S, K, E)  # [B,S,K,E]
-    pos = jnp.einsum("bske,bske->bsk", pos, onehot).astype(jnp.int32)
-    keep = pos < cap
-    pos_oh = jax.nn.one_hot(pos, cap, dtype=jnp.float32) * keep[..., None]
-    # dispatch/combine tensors [B, S, E, C]
-    disp = jnp.einsum("bske,bskc->bsec", onehot, pos_oh)
-    comb = jnp.einsum("bsk,bske,bskc->bsec", w, onehot, pos_oh)
+        # position of each (token, k) pick inside its expert's buffer, in
+        # token-major priority order (reference dispatch order)
+        onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)  # [B,S,K,E]
+        flat = onehot.reshape(B, S * K, E)
+        pos = (jnp.cumsum(flat, axis=1) - flat).reshape(B, S, K, E)  # [B,S,K,E]
+        pos = jnp.einsum("bske,bske->bsk", pos, onehot).astype(jnp.int32)
+        keep = pos < cap
+        pos_oh = jax.nn.one_hot(pos, cap, dtype=jnp.float32) * keep[..., None]
+        # dispatch/combine tensors [B, S, E, C]
+        disp = jnp.einsum("bske,bskc->bsec", onehot, pos_oh)
+        comb = jnp.einsum("bsk,bske,bskc->bsec", w, onehot, pos_oh)
 
-    expert_in = jnp.einsum("bsec,bsd->ebcd", disp, x.astype(jnp.float32)).astype(
-        x.dtype
-    )
-    expert_in = constrain(expert_in, ("expert", "expert_batch", None, None))
-    expert_out = jax.vmap(
-        lambda h, w: _ffn(h, w, act2, cfg.interleaved_gate_up, cfg.gated)
-    )(expert_in, weights)  # [E, B, C, D]
-    expert_out = constrain(expert_out, ("expert", "expert_batch", None, None))
-    out = jnp.einsum(
-        "bsec,ebcd->bsd", comb, expert_out.astype(jnp.float32)
-    )
-    return out.astype(x.dtype)
+        expert_in = jnp.einsum(
+            "bsec,bsd->ebcd", disp, x.astype(jnp.float32)
+        ).astype(x.dtype)
+        expert_in = constrain(expert_in, ("expert", "expert_batch", None, None))
+    with jax.named_scope("experts"):
+        expert_out = jax.vmap(
+            lambda h, w: _ffn(h, w, act2, cfg.interleaved_gate_up, cfg.gated)
+        )(expert_in, weights)  # [E, B, C, D]
+        expert_out = constrain(expert_out, ("expert", "expert_batch", None, None))
+    with jax.named_scope("combine"):
+        out = jnp.einsum(
+            "bsec,ebcd->bsd", comb, expert_out.astype(jnp.float32)
+        )
+        return out.astype(x.dtype)
 
 
 def _name_ckpt(x: jnp.ndarray, name: str) -> jnp.ndarray:
@@ -298,32 +308,35 @@ def ragged_experts(
     grads (reference GroupedExpertsFP8, components/moe/experts.py:478)."""
     T, D = x.shape
     E, K = cfg.num_experts, cfg.num_experts_per_tok
-    flat_expert = gate_out.topk_idx.reshape(-1)  # [T*K]
-    order = _name_ckpt(jnp.argsort(flat_expert), "moe_sort_order")  # stable
-    inv = _name_ckpt(jnp.argsort(order), "moe_sort_inv")
-    group_sizes = gate_out.expert_counts.astype(jnp.int32)
-    sorted_expert = flat_expert[order]
-    xs = _dispatch_take(x, order, inv, K)  # [T*K, D] sorted by expert
+    with jax.named_scope("dispatch"):
+        flat_expert = gate_out.topk_idx.reshape(-1)  # [T*K]
+        order = _name_ckpt(jnp.argsort(flat_expert), "moe_sort_order")  # stable
+        inv = _name_ckpt(jnp.argsort(order), "moe_sort_inv")
+        group_sizes = gate_out.expert_counts.astype(jnp.int32)
+        sorted_expert = flat_expert[order]
+        xs = _dispatch_take(x, order, inv, K)  # [T*K, D] sorted by expert
 
-    w_gu = weights["gate_up"].astype(xs.dtype)
-    w_dn = weights["down"].astype(xs.dtype)
-    if fp8:
-        xs = fp8_qdq_tensor(xs)
-        w_gu = fp8_qdq_blockwise(w_gu)
-        w_dn = fp8_qdq_blockwise(w_dn)
-    gu = ragged_dot(xs, w_gu, group_sizes, platform=platform)
-    if "gate_up_bias" in weights:
-        gu = gu + weights["gate_up_bias"].astype(xs.dtype)[sorted_expert]
-    g, u = _split_gate_up(gu, cfg.interleaved_gate_up) if cfg.gated else (gu, gu)
-    h_mid = act2(g, u)
-    if fp8:
-        h_mid = fp8_qdq_tensor(h_mid)
-    ys = ragged_dot(h_mid, w_dn, group_sizes, platform=platform)
-    if "down_bias" in weights:
-        ys = ys + weights["down_bias"].astype(xs.dtype)[sorted_expert]
+        w_gu = weights["gate_up"].astype(xs.dtype)
+        w_dn = weights["down"].astype(xs.dtype)
+        if fp8:
+            xs = fp8_qdq_tensor(xs)
+            w_gu = fp8_qdq_blockwise(w_gu)
+            w_dn = fp8_qdq_blockwise(w_dn)
+    with jax.named_scope("experts"):
+        gu = ragged_dot(xs, w_gu, group_sizes, platform=platform)
+        if "gate_up_bias" in weights:
+            gu = gu + weights["gate_up_bias"].astype(xs.dtype)[sorted_expert]
+        g, u = _split_gate_up(gu, cfg.interleaved_gate_up) if cfg.gated else (gu, gu)
+        h_mid = act2(g, u)
+        if fp8:
+            h_mid = fp8_qdq_tensor(h_mid)
+        ys = ragged_dot(h_mid, w_dn, group_sizes, platform=platform)
+        if "down_bias" in weights:
+            ys = ys + weights["down_bias"].astype(xs.dtype)[sorted_expert]
 
-    out = _sorted_combine(ys, gate_out.topk_weights, order, inv, K)
-    return out.astype(x.dtype)
+    with jax.named_scope("combine"):
+        out = _sorted_combine(ys, gate_out.topk_weights, order, inv, K)
+        return out.astype(x.dtype)
 
 
 def _fused_act_of(cfg: MoEConfig, act_name: str, fp8: bool):
@@ -419,7 +432,8 @@ def a2a_experts(
         cap = min(cap, int(math.ceil(cfg.a2a_capacity_factor * Tl * K / ep)))
     C = -(-cap // 8) * 8  # chunk rows per peer, padded for TPU layouts
 
-    wd = _a2a_weights(weights, cfg)
+    with jax.named_scope("dispatch"):
+        wd = _a2a_weights(weights, cfg)
 
     batch_axes = (A.DP_REPLICATE, A.DP_SHARD, A.EP)
     tok_spec = P(batch_axes, A.CP, None)
@@ -482,132 +496,135 @@ def _a2a_body(xb, idxb, cwb, wd, *, ep, ep_axis, E, E_loc, C, D, K, act2,
     ``tp_axis`` is set, that axis too) to be MANUAL in the calling context —
     either a2a_experts' own shard_map, or a pipeline region already manual
     over {pp, ep} (parallel.pp ep_manual mode, tp_axis=None)."""
-    Bl, Sl, _ = xb.shape
-    T = Bl * Sl
-    xt = xb.reshape(T, D)
-    flat = idxb.reshape(T * K)
-    order = _name_ckpt(
-        jnp.argsort(flat, stable=True), "moe_sort_order"
-    )  # sorted-pick → original-pick
-    inv_order = _name_ckpt(jnp.argsort(order), "moe_sort_order_inv")
-    sorted_e = flat[order]
-    # [T*K, D] picks sorted by global expert id; gather-only VJP (the K-fold
-    # dense sum) instead of autodiff's scatter-add transpose
-    xs = _dispatch_take(xt, order, inv_order, K)
+    with jax.named_scope("dispatch"):
+        Bl, Sl, _ = xb.shape
+        T = Bl * Sl
+        xt = xb.reshape(T, D)
+        flat = idxb.reshape(T * K)
+        order = _name_ckpt(
+            jnp.argsort(flat, stable=True), "moe_sort_order"
+        )  # sorted-pick → original-pick
+        inv_order = _name_ckpt(jnp.argsort(order), "moe_sort_order_inv")
+        sorted_e = flat[order]
+        # [T*K, D] picks sorted by global expert id; gather-only VJP (the K-fold
+        # dense sum) instead of autodiff's scatter-add transpose
+        xs = _dispatch_take(xt, order, inv_order, K)
 
-    counts = jnp.bincount(flat, length=E).astype(jnp.int32)
-    if ep == 1:
-        # one expert shard: the sorted picks are already grouped by local
-        # expert and nothing is dropped — no exchange, no second sort
-        xs2, sid, gsz = xs, sorted_e, counts
-    else:
-        peer_counts = counts.reshape(ep, E_loc).sum(-1)
-        peer_off = jnp.concatenate(
-            [jnp.zeros((1,), jnp.int32), jnp.cumsum(peer_counts)[:-1]]
-        )
-        peer_of = sorted_e // E_loc
-        pos_in_peer = jnp.arange(T * K, dtype=jnp.int32) - peer_off[peer_of]
-        keep = pos_in_peer < C  # over-capacity picks drop (zero contribution)
-        dst = jnp.where(keep, peer_of * C + pos_in_peer, ep * C)
-        # slot r of peer p holds pick peer_off[p] + r%C (picks are sorted,
-        # hence peer-contiguous) — the send buffer is a gather, not an
-        # .at[].set
-        slot = jnp.arange(ep * C, dtype=jnp.int32)
-        slot_c = slot % C
-        slot_valid = slot_c < peer_counts[slot // C]
-        src = jnp.minimum(peer_off[slot // C] + slot_c, T * K - 1)
-
-        send_x = _slot_pack(xs, src, dst, slot_valid)
-        send_id = jnp.where(slot_valid, sorted_e[src] % E_loc, E_loc)
-        a2a = lambda a: jax.lax.all_to_all(
-            a, ep_axis, split_axis=0, concat_axis=0, tiled=True
-        )
-        recv_x, recv_id = a2a(send_x), a2a(send_id)  # [ep*C, ...] by sender
-
-        order2 = _name_ckpt(
-            jnp.argsort(recv_id, stable=True), "moe_sort_inv"
-        )  # sentinel E_loc sorts last
-        inv_order2 = _name_ckpt(jnp.argsort(order2), "moe_sort_inv2")
-        xs2 = _perm_take(recv_x, order2, inv_order2)
-        sid = jnp.minimum(recv_id[order2], E_loc - 1)
-        gsz = jnp.bincount(recv_id, length=E_loc).astype(jnp.int32)  # sentinel drops
-
-    w_g = wd["gw"].astype(xs2.dtype)
-    w_d = wd["dw"].astype(xs2.dtype)
-    if fused_act is not None:
-        # one-kernel local expert MLP (ops/fused_expert_mlp): the [rows, 2I]
-        # gate_up output and the [rows, I] activation never touch HBM —
-        # the same win the single-chip ragged_fused backend gets, on the
-        # post-exchange rows. The down bias stays OUTSIDE the kernel when
-        # tp shards the experts (it must land on one tp shard only).
-        act_kind, limit = fused_act
-        from automodel_tpu.ops.fused_expert_mlp import fused_expert_mlp
-
-        w_u = wd["uw"].astype(xs2.dtype)
-        gb = wd["gb"].astype(xs2.dtype) if "gb" in wd else None
-        ub = wd["ub"].astype(xs2.dtype) if "ub" in wd else None
-        db = wd.get("db")
-        db_in_kernel = db if tp_axis is None else None
-        y = fused_expert_mlp(
-            xs2, w_g, w_u, w_d, gsz,
-            gb, ub,
-            None if db_in_kernel is None else db_in_kernel.astype(xs2.dtype),
-            act_kind, limit, platform, None,
-        )
-        if db is not None and tp_axis is not None:
-            y = y + jnp.where(
-                jax.lax.axis_index(tp_axis) == 0, db.astype(y.dtype)[sid], 0.0
+        counts = jnp.bincount(flat, length=E).astype(jnp.int32)
+        if ep == 1:
+            # one expert shard: the sorted picks are already grouped by local
+            # expert and nothing is dropped — no exchange, no second sort
+            xs2, sid, gsz = xs, sorted_e, counts
+        else:
+            peer_counts = counts.reshape(ep, E_loc).sum(-1)
+            peer_off = jnp.concatenate(
+                [jnp.zeros((1,), jnp.int32), jnp.cumsum(peer_counts)[:-1]]
             )
-    else:
-        if fp8:
-            xs2 = fp8_qdq_tensor(xs2)
-            w_g, w_d = fp8_qdq_blockwise(w_g), fp8_qdq_blockwise(w_d)
-        g = ragged_dot(xs2, w_g, gsz, platform=platform)
-        if "gb" in wd:
-            g = g + wd["gb"].astype(g.dtype)[sid]
-        if gated:
-            w_u = wd["uw"].astype(xs2.dtype)
-            if fp8:
-                w_u = fp8_qdq_blockwise(w_u)
-            u = ragged_dot(xs2, w_u, gsz, platform=platform)
-            if "ub" in wd:
-                u = u + wd["ub"].astype(u.dtype)[sid]
-        else:  # non-gated (relu2): one projection, act2 ignores its 2nd operand
-            u = g
-        h_mid = act2(g, u)
-        if fp8:
-            h_mid = fp8_qdq_tensor(h_mid)
-        y = ragged_dot(h_mid, w_d, gsz, platform=platform)
-        if "db" in wd:
-            if tp_axis is not None:  # partial over tp: bias on one shard only
-                y = y + jnp.where(
-                    jax.lax.axis_index(tp_axis) == 0,
-                    wd["db"].astype(y.dtype)[sid], 0.0,
-                )
-            else:
-                y = y + wd["db"].astype(y.dtype)[sid]
-    # permutations invert as forward GATHERS (out[p[i]] = y[i] is exactly
-    # y[argsort(p)]), and every gather here carries a gather-only custom VJP
-    # — the EP backward contains no XLA scatter (VERDICT r4 weak #3; jax
-    # 0.9's shard_map infers vma through custom_vjp cleanly, which blocked
-    # this in r4).
-    if ep > 1:
-        y = _perm_take(y, inv_order2, order2)  # back to recv order
-        y = a2a(y)  # [ep*C, D] back in my send layout
-        y = _slot_unpack(y, dst, src, slot_valid)  # picks; dropped → 0
-    y = _perm_take(y, inv_order, order)  # original pick order
+            peer_of = sorted_e // E_loc
+            pos_in_peer = jnp.arange(T * K, dtype=jnp.int32) - peer_off[peer_of]
+            keep = pos_in_peer < C  # over-capacity picks drop (zero contribution)
+            dst = jnp.where(keep, peer_of * C + pos_in_peer, ep * C)
+            # slot r of peer p holds pick peer_off[p] + r%C (picks are sorted,
+            # hence peer-contiguous) — the send buffer is a gather, not an
+            # .at[].set
+            slot = jnp.arange(ep * C, dtype=jnp.int32)
+            slot_c = slot % C
+            slot_valid = slot_c < peer_counts[slot // C]
+            src = jnp.minimum(peer_off[slot // C] + slot_c, T * K - 1)
 
-    # picks of token t are rows [t*K, t*K+K) → combine is a dense reshape
-    # + weighted K-fold sum, no scatter in the forward
-    out = jnp.einsum(
-        "tkd,tk->td",
-        y.reshape(T, K, D),
-        cwb.reshape(T, K),
-        preferred_element_type=jnp.float32,
-    )
-    if tp_axis is not None:
-        out = jax.lax.psum(out, tp_axis)  # down-proj partials, deferred to [T, D]
-    return out.astype(xb.dtype).reshape(Bl, Sl, D)
+            send_x = _slot_pack(xs, src, dst, slot_valid)
+            send_id = jnp.where(slot_valid, sorted_e[src] % E_loc, E_loc)
+            a2a = lambda a: jax.lax.all_to_all(
+                a, ep_axis, split_axis=0, concat_axis=0, tiled=True
+            )
+            recv_x, recv_id = a2a(send_x), a2a(send_id)  # [ep*C, ...] by sender
+
+            order2 = _name_ckpt(
+                jnp.argsort(recv_id, stable=True), "moe_sort_inv"
+            )  # sentinel E_loc sorts last
+            inv_order2 = _name_ckpt(jnp.argsort(order2), "moe_sort_inv2")
+            xs2 = _perm_take(recv_x, order2, inv_order2)
+            sid = jnp.minimum(recv_id[order2], E_loc - 1)
+            gsz = jnp.bincount(recv_id, length=E_loc).astype(jnp.int32)  # sentinel drops
+
+        w_g = wd["gw"].astype(xs2.dtype)
+        w_d = wd["dw"].astype(xs2.dtype)
+    with jax.named_scope("experts"):
+        if fused_act is not None:
+            # one-kernel local expert MLP (ops/fused_expert_mlp): the [rows, 2I]
+            # gate_up output and the [rows, I] activation never touch HBM —
+            # the same win the single-chip ragged_fused backend gets, on the
+            # post-exchange rows. The down bias stays OUTSIDE the kernel when
+            # tp shards the experts (it must land on one tp shard only).
+            act_kind, limit = fused_act
+            from automodel_tpu.ops.fused_expert_mlp import fused_expert_mlp
+
+            w_u = wd["uw"].astype(xs2.dtype)
+            gb = wd["gb"].astype(xs2.dtype) if "gb" in wd else None
+            ub = wd["ub"].astype(xs2.dtype) if "ub" in wd else None
+            db = wd.get("db")
+            db_in_kernel = db if tp_axis is None else None
+            y = fused_expert_mlp(
+                xs2, w_g, w_u, w_d, gsz,
+                gb, ub,
+                None if db_in_kernel is None else db_in_kernel.astype(xs2.dtype),
+                act_kind, limit, platform, None,
+            )
+            if db is not None and tp_axis is not None:
+                y = y + jnp.where(
+                    jax.lax.axis_index(tp_axis) == 0, db.astype(y.dtype)[sid], 0.0
+                )
+        else:
+            if fp8:
+                xs2 = fp8_qdq_tensor(xs2)
+                w_g, w_d = fp8_qdq_blockwise(w_g), fp8_qdq_blockwise(w_d)
+            g = ragged_dot(xs2, w_g, gsz, platform=platform)
+            if "gb" in wd:
+                g = g + wd["gb"].astype(g.dtype)[sid]
+            if gated:
+                w_u = wd["uw"].astype(xs2.dtype)
+                if fp8:
+                    w_u = fp8_qdq_blockwise(w_u)
+                u = ragged_dot(xs2, w_u, gsz, platform=platform)
+                if "ub" in wd:
+                    u = u + wd["ub"].astype(u.dtype)[sid]
+            else:  # non-gated (relu2): one projection, act2 ignores its 2nd operand
+                u = g
+            h_mid = act2(g, u)
+            if fp8:
+                h_mid = fp8_qdq_tensor(h_mid)
+            y = ragged_dot(h_mid, w_d, gsz, platform=platform)
+            if "db" in wd:
+                if tp_axis is not None:  # partial over tp: bias on one shard only
+                    y = y + jnp.where(
+                        jax.lax.axis_index(tp_axis) == 0,
+                        wd["db"].astype(y.dtype)[sid], 0.0,
+                    )
+                else:
+                    y = y + wd["db"].astype(y.dtype)[sid]
+    with jax.named_scope("combine"):
+        # permutations invert as forward GATHERS (out[p[i]] = y[i] is exactly
+        # y[argsort(p)]), and every gather here carries a gather-only custom VJP
+        # — the EP backward contains no XLA scatter (VERDICT r4 weak #3; jax
+        # 0.9's shard_map infers vma through custom_vjp cleanly, which blocked
+        # this in r4).
+        if ep > 1:
+            y = _perm_take(y, inv_order2, order2)  # back to recv order
+            y = a2a(y)  # [ep*C, D] back in my send layout
+            y = _slot_unpack(y, dst, src, slot_valid)  # picks; dropped → 0
+        y = _perm_take(y, inv_order, order)  # original pick order
+
+        # picks of token t are rows [t*K, t*K+K) → combine is a dense reshape
+        # + weighted K-fold sum, no scatter in the forward
+        out = jnp.einsum(
+            "tkd,tk->td",
+            y.reshape(T, K, D),
+            cwb.reshape(T, K),
+            preferred_element_type=jnp.float32,
+        )
+        if tp_axis is not None:
+            out = jax.lax.psum(out, tp_axis)  # down-proj partials, deferred to [T, D]
+        return out.astype(xb.dtype).reshape(Bl, Sl, D)
 
 
 def a2a_experts_manual(
@@ -637,7 +654,8 @@ def a2a_experts_manual(
         cap = min(cap, int(math.ceil(cfg.a2a_capacity_factor * Tl * K / ep)))
     C = -(-cap // 8) * 8
 
-    wd = _a2a_weights(weights, cfg)
+    with jax.named_scope("dispatch"):
+        wd = _a2a_weights(weights, cfg)
 
     idx = gate_out.topk_idx.reshape(Bl, Sl, K)
     cw = gate_out.topk_weights.reshape(Bl, Sl, K)
@@ -725,27 +743,31 @@ def ragged_fused_experts(
     act_kind, limit = _fused_act_of(cfg, act_name, fp8=False)
     T, D = x.shape
     E, K = cfg.num_experts, cfg.num_experts_per_tok
-    flat_expert = gate_out.topk_idx.reshape(-1)
-    order = _name_ckpt(jnp.argsort(flat_expert), "moe_sort_order")
-    inv = _name_ckpt(jnp.argsort(order), "moe_sort_inv")
-    group_sizes = gate_out.expert_counts.astype(jnp.int32)
-    xs = _dispatch_take(x, order, inv, K)
-    gw, uw = _split_gate_up(weights["gate_up"], cfg.interleaved_gate_up)
-    gb = ub = db = None
-    if "gate_up_bias" in weights:  # gpt-oss expert biases, per I-chunk in-kernel
-        gb, ub = _split_gate_up(
-            weights["gate_up_bias"], cfg.interleaved_gate_up
+    with jax.named_scope("dispatch"):
+        flat_expert = gate_out.topk_idx.reshape(-1)
+        order = _name_ckpt(jnp.argsort(flat_expert), "moe_sort_order")
+        inv = _name_ckpt(jnp.argsort(order), "moe_sort_inv")
+        group_sizes = gate_out.expert_counts.astype(jnp.int32)
+        xs = _dispatch_take(x, order, inv, K)
+        gw, uw = _split_gate_up(weights["gate_up"], cfg.interleaved_gate_up)
+        gw, uw = gw.astype(xs.dtype), uw.astype(xs.dtype)
+        w_dn = weights["down"].astype(xs.dtype)
+        gb = ub = db = None
+        if "gate_up_bias" in weights:  # gpt-oss expert biases, per I-chunk in-kernel
+            gb, ub = _split_gate_up(
+                weights["gate_up_bias"], cfg.interleaved_gate_up
+            )
+            gb, ub = gb.astype(xs.dtype), ub.astype(xs.dtype)
+        if "down_bias" in weights:
+            db = weights["down_bias"].astype(xs.dtype)
+    with jax.named_scope("experts"):
+        ys = fused_expert_mlp(
+            xs, gw, uw, w_dn, group_sizes,
+            gb, ub, db, act_kind, limit, platform, None,
         )
-        gb, ub = gb.astype(xs.dtype), ub.astype(xs.dtype)
-    if "down_bias" in weights:
-        db = weights["down_bias"].astype(xs.dtype)
-    ys = fused_expert_mlp(
-        xs, gw.astype(xs.dtype), uw.astype(xs.dtype),
-        weights["down"].astype(xs.dtype), group_sizes,
-        gb, ub, db, act_kind, limit, platform, None,
-    )
-    out = _sorted_combine(ys, gate_out.topk_weights, order, inv, K)
-    return out.astype(x.dtype)
+    with jax.named_scope("combine"):
+        out = _sorted_combine(ys, gate_out.topk_weights, order, inv, K)
+        return out.astype(x.dtype)
 
 
 # `ragged` / `ragged_fused` name the same code as `a2a` / `a2a_fused`:

@@ -12,6 +12,7 @@ from __future__ import annotations
 import logging
 from typing import Callable, NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 
 logger = logging.getLogger(__name__)
@@ -73,65 +74,69 @@ def moe_block(
     fp8: bool = False,
     act_name: str = "silu",
 ) -> tuple[jnp.ndarray, MoEAux]:
-    B, S, D = x.shape
-    xt = x.reshape(-1, D)
+    with jax.named_scope("moe"):  # its parts name themselves inside (experts.py)
+        B, S, D = x.shape
+        xt = x.reshape(-1, D)
 
-    if fake_gate:
-        gout = fake_balanced_gate(xt, cfg)
-    else:
-        gout = gate(
-            xt,
-            mp["router"]["weight"],
-            cfg,
-            bias=mp["router"].get("bias"),
-            seq_len=S,
-            linear_bias=mp["router"].get("linear_bias"),
-        )
-
-    act2 = make_act2(cfg, act)
-    # mesh-aware backends (a2a) need the real Mesh for their shard_map
-    # region; make_constrain attaches it to the constrain callback
-    ctx = getattr(constrain, "mesh_ctx", None)
-    if experts_backend in ("a2a", "a2a_fused") and ctx is None:
-        logger.warning(
-            "experts=%r but the constrain callback carries no mesh_ctx "
-            "(use parallel.plans.make_constrain, or a custom wrapper must "
-            "preserve the attribute); falling back to the single-slice "
-            "ragged path — NO expert-parallel token exchange will happen.",
-            experts_backend,
-        )
-    # a callable backend (e.g. the pipeline's ep-manual a2a binding) uses the
-    # registry's uniform signature directly
-    backend_fn = (
-        experts_backend if callable(experts_backend)
-        else EXPERT_BACKENDS[experts_backend]
-    )
-    routed = backend_fn(
-        x, gout, mp["experts"], cfg, act2,
-        ctx=ctx, constrain=constrain, platform=platform, fp8=fp8,
-        act_name=act_name,
-    )
-
-    out = routed
-    if "shared" in mp:
-        sp = mp["shared"]
-        u = xt @ sp["up_proj"]["kernel"].astype(xt.dtype)
-        if "gate_proj" in sp:
-            g = xt @ sp["gate_proj"]["kernel"].astype(xt.dtype)
-            if cfg.activation_limit is not None:
-                lim = float(cfg.activation_limit)
-                mid = jnp.minimum(act(g), lim) * jnp.clip(u, -lim, lim)
+        with jax.named_scope("router"):
+            if fake_gate:
+                gout = fake_balanced_gate(xt, cfg)
             else:
-                mid = act(g) * u
-        else:  # non-gated shared expert (nemotron relu2)
-            mid = act2(u, u)
-        shared = mid @ sp["down_proj"]["kernel"].astype(xt.dtype)
-        if "shared_gate" in mp:
-            sg = jnp.asarray(xt @ mp["shared_gate"]["kernel"].astype(xt.dtype))
-            shared = shared * jnp.asarray(jnp.reciprocal(1 + jnp.exp(-sg)))
-        out = out + shared.reshape(B, S, D)
+                gout = gate(
+                    xt,
+                    mp["router"]["weight"],
+                    cfg,
+                    bias=mp["router"].get("bias"),
+                    seq_len=S,
+                    linear_bias=mp["router"].get("linear_bias"),
+                )
 
-    return out, MoEAux(gout.expert_counts, gout.aux_loss)
+        act2 = make_act2(cfg, act)
+        # mesh-aware backends (a2a) need the real Mesh for their shard_map
+        # region; make_constrain attaches it to the constrain callback
+        ctx = getattr(constrain, "mesh_ctx", None)
+        if experts_backend in ("a2a", "a2a_fused") and ctx is None:
+            logger.warning(
+                "experts=%r but the constrain callback carries no mesh_ctx "
+                "(use parallel.plans.make_constrain, or a custom wrapper must "
+                "preserve the attribute); falling back to the single-slice "
+                "ragged path — NO expert-parallel token exchange will happen.",
+                experts_backend,
+            )
+        # a callable backend (e.g. the pipeline's ep-manual a2a binding) uses the
+        # registry's uniform signature directly
+        backend_fn = (
+            experts_backend if callable(experts_backend)
+            else EXPERT_BACKENDS[experts_backend]
+        )
+        routed = backend_fn(
+            x, gout, mp["experts"], cfg, act2,
+            ctx=ctx, constrain=constrain, platform=platform, fp8=fp8,
+            act_name=act_name,
+        )
+
+        out = routed
+        if "shared" in mp:
+            # the shared experts are a dense MLP every token takes
+            with jax.named_scope("mlp"):
+                sp = mp["shared"]
+                u = xt @ sp["up_proj"]["kernel"].astype(xt.dtype)
+                if "gate_proj" in sp:
+                    g = xt @ sp["gate_proj"]["kernel"].astype(xt.dtype)
+                    if cfg.activation_limit is not None:
+                        lim = float(cfg.activation_limit)
+                        mid = jnp.minimum(act(g), lim) * jnp.clip(u, -lim, lim)
+                    else:
+                        mid = act(g) * u
+                else:  # non-gated shared expert (nemotron relu2)
+                    mid = act2(u, u)
+                shared = mid @ sp["down_proj"]["kernel"].astype(xt.dtype)
+                if "shared_gate" in mp:
+                    sg = jnp.asarray(xt @ mp["shared_gate"]["kernel"].astype(xt.dtype))
+                    shared = shared * jnp.asarray(jnp.reciprocal(1 + jnp.exp(-sg)))
+                out = out + shared.reshape(B, S, D)
+
+        return out, MoEAux(gout.expert_counts, gout.aux_loss)
 
 
 def init_moe_params(
@@ -143,8 +148,6 @@ def init_moe_params(
 ) -> dict:
     """Init one MoE block's params; with n_layers, leaves get a leading
     stacked layer axis (lax.scan layout shared with the dense family)."""
-    import jax
-
     def shape(*s):
         return (n_layers, *s) if n_layers else s
 

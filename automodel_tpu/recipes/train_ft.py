@@ -26,6 +26,7 @@ from typing import Any, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from automodel_tpu import auto_model
 from automodel_tpu.checkpoint.checkpointer import Checkpointer, CheckpointingConfig
@@ -729,28 +730,30 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         """One grad-acc group of collated microbatches → ([A]-stacked host
         batch with zigzag-CP permutation applied, token count). Shared by
         the sync loop body and the prefetch producer thread, so both paths
-        build bit-identical batches."""
-        stacked = stack_microbatches(group)
-        if self._zigzag_cp:
-            from automodel_tpu.parallel.cp import apply_zigzag
+        build bit-identical batches. ``train.collate`` on the profiler's
+        clock, on whichever thread runs it."""
+        with TraceAnnotation("train.collate"):
+            stacked = stack_microbatches(group)
+            if self._zigzag_cp:
+                from automodel_tpu.parallel.cp import apply_zigzag
 
-            stacked = {
-                k: (
-                    apply_zigzag(v, self._zigzag_cp, axis=2)
-                    if k in ("input_ids", "labels", "position_ids", "segment_ids")
-                    else v
+                stacked = {
+                    k: (
+                        apply_zigzag(v, self._zigzag_cp, axis=2)
+                        if k in ("input_ids", "labels", "position_ids", "segment_ids")
+                        else v
+                    )
+                    for k, v in stacked.items()
+                }
+            # tps numerator: all *input_ids leaves (biencoder batches carry
+            # query_/doc_input_ids instead of a single input_ids)
+            n_tokens = int(
+                sum(
+                    np.prod(v.shape)
+                    for k, v in stacked.items()
+                    if k.endswith("input_ids") and isinstance(v, np.ndarray)
                 )
-                for k, v in stacked.items()
-            }
-        # tps numerator: all *input_ids leaves (biencoder batches carry
-        # query_/doc_input_ids instead of a single input_ids)
-        n_tokens = int(
-            sum(
-                np.prod(v.shape)
-                for k, v in stacked.items()
-                if k.endswith("input_ids") and isinstance(v, np.ndarray)
             )
-        )
         return stacked, n_tokens
 
     def _prepare_val_group(self, group: list) -> tuple[dict, int]:
@@ -768,7 +771,8 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         return stacked, n_tokens
 
     def _place_group(self, stacked: dict) -> dict:
-        return place_batch(self.mesh_ctx, stacked)
+        with TraceAnnotation("train.place"):
+            return place_batch(self.mesh_ctx, stacked)
 
     def _close_prefetch(self) -> None:
         """Join the prefetch producers and drop their run-ahead (idempotent;

@@ -102,6 +102,7 @@ class Watchdog:
         requeue_eligible: Optional[Callable[[], bool]] = None,
         peer_marker_root: Optional[str] = None,
         on_hang: Optional[Callable[[dict], None]] = None,
+        evidence: Optional[Callable[[], dict]] = None,
     ):
         self.config = config
         self.flight_recorder = flight_recorder
@@ -115,6 +116,10 @@ class Watchdog:
         # burning the launcher's backoff budget
         self.peer_marker_root = peer_marker_root
         self.on_hang = on_hang  # test seam: observe instead of exiting
+        # the watched loop's own account of where it is, read when the
+        # deadline expires and merged into the record (the serving engine
+        # gives the phase of step() the wedged call belongs to)
+        self.evidence = evidence
         self.fired: Optional[dict] = None
         self._last_pet = 0.0
         self._last_step = 0
@@ -238,6 +243,11 @@ class Watchdog:
             "ema_step_time_s": self._ema_s,
             "ts": time.time(),
         }
+        if self.evidence is not None:
+            try:
+                rec.update(self.evidence())
+            except Exception:
+                pass
         self.fired = rec
         print(
             f"[watchdog] {self.EVENT.upper()}: no heartbeat for {age:.1f}s "

@@ -65,6 +65,7 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from automodel_tpu.generation.engine import (
     GenerationConfig,
@@ -808,6 +809,16 @@ class ServingEngine:
         self._queue: deque[_Queued] = deque()
         self._ids = itertools.count()
         self._step_counter = 0
+        # step phases (docs/observability.md "Step phases and program
+        # scopes"): each phase of step() is a TraceAnnotation on the
+        # profiler's clock, so a device trace names what the host was doing
+        # in every idle gap. `step_phase` holds the name of the phase in
+        # progress (one store per phase; None between iterations) for the
+        # watchdog's stall evidence; the _n_* integers become the stats of
+        # the `serve.counts` event that closes each iteration.
+        self.step_phase: Optional[str] = None
+        self._n_admitted = self._n_chunks = 0
+        self._n_decoded = self._n_context_tokens = 0
         # live weight hot-swap (swap_weights): monotonic version tag
         # advertised on /stats + /metrics, and the validated replacement
         # tree staged until a step boundary with zero busy slots
@@ -1038,6 +1049,9 @@ class ServingEngine:
         self._watchdog = EngineWatchdog(
             wcfg, flight_recorder=flight_recorder, metric_logger=metric_logger,
             on_hang=self._note_stall,
+            # read from the watchdog thread while the scheduler thread sits
+            # in the wedged call: which phase of step() that call is
+            evidence=lambda: {"step_phase": self.step_phase},
         )
         self._watchdog.start()
         return self._watchdog
@@ -2002,6 +2016,11 @@ class ServingEngine:
         return best_i
 
     def _admit(self, done: list[dict]) -> None:
+        self.step_phase = "admit"
+        with TraceAnnotation("serve.admit"):
+            self._admit_free_slots(done)
+
+    def _admit_free_slots(self, done: list[dict]) -> None:
         for b in range(self.config.slots):
             if self._slots[b] is not None or not self._queue:
                 continue
@@ -2056,6 +2075,7 @@ class ServingEngine:
                         hit_tokens, max(matchable - hit_tokens, 0)
                     )
                     self._bind_slot(b, q, blocks, hit_tokens)
+                self._n_admitted += 1
                 # queue wait and admission (prefix match + whole-budget
                 # block allocation + slot bind) as sibling stages under the
                 # request root — the two ways a slow admission can hide
@@ -2158,34 +2178,39 @@ class ServingEngine:
             p = len(slot.prompt)
             start = slot.prefill_pos
             real = min(chunk_len, p - start)
-            ids = np.full((chunk_len,), pad, np.int32)
-            ids[:real] = slot.prompt[start : start + real]
-            t_chunk0 = time.perf_counter()
-            if inj is not None:
-                inj.maybe_trace_delay("prefill")
-                inj.maybe_slo_breach("prefill", self._step_counter)
-            if self.collect_program_costs and "chunk_prefill" not in self.program_costs:
-                self._record_cost(
-                    "chunk_prefill", self._chunk,
+            self.step_phase = "prefill_dispatch"
+            with TraceAnnotation(
+                "serve.prefill_dispatch", slot=b, pos=start, tokens=real
+            ):
+                ids = np.full((chunk_len,), pad, np.int32)
+                ids[:real] = slot.prompt[start : start + real]
+                t_chunk0 = time.perf_counter()
+                if inj is not None:
+                    inj.maybe_trace_delay("prefill")
+                    inj.maybe_slo_breach("prefill", self._step_counter)
+                if self.collect_program_costs and "chunk_prefill" not in self.program_costs:
+                    self._record_cost(
+                        "chunk_prefill", self._chunk,
+                        self.auto.params, self._pool,
+                        jnp.asarray(self._tables[b]), jnp.asarray(ids),
+                        jnp.int32(start), jnp.int32(real),
+                    )
+                last, self._pool = self._chunk(
                     self.auto.params, self._pool,
                     jnp.asarray(self._tables[b]), jnp.asarray(ids),
                     jnp.int32(start), jnp.int32(real),
                 )
-            last, self._pool = self._chunk(
-                self.auto.params, self._pool,
-                jnp.asarray(self._tables[b]), jnp.asarray(ids),
-                jnp.int32(start), jnp.int32(real),
-            )
-            if self._spec_enabled:
-                # the draft model prefills the same chunk into its parallel
-                # pool (same tables/offsets) so its proposals see the whole
-                # prompt; its last-token logits are unused — the first
-                # sampled token always comes from the TARGET
-                _, self._draft_pool = self._draft_chunk(
-                    self.draft_auto.params, self._draft_pool,
-                    jnp.asarray(self._tables[b]), jnp.asarray(ids),
-                    jnp.int32(start), jnp.int32(real),
-                )
+                if self._spec_enabled:
+                    # the draft model prefills the same chunk into its
+                    # parallel pool (same tables/offsets) so its proposals
+                    # see the whole prompt; its last-token logits are unused
+                    # — the first sampled token always comes from the TARGET
+                    _, self._draft_pool = self._draft_chunk(
+                        self.draft_auto.params, self._draft_pool,
+                        jnp.asarray(self._tables[b]), jnp.asarray(ids),
+                        jnp.int32(start), jnp.int32(real),
+                    )
+            self._n_chunks += 1
             # one span per chunk: a single long prompt's prefill shows as a
             # chunk train, and a stall inside one chunk names its offset
             self._child_span(
@@ -2196,48 +2221,54 @@ class ServingEngine:
             self._lengths[b] = slot.prefill_pos
             if slot.prefill_pos < p:
                 continue
-            # prompt fully in: sample the first token (charged to ttft),
-            # publish the prompt blocks to the prefix cache, flip to decode
-            first = int(
-                sample(
-                    last[None, :],
-                    jax.random.fold_in(self._base_key, self._step_counter),
-                    self.gen_config.sampling,
-                )[0]
-            )
-            if slot.logprobs is not None:
-                # same raw-logits rule as the decode program (the chunk
-                # already handed `last` to the host, so this is free)
-                slot.logprobs.append(
-                    float(jax.nn.log_softmax(last.astype(jnp.float32))[first])
+            # prompt fully in: sample the first token (charged to ttft) —
+            # the host blocks here until the chunk program has run
+            self.step_phase = "first_token_wait"
+            with TraceAnnotation("serve.first_token_wait", slot=b):
+                first = int(
+                    sample(
+                        last[None, :],
+                        jax.random.fold_in(self._base_key, self._step_counter),
+                        self.gen_config.sampling,
+                    )[0]
                 )
-            self.pool.register_prefix(slot.prompt, slot.blocks)
-            slot.t_first = time.perf_counter()
-            slot.generated = [first]
-            if slot.prefill_only:
-                # disaggregated fleet: the prompt's block rows leave for a
-                # decode replica — extract BEFORE _terminate decrefs the
-                # blocks (contents survive until reuse, but extraction from
-                # owned blocks is the contract the transfer relies on)
-                k, v = paged.extract_blocks(self._pool, slot.blocks)
-                self._stash_prefill_payload(slot.request_id, {
-                    "first_token": first,
-                    "prompt_len": p,
-                    "kv": {"k": k, "v": v},
-                    # host-side only: the /prefill handler parents its
-                    # kv_send span under this request's root
-                    "trace": slot.trace,
-                })
-                done.append(self._terminate(b, "prefilled"))
-                continue
-            slot.decoding = True
-            self._cur[b] = first
-            self._active[b] = True
-            self._lengths[b] = p
-            if first in self._eos:
-                done.append(self._terminate(b, "stop"))
-            elif slot.max_new <= 1:
-                done.append(self._terminate(b, "length"))
+            # publish the prompt blocks to the prefix cache, flip to decode
+            self.step_phase = "record"
+            with TraceAnnotation("serve.record"):
+                if slot.logprobs is not None:
+                    # same raw-logits rule as the decode program (the chunk
+                    # already handed `last` to the host, so this is free)
+                    slot.logprobs.append(
+                        float(jax.nn.log_softmax(last.astype(jnp.float32))[first])
+                    )
+                self.pool.register_prefix(slot.prompt, slot.blocks)
+                slot.t_first = time.perf_counter()
+                slot.generated = [first]
+                if slot.prefill_only:
+                    # disaggregated fleet: the prompt's block rows leave for
+                    # a decode replica — extract BEFORE _terminate decrefs
+                    # the blocks (contents survive until reuse, but
+                    # extraction from owned blocks is the contract the
+                    # transfer relies on)
+                    k, v = paged.extract_blocks(self._pool, slot.blocks)
+                    self._stash_prefill_payload(slot.request_id, {
+                        "first_token": first,
+                        "prompt_len": p,
+                        "kv": {"k": k, "v": v},
+                        # host-side only: the /prefill handler parents its
+                        # kv_send span under this request's root
+                        "trace": slot.trace,
+                    })
+                    done.append(self._terminate(b, "prefilled"))
+                    continue
+                slot.decoding = True
+                self._cur[b] = first
+                self._active[b] = True
+                self._lengths[b] = p
+                if first in self._eos:
+                    done.append(self._terminate(b, "stop"))
+                elif slot.max_new <= 1:
+                    done.append(self._terminate(b, "length"))
         return done
 
     def _decode_tick(self) -> list[dict]:
@@ -2262,30 +2293,43 @@ class ServingEngine:
                 jnp.asarray(self._cur), jnp.asarray(self._active),
                 self._base_key, jnp.int32(self._step_counter),
             )
-        tokens, logps, self._pool = self._decode(
-            params, self._pool,
-            jnp.asarray(self._tables), jnp.asarray(self._lengths),
-            jnp.asarray(self._cur), jnp.asarray(self._active),
-            self._base_key, jnp.int32(self._step_counter),
-        )
-        tokens = np.asarray(jax.device_get(tokens))
-        logps = np.asarray(jax.device_get(logps))
+        self._note_decode_wave()
+        self.step_phase = "decode_dispatch"
+        with TraceAnnotation("serve.decode_dispatch"):
+            tokens, logps, self._pool = self._decode(
+                params, self._pool,
+                jnp.asarray(self._tables), jnp.asarray(self._lengths),
+                jnp.asarray(self._cur), jnp.asarray(self._active),
+                self._base_key, jnp.int32(self._step_counter),
+            )
+        self.step_phase = "decode_wait"
+        with TraceAnnotation("serve.decode_wait"):
+            tokens = np.asarray(jax.device_get(tokens))
+            logps = np.asarray(jax.device_get(logps))
         self.first_decode_done = True
         done: list[dict] = []
-        for b, slot in enumerate(self._slots):
-            if slot is None or not self._active[b]:
-                continue
-            tok = int(tokens[b])
-            slot.generated.append(tok)
-            if slot.logprobs is not None:
-                slot.logprobs.append(float(logps[b]))
-            self._lengths[b] += 1
-            self._cur[b] = tok
-            if tok in self._eos:
-                done.append(self._terminate(b, "stop"))
-            elif len(slot.generated) >= slot.max_new:
-                done.append(self._terminate(b, "length"))
+        self.step_phase = "record"
+        with TraceAnnotation("serve.record"):
+            for b, slot in enumerate(self._slots):
+                if slot is None or not self._active[b]:
+                    continue
+                tok = int(tokens[b])
+                slot.generated.append(tok)
+                if slot.logprobs is not None:
+                    slot.logprobs.append(float(logps[b]))
+                self._lengths[b] += 1
+                self._cur[b] = tok
+                if tok in self._eos:
+                    done.append(self._terminate(b, "stop"))
+                elif len(slot.generated) >= slot.max_new:
+                    done.append(self._terminate(b, "length"))
         return done
+
+    def _note_decode_wave(self) -> None:
+        """What the decode program is about to read, for `serve.counts`:
+        the active slots and the context tokens their attention covers."""
+        self._n_decoded = int(self._active.sum())
+        self._n_context_tokens = int(self._lengths[self._active].sum())
 
     def _spec_decode_tick(self) -> list[dict]:
         """One speculative round for the whole decode wave: the draft
@@ -2301,28 +2345,43 @@ class ServingEngine:
         cur = jnp.asarray(self._cur)
         active = jnp.asarray(self._active)
         step = jnp.int32(self._step_counter)
+        self._note_decode_wave()
         t_propose0 = time.perf_counter()
-        drafts, draft_logits, self._draft_pool = self._propose(
-            self.draft_auto.params, self._draft_pool,
-            tables, lengths, cur, active, self._base_key, step,
-        )
+        self.step_phase = "spec_propose"
+        with TraceAnnotation("serve.spec_propose"):
+            drafts, draft_logits, self._draft_pool = self._propose(
+                self.draft_auto.params, self._draft_pool,
+                tables, lengths, cur, active, self._base_key, step,
+            )
         t_verify0 = time.perf_counter()
-        if self.collect_program_costs and "spec_verify" not in self.program_costs:
-            self._record_cost(
-                "spec_verify", self._verify,
+        self.step_phase = "spec_verify"
+        with TraceAnnotation("serve.spec_verify"):
+            if self.collect_program_costs and "spec_verify" not in self.program_costs:
+                self._record_cost(
+                    "spec_verify", self._verify,
+                    self.auto.params, self._pool, tables, lengths, cur,
+                    drafts, draft_logits, active, self._base_key, step,
+                )
+            tokens, n_commit, self._pool = self._verify(
                 self.auto.params, self._pool, tables, lengths, cur,
                 drafts, draft_logits, active, self._base_key, step,
             )
-        tokens, n_commit, self._pool = self._verify(
-            self.auto.params, self._pool, tables, lengths, cur,
-            drafts, draft_logits, active, self._base_key, step,
-        )
-        tokens = np.asarray(jax.device_get(tokens))
-        n_commit = np.asarray(jax.device_get(n_commit))
+            tokens = np.asarray(jax.device_get(tokens))
+            n_commit = np.asarray(jax.device_get(n_commit))
         t_wave_end = time.perf_counter()
         self.first_decode_done = True
         self.spec_rounds += 1  # one propose+verify round per WAVE, not per slot
         done: list[dict] = []
+        self.step_phase = "record"
+        with TraceAnnotation("serve.record"):
+            self._record_spec_wave(
+                done, tokens, n_commit, k, t_propose0, t_verify0, t_wave_end
+            )
+        return done
+
+    def _record_spec_wave(
+        self, done, tokens, n_commit, k, t_propose0, t_verify0, t_wave_end
+    ) -> None:
         for b, slot in enumerate(self._slots):
             if slot is None or not self._active[b]:
                 continue
@@ -2362,7 +2421,6 @@ class ServingEngine:
             self._cur[b] = slot.generated[-1]
             if reason is not None:
                 done.append(self._terminate(b, reason))
-        return done
 
     def _rebuild(self, reason: str, detail: Optional[str] = None) -> list[dict]:
         """Recover from a stalled or failed program: fail the affected
@@ -2464,7 +2522,29 @@ class ServingEngine:
 
     def step(self) -> list[dict]:
         """One scheduler iteration → the requests that reached a terminal
-        state in it (every record carries a ``completion_reason``)."""
+        state in it (every record carries a ``completion_reason``).
+
+        On the profiler's clock the iteration is one ``serve.step`` span;
+        its phases (admit, each chunk's dispatch, the first-token wait, the
+        decode dispatch, the decode wait, the records) are its children,
+        and ``serve.counts`` closes it with the iteration's integers."""
+        with TraceAnnotation(
+            "serve.step", step=self._step_counter,
+            queued=len(self._queue), busy=self.busy_slots,
+        ):
+            self._n_admitted = self._n_chunks = 0
+            self._n_decoded = self._n_context_tokens = 0
+            done = self._iterate()
+            self.step_phase = None
+            with TraceAnnotation(
+                "serve.counts", admitted=self._n_admitted,
+                chunks=self._n_chunks, decoded=self._n_decoded,
+                context_tokens=self._n_context_tokens, finished=len(done),
+            ):
+                pass
+        return done
+
+    def _iterate(self) -> list[dict]:
         if self._watchdog is not None:
             self._watchdog.pet(self._step_counter)
             if not self.first_decode_done:
@@ -2536,7 +2616,8 @@ class ServingEngine:
                 "engine_stall",
                 detail=(
                     f"no step-boundary heartbeat for {ev.get('heartbeat_age_s')}s "
-                    f"(deadline {ev.get('deadline_s')}s)"
+                    f"(deadline {ev.get('deadline_s')}s) in phase "
+                    f"{ev.get('step_phase')}"
                 ),
             )
         if not rebuilt:

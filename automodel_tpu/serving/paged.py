@@ -159,11 +159,12 @@ def _gather_side(side, tables: jnp.ndarray, dtype) -> jnp.ndarray:
 def _scatter_rows(side, rows: jnp.ndarray, blk: jnp.ndarray, off: jnp.ndarray):
     """Scatter written token rows [L, B, S, Nkv, H] back into one pool side
     at (blk, off) [B, S] — quantize-on-write when the side is int8."""
-    if isinstance(side, tuple):
-        vals, scales = side
-        q, s = quantize_kv_rows(rows)
-        return (vals.at[:, blk, off].set(q), scales.at[:, blk, off].set(s))
-    return side.at[:, blk, off].set(rows.astype(side.dtype))
+    with jax.named_scope("kv_write"):
+        if isinstance(side, tuple):
+            vals, scales = side
+            q, s = quantize_kv_rows(rows)
+            return (vals.at[:, blk, off].set(q), scales.at[:, blk, off].set(s))
+        return side.at[:, blk, off].set(rows.astype(side.dtype))
 
 
 def _scatter_table(side, new: jnp.ndarray, table: jnp.ndarray):
@@ -172,11 +173,12 @@ def _scatter_table(side, new: jnp.ndarray, table: jnp.ndarray):
     blocks rewrite their own bytes (quantize∘dequantize is idempotent, so
     int8 prefix blocks are bit-identical); padded table entries write to
     scratch block 0."""
-    if isinstance(side, tuple):
-        vals, scales = side
-        q, s = quantize_kv_rows(new)
-        return (vals.at[:, table].set(q), scales.at[:, table].set(s))
-    return side.at[:, table].set(new.astype(side.dtype))
+    with jax.named_scope("kv_write"):
+        if isinstance(side, tuple):
+            vals, scales = side
+            q, s = quantize_kv_rows(new)
+            return (vals.at[:, table].set(q), scales.at[:, table].set(s))
+        return side.at[:, table].set(new.astype(side.dtype))
 
 
 # gather scatter-back targets resolve through the SAME helper the fused
@@ -349,24 +351,27 @@ def _gather_forward(
     NBseq = tables.shape[1]
     BS = pool.values_shape[2]
     lengths = lengths.astype(jnp.int32)
-    view = kv_cache.KVCache(
-        k=_gather_side(pool.k, tables, compute_dtype),
-        v=_gather_side(pool.v, tables, compute_dtype),
-        pos=jnp.full((B, NBseq * BS), -1, jnp.int32),  # chunk_ctx retags
-        lengths=jnp.zeros((B,), jnp.int32),
-    )
-    kvc, ctx = kv_cache.chunk_ctx(
-        view, S, lengths, jnp.where(active, S, 0).astype(jnp.int32)
-    )
-    positions = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    with jax.named_scope("attn"):  # the view attention reads, its plan, positions
+        view = kv_cache.KVCache(
+            k=_gather_side(pool.k, tables, compute_dtype),
+            v=_gather_side(pool.v, tables, compute_dtype),
+            pos=jnp.full((B, NBseq * BS), -1, jnp.int32),  # chunk_ctx retags
+            lengths=jnp.zeros((B,), jnp.int32),
+        )
+        kvc, ctx = kv_cache.chunk_ctx(
+            view, S, lengths, jnp.where(active, S, 0).astype(jnp.int32)
+        )
+        positions = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     primary, new_view = apply(
         params, tokens, position_ids=positions, cache=(kvc, ctx)
     )
-    logits = _logits_of(primary).astype(jnp.float32)
-    b_idx = jnp.arange(B)
-    rows_k = new_view.k[:, b_idx[:, None], positions]  # [L, B, S, Nkv, H]
-    rows_v = new_view.v[:, b_idx[:, None], positions]
-    blk, off = _write_targets(tables, lengths, S, active, block_size)
+    with jax.named_scope("lm_head"):
+        logits = _logits_of(primary).astype(jnp.float32)
+    with jax.named_scope("kv_write"):
+        b_idx = jnp.arange(B)
+        rows_k = new_view.k[:, b_idx[:, None], positions]  # [L, B, S, Nkv, H]
+        rows_v = new_view.v[:, b_idx[:, None], positions]
+        blk, off = _write_targets(tables, lengths, S, active, block_size)
     return logits, PagedKV(
         k=_scatter_rows(pool.k, rows_k, blk, off),
         v=_scatter_rows(pool.v, rows_v, blk, off),
@@ -387,16 +392,18 @@ def _fused_forward(
         k=pool.k, v=pool.v,
         pos=jnp.zeros((B, 1), jnp.int32), lengths=lengths,
     )
-    kvc, ctx = kv_cache.paged_ctx(
-        kvc, tables, lengths, S, active, block_size, interpret=interpret
-    )
-    positions = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    with jax.named_scope("kv_write"):  # the write targets
+        kvc, ctx = kv_cache.paged_ctx(
+            kvc, tables, lengths, S, active, block_size, interpret=interpret
+        )
+    with jax.named_scope("attn"):
+        positions = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     primary, new_kvc = apply(
         params, tokens, position_ids=positions, cache=(kvc, ctx)
     )
-    return _logits_of(primary).astype(jnp.float32), PagedKV(
-        k=new_kvc.k, v=new_kvc.v
-    )
+    with jax.named_scope("lm_head"):
+        logits = _logits_of(primary).astype(jnp.float32)
+    return logits, PagedKV(k=new_kvc.k, v=new_kvc.v)
 
 
 def _make_forward(
@@ -436,26 +443,29 @@ def build_chunk_prefill_fn(
             pool.k.dtype if not pool.quantized else jnp.bfloat16
         )
         tables = table[None, :]
-        view = kv_cache.KVCache(
-            k=_gather_side(pool.k, tables, cd),
-            v=_gather_side(pool.v, tables, cd),
-            pos=jnp.full((1, NBseq * BS), -1, jnp.int32),
-            lengths=jnp.zeros((1,), jnp.int32),
-        )
-        kvc, ctx = kv_cache.chunk_ctx(
-            view, chunk_len, start[None].astype(jnp.int32),
-            real_len[None].astype(jnp.int32),
-        )
-        positions = (
-            start.astype(jnp.int32) + jnp.arange(chunk_len, dtype=jnp.int32)
-        )[None, :]
+        with jax.named_scope("attn"):  # the view attention reads, its plan, positions
+            view = kv_cache.KVCache(
+                k=_gather_side(pool.k, tables, cd),
+                v=_gather_side(pool.v, tables, cd),
+                pos=jnp.full((1, NBseq * BS), -1, jnp.int32),
+                lengths=jnp.zeros((1,), jnp.int32),
+            )
+            kvc, ctx = kv_cache.chunk_ctx(
+                view, chunk_len, start[None].astype(jnp.int32),
+                real_len[None].astype(jnp.int32),
+            )
+            positions = (
+                start.astype(jnp.int32) + jnp.arange(chunk_len, dtype=jnp.int32)
+            )[None, :]
         primary, new_view = apply(
             params, chunk_ids[None, :], position_ids=positions, cache=(kvc, ctx)
         )
-        logits = _logits_of(primary)[0].astype(jnp.float32)  # [chunk_len, V]
-        last = logits[real_len - 1]
-        newk = new_view.k.reshape(L, NBseq, BS, Nkv, H)
-        newv = new_view.v.reshape(L, NBseq, BS, Nkv, H)
+        with jax.named_scope("lm_head"):
+            logits = _logits_of(primary)[0].astype(jnp.float32)  # [chunk_len, V]
+            last = logits[real_len - 1]
+        with jax.named_scope("kv_write"):
+            newk = new_view.k.reshape(L, NBseq, BS, Nkv, H)
+            newv = new_view.v.reshape(L, NBseq, BS, Nkv, H)
         return last, PagedKV(
             k=_scatter_table(pool.k, newk, table),
             v=_scatter_table(pool.v, newv, table),
@@ -494,15 +504,16 @@ def build_paged_decode_fn(
         logits, pool = forward(
             params, pool, tables, lengths, cur[:, None], active
         )
-        skey = jax.random.fold_in(key, step_idx)
-        if with_logprobs:
-            nxt, logp = sample_with_logprobs(logits[:, -1], skey, sampling)
+        with jax.named_scope("sample"):
+            skey = jax.random.fold_in(key, step_idx)
+            if with_logprobs:
+                nxt, logp = sample_with_logprobs(logits[:, -1], skey, sampling)
+                nxt = jnp.where(active, nxt, jnp.int32(pad_id))
+                logp = jnp.where(active, logp, jnp.float32(0.0))
+                return nxt, logp, pool
+            nxt = sample(logits[:, -1], skey, sampling)
             nxt = jnp.where(active, nxt, jnp.int32(pad_id))
-            logp = jnp.where(active, logp, jnp.float32(0.0))
-            return nxt, logp, pool
-        nxt = sample(logits[:, -1], skey, sampling)
-        nxt = jnp.where(active, nxt, jnp.int32(pad_id))
-        return nxt, pool
+            return nxt, pool
 
     return step
 
@@ -538,8 +549,9 @@ def build_draft_propose_fn(
                 params, pool, tables, length, c[:, None], active
             )
             lg = logits[:, -1]
-            nxt = sample(lg, jax.random.fold_in(kstep, 1 + i), sampling)
-            nxt = jnp.where(active, nxt, jnp.int32(pad_id))
+            with jax.named_scope("sample"):
+                nxt = sample(lg, jax.random.fold_in(kstep, 1 + i), sampling)
+                nxt = jnp.where(active, nxt, jnp.int32(pad_id))
             toks.append(nxt)
             logs.append(lg)
             length = length + 1
@@ -577,10 +589,13 @@ def build_verify_fn(
     ):
         fed = jnp.concatenate([cur[:, None], drafts], axis=1)  # [B, k+1]
         logits, pool = forward(params, pool, tables, lengths, fed, active)
-        kstep = jax.random.fold_in(jax.random.fold_in(key, step_idx), 0)
-        toks, n = speculative_verify(logits, draft_logits, drafts, kstep, sampling)
-        n = jnp.where(active, n, 0).astype(jnp.int32)
-        toks = jnp.where(active[:, None], toks, jnp.int32(pad_id))
+        with jax.named_scope("sample"):
+            kstep = jax.random.fold_in(jax.random.fold_in(key, step_idx), 0)
+            toks, n = speculative_verify(
+                logits, draft_logits, drafts, kstep, sampling
+            )
+            n = jnp.where(active, n, 0).astype(jnp.int32)
+            toks = jnp.where(active[:, None], toks, jnp.int32(pad_id))
         return toks, n, pool
 
     return verify

@@ -27,17 +27,29 @@ _EVENT_SUFFIXES = (
     "jaxpr_to_mlir_module_duration",
 )
 
+# a jit call that had to trace again (new shapes, a new closure) announces it
+# here even when the persistent cache then spares the backend compile: counted
+# apart, so a retrace that hits the cache is visible and `compile_secs` keeps
+# meaning what it meant
+_TRACE_EVENT_SUFFIX = "jaxpr_trace_duration"
+
 _CACHE_EVENTS = {
     "/jax/compilation_cache/cache_hits": "cache_hits",
     "/jax/compilation_cache/cache_misses": "cache_misses",
 }
 
 _lock = threading.Lock()
-_totals = {"count": 0, "secs": 0.0, "cache_hits": 0, "cache_misses": 0}
+_totals = {"count": 0, "secs": 0.0, "cache_hits": 0, "cache_misses": 0,
+           "traces": 0, "trace_secs": 0.0}
 _registered = False
 
 
 def _listener(event: str, duration_secs: float, **kwargs) -> None:
+    if event.endswith(_TRACE_EVENT_SUFFIX):
+        with _lock:
+            _totals["traces"] += 1
+            _totals["trace_secs"] += float(duration_secs)
+        return
     if not event.endswith(_EVENT_SUFFIXES):
         return
     with _lock:
@@ -69,8 +81,10 @@ def _ensure_registered() -> None:
 
 def compile_totals() -> dict[str, float] | None:
     """Process totals since the listeners were registered — compiles, the
-    seconds they took (a persistent-cache hit costs its retrieval only) and
-    how many were hits or misses of that cache. None when nothing in this
+    seconds they took (a persistent-cache hit costs its retrieval only), how
+    many were hits or misses of that cache, and how many times a function
+    was traced to a jaxpr (every compile starts with one; so does a retrace
+    whose executable then comes from the cache). None when nothing in this
     process registered them (no device command ran)."""
     with _lock:
         if not _registered:
@@ -80,6 +94,8 @@ def compile_totals() -> dict[str, float] | None:
             "compile_secs": round(_totals["secs"], 3),
             "cache_hits": _totals["cache_hits"],
             "cache_misses": _totals["cache_misses"],
+            "traces": _totals["traces"],
+            "trace_secs": round(_totals["trace_secs"], 3),
         }
 
 

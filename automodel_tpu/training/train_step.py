@@ -147,33 +147,37 @@ def build_train_step(
             )
             extras_sum = extras
         else:
-            grads0 = jax.tree.map(
-                lambda p: jnp.zeros_like(p, dtype=jnp.float32), state.params
-            )
-            carry0 = (grads0, jnp.float32(0.0), jnp.int32(0))
-
-            def body(carry, mb_and_i):
-                mb, mb_i = mb_and_i
-                g_acc, l_acc, n_acc = carry
-                (loss_sum, (n, extras)), grads = mb_value_and_grad(
-                    state.params, mb, bound, state.step, mb_i
+            # the model's own scopes sit inside this one and win; what is
+            # left under `grad_accum` is the loop's bookkeeping
+            with jax.named_scope("grad_accum"):
+                grads0 = jax.tree.map(
+                    lambda p: jnp.zeros_like(p, dtype=jnp.float32), state.params
                 )
-                g_acc = jax.tree.map(
-                    lambda a, g: a + g.astype(jnp.float32), g_acc, grads
-                )
-                return (g_acc, l_acc + loss_sum, n_acc + n), extras
+                carry0 = (grads0, jnp.float32(0.0), jnp.int32(0))
 
-            (grads, loss_sum, n_tokens), extras_stacked = jax.lax.scan(
-                body, carry0, (batch, jnp.arange(n_mb, dtype=jnp.int32))
-            )
-            extras_sum = jax.tree.map(lambda x: x.sum(axis=0), extras_stacked)
+                def body(carry, mb_and_i):
+                    mb, mb_i = mb_and_i
+                    g_acc, l_acc, n_acc = carry
+                    (loss_sum, (n, extras)), grads = mb_value_and_grad(
+                        state.params, mb, bound, state.step, mb_i
+                    )
+                    g_acc = jax.tree.map(
+                        lambda a, g: a + g.astype(jnp.float32), g_acc, grads
+                    )
+                    return (g_acc, l_acc + loss_sum, n_acc + n), extras
+
+                (grads, loss_sum, n_tokens), extras_stacked = jax.lax.scan(
+                    body, carry0, (batch, jnp.arange(n_mb, dtype=jnp.int32))
+                )
+                extras_sum = jax.tree.map(lambda x: x.sum(axis=0), extras_stacked)
         denom = jnp.maximum(n_tokens, 1).astype(jnp.float32)
         # divide in fp32 even for bf16 grads (a bf16-rounded token count is
         # off by up to 0.4%); the convert/divide/convert fuses — no
         # materialized fp32 copy
-        grads = jax.tree.map(
-            lambda g: (g.astype(jnp.float32) / denom).astype(g.dtype), grads
-        )
+        with jax.named_scope("grad_accum"):
+            grads = jax.tree.map(
+                lambda g: (g.astype(jnp.float32) / denom).astype(g.dtype), grads
+            )
         if nan_grads_at_step is not None:
             poison = jnp.where(
                 state.step + 1 == nan_grads_at_step, jnp.float32(jnp.nan), 0.0
@@ -181,15 +185,19 @@ def build_train_step(
             grads = jax.tree.map(lambda g: g + poison.astype(g.dtype), grads)
         from automodel_tpu.optim.builders import global_norm_fp32
 
-        grad_norm = global_norm_fp32(grads)
-        updates, new_opt_state = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        # keep params in their original dtype (apply_updates may upcast)
-        new_params = jax.tree.map(
-            lambda new, old: new.astype(old.dtype), new_params, state.params
-        )
-        if post_step_fn is not None:
-            new_params = post_step_fn(new_params, extras_sum)
+        with jax.named_scope("grad_clip"):
+            grad_norm = global_norm_fp32(grads)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
+            # keep params in their original dtype (apply_updates may upcast)
+            new_params = jax.tree.map(
+                lambda new, old: new.astype(old.dtype), new_params, state.params
+            )
+            if post_step_fn is not None:
+                new_params = post_step_fn(new_params, extras_sum)
         metrics = {
             "loss": loss_sum / denom,
             "grad_norm": grad_norm,
@@ -199,28 +207,32 @@ def build_train_step(
         if anomaly_flags:
             from automodel_tpu.telemetry.anomaly import anomaly_metrics
 
-            metrics.update(anomaly_metrics(loss_sum, grads))
+            with jax.named_scope("anomaly"):
+                metrics.update(anomaly_metrics(loss_sum, grads))
         elif on_nonfinite != "raise" or nan_grads_at_step is not None:
             # the host-side policies need the flag even with the full
             # anomaly reductions disabled
             from automodel_tpu.telemetry.anomaly import nonfinite_count
 
-            metrics["nonfinite"] = ~jnp.isfinite(loss_sum) | (
-                nonfinite_count(grads) > 0
-            )
+            with jax.named_scope("anomaly"):
+                metrics["nonfinite"] = ~jnp.isfinite(loss_sum) | (
+                    nonfinite_count(grads) > 0
+                )
         if on_nonfinite == "skip":
             bad = metrics["nonfinite"]
             # carry params AND opt-state through unchanged (bit-identical:
             # jnp.where with a scalar pred selects whole buffers) — the NaN
             # never reaches the weights or the Adam moments
-            new_params = jax.tree.map(
-                lambda new, old: jnp.where(bad, old, new), new_params, state.params
-            )
-            new_opt_state = jax.tree.map(
-                lambda new, old: jnp.where(bad, old, new),
-                new_opt_state,
-                state.opt_state,
-            )
+            with jax.named_scope("optimizer"):
+                new_params = jax.tree.map(
+                    lambda new, old: jnp.where(bad, old, new),
+                    new_params, state.params,
+                )
+                new_opt_state = jax.tree.map(
+                    lambda new, old: jnp.where(bad, old, new),
+                    new_opt_state,
+                    state.opt_state,
+                )
             metrics["skipped"] = bad
         if "moe_aux_loss" in extras_sum:
             metrics["moe_aux_loss"] = extras_sum["moe_aux_loss"] / batch_size(batch)
@@ -319,22 +331,24 @@ def make_causal_lm_loss(
         if loss in ("fused_linear_ce", "vocab_parallel_ce"):
             out = model.hidden(params, mb["input_ids"], constrain=constrain, **kw)
             hidden, maux = out if isinstance(out, tuple) else (out, None)
-            kernel = model.lm_head(params).astype(hidden.dtype)
             mesh_ctx = getattr(constrain, "mesh_ctx", None)
-            if loss == "vocab_parallel_ce" and mesh_ctx is not None:
-                loss_sum, n = L.vocab_parallel_cross_entropy(
-                    hidden, kernel, mb["labels"], mesh_ctx,
-                    logits_soft_cap=model.config.logits_soft_cap, **loss_kwargs,
-                )
-            else:
-                loss_sum, n = L.fused_linear_cross_entropy(
-                    hidden, kernel, mb["labels"],
-                    logits_soft_cap=model.config.logits_soft_cap, **loss_kwargs,
-                )
+            with jax.named_scope("lm_head_ce"):  # head and loss, chunk scan included
+                kernel = model.lm_head(params).astype(hidden.dtype)
+                if loss == "vocab_parallel_ce" and mesh_ctx is not None:
+                    loss_sum, n = L.vocab_parallel_cross_entropy(
+                        hidden, kernel, mb["labels"], mesh_ctx,
+                        logits_soft_cap=model.config.logits_soft_cap, **loss_kwargs,
+                    )
+                else:
+                    loss_sum, n = L.fused_linear_cross_entropy(
+                        hidden, kernel, mb["labels"],
+                        logits_soft_cap=model.config.logits_soft_cap, **loss_kwargs,
+                    )
         else:
             out = model(params, mb["input_ids"], constrain=constrain, **kw)
             logits, maux = out if isinstance(out, tuple) else (out, None)
-            loss_sum, n = L.build_loss(loss, **loss_kwargs)(logits, mb["labels"])
+            with jax.named_scope("lm_head_ce"):
+                loss_sum, n = L.build_loss(loss, **loss_kwargs)(logits, mb["labels"])
         if maux is None:
             return loss_sum, n
         # MoE models return (output, aux). The aux loss is a per-batch mean;

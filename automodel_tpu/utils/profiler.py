@@ -74,4 +74,17 @@ class StepProfiler:
             self._active = False
 
 
-annotate = jax.named_scope  # model-code annotation (NVTX range equivalent)
+# The scope vocabulary of the two jitted programs (train step; serve chunk and
+# decode). Each name is a `jax.named_scope` at the place the work happens
+# ("a/b" = scope `b` inside scope `a`), so it lands in every op's name path,
+# which a device trace carries as the op's `tf_op` stat: a trace viewer groups
+# by it, and benchmarks/harness/program_trace.py (which holds its own copy; a
+# test keeps the two equal) gives each op to the innermost name in its path.
+# Metadata only: the compiled program is the same with or without them.
+# docs/observability.md says what each covers.
+SCOPES = (
+    "embed", "layers", "norm", "attn", "kv_write",
+    "moe/router", "moe/dispatch", "moe/experts", "moe/combine", "mlp",
+    "final_norm", "lm_head_ce", "lm_head", "sample",
+    "grad_accum", "grad_clip", "anomaly", "optimizer",
+)

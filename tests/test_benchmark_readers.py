@@ -1,0 +1,24 @@
+"""Tier-1 runs the benchmark's program-trace reader tests too.
+
+``benchmarks/tests`` is the harness's own suite and is not collected by
+``pytest tests/``; the reader there is the other half of the spans and scopes
+this repository's program writes (tests/test_step_spans.py,
+tests/test_step_scopes.py), so its tests are run from here as well, from the
+file they live in.
+"""
+
+import importlib.util
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+_spec = importlib.util.spec_from_file_location(
+    "benchmarks_tests_program_trace", ROOT / "benchmarks" / "tests" / "test_program_trace.py")
+_module = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_module)
+# the tests and the fixtures they ask for, under the names pytest looks for
+globals().update({k: v for k, v in vars(_module).items()
+                  if k.startswith("test_") or k in ("train_xplane", "train_ops", "serve_xplane")})

@@ -1,0 +1,210 @@
+"""The phase spans inside ``ServingEngine.step`` and the train input path, read
+back from a REAL ``jax.profiler`` trace (one trace for the whole file; nothing
+here depends on how long anything took).
+
+Ground truth is the engine's own state: every iteration is driven through a
+recorder that notes what ``step()`` returned, the arrays the decode program was
+handed, and ``_lengths``/``_active`` afterwards; ``serve.counts`` must say the
+same. The reader is the benchmark's (``benchmarks/harness/program_trace.py``),
+so the pair is tested together.
+"""
+
+import sys
+import time
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from automodel_tpu.auto_model import AutoModel
+from automodel_tpu.generation.engine import GenerationConfig
+from automodel_tpu.models.common.config import BackendConfig, TransformerConfig
+from automodel_tpu.serving.engine import ServeConfig, ServingEngine, StallConfig
+from benchmarks.harness import program_trace, trace
+
+FP32 = BackendConfig(attn="sdpa", param_dtype="float32", compute_dtype="float32")
+PHASES = ("serve.admit", "serve.prefill_dispatch", "serve.first_token_wait",
+          "serve.decode_dispatch", "serve.decode_wait", "serve.record", "serve.counts")
+
+
+def _tiny_auto():
+    from automodel_tpu.models.llama import LlamaForCausalLM
+
+    model = LlamaForCausalLM(
+        TransformerConfig(vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=2,
+                          num_heads=4, num_kv_heads=2, head_dim=8),
+        FP32,
+    )
+    return AutoModel(model=model, params=model.init(jax.random.key(0)), adapter=None,
+                     mesh_ctx=None)
+
+
+def _engine(**serve_over):
+    return ServingEngine(
+        _tiny_auto(),
+        ServeConfig(slots=3, block_size=4, num_blocks=64, prefill_chunk=4, max_seq_len=48,
+                    **serve_over),
+        GenerationConfig(max_new_tokens=6, greedy=True),
+    )
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """Five requests (prompts of 3 to 11 tokens: one to three chunks each; one
+    asks for a single token and ends at its prefill) through a three-slot
+    engine, and two input groups through the train recipe's collate/place,
+    under one trace."""
+    from automodel_tpu.recipes.train_ft import TrainFinetuneRecipeForNextTokenPrediction
+
+    eng = _engine()
+    eng.submit([5, 6, 7], request_id="warm", max_new_tokens=3)
+    eng.run()  # both programs compiled before the trace
+
+    recipe = TrainFinetuneRecipeForNextTokenPrediction.__new__(
+        TrainFinetuneRecipeForNextTokenPrediction)
+    recipe._zigzag_cp = 0
+    recipe.mesh_ctx = None
+    group = [{"input_ids": np.ones((2, 8), np.int32), "labels": np.ones((2, 8), np.int32)}]
+
+    rows = []
+    real_decode = eng._decode
+
+    def spy(params, pool, tables, lengths, cur, active, key, step_idx):
+        rows[-1]["read"] = int(np.asarray(lengths)[np.asarray(active)].sum())
+        rows[-1]["wave"] = int(np.asarray(active).sum())
+        return real_decode(params, pool, tables, lengths, cur, active, key, step_idx)
+
+    eng._decode = spy
+    trace_dir = tmp_path_factory.mktemp("trace")
+    rng = np.random.default_rng(0)
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        for _ in range(2):
+            stacked, _ = recipe._prepare_group(group)
+            recipe._place_group(stacked)
+        for i, (n_prompt, n_new) in enumerate([(3, 6), (9, 4), (11, 1), (5, 6), (7, 3)]):
+            eng.submit(rng.integers(1, 64, size=n_prompt).tolist(), request_id=f"r{i}",
+                       max_new_tokens=n_new)
+        while not eng.idle():
+            rows.append({"step": eng._step_counter, "queued": eng.queue_depth,
+                         "busy": eng.busy_slots, "read": 0, "wave": 0})
+            done = eng.step()
+            rows[-1].update(done=done, after=int((eng._lengths * eng._active).sum()))
+    finally:
+        jax.profiler.stop_trace()
+    spans = program_trace.read_spans(trace.find_xplane(trace_dir))
+    return {"rows": rows, "spans": spans}
+
+
+def _steps(spans):
+    return [(i, s) for i, s in enumerate(spans) if s["name"] == "serve.step"]
+
+
+def test_every_phase_is_a_child_of_its_step(traced):
+    spans = traced["spans"]
+    steps = _steps(spans)
+    assert len(steps) == len(traced["rows"])
+    seen = set()
+    for sp in spans:
+        if sp["name"].startswith("serve.") and sp["name"] != "serve.step":
+            parent = spans[sp["parent"]]
+            assert parent["name"] == "serve.step", sp
+            assert parent["start_s"] <= sp["start_s"] and sp["end_s"] <= parent["end_s"]
+            seen.add(sp["name"])
+    assert seen == set(PHASES)
+    # one thread, in order, and counts is the last child of every step
+    for i, step in steps:
+        kids = program_trace.children_of(spans, i)
+        assert kids[-1]["name"] == "serve.counts"
+        assert all(a["end_s"] <= b["start_s"] for a, b in zip(kids, kids[1:]))
+
+
+def test_step_stats_are_the_engine_s_state_at_entry(traced):
+    for (_, sp), row in zip(_steps(traced["spans"]), traced["rows"]):
+        assert {k: int(sp["stats"][k]) for k in ("step", "queued", "busy")} == {
+            k: row[k] for k in ("step", "queued", "busy")}
+
+
+def test_counts_agree_with_the_records_and_the_arrays(traced):
+    spans = traced["spans"]
+    admitted = 0
+    for (i, _), row in zip(_steps(spans), traced["rows"]):
+        kids = program_trace.children_of(spans, i)
+        counts = {k: int(v) for k, v in kids[-1]["stats"].items()}
+        chunks = [k for k in kids if k["name"] == "serve.prefill_dispatch"]
+        assert counts["chunks"] == len(chunks)
+        assert all(0 < int(c["stats"]["tokens"]) <= 4 for c in chunks)
+        assert counts["finished"] == len(row["done"])
+        # what the decode program was handed
+        assert counts["decoded"] == row["wave"]
+        assert counts["context_tokens"] == row["read"]
+        assert (counts["decoded"] > 0) == any(k["name"] == "serve.decode_wait" for k in kids)
+        # the relation to an after-step sample of _lengths * _active (the
+        # harness's): the step added one token an active slot and freed the
+        # slots it finished; a request that ends at its decode has
+        # prompt + n_generated - 1 tokens in the cache when it is freed
+        freed = sum(r["prompt_tokens"] + r["n_generated"] - 1 for r in row["done"]
+                    if r["n_generated"] >= 2)
+        assert row["after"] == counts["context_tokens"] + counts["decoded"] - freed
+        admitted += counts["admitted"]
+    assert admitted == 5
+    first_waits = [s for s in spans if s["name"] == "serve.first_token_wait"]
+    assert len(first_waits) == 5  # one a prompt, after its last chunk
+
+
+def test_train_input_path_spans(traced):
+    names = [s["name"] for s in traced["spans"] if s["name"].startswith("train.")]
+    assert names.count("train.collate") == 2 and names.count("train.place") == 2
+
+
+def test_gap_goes_to_the_innermost_span_covering_it(traced):
+    spans = traced["spans"]
+    wait = next(s for s in spans if s["name"] == "serve.decode_wait")
+    step = spans[wait["parent"]]
+    mid = 0.5 * (wait["start_s"] + wait["end_s"])
+    rows = program_trace.gaps_by_span(
+        [(mid - 1e-7, mid + 1e-7), (step["start_s"] - 2.0, step["start_s"] - 1.0)], spans)
+    assert rows[0][1:] == ("serve.decode_wait", "serve.step")
+    assert rows[1][1:] == (None, None)
+
+
+def test_stall_evidence_names_the_phase(tmp_path):
+    """A decode program that does not come back: the watchdog's record and the
+    engine's event both say the iteration sat in ``decode_dispatch``."""
+    records = []
+    eng = _engine(watchdog=StallConfig(
+        min_deadline_s=0.2, max_deadline_s=0.5, multiplier=4.0, poll_interval_s=0.02,
+        compile_grace_s=60.0, stacks_path=str(tmp_path / "stacks.txt")))
+    eng.on_record = records.append
+    eng.submit([5, 6, 7], max_new_tokens=3)
+    eng.run()
+    assert eng.step_phase is None  # between iterations
+    wd = eng.start_watchdog()
+    try:
+        for _ in range(4):  # seed the deadline's average with real iterations
+            eng.submit([5, 6, 7], max_new_tokens=3)
+            eng.run()
+        real = eng._decode
+
+        def hung(*a):
+            time.sleep(1.2)
+            return real(*a)
+
+        eng._decode = hung
+        eng.submit([9, 8, 7], max_new_tokens=3)
+        done = []
+        while not eng.idle():
+            done += eng.step()
+            eng._decode = real
+        assert wd.fired is not None and wd.fired["step_phase"] == "decode_dispatch"
+        assert [r["completion_reason"] for r in done] == ["engine_stall"]
+        event = next(r for r in records if r.get("event") == "serve_engine_event")
+        assert "in phase decode_dispatch" in event["detail"]
+    finally:
+        eng.stop_watchdog()

@@ -224,6 +224,33 @@ def test_compile_bridge_counts_recompiles():
     assert CompileEventBridge().drain()["compiles"] == 0
 
 
+def test_compile_totals_count_traces_apart_from_compiles():
+    """A function traced again is counted under ``traces`` whether or not a
+    backend compile follows, and its seconds stay out of ``compile_secs``."""
+    from automodel_tpu.telemetry import compile_events
+
+    compile_events._ensure_registered()
+    before = compile_events.compile_totals()
+
+    @jax.jit
+    def g(x):
+        return x * 3 - 1
+
+    g(jnp.ones((5,)))
+    mid = compile_events.compile_totals()
+    assert mid["traces"] > before["traces"] and mid["trace_secs"] >= before["trace_secs"]
+    assert mid["compiles"] > before["compiles"]
+    g(jnp.ones((5,)))  # cached: neither traced nor compiled again
+    assert compile_events.compile_totals() == mid
+    # the listener itself: a trace event moves only the two new keys
+    compile_events._listener("/jax/core/compile/jaxpr_trace_duration", 0.25)
+    after = compile_events.compile_totals()
+    assert after["traces"] == mid["traces"] + 1
+    assert after["trace_secs"] == pytest.approx(mid["trace_secs"] + 0.25, abs=2e-3)
+    assert {k: after[k] for k in ("compiles", "compile_secs", "cache_hits", "cache_misses")} == {
+        k: mid[k] for k in ("compiles", "compile_secs", "cache_hits", "cache_misses")}
+
+
 # -- flight recorder (tentpole pillar 4) -------------------------------------
 
 def test_flight_recorder_crash_dump(tmp_path):
