@@ -22,17 +22,26 @@ backends:
   `ragged_dot` grouped matmul. Dropless by construction at the default
   capacity (per-peer worst case); `a2a_capacity_factor` bounds buffers for
   perf runs (over-capacity picks contribute zero, like the reference's
-  bounded dispatch buffers). TP is handled inside the manual region: gate/up
-  are pre-split so their tp shards align, down-proj partial sums ride the
-  combine all_to_all and a single psum("tp") happens at [T, D].
+  bounded dispatch buffers). TP is handled inside the manual region: with
+  tp > 1 gate/up are pre-split so their tp shards align (with one tp shard
+  the fused kernel takes the stored tensor whole), down-proj partial sums
+  ride the combine all_to_all and a single psum("tp") happens at [T, D].
 
 All backends take fused gate_up weights [E, D, 2I] and down [E, I, D];
 SwiGLU-family activation.
 
 Every backend runs inside moe_block's ``moe`` scope and names its three
 parts (utils/profiler.SCOPES): ``dispatch`` (sort, permute, exchange, the
-gate/up weight split and casts), ``experts`` (the grouped matmuls or the
-fused kernel), ``combine`` (unpermute, weighted sum).
+weight casts, and the gate/up weight split where one is still needed),
+``experts`` (the grouped matmuls or the fused kernel), ``combine``
+(unpermute, weighted sum).
+
+The fused-kernel backends hand ``gate_up`` to ops/fused_expert_mlp WHOLE:
+its kernels block the gate and the up half out of the stored [E, D, 2I]
+tensor by index map, so no per-call copy of the expert weights is made
+(`_fused_gate_up`). A copy remains only where no block index can express the
+read: gpt-oss's column interleave, tp > 1 (the halves' shards must align),
+and widths off the 128 grid, which the kernels pad anyway.
 """
 
 from __future__ import annotations
@@ -57,6 +66,18 @@ def _split_gate_up(gu: jnp.ndarray, interleaved: bool) -> tuple[jnp.ndarray, jnp
     if interleaved:  # gpt-oss checkpoints interleave gate/up on the last dim
         return gu[..., ::2], gu[..., 1::2]
     return jnp.split(gu, 2, axis=-1)
+
+
+def _fused_gate_up(
+    gate_up: jnp.ndarray, cfg: MoEConfig, whole: bool = True
+) -> tuple[jnp.ndarray, jnp.ndarray | None]:
+    """(gate, up) operands of ops.fused_expert_mlp from the stored fused
+    weight: ``(gate_up, None)`` — read in place, no copy — unless the
+    columns are interleaved or the caller needs two arrays (``whole=False``:
+    tp shards). Unaligned widths are the op's own fallback."""
+    if whole and not cfg.interleaved_gate_up:
+        return gate_up, None
+    return _split_gate_up(gate_up, cfg.interleaved_gate_up)
 
 
 def _ffn(
@@ -433,7 +454,10 @@ def a2a_experts(
     C = -(-cap // 8) * 8  # chunk rows per peer, padded for TPU layouts
 
     with jax.named_scope("dispatch"):
-        wd = _a2a_weights(weights, cfg)
+        wd = _a2a_weights(
+            weights, cfg,
+            whole=fused_act is not None and mesh.shape[A.TP] == 1,
+        )
 
     batch_axes = (A.DP_REPLICATE, A.DP_SHARD, A.EP)
     tok_spec = P(batch_axes, A.CP, None)
@@ -468,14 +492,18 @@ def a2a_experts(
     )(x, idx, cw, wd)
 
 
-def _a2a_weights(weights: dict, cfg: MoEConfig) -> dict:
+def _a2a_weights(weights: dict, cfg: MoEConfig, whole: bool = False) -> dict:
     """Per-shard weight dict for the a2a body. Gated experts pre-split
-    gate/up so their tp shards align; non-gated (nemotron relu2) experts
-    carry the single up projection as 'gw' and act2 ignores its second
-    operand (same convention as _ffn)."""
+    gate/up so their tp shards align; ``whole`` (the fused kernel, one tp
+    shard) carries the stored fused tensor as 'gw' with no 'uw' instead
+    (`_fused_gate_up`). Non-gated (nemotron relu2) experts carry the single
+    up projection as 'gw' and act2 ignores its second operand (same
+    convention as _ffn)."""
     if cfg.gated:
-        gw, uw = _split_gate_up(weights["gate_up"], cfg.interleaved_gate_up)
-        wd = {"gw": gw, "uw": uw, "dw": weights["down"]}
+        gw, uw = _fused_gate_up(weights["gate_up"], cfg, whole)
+        wd = {"gw": gw, "dw": weights["down"]}
+        if uw is not None:
+            wd["uw"] = uw
         if "gate_up_bias" in weights:
             wd["gb"], wd["ub"] = _split_gate_up(
                 weights["gate_up_bias"], cfg.interleaved_gate_up
@@ -559,7 +587,8 @@ def _a2a_body(xb, idxb, cwb, wd, *, ep, ep_axis, E, E_loc, C, D, K, act2,
             act_kind, limit = fused_act
             from automodel_tpu.ops.fused_expert_mlp import fused_expert_mlp
 
-            w_u = wd["uw"].astype(xs2.dtype)
+            # no 'uw': 'gw' is the fused [E_loc, D, 2I] weight, read in place
+            w_u = wd["uw"].astype(xs2.dtype) if "uw" in wd else None
             gb = wd["gb"].astype(xs2.dtype) if "gb" in wd else None
             ub = wd["ub"].astype(xs2.dtype) if "ub" in wd else None
             db = wd.get("db")
@@ -655,7 +684,7 @@ def a2a_experts_manual(
     C = -(-cap // 8) * 8
 
     with jax.named_scope("dispatch"):
-        wd = _a2a_weights(weights, cfg)
+        wd = _a2a_weights(weights, cfg, whole=fused_act is not None)
 
     idx = gate_out.topk_idx.reshape(Bl, Sl, K)
     cw = gate_out.topk_weights.reshape(Bl, Sl, K)
@@ -736,8 +765,10 @@ def ragged_fused_experts(
 ) -> jnp.ndarray:
     """ragged_experts with the WHOLE expert MLP in one Pallas kernel
     (ops/fused_expert_mlp): the [T·K, 2I] gate_up output and the [T·K, I]
-    activation never touch HBM. Same dropless sort + permutation-gather
-    dispatch/combine; backward recomputes through the two-gmm composition."""
+    activation never touch HBM, and the kernels read the gate and up halves
+    of the stored fused weight in place (`_fused_gate_up`). Same dropless
+    sort + permutation-gather dispatch/combine; backward through the
+    purpose-tiled kernels of the same module."""
     from automodel_tpu.ops.fused_expert_mlp import fused_expert_mlp
 
     act_kind, limit = _fused_act_of(cfg, act_name, fp8=False)
@@ -749,8 +780,9 @@ def ragged_fused_experts(
         inv = _name_ckpt(jnp.argsort(order), "moe_sort_inv")
         group_sizes = gate_out.expert_counts.astype(jnp.int32)
         xs = _dispatch_take(x, order, inv, K)
-        gw, uw = _split_gate_up(weights["gate_up"], cfg.interleaved_gate_up)
-        gw, uw = gw.astype(xs.dtype), uw.astype(xs.dtype)
+        gw, uw = _fused_gate_up(weights["gate_up"], cfg)
+        gw = gw.astype(xs.dtype)
+        uw = None if uw is None else uw.astype(xs.dtype)
         w_dn = weights["down"].astype(xs.dtype)
         gb = ub = db = None
         if "gate_up_bias" in weights:  # gpt-oss expert biases, per I-chunk in-kernel

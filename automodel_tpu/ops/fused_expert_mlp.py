@@ -29,6 +29,14 @@ preserved bit-for-bit — every row outside a work unit's window (boundary
 rows of the neighbouring group AND the a2a sentinel tail) is zeroed on the
 ``dout`` side in-kernel, where 0·NaN can no longer survive.
 
+Operand forms: gate and up as two [G, D, I] arrays, or ``up=None`` and
+``gate`` the stored fused [G, D, 2I] weight. The second costs no copy: the
+forward and all three backward kernels block both halves out of the one
+array (and g/u out of one [M, 2I] product) by a column-block offset in the
+up operand's index map (`_halves`, `_col_off`) — same blocks, same
+arithmetic, bit-equal results. A per-call ``jnp.split`` of the weight was
+43 % of every serve program at 256 experts x 3072 x 1536 (PERF.md, PR 26).
+
 Same dropless semantics and work-unit plan as ops/grouped_matmul (reference
 capability: the fused SwiGLU+GEMM epilogues TE/DeepEP provide on GPU).
 """
@@ -72,11 +80,13 @@ def _kernel(wg, wt, ws, we, lhs_ref, wg_ref, wu_ref, wd_ref, *rest,
     lmask = (rows >= ws[w]) & (rows < we[w])
     lhs = jnp.where(lmask, lhs_ref[...], jnp.zeros_like(lhs_ref))
 
-    # gate and up are SEPARATE operands blocked straight from the stored
-    # [G, D, I] layout — an interleaved [G, D, 2I] operand would need a
-    # host-side concat + transpose whose AD transpose leaks a non-default
-    # layout onto the weight grads, forcing full-size fp32 relayout copies
-    # in every downstream elementwise consumer (optimizer, grad-norm)
+    # gate and up are SEPARATE [Dp, ic] blocks: of two [G, D, I] arrays, or
+    # of the two halves of the ONE stored [G, D, 2I] array (`_fwd` passes
+    # it twice and offsets the up block's column index). A column-
+    # interleaved operand would need a host-side concat + transpose whose
+    # AD transpose leaks a non-default layout onto the weight grads,
+    # forcing full-size fp32 relayout copies in every downstream
+    # elementwise consumer (optimizer, grad-norm)
     g = jax.lax.dot_general(
         lhs, wg_ref[0], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -123,13 +133,41 @@ def _divisor_chunk(n128: int, cap: int = 512) -> int:
     return next(c for c in _IC_CANDS if c <= cap and c <= n128 and n128 % c == 0)
 
 
+def _halves(gate, up):
+    """(gate operand, up operand, I) of the two forms every kernel here
+    takes: two [.., I] arrays, or ``up=None`` and ``gate`` holding both
+    halves side by side as [.., 2I] (gate columns first) — then the SAME
+    array is handed to the pallas_call twice and the up BlockSpec adds a
+    whole number of column blocks (`_col_off`), so nothing is copied."""
+    if up is None:
+        return gate, gate, gate.shape[-1] // 2
+    return gate, up, gate.shape[-1]
+
+
+def _col_off(fused: bool, I: int, block: int) -> int:
+    """Column-BLOCK offset of the up half inside a fused [.., 2I] array."""
+    if not fused:
+        return 0
+    assert I % block == 0, (I, block)  # in_place_ok + the exact tile pickers
+    return I // block
+
+
+def in_place_ok(D: int, I: int) -> bool:
+    """Whether the halves of a fused [G, D, 2I] weight can be blocked in
+    place: unaligned widths are padded by every kernel here, which is a
+    copy already, so those keep the split-then-pad path."""
+    return D % 128 == 0 and I % 128 == 0
+
+
 def _fwd(lhs, gate, up, down, group_sizes, gb, ub, db, act_kind, limit,
          interpret):
-    """lhs [M, D] sorted by group; gate/up [G, D, I] (pre-split halves);
-    down [G, I, D]; optional per-expert biases gb/ub [G, I], db [G, D]
-    (gpt-oss) → [M, D]."""
+    """lhs [M, D] sorted by group; gate/up [G, D, I] halves, or up=None and
+    gate the fused [G, D, 2I] (`_halves`); down [G, I, D]; optional
+    per-expert biases gb/ub [G, I], db [G, D] (gpt-oss) → [M, D]."""
     M, D = lhs.shape
-    G, _, I = gate.shape
+    fused = up is None
+    gate, up, I = _halves(gate, up)
+    G = gate.shape[0]
     has_bias = gb is not None or ub is not None or db is not None
     tm = 512
     Dp = _round_up(D, 128)
@@ -159,22 +197,27 @@ def _fwd(lhs, gate, up, down, group_sizes, gb, ub, db, act_kind, limit,
     if (Mp, Dp) != (M, D):
         lhs = jnp.pad(lhs, ((0, Mp - M), (0, Dp - D)))
     if (Dp, Ip) != (D, I):
+        assert not fused, (D, I)  # fused_expert_mlp splits unaligned widths
         gate = jnp.pad(gate, ((0, 0), (0, Dp - D), (0, Ip - I)))
         up = jnp.pad(up, ((0, 0), (0, Dp - D), (0, Ip - I)))
         down = jnp.pad(down, ((0, 0), (0, Ip - I), (0, Dp - D)))
-    # gate/up/down are blocked DIRECTLY from their stored [G, D, I] /
-    # [G, I, D] layouts — no concat, no transpose: a transposed weight
+    # gate/up/down are blocked DIRECTLY from their stored [G, D, I] (or
+    # fused [G, D, 2I]) / [G, I, D] layouts — no split, no concat, no
+    # transpose: a transposed weight
     # operand's AD transpose emits the weight grads in a non-default layout,
     # and every fp32 elementwise consumer downstream (Adam, grad-norm) then
     # pays a full-size relayout copy (2.25GB per stacked expert tensor at
     # the MoE bench shape; the difference between fitting and OOM on 16GB)
     n_ic = Ip // ic
+    off = _col_off(fused, I, ic)
 
     operands = [lhs, gate, up, down]
     in_specs = [
         pl.BlockSpec((tm, Dp), lambda w, i, wg, wt, ws, we: (wt[w], 0)),
         pl.BlockSpec((1, Dp, ic), lambda w, i, wg, wt, ws, we: (wg[w], 0, i)),
-        pl.BlockSpec((1, Dp, ic), lambda w, i, wg, wt, ws, we: (wg[w], 0, i)),
+        pl.BlockSpec(
+            (1, Dp, ic), lambda w, i, wg, wt, ws, we: (wg[w], 0, i + off)
+        ),
         pl.BlockSpec((1, ic, Dp), lambda w, i, wg, wt, ws, we: (wg[w], i, 0)),
     ]
     if has_bias:
@@ -236,9 +279,15 @@ def _fwd(lhs, gate, up, down, group_sizes, gb, ub, db, act_kind, limit,
 def _reference(lhs, gate, up, down, group_sizes, gb, ub, db, act_kind, limit,
                platform):
     """The two-grouped-matmul composition — the backward path and the
-    numerics reference."""
-    gu_g = ragged_dot(lhs, gate, group_sizes, platform=platform)
-    gu_u = ragged_dot(lhs, up, group_sizes, platform=platform)
+    numerics reference. up=None: ``gate`` is the fused [G, D, 2I] weight;
+    one grouped matmul, and the small [M, 2I] product is what is split."""
+    if up is None:
+        gu_g, gu_u = jnp.split(
+            ragged_dot(lhs, gate, group_sizes, platform=platform), 2, axis=-1
+        )
+    else:
+        gu_g = ragged_dot(lhs, gate, group_sizes, platform=platform)
+        gu_u = ragged_dot(lhs, up, group_sizes, platform=platform)
     if gb is not None or ub is not None or db is not None:
         # row r belongs to group g iff cumsum[g-1] <= r < cumsum[g]
         bounds = jnp.cumsum(group_sizes.astype(jnp.int32))
@@ -265,11 +314,25 @@ def _reference(lhs, gate, up, down, group_sizes, gb, ub, db, act_kind, limit,
     return out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
 def fused_expert_mlp(lhs, gate, up, down, group_sizes,
                      gb=None, ub=None, db=None,
                      act_kind="swiglu", limit=None, platform=None,
                      interpret=None):
+    """The fused expert MLP. ``gate``/``up`` are the [G, D, I] halves, or
+    ``up=None`` and ``gate`` is the stored fused [G, D, 2I] weight (gate
+    columns first, NOT gpt-oss's interleave): the kernels then block both
+    halves out of it in place and its gradient comes back as one
+    [G, D, 2I] array. Widths the kernels would pad anyway (`in_place_ok`)
+    are split here instead — the pad is already a copy."""
+    if up is None and not in_place_ok(lhs.shape[1], gate.shape[-1] // 2):
+        gate, up = jnp.split(gate, 2, axis=-1)
+    return _fused_expert_mlp(lhs, gate, up, down, group_sizes, gb, ub, db,
+                             act_kind, limit, platform, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
+def _fused_expert_mlp(lhs, gate, up, down, group_sizes, gb, ub, db,
+                      act_kind, limit, platform, interpret):
     """Forward through the fused kernel; backward through the purpose-tiled
     manual kernels below (the bwd needs the g/u intermediates anyway — a
     remat-style re-run of the cheap gate_up GEMMs feeds them without ever
@@ -285,7 +348,7 @@ def fused_expert_mlp(lhs, gate, up, down, group_sizes,
 
 def _vjp_fwd(lhs, gate, up, down, group_sizes, gb, ub, db,
              act_kind, limit, platform, interpret):
-    y = fused_expert_mlp(
+    y = _fused_expert_mlp(
         lhs, gate, up, down, group_sizes, gb, ub, db,
         act_kind, limit, platform, interpret
     )
@@ -376,11 +439,18 @@ def _bwd_dx_budget_ok(tm, tn, ic, itemsize):
     return need <= _VMEM_BUDGET
 
 
-def _bwd_gu_tiles(D, I, dtype):
+# ``exact``: the operands are fused [.., 2I] arrays read in place, so a table
+# entry must also tile D and I with no remainder (nothing may be padded; the
+# divisor-chunk fallbacks always do at `in_place_ok` widths).
+
+
+def _bwd_gu_tiles(D, I, dtype, exact=False):
     from automodel_tpu.ops import autotune
 
     it = jnp.dtype(dtype).itemsize
-    ok = lambda tm, tk, tn: _bwd_gu_budget_ok(tm, tk, tn, it)
+    ok = lambda tm, tk, tn: _bwd_gu_budget_ok(tm, tk, tn, it) and not (
+        exact and (D % tk or I % tn)
+    )
     fb_tk = _divisor_chunk(_round_up(D, 128))
     fb_tn = _divisor_chunk(_round_up(I, 128))
     fb = (512, fb_tk, fb_tn)
@@ -391,11 +461,13 @@ def _bwd_gu_tiles(D, I, dtype):
     )
 
 
-def _bwd_dwd_tiles(I, D, dtype):
+def _bwd_dwd_tiles(I, D, dtype, exact=False):
     from automodel_tpu.ops import autotune
 
     it = jnp.dtype(dtype).itemsize
-    ok = lambda tm, tk, tn: _bwd_dwd_budget_ok(tm, tk, tn, it)
+    ok = lambda tm, tk, tn: _bwd_dwd_budget_ok(tm, tk, tn, it) and not (
+        exact and I % tk
+    )
     fb = (512, _divisor_chunk(_round_up(I, 128)), _divisor_chunk(_round_up(D, 128)))
     while not ok(*fb) and fb[0] > 128:
         fb = (fb[0] // 2, fb[1], fb[2])
@@ -404,11 +476,13 @@ def _bwd_dwd_tiles(I, D, dtype):
     )
 
 
-def _bwd_dx_tiles(D, I, dtype):
+def _bwd_dx_tiles(D, I, dtype, exact=False):
     from automodel_tpu.ops import autotune
 
     it = jnp.dtype(dtype).itemsize
-    ok = lambda tm, tn, ic: _bwd_dx_budget_ok(tm, tn, ic, it)
+    ok = lambda tm, tn, ic: _bwd_dx_budget_ok(tm, tn, ic, it) and not (
+        exact and (D % tn or I % ic)
+    )
     fb = (512, _divisor_chunk(_round_up(D, 128)), _divisor_chunk(_round_up(I, 128)))
     while not ok(*fb) and fb[0] > 128:
         fb = (fb[0] // 2, fb[1], fb[2])
@@ -462,26 +536,31 @@ def _bwd_gu_kernel(wg, wt, ws, we, lhs_ref, g_ref, u_ref, dmid_ref,
 def _bwd_gu(lhs, g, u, dmid, group_sizes, act_kind, limit, interpret,
             has_bias):
     """One pass over lhs → (dWg [G,D,I] f32, dWu, dgb [G,I] f32 | None,
-    dub | None). The dgate·dup chain runs in-kernel on the g/u/dmid tiles."""
+    dub | None). The dgate·dup chain runs in-kernel on the g/u/dmid tiles.
+    u=None: ``g`` is the fused [M, 2I] product, read in place (`_halves`)."""
     from automodel_tpu.ops.grouped_matmul import _out_sds
 
     M, D = lhs.shape
-    _, I = g.shape
+    fused = u is None
+    g, u, I = _halves(g, u)
     G = group_sizes.shape[0]
-    tm, tk, tn = _bwd_gu_tiles(D, I, lhs.dtype)
+    tm, tk, tn = _bwd_gu_tiles(D, I, lhs.dtype, exact=fused)
     Mp, Kp, Np = _round_up(M, tm), _round_up(D, tk), _round_up(I, tn)
+    off = _col_off(fused, I, tn)
     if (Mp, Kp) != (M, D):
         lhs = jnp.pad(lhs, ((0, Mp - M), (0, Kp - D)))
     if (Mp, Np) != (M, I):
-        pad = ((0, Mp - M), (0, Np - I))
-        g, u, dmid = jnp.pad(g, pad), jnp.pad(u, pad), jnp.pad(dmid, pad)
+        pad = ((0, Mp - M), (0, Np - I))  # fused: rows only (exact tiles)
+        g = jnp.pad(g, pad)
+        u = g if fused else jnp.pad(u, pad)
+        dmid = jnp.pad(dmid, pad)
     wg, wt, ws, we = _plan(group_sizes, Mp, tm, G)
     W = Mp // tm + G
     grid = (Kp // tk, Np // tn, W)
     in_specs = [
         pl.BlockSpec((tm, tk), lambda k, n, w, wg, wt, ws, we: (wt[w], k)),
         pl.BlockSpec((tm, tn), lambda k, n, w, wg, wt, ws, we: (wt[w], n)),
-        pl.BlockSpec((tm, tn), lambda k, n, w, wg, wt, ws, we: (wt[w], n)),
+        pl.BlockSpec((tm, tn), lambda k, n, w, wg, wt, ws, we: (wt[w], n + off)),
         pl.BlockSpec((tm, tn), lambda k, n, w, wg, wt, ws, we: (wt[w], n)),
     ]
     slab = pl.BlockSpec((1, tk, tn), lambda k, n, w, wg, wt, ws, we: (wg[w], k, n))
@@ -557,17 +636,22 @@ def _bwd_dwd_kernel(wg, wt, ws, we, g_ref, u_ref, dy_ref, dwd_ref, *rest,
 
 def _bwd_dwd(g, u, dy, group_sizes, act_kind, limit, interpret, want_db):
     """Down-proj transpose GEMM with the activation mid recomputed in-kernel
-    → (dWd [G,I,D] f32, ddb [G,D] f32 | None)."""
+    → (dWd [G,I,D] f32, ddb [G,D] f32 | None). u=None: ``g`` is the fused
+    [M, 2I] product, read in place (`_halves`)."""
     from automodel_tpu.ops.grouped_matmul import _out_sds
 
-    M, I = g.shape
+    M = g.shape[0]
+    fused = u is None
+    g, u, I = _halves(g, u)
     _, D = dy.shape
     G = group_sizes.shape[0]
-    tm, tk, tn = _bwd_dwd_tiles(I, D, g.dtype)
+    tm, tk, tn = _bwd_dwd_tiles(I, D, g.dtype, exact=fused)
     Mp, Kp, Np = _round_up(M, tm), _round_up(I, tk), _round_up(D, tn)
+    off = _col_off(fused, I, tk)
     if (Mp, Kp) != (M, I):
-        pad = ((0, Mp - M), (0, Kp - I))
-        g, u = jnp.pad(g, pad), jnp.pad(u, pad)
+        pad = ((0, Mp - M), (0, Kp - I))  # fused: rows only (exact tiles)
+        g = jnp.pad(g, pad)
+        u = g if fused else jnp.pad(u, pad)
     if (Mp, Np) != (M, D):
         dy = jnp.pad(dy, ((0, Mp - M), (0, Np - D)))
     wg, wt, ws, we = _plan(group_sizes, Mp, tm, G)
@@ -575,7 +659,7 @@ def _bwd_dwd(g, u, dy, group_sizes, act_kind, limit, interpret, want_db):
     grid = (Kp // tk, Np // tn, W)
     in_specs = [
         pl.BlockSpec((tm, tk), lambda k, n, w, wg, wt, ws, we: (wt[w], k)),
-        pl.BlockSpec((tm, tk), lambda k, n, w, wg, wt, ws, we: (wt[w], k)),
+        pl.BlockSpec((tm, tk), lambda k, n, w, wg, wt, ws, we: (wt[w], k + off)),
         pl.BlockSpec((tm, tn), lambda k, n, w, wg, wt, ws, we: (wt[w], n)),
     ]
     out_specs = [
@@ -649,17 +733,26 @@ def _bwd_dx(g, u, dmid, gate, up, group_sizes, interpret, act_kind, limit):
     """dlhs = dg·Wg^T + du·Wu^T in one kernel, I-chunked with an fp32
     accumulator (the forward's summable-contraction trick, transposed).
     Sentinel-tail rows come out zero or stay unwritten — the a2a consumer
-    never reads them (ragged_dot precondition)."""
+    never reads them (ragged_dot precondition). u=None / up=None: ``g`` is
+    the fused [M, 2I] product / ``gate`` the fused [G, D, 2I] weight, read
+    in place (`_halves`)."""
     from automodel_tpu.ops.grouped_matmul import _out_sds
 
-    M, I = g.shape
+    M = g.shape[0]
+    fused_m, fused_w = u is None, up is None
+    g, u, I = _halves(g, u)
+    gate, up, _ = _halves(gate, up)
     G, D, _ = gate.shape
-    tm, tn, ic = _bwd_dx_tiles(D, I, g.dtype)
+    tm, tn, ic = _bwd_dx_tiles(D, I, g.dtype, exact=fused_m or fused_w)
     Mp, Np, Ip = _round_up(M, tm), _round_up(D, tn), _round_up(I, ic)
+    off_m, off_w = _col_off(fused_m, I, ic), _col_off(fused_w, I, ic)
     if (Mp, Ip) != (M, I):
-        pad = ((0, Mp - M), (0, Ip - I))
-        g, u, dmid = jnp.pad(g, pad), jnp.pad(u, pad), jnp.pad(dmid, pad)
+        pad = ((0, Mp - M), (0, Ip - I))  # fused: rows only (exact tiles)
+        g = jnp.pad(g, pad)
+        u = g if fused_m else jnp.pad(u, pad)
+        dmid = jnp.pad(dmid, pad)
     if (Np, Ip) != (D, I):
+        assert not fused_w, (D, I, tn, ic)
         wpad = ((0, 0), (0, Np - D), (0, Ip - I))
         gate, up = jnp.pad(gate, wpad), jnp.pad(up, wpad)
     n_ic = Ip // ic
@@ -667,7 +760,13 @@ def _bwd_dx(g, u, dmid, gate, up, group_sizes, interpret, act_kind, limit):
     W = Mp // tm + G
     grid = (Np // tn, W, n_ic)
     mrow = pl.BlockSpec((tm, ic), lambda n, w, i, wg, wt, ws, we: (wt[w], i))
+    mrow_u = pl.BlockSpec(
+        (tm, ic), lambda n, w, i, wg, wt, ws, we: (wt[w], i + off_m)
+    )
     wslab = pl.BlockSpec((1, tn, ic), lambda n, w, i, wg, wt, ws, we: (wg[w], n, i))
+    wslab_u = pl.BlockSpec(
+        (1, tn, ic), lambda n, w, i, wg, wt, ws, we: (wg[w], n, i + off_w)
+    )
     out = pl.pallas_call(
         functools.partial(
             _bwd_dx_kernel, tm=tm, n_ic=n_ic, act_kind=act_kind, limit=limit,
@@ -676,7 +775,7 @@ def _bwd_dx(g, u, dmid, gate, up, group_sizes, interpret, act_kind, limit):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=grid,
-            in_specs=[mrow, mrow, mrow, wslab, wslab],
+            in_specs=[mrow, mrow_u, mrow, wslab, wslab_u],
             out_specs=pl.BlockSpec(
                 (tm, tn), lambda n, w, i, wg, wt, ws, we: (wt[w], n)
             ),
@@ -725,22 +824,32 @@ def _vjp_bwd(act_kind, limit, platform, interpret, res, dy):
         )
 
     if not _fused_bwd_enabled():
-        return _vjp_bwd_composed(
+        if up is None:
+            # the A/B baseline keeps its two-array form: split the fused
+            # residual for it and join its two weight gradients
+            halves = tuple(jnp.split(gate, 2, axis=-1))
+            res = (lhs, *halves, down, group_sizes, gb, ub, db)
+        out = _vjp_bwd_composed(
             act_kind, limit, platform, interpret, res, dy, mv
         )
+        if up is None:
+            out = (out[0], jnp.concatenate(out[1:3], axis=-1), None) + out[3:]
+        return out
 
-    # purpose-tiled manual backward: recompute the two cheap gate_up GEMMs
+    # purpose-tiled manual backward: recompute the cheap gate_up GEMMs
     # (g, u) and the dmid transpose GEMM, then run the three fused kernels.
     # vs the r5 composed backward this never materializes mid/dg/du (or
     # their masked copies), reads lhs once for both weight grads, and folds
     # the sentinel-tail dout mask + the bias-grad row sums in-kernel:
     # 6 grouped passes total vs 8 + five [M, N]-sized selects/elementwise
-    # round trips.
+    # round trips. With the fused weight (up=None) g and u are ONE
+    # [M, 2I] product of one pass over lhs, and the three kernels block
+    # their g/u/gate/up operands out of the fused arrays in place.
     kw = dict(platform=platform, interpret=interpret)
     M = lhs.shape[0]
     G = gate.shape[0]
     g = ragged_dot(lhs, gate, group_sizes, **kw)
-    u = ragged_dot(lhs, up, group_sizes, **kw)
+    u = None if up is None else ragged_dot(lhs, up, group_sizes, **kw)
     has_bias = gb is not None or ub is not None or db is not None
     if has_bias:
         bounds = jnp.cumsum(group_sizes.astype(jnp.int32))
@@ -753,10 +862,19 @@ def _vjp_bwd(act_kind, limit, platform, interpret, res, dy):
         # out-of-bounds clamp semantics for rows whose content is garbage
         # anyway
         row_gc = jnp.minimum(row_g, G - 1)
-    if gb is not None:
-        g = g + jnp.where(valid, gb.astype(g.dtype)[row_gc], 0)
-    if ub is not None:
-        u = u + jnp.where(valid, ub.astype(u.dtype)[row_gc], 0)
+        bias_rows = lambda b: jnp.where(valid, b.astype(g.dtype)[row_gc], 0)
+    if up is None and (gb is not None or ub is not None):
+        # one [G, 2I] row of both biases for the one [M, 2I] product
+        zeros_i = jnp.zeros((G, gate.shape[-1] // 2), g.dtype)
+        g = g + bias_rows(jnp.concatenate(
+            [zeros_i if b is None else b.astype(g.dtype) for b in (gb, ub)],
+            axis=-1,
+        ))
+    else:
+        if gb is not None:
+            g = g + bias_rows(gb)
+        if ub is not None:
+            u = u + bias_rows(ub)
 
     dmid = ragged_dot(dy, down, group_sizes, transpose_rhs=True, **kw)
     dWd, ddb = _bwd_dwd(
@@ -770,10 +888,18 @@ def _vjp_bwd(act_kind, limit, platform, interpret, res, dy):
     # and the a2a consumer never reads them (ragged_dot precondition)
     dlhs = _bwd_dx(g, u, dmid, gate, up, group_sizes, interpret, act_kind,
                    limit)
+    dWg = dWg.astype(gate.dtype)
+    if up is None:
+        # one [G, D, 2I] cotangent in the default layout; the concatenate is
+        # what the weight split's AD transpose cost before (writing the two
+        # halves in place from `_bwd_gu` is the follow-up)
+        dWg, dWu = jnp.concatenate([dWg, dWu.astype(gate.dtype)], axis=-1), None
+    else:
+        dWu = dWu.astype(up.dtype)
     return (
         mv(dlhs.astype(lhs.dtype), lhs),
-        mv(dWg.astype(gate.dtype), gate),
-        mv(dWu.astype(up.dtype), up),
+        mv(dWg, gate),
+        mv(dWu, up),
         mv(dWd.astype(down.dtype), down),
         None,
         mv(dgb.astype(gb.dtype), gb) if gb is not None else None,
@@ -850,4 +976,4 @@ def _vjp_bwd_composed(act_kind, limit, platform, interpret, res, dy, mv):
     )
 
 
-fused_expert_mlp.defvjp(_vjp_fwd, _vjp_bwd)
+_fused_expert_mlp.defvjp(_vjp_fwd, _vjp_bwd)

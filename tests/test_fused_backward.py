@@ -195,3 +195,135 @@ def test_manual_backward_bfloat16_smoke():
         assert np.isfinite(a).all(), n
         np.testing.assert_allclose(a, np.asarray(b), atol=0.15, rtol=0.1,
                                    err_msg=n)
+
+
+# -- the fused [G, D, 2I] weight read in place (up=None) ---------------------
+#
+# The kernels block the gate and the up half out of ONE stored array by a
+# column-block offset in the up BlockSpec's index map; same blocks, same
+# arithmetic in the same order, so the result must be BIT-equal to handing
+# them the two pre-split copies, and the [G, D, 2I] cotangent must equal the
+# concatenate of the pre-split path's two.
+
+IN_PLACE_CASES = [
+    # id, D, I, dtype, act, biased, tail (rows past sum(group_sizes)), path
+    ("i128-off1", 128, 128, jnp.float32, "swiglu", False, 0, "in_place"),
+    ("i768-off2", 128, 768, jnp.float32, "swiglu", False, 0, "in_place"),
+    ("i1536-off3", 128, 1536, jnp.float32, "swiglu", False, 0, "in_place"),
+    ("i768-bf16", 256, 768, jnp.bfloat16, "swiglu", False, 0, "in_place"),
+    ("i128-biased-oai", 128, 128, jnp.float32, "swiglu_oai", True, 0,
+     "in_place"),
+    ("i768-biased-limit", 128, 768, jnp.float32, "swiglu", True, 0,
+     "in_place"),
+    ("i1536-nan-tail", 128, 1536, jnp.float32, "swiglu", False, 26,
+     "in_place"),
+    ("i128-biased-nan-tail", 128, 128, jnp.float32, "swiglu", True, 26,
+     "in_place"),
+    # widths the kernels pad anyway: the op splits the weight itself
+    ("i80-unaligned-falls-back", 128, 80, jnp.float32, "swiglu", False, 0,
+     "op_splits"),
+    ("d96-unaligned-falls-back", 96, 128, jnp.float32, "swiglu", True, 0,
+     "op_splits"),
+    # gpt-oss's column interleave: no block index expresses gu[..., ::2]
+    ("interleaved-falls-back", 128, 128, jnp.float32, "swiglu_oai", True, 0,
+     "caller_splits"),
+]
+
+
+@pytest.mark.parametrize(
+    "D,I,dtype,act,biased,tail,path",
+    [c[1:] for c in IN_PLACE_CASES], ids=[c[0] for c in IN_PLACE_CASES],
+)
+def test_fused_weight_read_in_place(D, I, dtype, act, biased, tail, path):
+    from automodel_tpu.moe.config import MoEConfig
+    from automodel_tpu.moe.experts import _fused_gate_up, _split_gate_up
+
+    rng = np.random.default_rng(I + D + tail)
+    G, sizes = 4, [40, 0, 33, 55]
+    M = sum(sizes) + tail
+    lhs, gate, up, down, gs, gb, ub, db, dy = _case(
+        rng, M, D, I, G, sizes, biased, dtype
+    )
+    limit = 1.5 if (biased and act == "swiglu") else None
+    interleaved = path == "caller_splits"
+    cfg = MoEConfig(num_experts=G, num_experts_per_tok=1,
+                    moe_intermediate_size=I, interleaved_gate_up=interleaved)
+    if interleaved:
+        gate_up = jnp.stack([gate, up], axis=-1).reshape(G, D, 2 * I)
+    else:
+        gate_up = jnp.concatenate([gate, up], axis=-1)
+    if tail:  # the a2a sentinel tail: garbage in the inputs AND the cotangents
+        n_real = M - tail
+        lhs = lhs.at[n_real:].set(jnp.nan)
+        dy = dy.at[n_real:].set(jnp.nan)
+    bias = (gb, ub, db) if biased else ()
+
+    def run(operands_of):
+        def f(l, w, d, *b):
+            g_, u_ = operands_of(w)
+            return fused_expert_mlp(l, g_, u_, d, gs, *(b or (None,) * 3),
+                                    act, limit, None, True)
+
+        y, vjp = jax.vjp(f, lhs, gate_up, down, *bias)
+        return f, y, vjp(dy)
+
+    f_new, y_new, g_new = run(lambda w: _fused_gate_up(w, cfg))
+    _, y_old, g_old = run(lambda w: _split_gate_up(w, interleaved))
+
+    # which path ran: no [G, D, I] value in the program = nothing was copied
+    half = (G, D, I)
+    shapes = _all_shapes(jax.make_jaxpr(f_new)(lhs, gate_up, down, *bias).jaxpr)
+    assert (half in shapes) == (path != "in_place"), path
+    g_, u_ = _fused_gate_up(gate_up, cfg)
+    assert (u_ is None) == (path != "caller_splits")
+
+    real = slice(0, M - tail)
+    assert np.array_equal(np.asarray(y_new[real]), np.asarray(y_old[real]))
+    names = ("dlhs", "dW_gate_up", "dWd", "dgb", "dub", "ddb")
+    for n, a, b in zip(names, g_new, g_old):
+        a, b = np.asarray(a), np.asarray(b)
+        if n == "dlhs":  # tail rows are dont-care by contract
+            a, b = a[real], b[real]
+        assert a.shape == b.shape and np.isfinite(a.astype(np.float32)).all(), n
+        assert np.array_equal(a, b), (n, np.abs(a - b).max())
+        assert np.abs(a.astype(np.float32)).max() > 0, n
+    assert g_new[1].shape == gate_up.shape
+
+
+def _all_shapes(jaxpr) -> set:
+    """Shapes of every value a jaxpr (and its sub-jaxprs) defines."""
+    out = set()
+    for eqn in jaxpr.eqns:
+        out.update(v.aval.shape for v in eqn.outvars if hasattr(v.aval, "shape"))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out |= _all_shapes(sub)
+    return out
+
+
+def test_fused_weight_composed_backward_and_reference_paths_agree(monkeypatch):
+    """The other two routes of the same custom VJP take the fused operand
+    too: AUTOMODEL_FUSED_BWD=0 (splits the residual itself) and the
+    non-Pallas `_reference` composition (one grouped matmul, product split)."""
+    rng = np.random.default_rng(13)
+    D, I, G, sizes = 128, 128, 3, [30, 26, 40]
+    lhs, gate, up, down, gs, gb, ub, db, dy = _case(
+        rng, 96, D, I, G, sizes, biased=True
+    )
+    gate_up = jnp.concatenate([gate, up], axis=-1)
+
+    def grads(interpret):
+        def f(l, w, d, gb_, ub_, db_):
+            return fused_expert_mlp(l, w, None, d, gs, gb_, ub_, db_,
+                                    "swiglu", 1.5, None, interpret)
+
+        return jax.vjp(f, lhs, gate_up, down, gb, ub, db)[1](dy)
+
+    fused = grads(True)
+    monkeypatch.setenv("AUTOMODEL_FUSED_BWD", "0")
+    composed = grads(True)
+    monkeypatch.delenv("AUTOMODEL_FUSED_BWD")
+    reference = grads(False)  # CPU, no interpret: the XLA composition
+    for a, b, c in zip(fused, composed, reference):
+        assert a.shape == b.shape == c.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=5e-4)

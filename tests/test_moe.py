@@ -320,20 +320,31 @@ def test_a2a_backward_is_scatter_free(devices8):
     assert not float_scatters, float_scatters[:4]
 
 
-def test_a2a_fused_matches_a2a(devices8, monkeypatch):
+@pytest.mark.parametrize(
+    "tp,d,inter,oai",
+    [(2, 16, 32, True), (1, 128, 128, False), (1, 16, 32, False)],
+    ids=["ep4-tp2-oai-interleaved", "ep4-tp1-in-place", "ep4-tp1-unaligned"],
+)
+def test_a2a_fused_matches_a2a(devices8, monkeypatch, tp, d, inter, oai):
     """experts='a2a_fused' (token exchange + one-kernel local expert MLP,
-    interpret mode): numerics AND grads match the unfused a2a path on an
-    ep=4 × tp=2 mesh, with gpt-oss-style biased interleaved swiglu_oai
-    experts — the fused kernel's bias path inside the manual region."""
+    interpret mode): numerics AND grads match the unfused a2a path. On the
+    ep=4 × tp=2 mesh with gpt-oss-style biased interleaved swiglu_oai
+    experts — the fused kernel's bias path inside the manual region, the
+    halves pre-split so their tp shards align. With ONE tp shard the stored
+    fused gate_up goes into the region whole: read in place at 128-multiple
+    widths, split by the op itself at unaligned ones."""
     monkeypatch.setenv("AUTOMODEL_GMM_INTERPRET", "1")
     cfg = MoEConfig(
-        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32,
-        activation="swiglu_oai", interleaved_gate_up=True,
-        expert_mlp_bias=True,
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=inter,
+        **(dict(activation="swiglu_oai", interleaved_gate_up=True,
+                expert_mlp_bias=True) if oai else {}),
     )
-    p, x, ps, xs, ctx, constrain = _a2a_setup(devices8, cfg)
+    p, x, ps, xs, ctx, constrain = _a2a_setup(devices8, cfg, d=d, tp=tp)
+    from automodel_tpu.moe.experts import _a2a_weights
+
+    assert ("uw" in _a2a_weights(p["experts"], cfg, whole=tp == 1)) == oai
     rng = np.random.default_rng(5)
-    for name in ("gate_up_bias", "down_bias"):
+    for name in ("gate_up_bias", "down_bias") if oai else ():
         b = jnp.asarray(
             rng.standard_normal(p["experts"][name].shape) * 0.1, jnp.float32
         )
@@ -466,3 +477,64 @@ def test_ragged_fused_matches_ragged(monkeypatch):
         for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
             np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                        atol=1e-4, rtol=1e-4, err_msg=activation)
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
+
+
+@pytest.mark.parametrize(
+    "d,inter,interleaved,copies",
+    [(128, 256, False, False), (128, 256, True, True), (96, 256, False, True),
+     (128, 80, False, True)],
+    ids=["aligned-in-place", "interleaved-copies", "d96-copies", "i80-copies"],
+)
+def test_ragged_fused_makes_no_copy_of_the_expert_weight(
+    monkeypatch, d, inter, interleaved, copies
+):
+    """The guard that the per-call weight split does not creep back: at
+    non-interleaved 128-multiple widths the forward AND backward programs of
+    ragged_fused_experts define no [E, D, I] value (a half of gate_up) and
+    apply no split/slice/gather to anything as large as the weight; the
+    interleaved layout and widths off the 128 grid still copy."""
+    monkeypatch.setenv("AUTOMODEL_GMM_INTERPRET", "1")
+    from automodel_tpu.moe.experts import ragged_fused_experts
+    from automodel_tpu.moe.layer import make_act2
+
+    cfg = MoEConfig(num_experts=4, num_experts_per_tok=2,
+                    moe_intermediate_size=inter,
+                    interleaved_gate_up=interleaved)
+    p, x = _params(cfg, d=d), _x(d=d)
+    gout = gate(x, p["router"]["weight"], cfg)
+    act2 = make_act2(cfg, jax.nn.silu)
+
+    def loss(x_, w):
+        y = ragged_fused_experts(x_, gout, w, cfg, act2)
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    E, D, I = cfg.num_experts, d, inter
+
+    def halves_and_cuts(fn):
+        eqns = list(_all_eqns(jax.make_jaxpr(fn)(x, p["experts"]).jaxpr))
+        halves = [
+            e.primitive.name for e in eqns
+            if any(getattr(v.aval, "shape", None) == (E, D, I) for v in e.outvars)
+        ]
+        cuts = [
+            e.primitive.name for e in eqns
+            if e.primitive.name in ("split", "slice", "dynamic_slice", "gather")
+            and any(getattr(v.aval, "shape", ()) == (E, D, 2 * I)
+                    for v in e.invars if hasattr(v, "aval"))
+        ]
+        return halves, cuts
+
+    halves, cuts = halves_and_cuts(loss)
+    assert bool(halves or cuts) == copies, (halves, cuts)
+    # backward: the two halves of the weight's GRADIENT are [E, D, I] values
+    # (one concatenate joins them); the weight itself must still not be cut
+    halves, cuts = halves_and_cuts(jax.grad(loss, argnums=(0, 1)))
+    assert bool(cuts) == copies, cuts
+    assert halves

@@ -101,8 +101,11 @@ def test_paged_decode_lowers(n, degrees, sq, int8):
         (1, {}, "ragged"),
         (4, {"dp_shard": 4}, "ragged"),
         (4, {"dp_shard": 4, "ep": 4}, "a2a_fused"),
+        # the one-device branch both benchmark cells take: the kernels block
+        # both halves out of the stored fused gate_up (up index offset)
+        (1, {}, "ragged_fused"),
     ],
-    ids=["1chip-ragged", "dp4-ragged", "ep4-a2a_fused"],
+    ids=["1chip-ragged", "dp4-ragged", "ep4-a2a_fused", "1chip-ragged_fused"],
 )
 def test_expert_kernels_fwd_bwd_lower(n, degrees, backend):
     ctx = _tpu_ctx(n, **degrees)
