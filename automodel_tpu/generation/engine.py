@@ -110,11 +110,21 @@ class GenerationEngine:
     ``state.params``)."""
 
     def __init__(self, auto: Any, config: Optional[GenerationConfig] = None, tokenizer: Any = None):
-        if not getattr(auto.model, "supports_kv_cache", False):
+        layout = kv_cache.layout_of(auto.model)
+        if layout is None:
             raise GenerationUnsupported(
-                f"{type(auto.model).__name__} has no KV-cache decode path; "
-                "cache-capable families: llama-generic (llama/qwen2/qwen3/"
-                "mistral/phi3), gpt2, qwen3_moe"
+                f"{type(auto.model).__name__} states no cache layout "
+                "(cache_layout()): it has no decode path; cache-capable "
+                "families: llama-generic (llama/qwen2/qwen3/mistral/phi3), "
+                "gpt2, qwen3_moe, lfm2_moe (paged serving only)"
+            )
+        if kv_cache.recurrent_kinds(layout):
+            raise GenerationUnsupported(
+                f"{type(auto.model).__name__} keeps "
+                f"{'/'.join(kv_cache.recurrent_kinds(layout))} state beside K/V: "
+                "the contiguous generation cache holds per-head K/V alone "
+                "(no recurrent-state row a sequence); serve it through the "
+                "paged path (`automodel serve`, serving/engine.py)"
             )
         self.auto = auto
         self.model = auto.model

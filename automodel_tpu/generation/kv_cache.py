@@ -31,15 +31,78 @@ pad position p evicts real position p - C that a short slot still needed —
 in the worst case (len <= S_padded - C) a slot's entire in-window history.
 The engine therefore rejects ragged batches whose padded prompt wraps the
 ring (equal-length batches, or ragged ones fitting the window, are exact).
+
+Cache layout: a model states, per layer, WHAT it keeps between a sequence's
+tokens (``model.cache_layout()`` -> one :class:`LayerCache` a layer): per-head
+K/V (``kv``), or the last ``taps - 1`` inputs of a short causal conv
+(``conv``), a fixed-size recurrent state. The engines read that layout, never
+a family name. The paged serving path (serving/) holds K/V for the ``kv``
+layers only and, beside it, one state row a slot for the others
+(``KVCache.state``); this contiguous cache holds K/V alone, so the
+in-training GenerationEngine refuses a layout with any other kind.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerCache:
+    """What ONE layer keeps for a sequence. ``kv``: ``heads`` x ``head_dim``
+    keys and values a token (grows with the sequence, paged). ``conv``: the
+    ``taps - 1`` last inputs of a depthwise conv over ``channels`` (one fixed
+    size a sequence, indexed by slot)."""
+
+    kind: str  # "kv" | "conv"
+    heads: int = 0
+    head_dim: int = 0
+    channels: int = 0
+    taps: int = 0
+
+
+def kv_layer(heads: int, head_dim: int) -> LayerCache:
+    return LayerCache("kv", heads=int(heads), head_dim=int(head_dim))
+
+
+def conv_layer(channels: int, taps: int) -> LayerCache:
+    return LayerCache("conv", channels=int(channels), taps=int(taps))
+
+
+def uniform_kv_layout(cfg) -> tuple:
+    """Every layer keeps per-head K/V (llama, gpt2, the qwen3_moe family)."""
+    return (kv_layer(cfg.num_kv_heads, cfg.head_dim),) * int(cfg.num_layers)
+
+
+def layout_of(model) -> Optional[tuple]:
+    """The layout a model states, or None for a model with no decode path."""
+    fn = getattr(model, "cache_layout", None)
+    return tuple(fn()) if callable(fn) else None
+
+
+def layers_of(layout, kind: str) -> list:
+    return [c for c in layout if c.kind == kind]
+
+
+def recurrent_kinds(layout) -> list[str]:
+    """The kinds of fixed-size per-sequence state in a layout (not K/V):
+    what "state = blocks + a length" does not describe."""
+    return sorted({c.kind for c in layout if c.kind != "kv"})
+
+
+def one_geometry(layout, kind: str) -> Optional[LayerCache]:
+    """The one size every layer of ``kind`` has (a pool is one array a kind),
+    None when the layout has no such layer."""
+    found = sorted(set(layers_of(layout, kind)), key=repr)
+    if len(found) > 1:
+        raise NotImplementedError(
+            f"cache layout: {kind} layers of different sizes in one pool: {found}"
+        )
+    return found[0] if found else None
 
 
 @jax.tree_util.register_dataclass
@@ -56,6 +119,10 @@ class KVCache:
     window: Optional[int] = dataclasses.field(
         default=None, metadata={"static": True}
     )
+    # serving/ only: the recurrent layers' state, one row a slot —
+    # [L_state, slots, taps - 1, channels] for a layout with ``conv`` layers
+    # (then k/v cover the ``kv`` layers alone); None for a K/V-only layout
+    state: Any = None
 
     @property
     def capacity(self) -> int:
@@ -171,6 +238,57 @@ class CacheContext:
     write_block: Optional[jnp.ndarray] = None  # [B, S] int32
     write_off: Optional[jnp.ndarray] = None  # [B, S] int32
     paged_interpret: bool = False  # run the Pallas kernel interpreted (CPU)
+    # a stack that is NOT scanned (layers of several kinds, models/lfm2_moe)
+    # hands ``write``/``attend`` the WHOLE stacked sides and names the K/V
+    # layer here (``at_layer``): the paged write scatters into the stacked
+    # pool in place and the kernel indexes the layer through its block
+    # spec, so no per-layer slice of the pool is ever copied out
+    layer: Optional[int] = None
+    # recurrent state plan (``with_state_plan``; serving/ only): the state
+    # rows these sequences own (None: row b is batch row b), the absolute
+    # position of the first token fed (0: the state is reset, not read) and
+    # the count of REAL tokens fed (0: the state is left as it is)
+    state_rows: Optional[jnp.ndarray] = None  # [B] int32
+    state_start: Optional[jnp.ndarray] = None  # [B] int32
+    state_len: Optional[jnp.ndarray] = None  # [B] int32
+
+    def at_layer(self, layer: int) -> "CacheContext":
+        return dataclasses.replace(self, layer=int(layer))
+
+    # -- recurrent (conv) state ------------------------------------------------
+    def conv_prev(self, layer_state: jnp.ndarray) -> jnp.ndarray:
+        """One conv layer's state ``[rows, taps - 1, C]`` -> the inputs at
+        the ``taps - 1`` positions before this call's first token, ``[B,
+        taps - 1, C]``: the slot's state, or zeros for a sequence that
+        starts at position 0. That IS the reset on slot reuse: whatever the
+        previous tenant left in the row is never read."""
+        if self.state_start is None:
+            raise NotImplementedError(
+                f"cache mode {self.mode!r} carries no recurrent state: a "
+                "conv layer decodes through the paged serving path only"
+            )
+        held = layer_state if self.state_rows is None else layer_state[self.state_rows]
+        return jnp.where((self.state_start > 0)[:, None, None], held, 0)
+
+    def conv_next(self, prev: jnp.ndarray, u: jnp.ndarray) -> jnp.ndarray:
+        """The state after this call: the last ``taps - 1`` inputs up to the
+        last REAL token (``state_len``, not the padded end). ``prev`` [B,
+        taps - 1, C] from ``conv_prev``, ``u`` [B, S, C] this call's inputs.
+        A sequence that fed nothing (inactive in a decode wave, e.g. between
+        the chunks of its prompt) gets ``prev`` back."""
+        seq = jnp.concatenate([prev, u.astype(prev.dtype)], axis=1)
+        take = lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, prev.shape[1], axis=0)
+        return jax.vmap(take)(seq, self.state_len)
+
+    def write_state(self, state: jnp.ndarray, new: list) -> jnp.ndarray:
+        """All state layers at once: ``new``, one ``[B, taps - 1, C]`` a
+        layer (``conv_next``), into ``state`` [L_state, rows, taps - 1, C] at
+        this call's rows (every row when batch row b is slot b)."""
+        with jax.named_scope("state_write"):
+            new = jnp.stack(new).astype(state.dtype)
+            if self.state_rows is None:
+                return new
+            return state.at[:, self.state_rows].set(new)
 
     @property
     def decode(self) -> bool:
@@ -193,12 +311,22 @@ class CacheContext:
         chunk mode, 1 in decode). Paged mode: ck/cv are the layer's POOL
         slice — ``[NB, BS, N_kv, H]``, or ``(int8 values, fp32 scales)``
         when the pool is quantized — and the write scatters the S token
-        rows through the block table (quantize-on-write for int8)."""
+        rows through the block table (quantize-on-write for int8). With
+        ``layer`` set (``at_layer``) ck/cv are the whole STACKED sides and
+        come back whole."""
+        k, v = pack_rows(k, ck), pack_rows(v, cv)  # a lane-packed pool's rows
         if self.mode == "paged":
             return (
-                _paged_scatter(ck, k, self.write_block, self.write_off),
-                _paged_scatter(cv, v, self.write_block, self.write_off),
+                _paged_scatter(ck, k, self.write_block, self.write_off, self.layer),
+                _paged_scatter(cv, v, self.write_block, self.write_off, self.layer),
             )
+        if self.layer is not None:
+            # a gathered view (chunk mode): one sequence's blocks, small
+            nk, nv = dataclasses.replace(self, layer=None).write(
+                layer_slice(ck, self.layer), layer_slice(cv, self.layer), k, v
+            )
+            put = lambda side, new: side.at[self.layer].set(new)
+            return jax.tree.map(put, ck, nk), jax.tree.map(put, cv, nv)
         if self.mode == "chunk":
             # per-slot chunk write at the slot's own absolute offset (full
             # layout only: position == slot). dynamic_update_slice takes
@@ -262,11 +390,15 @@ class CacheContext:
                 scale=scale, sliding_window=sliding_window,
                 logits_soft_cap=logits_soft_cap,
                 interpret=self.paged_interpret, mesh_ctx=mesh_ctx,
+                **({} if self.layer is None else {"layer": self.layer}),
             )
         from automodel_tpu.ops.attention import sdpa_decode
 
+        if self.layer is not None:
+            layer_kv = (layer_slice(layer_kv[0], self.layer),
+                        layer_slice(layer_kv[1], self.layer))
         return sdpa_decode(
-            q, layer_kv[0], layer_kv[1],
+            q, unpack_heads(layer_kv[0], q.shape[-1]), unpack_heads(layer_kv[1], q.shape[-1]),
             kv_mask=self.attend_mask(sliding_window),
             scale=scale, logits_soft_cap=logits_soft_cap,
         )
@@ -348,6 +480,50 @@ def chunk_ctx(
     return new_cache, ctx
 
 
+LANES = 128  # the TPU's lane width: a narrower minor dim is padded to it
+
+
+def packed_heads(
+    heads: int, head_dim: int, quantized: bool = False, mesh_ctx=None
+) -> tuple[int, int]:
+    """The trailing ``(heads, width)`` a paged pool is ALLOCATED with. Heads
+    narrower than the lane width are packed side by side into rows of 128
+    (``[8, 64]`` -> ``[4, 128]``, a plain row-major reshape): a pool whose
+    minor dim is 64 is laid out by the compiler with another dim minor-most,
+    and the paged kernel, which needs heads minor-most, then gets a
+    relayout copy of the whole pool before and after every program, on
+    twice the memory. Writes pack the new rows (``pack_rows``), the
+    gathered-view attention unpacks (``unpack_heads``), the paged kernel's
+    wrapper pads the queries to match (ops/paged_attention.py).
+
+    Packing never changes what the pool MEANS elsewhere: an int8 pool keeps
+    one scale a (token row, kv head), which a packed row of two heads could
+    not, so it is left unpacked; and on a mesh the heads are packed only if
+    the packed count shards over the tensor axes exactly as the heads did."""
+    pack = LANES // head_dim if head_dim < LANES and LANES % head_dim == 0 else 1
+    if pack == 1 or quantized or heads % pack:
+        return heads, head_dim
+    if mesh_ctx is not None and usable_axes(
+        mesh_ctx, heads // pack, "tensor"
+    ) != usable_axes(mesh_ctx, heads, "tensor"):
+        return heads, head_dim
+    return heads // pack, head_dim * pack
+
+
+def pack_rows(new: jnp.ndarray, like) -> jnp.ndarray:
+    """Token rows ``[..., N_kv, H]`` in the trailing shape of the cache side
+    they are written into (its values, for an int8 pair)."""
+    tail = (like[0] if isinstance(like, tuple) else like).shape[-2:]
+    return new if new.shape[-2:] == tail else new.reshape(*new.shape[:-2], *tail)
+
+
+def unpack_heads(side: jnp.ndarray, head_dim: int) -> jnp.ndarray:
+    """A cache side ``[..., heads', width]`` back as ``[..., N_kv, H]``."""
+    if side.shape[-1] == head_dim:
+        return side
+    return side.reshape(*side.shape[:-2], -1, head_dim)
+
+
 def layer_slice(side, i: int):
     """Layer ``i`` of a cache side — a plain ``[L, ...]`` array or the
     paged-int8 ``(values, scales)`` pair (models' per-layer loop path)."""
@@ -374,21 +550,43 @@ def concat_layer_sides(parts: list):
     return jax.tree.map(lambda *xs: jnp.concatenate(xs), *parts)
 
 
-def _paged_scatter(side, new, blk: jnp.ndarray, off: jnp.ndarray):
+def _paged_scatter(side, new, blk: jnp.ndarray, off: jnp.ndarray,
+                   layer: Optional[int] = None):
     """Scatter ``new`` [B, S, Nkv, H] token rows into one layer's pool slice
     at (blk, off) [B, S] — the paged write. ``side`` is the raw pool array
     [NB, BS, Nkv, H] or, when the pool is int8, ``(values, scales)`` with
-    quantize-on-write (ops/paged_attention.quantize_kv_rows)."""
+    quantize-on-write (ops/paged_attention.quantize_kv_rows). With ``layer``
+    the side is the whole stacked pool ``[L, NB, BS, Nkv, H]`` and the rows
+    land in that layer of it, in place."""
+    at = (blk, off) if layer is None else (layer, blk, off)
     if isinstance(side, tuple):
         from automodel_tpu.ops.paged_attention import quantize_kv_rows
 
         vals, scales = side
         q, s = quantize_kv_rows(new)
         return (
-            vals.at[blk, off].set(q),
-            scales.at[blk, off].set(s.astype(scales.dtype)),
+            vals.at[at].set(q),
+            scales.at[at].set(s.astype(scales.dtype)),
         )
-    return side.at[blk, off].set(new.astype(side.dtype))
+    return side.at[at].set(new.astype(side.dtype))
+
+
+def with_state_plan(
+    ctx: CacheContext,
+    start: jnp.ndarray,
+    real_len: jnp.ndarray,
+    rows: Optional[jnp.ndarray] = None,
+) -> CacheContext:
+    """Add the recurrent-state plan to a chunk or paged context (serving/):
+    ``start`` [B] the absolute position of each sequence's first fed token,
+    ``real_len`` [B] its real tokens this call (0 = not fed: inactive),
+    ``rows`` [B] the state rows (None: row b is batch row b)."""
+    return dataclasses.replace(
+        ctx,
+        state_start=start.astype(jnp.int32),
+        state_len=real_len.astype(jnp.int32),
+        state_rows=None if rows is None else rows.astype(jnp.int32),
+    )
 
 
 def paged_write_targets(

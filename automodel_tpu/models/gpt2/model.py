@@ -336,7 +336,11 @@ class GPT2ForCausalLM:
     backend: BackendConfig = BackendConfig()
 
     lora_graft_patterns = ("*/attn/[qkvo]_proj/kernel", "*/mlp/*/kernel")
-    supports_kv_cache = True
+
+    def cache_layout(self) -> tuple:
+        """What each layer keeps between a sequence's tokens (the engines
+        read this, generation/kv_cache.py): per-head K/V on every layer."""
+        return kv_cache.uniform_kv_layout(self.config)
 
     def init(self, key: jax.Array) -> dict:
         return init_params(self.config, self.backend, key)

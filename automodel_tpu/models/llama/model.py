@@ -409,9 +409,6 @@ class LlamaForCausalLM:
     the scan (QLoRA without materializing the full-precision stack)."""
 
     supports_packed_nf4 = True
-    # generation: forward/forward_hidden accept cache=(KVCache, CacheContext)
-    # and return (..., new_cache); the GenerationEngine keys off this flag
-    supports_kv_cache = True
 
     config: TransformerConfig
     backend: BackendConfig = BackendConfig()
@@ -419,6 +416,11 @@ class LlamaForCausalLM:
     # adapter paths `_proj` consumes activation-side when grafted into the
     # param tree (peft.make_lora_loss_fn grafts these; others stay merged)
     lora_graft_patterns = ("*/attn/[qkvo]_proj/kernel", "*/mlp/*_proj/kernel")
+
+    def cache_layout(self) -> tuple:
+        """What each layer keeps between a sequence's tokens (the engines
+        read this, generation/kv_cache.py): per-head K/V on every layer."""
+        return kv_cache.uniform_kv_layout(self.config)
 
     def init(self, key: jax.Array) -> dict:
         return init_params(self.config, self.backend, key)

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from automodel_tpu.models.common.config import BackendConfig, TransformerConfig
 from automodel_tpu.models.llama.model import ACT_FNS, _dense_init, _noop_constrain
 from automodel_tpu.models.nemotron_v3.ssd import mamba2_chunk_scan
-from automodel_tpu.models.qwen3_next.delta import causal_conv1d
+from automodel_tpu.ops.short_conv import causal_conv1d
 from automodel_tpu.moe.config import MoEConfig
 from automodel_tpu.moe.layer import init_moe_params, moe_block
 from automodel_tpu.ops.attention import attention

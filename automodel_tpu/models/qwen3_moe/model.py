@@ -448,8 +448,11 @@ class MoEForCausalLM:
     # LoRA activation-side; mlp/expert weights do raw kernel matmuls and
     # stay on the merged fallback (see peft.lora.graft_lora)
     lora_graft_patterns = ("*/attn/[qkvo]_proj/kernel",)
-    # generation: the MoE decode path (cache over dense-prefix + MoE stacks)
-    supports_kv_cache = True
+
+    def cache_layout(self) -> tuple:
+        """What each layer keeps between a sequence's tokens (the engines
+        read this, generation/kv_cache.py): per-head K/V on every layer."""
+        return kv_cache_mod.uniform_kv_layout(self.config)
 
     def init(self, key: jax.Array) -> dict:
         return init_params(self.config, self.backend, key)

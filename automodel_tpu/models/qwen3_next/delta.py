@@ -19,29 +19,6 @@ def l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + eps)
 
 
-def causal_conv1d(
-    x: jnp.ndarray, weight: jnp.ndarray, segment_ids: jnp.ndarray | None = None
-) -> jnp.ndarray:
-    """Depthwise causal conv over the seq dim. x: [B, S, C]; weight: [C, K]
-    (HF conv1d.weight squeezed). No bias (qwen3-next convs are bias-free).
-
-    ``segment_ids`` [B, S]: packed-sequence boundaries — taps that would mix
-    a PREVIOUS document's tokens into this one are zeroed (each document
-    sees the same left-zero-padding it would unpacked)."""
-    K = weight.shape[-1]
-    S = x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    out = x * weight[:, K - 1][None, None, :]
-    for j in range(1, K):  # K is 4 — unrolled adds fuse into one kernel
-        tap = xp[:, K - 1 - j : K - 1 - j + S, :]  # x shifted right by j
-        if segment_ids is not None:
-            sp = jnp.pad(segment_ids, ((0, 0), (j, 0)), constant_values=-1)
-            same = (sp[:, :S] == segment_ids)[..., None]
-            tap = tap * same.astype(tap.dtype)
-        out = out + tap * weight[:, K - 1 - j][None, None, :]
-    return out
-
-
 def chunk_gated_delta_rule(
     query: jnp.ndarray,  # [B, S, H, dk] (post GQA repeat)
     key: jnp.ndarray,  # [B, S, H, dk]

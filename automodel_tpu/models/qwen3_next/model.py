@@ -33,11 +33,12 @@ from automodel_tpu.models.qwen3_moe.model import (
     MoEModelAux,
     MoETransformerConfig,
 )
-from automodel_tpu.models.qwen3_next.delta import causal_conv1d, chunk_gated_delta_rule
+from automodel_tpu.models.qwen3_next.delta import chunk_gated_delta_rule
 from automodel_tpu.moe.config import MoEConfig
 from automodel_tpu.moe.layer import init_moe_params, moe_block
 from automodel_tpu.ops.attention import attention
 from automodel_tpu.ops.rope import apply_rope, rope_table
+from automodel_tpu.ops.short_conv import causal_conv1d
 
 
 @dataclasses.dataclass(frozen=True)

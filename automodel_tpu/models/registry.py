@@ -252,6 +252,18 @@ def _kimi_k25_vl_builder(hf_config: Any, backend: BackendConfig):
     )
 
 
+@register_architecture("Lfm2MoeForCausalLM")
+def _lfm2_moe_builder(hf_config: Any, backend: BackendConfig):
+    from automodel_tpu.models.lfm2_moe import (
+        Lfm2MoeConfig,
+        Lfm2MoeForCausalLM,
+        Lfm2MoeStateDictAdapter,
+    )
+
+    cfg = Lfm2MoeConfig.from_hf(hf_config)
+    return Lfm2MoeForCausalLM(cfg, backend), Lfm2MoeStateDictAdapter(cfg)
+
+
 @register_architecture("MiniMaxM2ForCausalLM")
 def _minimax_m2_builder(hf_config: Any, backend: BackendConfig):
     from automodel_tpu.models.minimax_m2 import MiniMaxM2Config, MiniMaxM2ForCausalLM

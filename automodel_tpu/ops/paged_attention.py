@@ -196,17 +196,19 @@ def paged_attend(
         return _paged_attend(
             q, k_pool, v_pool, tables, lengths, k_scale, v_scale, **kw
         )
+    # a stacked pool [L, NB, BS, Nkv, H] (``layer`` given) has one more leading axis
+    lead = () if kw.get("layer") is None else (None,)
     heads = sharded_axes(
-        mesh_ctx, "tensor", (q.shape[2], k_pool.shape[2]),
+        mesh_ctx, "tensor", (q.shape[2], k_pool.shape[-2]),
         "paged-attention heads / KV heads",
     )
     qo = P(None, None, heads, None)
-    pool = P(None, None, heads, None)
+    pool = P(*lead, None, None, heads, None)
     args = [q, k_pool, v_pool, tables, lengths]
     specs = [qo, pool, pool, P(), P()]
     if k_scale is not None:
         args += [k_scale, v_scale]
-        specs += [P(None, None, heads)] * 2
+        specs += [P(*lead, None, None, heads)] * 2
     return kernel_shard_map(
         mesh_ctx, functools.partial(_paged_attend, **kw), tuple(specs), qo
     )(*args)
@@ -215,7 +217,7 @@ def paged_attend(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "scale", "sliding_window", "logits_soft_cap", "interpret",
+        "scale", "sliding_window", "logits_soft_cap", "interpret", "layer",
     ),
 )
 def _paged_attend(
@@ -231,8 +233,13 @@ def _paged_attend(
     sliding_window: Optional[int] = None,
     logits_soft_cap: Optional[float] = None,
     interpret: bool = False,
+    layer: Optional[int] = None,
 ) -> jnp.ndarray:
     """Paged decode/verify attention, in place over the block pool.
+
+    With ``layer`` (static) the pools (and scales) are the whole stacked
+    arrays ``[L, NB, BS, Nkv, H]`` and the block specs index that layer of
+    them: a stack that is not scanned never slices a layer's pool out.
 
     q ``[B, Sq, N, H]`` (Sq = 1 for decode, the verify chunk for
     speculative decoding); k_pool/v_pool ``[NB, BS, Nkv, H]`` (one layer's
@@ -244,8 +251,28 @@ def _paged_attend(
     gathered (dequantized) view to fp32 accumulation order.
     """
     B, Sq, N, H = q.shape
-    NB, BS, Nkv, _ = k_pool.shape
+    NB, BS, Nkv, Hp = k_pool.shape[-4:]
+    if Hp != H:
+        # a lane-packed pool (generation/kv_cache.packed_heads): ``pack`` KV
+        # heads share one row of Hp = pack * H lanes. Each query is padded to
+        # Hp with zeros outside its own KV head's lanes, so the kernel's QK^T
+        # against the packed row is exactly the head's own dot product; PV
+        # then comes out for all packed heads and the query's own lanes are
+        # kept. The kernel sees Nkv packed heads of Hp, unchanged.
+        pack = Hp // H
+        own = jax.nn.one_hot((jnp.arange(N) // (N // (Nkv * pack))) % pack, pack, dtype=q.dtype)
+        qp = (q[:, :, :, None, :] * own[None, None, :, :, None]).reshape(B, Sq, N, Hp)
+        out = _paged_attend(
+            qp, k_pool, v_pool, tables, lengths, k_scale, v_scale,
+            scale=scale if scale is not None else 1.0 / (H**0.5),
+            sliding_window=sliding_window, logits_soft_cap=logits_soft_cap,
+            interpret=interpret, layer=layer,
+        )
+        return jnp.einsum("bsnph,np->bsnh", out.reshape(B, Sq, N, pack, H), own)
     NBseq = tables.shape[1]
+    # a stacked pool's leading axis: squeezed out of the block, fixed at ``layer``
+    lead_blk = () if layer is None else (None,)
+    lead_ix = () if layer is None else (layer,)
     rep = N // Nkv
     SR = Sq * rep
     quantized = k_scale is not None
@@ -271,21 +298,21 @@ def _paged_attend(
         return jnp.minimum(j, (lens[b] + (Sq - 1)) // BS)
 
     def ix_kv(b, j, tbl, lens):
-        return (tbl[b, _live_j(b, j, lens)], 0, 0, 0)
+        return (*lead_ix, tbl[b, _live_j(b, j, lens)], 0, 0, 0)
 
     def ix_scale(b, j, tbl, lens):
-        return (tbl[b, _live_j(b, j, lens)], 0, 0)
+        return (*lead_ix, tbl[b, _live_j(b, j, lens)], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, Nkv * SR, H), ix_q),
-        pl.BlockSpec((1, BS, Nkv, H), ix_kv),
-        pl.BlockSpec((1, BS, Nkv, H), ix_kv),
+        pl.BlockSpec((*lead_blk, 1, BS, Nkv, H), ix_kv),
+        pl.BlockSpec((*lead_blk, 1, BS, Nkv, H), ix_kv),
     ]
     args = [qf, k_pool, v_pool]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, BS, Nkv), ix_scale),
-            pl.BlockSpec((1, BS, Nkv), ix_scale),
+            pl.BlockSpec((*lead_blk, 1, BS, Nkv), ix_scale),
+            pl.BlockSpec((*lead_blk, 1, BS, Nkv), ix_scale),
         ]
         args += [k_scale, v_scale]
     kernel = functools.partial(

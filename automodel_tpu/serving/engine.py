@@ -72,6 +72,7 @@ from automodel_tpu.generation.engine import (
     GenerationUnsupported,
     _model_max_positions,
 )
+from automodel_tpu.generation import kv_cache
 from automodel_tpu.generation.sampling import sample
 from automodel_tpu.serving import paged
 from automodel_tpu.serving.block_pool import (
@@ -555,6 +556,34 @@ class ServeConfig:
                 d[key] = sub.from_dict(dict(v))
         return cls(**d)
 
+    def check_layout(self, layout, model_name: str) -> None:
+        """Refuse, before anything is built, every feature that takes a
+        sequence's state to be "blocks + a length" when the model's cache
+        layout (``cache_layout()``, generation/kv_cache.py) also keeps a
+        fixed-size recurrent state a slot: none of them is silently off."""
+        kinds = kv_cache.recurrent_kinds(layout)
+        if not kinds:
+            return
+        what = f"{model_name} keeps {'/'.join(kinds)} state beside K/V"
+        refused = [
+            (self.prefix_cache, "prefix_cache: true",
+             "a prefix hit resumes at a block boundary and would need the "
+             "recurrent state AT that boundary, which is kept only for a "
+             "slot's newest position (set serving.prefix_cache: false)"),
+            (self.kv_spill.enabled, "kv_spill.enabled",
+             "spilled and peer-fetched prefixes are K/V blocks by chain hash; "
+             "the recurrent state at their boundary is not among them"),
+            (self.speculative.enabled, "speculative.enabled",
+             "rollback of rejected drafts is a length decrement, which does "
+             "not undo the inputs already shifted into a recurrent state"),
+            (self.role != "mixed", f"role: {self.role}",
+             "the prefill->decode handoff ships K/V block rows only; the "
+             "slot's recurrent state would stay behind"),
+        ]
+        for on, key, why in refused:
+            if on:
+                raise ValueError(f"serving.{key} is refused: {what}, and {why}")
+
     @property
     def spec_overhang(self) -> int:
         """Positions a speculative verify may WRITE past a sequence's final
@@ -659,15 +688,22 @@ class ServingEngine:
         on_record: Optional[Callable[[dict], None]] = None,
         tracer: Optional[Tracer] = None,
     ):
-        if not getattr(auto.model, "supports_kv_cache", False):
+        self._layout = kv_cache.layout_of(auto.model)
+        if self._layout is None:
             raise GenerationUnsupported(
-                f"{type(auto.model).__name__} has no KV-cache decode path; "
-                "cache-capable families: llama-generic (llama/qwen2/qwen3/"
-                "mistral/phi3), gpt2, qwen3_moe"
+                f"{type(auto.model).__name__} states no cache layout "
+                "(cache_layout(): what each layer keeps for a sequence — `kv` "
+                "heads x head_dim, or `conv` channels x taps), so it has no "
+                "decode path; families that state one: llama-generic (llama/"
+                "qwen2/qwen3/mistral/phi3), gpt2, qwen3_moe (all `kv`), "
+                "lfm2_moe (`kv` + `conv`)"
             )
         self.auto = auto
         self.model = auto.model
         self.config = config or ServeConfig()
+        self.config.check_layout(self._layout, type(auto.model).__name__)
+        # a layout with a recurrent kind: the pool carries one state row a slot
+        self._stateful = bool(kv_cache.recurrent_kinds(self._layout))
         self.gen_config = gen_config or GenerationConfig()
         self.on_record = on_record
         mcfg = self.model.config
@@ -728,12 +764,16 @@ class ServingEngine:
             self.draft_auto = build_auto_from_model_section(
                 spec.draft, auto.mesh_ctx, seed=self.gen_config.seed
             )
-            if not getattr(self.draft_auto.model, "supports_kv_cache", False):
+            draft_layout = kv_cache.layout_of(self.draft_auto.model)
+            if draft_layout is None or kv_cache.recurrent_kinds(draft_layout):
                 raise GenerationUnsupported(
                     "serving.speculative.draft model "
-                    f"{type(self.draft_auto.model).__name__} has no KV-cache "
-                    "decode path"
+                    f"{type(self.draft_auto.model).__name__} must state a "
+                    "cache layout of `kv` layers only (rollback is a length "
+                    "decrement); it states "
+                    f"{sorted({c.kind for c in draft_layout or ()}) or 'none'}"
                 )
+            self._draft_layout = draft_layout
             dv = int(self.draft_auto.model.config.vocab_size)
             tv = int(mcfg.vocab_size)
             if dv != tv:
@@ -819,6 +859,7 @@ class ServingEngine:
         self.step_phase: Optional[str] = None
         self._n_admitted = self._n_chunks = 0
         self._n_decoded = self._n_context_tokens = 0
+        self._n_state_resets = 0  # prompts whose FIRST chunk ran this step
         # live weight hot-swap (swap_weights): monotonic version tag
         # advertised on /stats + /metrics, and the validated replacement
         # tree staged until a step boundary with zero busy slots
@@ -930,25 +971,21 @@ class ServingEngine:
         speculative decoding the draft model's parallel pool (same block
         geometry, its own layer/head dims) rebuilds in the same breath —
         a stall mid-verify must never leave half-trusted draft state."""
-        mcfg = self.model.config
         self._pool = paged.place_pool(
-            paged.init_pool(
-                int(mcfg.num_layers), self.config.num_blocks,
-                self.config.block_size, int(mcfg.num_kv_heads),
-                int(mcfg.head_dim), dtype=self._compute_dtype,
-                quantized=self._quantized,
+            paged.layout_pool(
+                self._layout, self.config.slots, self.config.num_blocks,
+                self.config.block_size, dtype=self._compute_dtype,
+                quantized=self._quantized, mesh_ctx=self.auto.mesh_ctx,
             ),
             self.auto.mesh_ctx,
         )
         if self._spec_enabled:
-            dcfg = self.draft_auto.model.config
             self._draft_pool = paged.place_pool(
-                paged.init_pool(
-                    int(dcfg.num_layers), self.config.num_blocks,
-                    self.config.block_size, int(dcfg.num_kv_heads),
-                    int(dcfg.head_dim),
+                paged.layout_pool(
+                    self._draft_layout, self.config.slots,
+                    self.config.num_blocks, self.config.block_size,
                     dtype=self.draft_auto.model.backend.compute_jnp_dtype,
-                    quantized=self._quantized,
+                    quantized=self._quantized, mesh_ctx=self.auto.mesh_ctx,
                 ),
                 self.auto.mesh_ctx,
             )
@@ -970,6 +1007,16 @@ class ServingEngine:
     @property
     def busy_slots(self) -> int:
         return sum(s is not None for s in self._slots)
+
+    @property
+    def state_slots(self) -> int:
+        """Slots holding live recurrent state: a prompt's first chunk has
+        run and the request has not left (0 for a K/V-only layout). The row
+        dies with the slot: the next tenant's first chunk starts at position
+        0, which reads zeros (serving/paged.py)."""
+        if not self._stateful:
+            return 0
+        return sum(s is not None and s.prefill_pos > 0 for s in self._slots)
 
     @property
     def pool_bytes(self) -> int:
@@ -1654,6 +1701,12 @@ class ServingEngine:
                 "supported: the draft model's parallel pool would miss the "
                 "prompt KV and proposals would attend garbage"
             )
+        if self._stateful:
+            raise GenerationUnsupported(
+                "KV handoff into an engine whose layout keeps recurrent state "
+                "is not supported: the shipped block rows are K/V only, the "
+                "prompt's recurrent state would be missing"
+            )
         prompt = [int(t) for t in prompt_ids]
         self._validate_kv_payload(prompt, kv)
         payload = {"first_token": int(first_token), "kv": kv}
@@ -2184,6 +2237,11 @@ class ServingEngine:
             ):
                 ids = np.full((chunk_len,), pad, np.int32)
                 ids[:real] = slot.prompt[start : start + real]
+                # a recurrent layout's chunk also names the slot's state row;
+                # a chunk at position 0 resets it inside the program
+                state_row = (jnp.int32(b),) if self._stateful else ()
+                if self._stateful and start == 0:
+                    self._n_state_resets += 1
                 t_chunk0 = time.perf_counter()
                 if inj is not None:
                     inj.maybe_trace_delay("prefill")
@@ -2193,12 +2251,12 @@ class ServingEngine:
                         "chunk_prefill", self._chunk,
                         self.auto.params, self._pool,
                         jnp.asarray(self._tables[b]), jnp.asarray(ids),
-                        jnp.int32(start), jnp.int32(real),
+                        jnp.int32(start), jnp.int32(real), *state_row,
                     )
                 last, self._pool = self._chunk(
                     self.auto.params, self._pool,
                     jnp.asarray(self._tables[b]), jnp.asarray(ids),
-                    jnp.int32(start), jnp.int32(real),
+                    jnp.int32(start), jnp.int32(real), *state_row,
                 )
                 if self._spec_enabled:
                     # the draft model prefills the same chunk into its
@@ -2532,7 +2590,7 @@ class ServingEngine:
             "serve.step", step=self._step_counter,
             queued=len(self._queue), busy=self.busy_slots,
         ):
-            self._n_admitted = self._n_chunks = 0
+            self._n_admitted = self._n_chunks = self._n_state_resets = 0
             self._n_decoded = self._n_context_tokens = 0
             done = self._iterate()
             self.step_phase = None
@@ -2540,6 +2598,7 @@ class ServingEngine:
                 "serve.counts", admitted=self._n_admitted,
                 chunks=self._n_chunks, decoded=self._n_decoded,
                 context_tokens=self._n_context_tokens, finished=len(done),
+                state_resets=self._n_state_resets, state_slots=self.state_slots,
             ):
                 pass
         return done

@@ -1,11 +1,22 @@
 """Jitted paged-KV programs: chunk prefill, paged decode (fused Pallas
 kernel or XLA-gather fallback), and the speculative draft/verify pair.
 
-The pool is a :class:`PagedKV`: per-layer k/v block arrays
-``[L, NB, BS, N_kv, H]`` in the model's compute dtype, or int8 with
-per-(token row, kv head) fp32 scales ``[L, NB, BS, N_kv]`` riding
-alongside (``serving.kv_cache_dtype: int8`` — roughly half the bytes per
-resident token, so ~2× the sequences per chip on the same HBM budget).
+The pool is a :class:`PagedKV`, laid out by the KIND of state each layer
+keeps (the model's ``cache_layout()``, generation/kv_cache.py): for the
+``L_kv`` layers that keep per-head K/V, k/v block arrays ``[L_kv, NB, BS,
+N_kv, H]`` in the model's compute dtype, or int8 with per-(token row, kv
+head) fp32 scales ``[L_kv, NB, BS, N_kv]`` riding alongside
+(``serving.kv_cache_dtype: int8`` — roughly half the bytes per resident
+token, so ~2× the sequences per chip on the same HBM budget); and for the
+``L_conv`` layers that keep a short conv's last inputs, ONE slot-indexed
+array ``state [L_conv, slots, taps - 1, C]`` beside them (None when every
+layer keeps K/V). Recurrent state is not paged: it has one fixed size a slot.
+A prompt's chunk reads its slot's row as the positions before its first
+token (zeros when that token is position 0: the reset on slot reuse is that
+test, on the traced offset) and writes back the inputs at its last REAL
+positions; a decode step shifts one input into every ACTIVE slot's row and
+hands an inactive slot's row (free, or between the chunks of its prompt)
+back untouched.
 
 Programs, each compiled once per static shape and donating the pool:
 
@@ -72,13 +83,17 @@ def _logits_of(primary: Any) -> jnp.ndarray:
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class PagedKV:
-    """The HBM block pool. ``k``/``v`` are each either a raw array
-    ``[L, NB, BS, N_kv, H]`` or, when quantized, a ``(values int8,
-    scales fp32 [L, NB, BS, N_kv])`` pair — the same pytree shape the
-    model's layer scan slices per layer."""
+    """The HBM pool, one array a KIND of layer state. ``k``/``v`` cover the
+    layers that keep K/V (``L`` = their count, not the model's depth): each
+    either a raw array ``[L, NB, BS, N_kv, H]`` or, when quantized, a
+    ``(values int8, scales fp32 [L, NB, BS, N_kv])`` pair — the same pytree
+    shape the model's layer scan slices per layer. ``state`` covers the
+    layers that keep a short conv's last inputs: ``[L_conv, slots, taps -
+    1, C]``, one row a slot, or None for a K/V-only layout."""
 
     k: Any
     v: Any
+    state: Any = None
 
     @property
     def quantized(self) -> bool:
@@ -90,7 +105,7 @@ class PagedKV:
 
     @property
     def nbytes(self) -> int:
-        return int(sum(x.nbytes for x in jax.tree.leaves((self.k, self.v))))
+        return int(sum(x.nbytes for x in jax.tree.leaves((self.k, self.v, self.state))))
 
 
 def init_pool(
@@ -101,33 +116,65 @@ def init_pool(
     head_dim: int,
     dtype=jnp.bfloat16,
     quantized: bool = False,
+    state_shape: Optional[tuple] = None,
 ) -> PagedKV:
-    """Zeroed pool; ``quantized`` stores int8 values + fp32 row scales."""
+    """Zeroed pool over ``num_layers`` K/V layers; ``quantized`` stores int8
+    values + fp32 row scales. ``state_shape`` ``(L_conv, slots, taps - 1,
+    C)``: the recurrent layers' slot-indexed state, in ``dtype``."""
     shape = (num_layers, num_blocks, block_size, num_kv_heads, head_dim)
+    state = None if state_shape is None else jnp.zeros(state_shape, dtype)
     if quantized:
         sshape = shape[:-1]
 
         def side():
             return (jnp.zeros(shape, jnp.int8), jnp.zeros(sshape, jnp.float32))
 
-        return PagedKV(k=side(), v=side())
-    return PagedKV(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+        return PagedKV(k=side(), v=side(), state=state)
+    return PagedKV(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype), state=state)
 
 
-def pool_shardings(mesh_ctx, num_kv_heads: int, quantized: bool) -> PagedKV:
+def layout_pool(
+    layout, slots: int, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
+    quantized: bool = False, mesh_ctx=None,
+) -> PagedKV:
+    """The pool a model's ``cache_layout()`` asks for: K/V blocks for its
+    ``kv`` layers only, a state row a slot for its ``conv`` layers.
+    ``mesh_ctx``: the mesh ``place_pool`` will shard it over."""
+    kv = kv_cache.one_geometry(layout, "kv")
+    conv = kv_cache.one_geometry(layout, "conv")
+    if kv is None:
+        raise NotImplementedError("cache layout without a K/V layer")
+    return init_pool(
+        len(kv_cache.layers_of(layout, "kv")), num_blocks, block_size,
+        # heads narrower than a lane row are packed side by side
+        *kv_cache.packed_heads(kv.heads, kv.head_dim, quantized, mesh_ctx),
+        dtype=dtype, quantized=quantized,
+        state_shape=None if conv is None else (
+            len(kv_cache.layers_of(layout, "conv")), int(slots),
+            conv.taps - 1, conv.channels,
+        ),
+    )
+
+
+def pool_shardings(
+    mesh_ctx, num_kv_heads: int, quantized: bool, with_state: bool = False
+) -> PagedKV:
     """Where the pool lives: KV heads over the tensor axes (each TP shard
     owns its heads' blocks — the same no-cache-collective decode layout as
     generation.kv_cache.place_cache); blocks are NOT batch-sharded (every
     sequence's table may point anywhere in the pool). Non-divisible axes
-    are dropped (replicated). Int8 scales shard on the same kv-head axis.
-    → a PagedKV of NamedShardings, leaf for leaf the pool's shape."""
+    are dropped (replicated). Int8 scales shard on the same kv-head axis. A
+    recurrent state (``with_state``), small and read whole by every shard,
+    is replicated. → a PagedKV of NamedShardings, leaf for leaf the pool's
+    shape."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     names = kv_cache.usable_axes(mesh_ctx, num_kv_heads, "tensor")
     val_s = NamedSharding(mesh_ctx.mesh, P(None, None, None, names, None))
     scale_s = NamedSharding(mesh_ctx.mesh, P(None, None, None, names))
     side = (val_s, scale_s) if quantized else val_s
-    return PagedKV(k=side, v=side)
+    state = NamedSharding(mesh_ctx.mesh, P()) if with_state else None
+    return PagedKV(k=side, v=side, state=state)
 
 
 def place_pool(pool: PagedKV, mesh_ctx) -> PagedKV:
@@ -135,11 +182,41 @@ def place_pool(pool: PagedKV, mesh_ctx) -> PagedKV:
     if mesh_ctx is None:
         return pool
     return jax.device_put(
-        pool, pool_shardings(mesh_ctx, pool.values_shape[3], pool.quantized)
+        pool,
+        pool_shardings(
+            mesh_ctx, pool.values_shape[3], pool.quantized, pool.state is not None
+        ),
     )
 
 
 # -- gather / scatter (the XLA fallback path + chunk prefill) ----------------
+
+
+def _block_rows(pool: jnp.ndarray, tables: jnp.ndarray) -> tuple:
+    """A pool of several layers ``[L, NB, BS, ...]`` as rows of whole blocks
+    ``[L * NB, BS * N_kv, H]`` (the same bytes in the same order: no copy),
+    and the rows ``tables`` [...] names in every layer, ``[L, *tables.shape]``.
+    A gather or scatter along axis 1 of the 5-D pool makes the compiler
+    relayout the WHOLE pool around the program (two copies a side, 10 ms a
+    chunk program at 3 x 0.8 GB) once a layer's second-minor dim is under 8
+    rows (4 packed KV heads); whole-block rows index the leading axis and
+    keep the layout the array arrived in."""
+    L, NB, BS, Nkv = pool.shape[:4]  # values [L, NB, BS, N_kv, H]; int8 scales have no H
+    rows = pool.reshape(L * NB, BS * Nkv, *pool.shape[4:])
+    first = (jnp.arange(L, dtype=tables.dtype) * NB).reshape(L, *(1,) * tables.ndim)
+    return rows, first + tables[None]
+
+
+def _take_blocks(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
+    """``pool[:, tables]``: [L, NB, BS, ...] -> [L, *tables.shape, BS, ...]."""
+    rows, at = _block_rows(pool, tables)
+    return rows[at].reshape(pool.shape[0], *tables.shape, *pool.shape[2:])
+
+
+def _set_blocks(pool: jnp.ndarray, table: jnp.ndarray, new: jnp.ndarray) -> jnp.ndarray:
+    """``pool[:, table] = new`` for ``pool`` [L, NB, ...], ``new`` [L, n, ...]."""
+    rows, at = _block_rows(pool, table)
+    return rows.at[at].set(new.reshape(*at.shape, *rows.shape[1:])).reshape(pool.shape)
 
 
 def _gather_side(side, tables: jnp.ndarray, dtype) -> jnp.ndarray:
@@ -149,11 +226,11 @@ def _gather_side(side, tables: jnp.ndarray, dtype) -> jnp.ndarray:
         vals, scales = side
         L, _, BS, Nkv, H = vals.shape
         B, NBseq = tables.shape
-        g = dequantize_kv(vals[:, tables], scales[:, tables], dtype)
+        g = dequantize_kv(_take_blocks(vals, tables), _take_blocks(scales, tables), dtype)
         return g.reshape(L, B, NBseq * BS, Nkv, H)
     L, _, BS, Nkv, H = side.shape
     B, NBseq = tables.shape
-    return side[:, tables].reshape(L, B, NBseq * BS, Nkv, H)
+    return _take_blocks(side, tables).reshape(L, B, NBseq * BS, Nkv, H)
 
 
 def _scatter_rows(side, rows: jnp.ndarray, blk: jnp.ndarray, off: jnp.ndarray):
@@ -177,8 +254,8 @@ def _scatter_table(side, new: jnp.ndarray, table: jnp.ndarray):
         if isinstance(side, tuple):
             vals, scales = side
             q, s = quantize_kv_rows(new)
-            return (vals.at[:, table].set(q), scales.at[:, table].set(s))
-        return side.at[:, table].set(new.astype(side.dtype))
+            return (_set_blocks(vals, table, q), _set_blocks(scales, table, s))
+        return _set_blocks(side, table, new.astype(side.dtype))
 
 
 # gather scatter-back targets resolve through the SAME helper the fused
@@ -229,7 +306,9 @@ def _inject_fn(nb: int, quantized: bool):
                 )
             return s.at[:, table].set(jnp.asarray(rows, s.dtype))
 
-        return PagedKV(k=side(pool.k, k_rows), v=side(pool.v, v_rows))
+        return PagedKV(
+            k=side(pool.k, k_rows), v=side(pool.v, v_rows), state=pool.state
+        )
 
     return inject
 
@@ -357,10 +436,12 @@ def _gather_forward(
             v=_gather_side(pool.v, tables, compute_dtype),
             pos=jnp.full((B, NBseq * BS), -1, jnp.int32),  # chunk_ctx retags
             lengths=jnp.zeros((B,), jnp.int32),
+            state=pool.state,
         )
-        kvc, ctx = kv_cache.chunk_ctx(
-            view, S, lengths, jnp.where(active, S, 0).astype(jnp.int32)
-        )
+        fed = jnp.where(active, S, 0).astype(jnp.int32)
+        kvc, ctx = kv_cache.chunk_ctx(view, S, lengths, fed)
+        if pool.state is not None:  # batch row b is slot b
+            ctx = kv_cache.with_state_plan(ctx, lengths, fed)
         positions = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     primary, new_view = apply(
         params, tokens, position_ids=positions, cache=(kvc, ctx)
@@ -375,6 +456,7 @@ def _gather_forward(
     return logits, PagedKV(
         k=_scatter_rows(pool.k, rows_k, blk, off),
         v=_scatter_rows(pool.v, rows_v, blk, off),
+        state=new_view.state,
     )
 
 
@@ -390,11 +472,15 @@ def _fused_forward(
     lengths = lengths.astype(jnp.int32)
     kvc = kv_cache.KVCache(
         k=pool.k, v=pool.v,
-        pos=jnp.zeros((B, 1), jnp.int32), lengths=lengths,
+        pos=jnp.zeros((B, 1), jnp.int32), lengths=lengths, state=pool.state,
     )
     with jax.named_scope("kv_write"):  # the write targets
         kvc, ctx = kv_cache.paged_ctx(
             kvc, tables, lengths, S, active, block_size, interpret=interpret
+        )
+    if pool.state is not None:  # batch row b is slot b; inactive slots feed nothing
+        ctx = kv_cache.with_state_plan(
+            ctx, lengths, jnp.where(active, S, 0).astype(jnp.int32)
         )
     with jax.named_scope("attn"):
         positions = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
@@ -403,7 +489,7 @@ def _fused_forward(
     )
     with jax.named_scope("lm_head"):
         logits = _logits_of(primary).astype(jnp.float32)
-    return logits, PagedKV(k=new_kvc.k, v=new_kvc.v)
+    return logits, PagedKV(k=new_kvc.k, v=new_kvc.v, state=new_kvc.state)
 
 
 def _make_forward(
@@ -427,16 +513,19 @@ def build_chunk_prefill_fn(
     apply: Callable, chunk_len: int, compute_dtype=None
 ) -> Callable:
     """→ jitted ``chunk(params, pool, table [NBseq], chunk_ids [chunk_len],
-    start, real_len)`` → ``(last_logits [V] fp32, pool)`` for ONE sequence.
-    ``start`` is the absolute position of the chunk's first token (= the
-    prefix-cache hit length for the first chunk); ``real_len`` the unpadded
-    chunk length; ``last_logits`` the logits of token ``start + real_len -
-    1`` (the first-token sample source once the whole prompt is in).
+    start, real_len[, slot])`` → ``(last_logits [V] fp32, pool)`` for ONE
+    sequence. ``start`` is the absolute position of the chunk's first token
+    (= the prefix-cache hit length for the first chunk); ``real_len`` the
+    unpadded chunk length; ``last_logits`` the logits of token ``start +
+    real_len - 1`` (the first-token sample source once the whole prompt is
+    in). ``slot`` (only for a pool with recurrent state) is the state row
+    the sequence owns: read as the positions before ``start`` (zeros when
+    ``start == 0``), written back at the chunk's last real positions.
     Always the gathered-view path: prefill is compute-bound and one
     compiled program serves every offset."""
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def chunk(params, pool: PagedKV, table, chunk_ids, start, real_len):
+    def chunk(params, pool: PagedKV, table, chunk_ids, start, real_len, slot=None):
         L, _, BS, Nkv, H = pool.values_shape
         NBseq = table.shape[0]
         cd = compute_dtype or (
@@ -449,11 +538,16 @@ def build_chunk_prefill_fn(
                 v=_gather_side(pool.v, tables, cd),
                 pos=jnp.full((1, NBseq * BS), -1, jnp.int32),
                 lengths=jnp.zeros((1,), jnp.int32),
+                state=pool.state,
             )
             kvc, ctx = kv_cache.chunk_ctx(
                 view, chunk_len, start[None].astype(jnp.int32),
                 real_len[None].astype(jnp.int32),
             )
+            if pool.state is not None:
+                ctx = kv_cache.with_state_plan(
+                    ctx, start[None], real_len[None], rows=slot[None]
+                )
             positions = (
                 start.astype(jnp.int32) + jnp.arange(chunk_len, dtype=jnp.int32)
             )[None, :]
@@ -469,6 +563,7 @@ def build_chunk_prefill_fn(
         return last, PagedKV(
             k=_scatter_table(pool.k, newk, table),
             v=_scatter_table(pool.v, newv, table),
+            state=new_view.state,
         )
 
     return chunk
@@ -493,9 +588,12 @@ def build_paged_decode_fn(
     inactive slots.
 
     One continuous-batching decode step: every ACTIVE slot advances one
-    token (its K/V written at ``(table[len // BS], len % BS)``); inactive
-    slots (free, or mid-prefill) compute junk that is masked from the
-    sampled output and scattered into scratch block 0. Stop-token/length
+    token (its K/V written at ``(table[len // BS], len % BS)``, one input
+    shifted into its row of a recurrent state); inactive slots (free, or
+    mid-prefill) compute junk that is masked from the sampled output,
+    scattered into scratch block 0 and kept out of the recurrent state
+    (their rows come back as they were: the decode steps that run between a
+    prompt's chunks must not touch what its chunks carry). Stop-token/length
     bookkeeping is the host scheduler's job — this program is stateless."""
     forward = _make_forward(apply, backend, block_size, compute_dtype, interpret)
 

@@ -78,8 +78,9 @@ class StepProfiler:
 # decode). Each name is a `jax.named_scope` at the place the work happens
 # ("a/b" = scope `b` inside scope `a`), so it lands in every op's name path,
 # which a device trace carries as the op's `tf_op` stat: a trace viewer groups
-# by it, and benchmarks/harness/program_trace.py (which holds its own copy; a
-# test keeps the two equal) gives each op to the innermost name in its path.
+# by it, and benchmarks/harness/program_trace.py (which holds a copy of the
+# names it knows; a test keeps every one of them a scope here, in this order)
+# gives each op to the innermost name in its path.
 # Metadata only: the compiled program is the same with or without them.
 # docs/observability.md says what each covers.
 SCOPES = (
@@ -87,4 +88,9 @@ SCOPES = (
     "moe/router", "moe/dispatch", "moe/experts", "moe/combine", "mlp",
     "final_norm", "lm_head_ce", "lm_head", "sample",
     "grad_accum", "grad_clip", "anomaly", "optimizer",
+    # a recurrent layer's operator and its state's write (models/lfm2_moe).
+    # The benchmark's frozen vocabulary lacks them and files their ops under
+    # the scope around them (`layers`, `kv_write`); its newer readers match
+    # the path segment (benchmarks/metrics/_scope_segments.py)
+    "conv", "kv_write/state_write",
 )

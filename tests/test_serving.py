@@ -459,6 +459,48 @@ def test_engine_on_mesh(devices8):
     assert len(done[a]["tokens"]) == 4
 
 
+@pytest.mark.parametrize("tp,tail,spec", [(2, (4, 128), "tp"), (8, (8, 64), "tp")])
+def test_narrow_heads_pack_only_where_the_pool_shards_the_same(
+    monkeypatch, devices8, tp, tail, spec
+):
+    """8 KV heads of 64 pack into 4 rows of 128 lanes (kv_cache.packed_heads).
+    On tp=2 the 4 packed rows still shard over tp, so the pool is packed; on
+    tp=8 they would not, so the pool stays [8, 64] and shards as the heads
+    did. Either way the engine decodes the one-device engine's tokens, through
+    the paged kernel (interpreted) inside its shard_map."""
+    monkeypatch.setenv("AUTOMODEL_FLASH_INTERPRET", "1")
+    from automodel_tpu import auto_model
+    from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    hf = {
+        "architectures": ["LlamaForCausalLM"], "model_type": "llama",
+        "vocab_size": 64, "hidden_size": 64, "intermediate_size": 64,
+        "num_hidden_layers": 2, "num_attention_heads": 8,
+        "num_key_value_heads": 8, "head_dim": 64,
+        "max_position_embeddings": 128,
+    }
+    backend = {"attn": "sdpa", "param_dtype": "float32", "compute_dtype": "float32"}
+
+    def tokens(ctx):
+        auto = auto_model.from_config(hf, ctx, backend)
+        srv = ServingEngine(
+            auto,
+            ServeConfig(slots=2, block_size=8, num_blocks=16, prefill_chunk=8, max_seq_len=64,
+                        decode_kernel="fused"),
+            GenerationConfig(max_new_tokens=4, greedy=True),
+        )
+        ids = [srv.submit([1, 2, 3, 4, 5]), srv.submit([9, 8, 7])]
+        done = {r["request_id"]: r for r in srv.run()}
+        return srv, [done[i]["tokens"] for i in ids]
+
+    srv, got = tokens(build_mesh(MeshConfig(dp_shard=8 // tp, tp=tp), devices=devices8))
+    assert srv._pool.values_shape[3:] == tail
+    assert srv._pool.k.sharding.spec[3] in (spec, (spec,))
+    one, want = tokens(build_mesh(MeshConfig(dp_shard=1), devices=devices8[:1]))
+    assert one._pool.values_shape[3:] == (4, 128)
+    assert got == want
+
+
 # -- serve CLI / HTTP ---------------------------------------------------------
 
 

@@ -225,6 +225,26 @@ def test_int8_pool_fused_matches_gather(monkeypatch):
     assert [r["tokens"] for r in fused] == [r["tokens"] for r in gather]
 
 
+@pytest.mark.parametrize("kernel", ["gather", "fused"])
+def test_int8_pool_of_narrow_heads_keeps_a_scale_a_head(monkeypatch, kernel):
+    """Heads of 64 are lane-packed two a row in a full-precision pool
+    (kv_cache.packed_heads); an int8 pool is left unpacked, so its scales
+    stay one a (token row, kv head), and decodes the full-precision pool's
+    greedy tokens through either backend."""
+    monkeypatch.setenv("AUTOMODEL_FLASH_INTERPRET", "1")
+    model, params = _tiny_llama(seed=3, hidden_size=64, num_heads=4, num_kv_heads=2, head_dim=64)
+    auto = _auto(model, params)
+    prompts = [[5, 6, 7, 8, 9], [30, 31]]
+    full = _serve(auto, decode_kernel=kernel)
+    int8 = _serve(auto, kv_cache_dtype="int8", decode_kernel=kernel)
+    assert full._pool.values_shape[3:] == (1, 128)
+    assert int8._pool.values_shape[3:] == (2, 64)
+    assert int8._pool.k[1].shape == int8._pool.values_shape[:4]
+    assert [r["tokens"] for r in _run(int8, prompts)] == [
+        r["tokens"] for r in _run(full, prompts)
+    ] == _greedy_refs(auto, prompts, 6)
+
+
 def test_int8_pool_halves_kv_bytes():
     """The capacity claim behind kv_cache_dtype: the int8 pool's value
     arrays are half the bf16-equivalent bytes (scale overhead is 1/(2H)

@@ -95,7 +95,11 @@ def _instructions(hlo: str):
 
 
 def test_reader_holds_the_same_vocabulary():
-    assert tuple(program_trace.VOCABULARY) == tuple(SCOPES)
+    # every name the harness's (frozen) vocabulary knows is a program scope,
+    # in the program's order; the program may name more (a newer family's
+    # scopes, read by their own metrics through the op's path segments)
+    assert tuple(s for s in SCOPES if s in program_trace.VOCABULARY) == tuple(
+        program_trace.VOCABULARY)
     # a leaf names one scope only, or the reader could not tell them apart
     leaves = [s.rsplit("/", 1)[-1] for s in SCOPES]
     assert len(set(leaves)) == len(leaves)
@@ -108,7 +112,10 @@ def test_every_scope_is_in_the_programs_op_names(programs):
     for low, _ in programs.values():
         for name in re.findall(r'loc\("([^"/][^"]*)"', low.as_text(debug_info=True)):
             found.add(program_trace.scope_of(name))  # file names start with "/"
-    assert found - {program_trace.UNSCOPED} == set(SCOPES)
+    # this model writes every scope but a recurrent family's own
+    # (tests/test_serving_recurrent_state.py finds those in its programs)
+    assert found - {program_trace.UNSCOPED} == set(program_trace.VOCABULARY)
+    assert set(SCOPES) - set(program_trace.VOCABULARY) == {"conv", "kv_write/state_write"}
 
 
 @pytest.mark.parametrize("program,must_have", [

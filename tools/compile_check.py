@@ -100,20 +100,28 @@ def serve_programs(cfg, ctx):
         ``paged.place_pool`` would place the arrays."""
 
         def _init_pool_arrays(self) -> None:
-            mcfg = self.model.config
             pool = jax.eval_shape(
-                lambda: paged.init_pool(
-                    int(mcfg.num_layers), self.config.num_blocks,
-                    self.config.block_size, int(mcfg.num_kv_heads),
-                    int(mcfg.head_dim), dtype=self._compute_dtype,
-                    quantized=self._quantized,
+                lambda: paged.layout_pool(
+                    self._layout, self.config.slots, self.config.num_blocks,
+                    self.config.block_size, dtype=self._compute_dtype,
+                    quantized=self._quantized, mesh_ctx=self.auto.mesh_ctx,
                 )
             )
+            # the pool is a real array when the programs run: row-major, as
+            # `jnp.zeros` makes it. Left open, the compiler picks the layout
+            # each program likes best for its parameter and hides the
+            # relayout copies it would make around the program on the chip
+            from jax.experimental.layout import Format, Layout
+
             self._pool = jax.tree.map(
-                lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+                lambda a, s: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype,
+                    sharding=Format(Layout(major_to_minor=tuple(range(a.ndim))), s),
+                ),
                 pool,
                 paged.pool_shardings(
-                    self.auto.mesh_ctx, int(mcfg.num_kv_heads), self._quantized
+                    self.auto.mesh_ctx, pool.values_shape[3], self._quantized,
+                    pool.state is not None,
                 ),
             )
 
@@ -137,7 +145,9 @@ def serve_programs(cfg, ctx):
         (
             "chunk_prefill", eng._chunk,
             (eng.auto.params, eng._pool, sds((NB,), jnp.int32),
-             sds((eng.config.prefill_chunk,), jnp.int32), i32, i32),
+             sds((eng.config.prefill_chunk,), jnp.int32), i32, i32,
+             # a layout with recurrent state also names the slot's state row
+             *((i32,) if eng._pool.state is not None else ())),
         ),
         (
             "paged_decode", eng._decode,
