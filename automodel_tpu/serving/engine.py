@@ -56,6 +56,7 @@ here. HBM cost is bounded by ``max_seq_len``, not the window.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import logging
 import time
@@ -74,6 +75,7 @@ from automodel_tpu.generation.engine import (
 )
 from automodel_tpu.generation import kv_cache
 from automodel_tpu.generation.sampling import sample
+from automodel_tpu.ops import paged_attention
 from automodel_tpu.serving import paged
 from automodel_tpu.serving.block_pool import (
     BlockPool,
@@ -860,6 +862,9 @@ class ServingEngine:
         self._n_admitted = self._n_chunks = 0
         self._n_decoded = self._n_context_tokens = 0
         self._n_state_resets = 0  # prompts whose FIRST chunk ran this step
+        # the fused decode kernel's grid over a K/V layer, and its steps that
+        # hold a live page (ops/paged_attention.grid_steps)
+        self._n_attn_grid_steps = self._n_attn_live_steps = 0
         # live weight hot-swap (swap_weights): monotonic version tag
         # advertised on /stats + /metrics, and the validated replacement
         # tree staged until a step boundary with zero busy slots
@@ -1017,6 +1022,30 @@ class ServingEngine:
         if not self._stateful:
             return 0
         return sum(s is not None and s.prefill_pos > 0 for s in self._slots)
+
+    @functools.cached_property
+    def attn_pages_per_step(self) -> int:
+        """Pool pages the fused decode kernel attends a grid step
+        (ops/paged_attention.pages_per_step), from what the kernel itself
+        sees: one TP shard's page of the pool as allocated (packed heads)
+        and the query rows a KV head."""
+        k = self._pool.k
+        values = k[0] if self._quantized else k
+        block_size, nkv, width = values.shape[-3:]
+        mesh_ctx = self.auto.mesh_ctx
+        names = mesh_ctx and kv_cache.usable_axes(mesh_ctx, nkv, "tensor")
+        shards = int(np.prod([mesh_ctx.mesh.shape[a] for a in names])) if names else 1
+        return paged_attention.pages_per_step(
+            block_size, nkv // shards, width,
+            self._attn_query_rows * int(self.model.config.num_heads) // nkv,
+            values.dtype.itemsize, self._quantized,
+        )
+
+    @property
+    def _attn_query_rows(self) -> int:
+        """Query rows a slot hands the decode kernel: the verify chunk of a
+        speculative engine, else the one new token."""
+        return self.config.speculative.k + 1 if self._spec_enabled else 1
 
     @property
     def pool_bytes(self) -> int:
@@ -2388,6 +2417,15 @@ class ServingEngine:
         the active slots and the context tokens their attention covers."""
         self._n_decoded = int(self._active.sum())
         self._n_context_tokens = int(self._lengths[self._active].sum())
+        if self.decode_backend == "fused":
+            # every slot's row, active or not: the kernel attends them all
+            self._n_attn_grid_steps, self._n_attn_live_steps = (
+                paged_attention.grid_steps(
+                    self._lengths, self._tables.shape[1],
+                    pages=self.attn_pages_per_step,
+                    block_size=self.config.block_size, sq=self._attn_query_rows,
+                )
+            )
 
     def _spec_decode_tick(self) -> list[dict]:
         """One speculative round for the whole decode wave: the draft
@@ -2592,6 +2630,7 @@ class ServingEngine:
         ):
             self._n_admitted = self._n_chunks = self._n_state_resets = 0
             self._n_decoded = self._n_context_tokens = 0
+            self._n_attn_grid_steps = self._n_attn_live_steps = 0
             done = self._iterate()
             self.step_phase = None
             with TraceAnnotation(
@@ -2599,6 +2638,8 @@ class ServingEngine:
                 chunks=self._n_chunks, decoded=self._n_decoded,
                 context_tokens=self._n_context_tokens, finished=len(done),
                 state_resets=self._n_state_resets, state_slots=self.state_slots,
+                attn_grid_steps=self._n_attn_grid_steps,
+                attn_live_steps=self._n_attn_live_steps,
             ):
                 pass
         return done

@@ -1,4 +1,4 @@
-"""Tier-1 runs the benchmark's program-trace reader tests too.
+"""Tier-1 runs the benchmark's program-trace and counter reader tests too.
 
 ``benchmarks/tests`` is the harness's own suite and is not collected by
 ``pytest tests/``; the reader there is the other half of the spans and scopes
@@ -15,10 +15,16 @@ ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-_spec = importlib.util.spec_from_file_location(
-    "benchmarks_tests_program_trace", ROOT / "benchmarks" / "tests" / "test_program_trace.py")
-_module = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(_module)
-# the tests and the fixtures they ask for, under the names pytest looks for
-globals().update({k: v for k, v in vars(_module).items()
-                  if k.startswith("test_") or k in ("train_xplane", "train_ops", "serve_xplane")})
+FIXTURES = ("train_xplane", "train_ops", "serve_xplane")
+# the trace reader, and the reader of the counter PR 29's engine writes
+# (``attn_grid_steps`` / ``attn_live_steps`` of ``serve.counts``)
+for _name in ("program_trace", "paged_grid_live_pct"):
+    # ``benchmarks_tests_program_trace`` is the name that file looks for to
+    # bring the architecture-dispatch cases along
+    _spec = importlib.util.spec_from_file_location(
+        f"benchmarks_tests_{_name}", ROOT / "benchmarks" / "tests" / f"test_{_name}.py")
+    _module = importlib.util.module_from_spec(_spec)
+    _spec.loader.exec_module(_module)
+    # the tests and the fixtures they ask for, under the names pytest looks for
+    globals().update({k: v for k, v in vars(_module).items()
+                      if k.startswith("test_") or k in FIXTURES})

@@ -112,6 +112,30 @@ def _kernel_case(seed=0, B=3, N=4, Nkv=2, H=16, NB=12, BS=4, NBseq=5):
     return kp, vp, tables, lengths
 
 
+# pages of [16, 2, 128] float32 are ones the kernel copies itself, 32 a grid
+# step (512 positions): a table 69 wide is three groups, the last one of 5
+_GROUP_BS, _GROUP_NBSEQ, _GROUP_POSITIONS = 16, 69, 512
+
+
+def _grouped_case(seed, sq, Nkv=2, H=128, NB=48):
+    """Slots whose lengths sit at the edges of the kernel's groups: one
+    position short of a group, exactly one, one over; an empty slot whose
+    table points at scratch block 0; query rows straddling the edge
+    (508 + Sq - 1 >= 512); a context in the second group; the table full."""
+    from automodel_tpu.ops import paged_attention as pa
+
+    BS, NBseq = _GROUP_BS, _GROUP_NBSEQ
+    per = pa.pages_per_step(BS, Nkv, H, sq * 2, 4) * BS
+    assert per == _GROUP_POSITIONS and NBseq * BS % per  # groups, and a ragged last one
+    rng = np.random.default_rng(seed)
+    kp = jnp.asarray(rng.normal(size=(NB, BS, Nkv, H)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(NB, BS, Nkv, H)), jnp.float32)
+    lengths = [per - 1, per, per + 1, 0, per - 4, per + 200, NBseq * BS - sq]
+    tables = rng.integers(1, NB, size=(len(lengths), NBseq))
+    tables[3] = 0
+    return kp, vp, jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32)
+
+
 def _gather_ref(q, kp, vp, tables, lengths, window=None, cap=None):
     from automodel_tpu.ops.attention import sdpa_decode
 
@@ -129,37 +153,78 @@ def _gather_ref(q, kp, vp, tables, lengths, window=None, cap=None):
     return sdpa_decode(q, view_k, view_v, kv_mask=mask, logits_soft_cap=cap)
 
 
-@pytest.mark.parametrize("sq", [1, 4])
-@pytest.mark.parametrize("window,cap", [(None, None), (6, None), (None, 5.0)])
-def test_paged_attend_kernel_parity_vs_gather(sq, window, cap):
+@pytest.mark.parametrize(
+    "geometry,sq,window,cap",
+    [("h16", sq, w, c) for sq in (1, 4) for w, c in [(None, None), (6, None), (None, 5.0)]]
+    + [
+        # the kernel's own groups of pages (``_grouped_case``): lengths at a
+        # group's edges, a table the group does not divide, verify rows
+        # across an edge, a window that opens in one group and closes in
+        # the next (length 712: positions 413..712, the edge at 512), a cap
+        ("groups", 1, None, None),
+        ("groups", 5, None, None),
+        ("groups", 1, 300, None),
+        ("groups", 5, 300, 5.0),
+        # heads of 64 packed two a lane row in a stacked pool, layer 1
+        ("packed-layer1", 1, None, None),
+        ("packed-layer1", 5, 300, None),
+    ],
+)
+def test_paged_attend_kernel_parity_vs_gather(geometry, sq, window, cap):
     """The fused kernel == the gathered-view sdpa_decode path: decode
-    (Sq=1) and verify-chunk (Sq=4) queries, causal per-query masks,
-    sliding window, logit soft cap."""
+    (Sq=1) and verify-chunk (Sq>1) queries, causal per-query masks,
+    sliding window, logit soft cap; one page a step (heads of 16) and
+    groups of 32 pages the kernel copies itself (heads of 128)."""
+    from automodel_tpu.generation import kv_cache
     from automodel_tpu.ops import paged_attention as pa
 
-    kp, vp, tables, lengths = _kernel_case()
     rng = np.random.default_rng(7)
-    q = jnp.asarray(rng.normal(size=(3, sq, 4, 16)), jnp.float32)
-    out = pa.paged_attend(
-        q, kp, vp, tables, lengths,
-        sliding_window=window, logits_soft_cap=cap, interpret=True,
-    )
+    kw = dict(sliding_window=window, logits_soft_cap=cap, interpret=True)
+    if geometry == "h16":
+        kp, vp, tables, lengths = _kernel_case()
+        q = jnp.asarray(rng.normal(size=(3, sq, 4, 16)), jnp.float32)
+        out = pa.paged_attend(q, kp, vp, tables, lengths, **kw)
+    elif geometry == "groups":
+        kp, vp, tables, lengths = _grouped_case(1, sq)
+        q = jnp.asarray(rng.normal(size=(len(lengths), sq, 4, 128)), jnp.float32)
+        out = pa.paged_attend(q, kp, vp, tables, lengths, **kw)
+    else:
+        # 4 KV heads of 64 live as [.., 2, 128] rows in layer 1 of a stacked
+        # pool; the reference attends the same pages unpacked
+        kp, vp, tables, lengths = _grouped_case(2, sq)
+        assert kv_cache.packed_heads(4, 64) == kp.shape[-2:]
+        q = jnp.asarray(rng.normal(size=(len(lengths), sq, 8, 64)), jnp.float32)
+        other = jnp.full_like(kp, jnp.nan)  # layer 0 must never be read
+        out = pa.paged_attend(
+            q, jnp.stack([other, kp]), jnp.stack([other, vp]), tables, lengths,
+            layer=1, **kw,
+        )
+        kp, vp = kv_cache.unpack_heads(kp, 64), kv_cache.unpack_heads(vp, 64)
     ref = _gather_ref(q, kp, vp, tables, lengths, window=window, cap=cap)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
-def test_paged_attend_kernel_parity_int8_blocks():
+@pytest.mark.parametrize("geometry", ["h16", "h128-group-edges"])
+def test_paged_attend_kernel_parity_int8_blocks(geometry):
     """Int8 pool blocks: the kernel's in-kernel dequant == dequantize the
     whole pool then run the gather reference; quantize∘dequantize is
     idempotent (the chunk-prefill rewrite-the-view scatter must not
-    drift)."""
+    drift). Its scales ``[NB, BS, Nkv]`` are not pages the kernel can copy
+    out of HBM itself, so an int8 pool runs one page a step at any width:
+    the same lengths as the grouped cases must come out the same."""
     from automodel_tpu.ops import paged_attention as pa
 
-    kp, vp, tables, lengths = _kernel_case(seed=3)
+    if geometry == "h16":
+        kp, vp, tables, lengths = _kernel_case(seed=3)
+        shape = (3, 2, 4, 16)
+    else:
+        kp, vp, tables, lengths = _grouped_case(3, 2)
+        shape = (len(lengths), 2, 4, 128)
+        assert pa.pages_per_step(_GROUP_BS, 2, 128, 4, 1, quantized=True) == 1
     kq, ks = pa.quantize_kv_rows(kp)
     vq, vs = pa.quantize_kv_rows(vp)
     rng = np.random.default_rng(9)
-    q = jnp.asarray(rng.normal(size=(3, 2, 4, 16)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=shape), jnp.float32)
     out = pa.paged_attend(q, kq, vq, tables, lengths, ks, vs, interpret=True)
     kd = pa.dequantize_kv(kq, ks, jnp.float32)
     vd = pa.dequantize_kv(vq, vs, jnp.float32)
@@ -167,6 +232,56 @@ def test_paged_attend_kernel_parity_int8_blocks():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
     kq2, ks2 = pa.quantize_kv_rows(pa.dequantize_kv(kq, ks, jnp.float32))
     assert bool((kq2 == kq).all()) and np.allclose(np.asarray(ks2), np.asarray(ks))
+
+
+@pytest.mark.parametrize(
+    "shape,pages",
+    [
+        # (block size, kv heads, head dim, Sq x rep, element bytes, int8 pool)
+        ((16, 8, 128, 6, 2, False), 32),  # MiniMax-M2's pool, one layer
+        ((16, 4, 128, 8, 2, False), 32),  # LFM2's, heads of 64 packed in twos
+        ((16, 8, 128, 5 * 6, 2, False), 32),  # a verify chunk of 5
+        ((32, 8, 128, 6, 2, False), 16),  # the same positions a step
+        ((16, 16, 128, 4, 2, False), 16),  # halved until the step fits VMEM
+        ((16, 8, 128, 6, 1, True), 1),  # int8: scales are no page to copy
+        ((16, 8, 64, 6, 2, False), 1),  # heads of 64 unpacked: half a lane row
+        ((16, 1, 128, 8, 2, False), 1),  # one bfloat16 head: half a sublane
+        ((16, 64, 128, 1, 4, False), 4),  # and halved again
+    ],
+)
+def test_pages_per_step_from_shapes(shape, pages):
+    """The pages a grid step is derived from what a call can see, and the
+    step it gives fits the budget the sweep filter holds it to."""
+    from automodel_tpu.ops import paged_attention as pa
+
+    bs, nkv, h, sr, itemsize, quantized = shape
+    assert pa.pages_per_step(bs, nkv, h, sr, itemsize, quantized) == pages
+    assert pa._paged_budget_ok(bs, nkv, h, 1, sr, itemsize, quantized)
+    assert pa._step_bytes(pages, bs, nkv, h, sr, itemsize, quantized) <= pa._VMEM_BUDGET
+
+
+def test_grid_steps_match_the_kernels_live_pages():
+    """The host's count of live grid steps is the kernel's own arithmetic:
+    a group is live iff one of its pages holds a position that the
+    slot's query rows attend."""
+    from automodel_tpu.ops import paged_attention as pa
+
+    BS, NBseq, P = 16, 37, 16
+    for sq, window in [(1, None), (5, None), (1, 200), (5, 40)]:
+        lengths = np.array([0, 1, 15, 16, 255, 256, 257, 252, 400, 592 - sq])  # 256 a group
+        grid, live = pa.grid_steps(
+            lengths, NBseq, pages=P, block_size=BS, sq=sq, window=window
+        )
+        assert grid == len(lengths) * 3
+        want = 0
+        for n in lengths:
+            # positions some query row attends: row qi sits at n + qi
+            seen = np.zeros(NBseq * BS, bool)
+            for qi in range(sq):
+                a = 0 if window is None else max(0, n + qi - window + 1)
+                seen[a : n + qi + 1] = True
+            want += sum(seen[g * P * BS : (g + 1) * P * BS].any() for g in range(3))
+        assert live == want, (sq, window, live, want)
 
 
 def test_fused_engine_greedy_parity(monkeypatch):

@@ -208,3 +208,73 @@ def test_stall_evidence_names_the_phase(tmp_path):
         assert "in phase decode_dispatch" in event["detail"]
     finally:
         eng.stop_watchdog()
+
+
+def test_counts_carry_the_fused_kernel_s_grid(monkeypatch):
+    """``attn_grid_steps`` / ``attn_live_steps`` of ``serve.counts`` are the
+    fused decode kernel's own grouping of the lengths it is handed: heads of
+    128 in blocks of 16 are pages it reads 32 a grid step, a table 69 wide is
+    three groups a slot, and a group is live up to the one holding position
+    ``length`` (the row written just before the attend)."""
+    from automodel_tpu.models.llama import LlamaForCausalLM
+    from automodel_tpu.ops import paged_attention
+    from automodel_tpu.serving import engine as engine_module
+
+    monkeypatch.setenv("AUTOMODEL_FLASH_INTERPRET", "1")
+    model = LlamaForCausalLM(
+        TransformerConfig(vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=1,
+                          num_heads=2, num_kv_heads=2, head_dim=128),
+        FP32,
+    )
+    auto = AutoModel(model=model, params=model.init(jax.random.key(0)), adapter=None,
+                     mesh_ctx=None)
+    eng = ServingEngine(
+        auto,
+        ServeConfig(slots=4, block_size=16, num_blocks=96, prefill_chunk=16,
+                    max_seq_len=16 * 69, decode_kernel="fused"),
+        GenerationConfig(max_new_tokens=3, greedy=True),
+    )
+    assert eng.decode_backend == "fused" and eng.attn_pages_per_step == 32
+    assert eng.attn_pages_per_step == paged_attention.pages_per_step(16, 2, 128, 1, 4)
+
+    # by hand: an empty slot still attends position 0 of its scratch page; 511
+    # ends the first group, 512 opens the second, an inactive slot counts too
+    eng._lengths[:] = [0, 511, 512, 600]
+    eng._active[:] = [False, True, True, False]
+    eng._note_decode_wave()
+    assert (eng._n_attn_grid_steps, eng._n_attn_live_steps) == (4 * 3, 1 + 1 + 2 + 2)
+    eng._lengths[:] = 0
+    eng._active[:] = False
+
+    # through step(): the event carries them, from the lengths the program read
+    events, handed = [], []
+
+    class Recorder:
+        def __init__(self, name, **stats):
+            if name == "serve.counts":
+                events.append(stats)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    real_decode = eng._decode
+
+    def spy(params, pool, tables, lengths, *rest):
+        handed.append(np.asarray(lengths).copy())
+        return real_decode(params, pool, tables, lengths, *rest)
+
+    eng._decode = spy
+    monkeypatch.setattr(engine_module, "TraceAnnotation", Recorder)
+    eng.submit(list(range(1, 20)), request_id="a")
+    eng.submit([3, 4, 5], request_id="b")
+    eng.run()
+    decoding = [e for e in events if e["decoded"]]
+    assert len(decoding) == len(handed) > 0
+    for event, lengths in zip(decoding, handed):
+        assert (event["attn_grid_steps"], event["attn_live_steps"]) == (
+            paged_attention.grid_steps(lengths, 69, pages=32, block_size=16))
+        assert event["attn_grid_steps"] == 12 and event["attn_live_steps"] == 4
+    assert all(e["attn_grid_steps"] == 0 for e in events if not e["decoded"])

@@ -75,24 +75,42 @@ def test_flash_refuses_heads_the_mesh_does_not_divide():
         ).lower(q, kv, kv)
 
 
+# B, Sq, N, H of the queries; the pool's shape; the table's width; layer
+_SMALL_PAGED = (4, None, 8, 128), (64, 16, 4, 128), 8, None
+_PAGED_CASES = {
+    "1chip-bf16-decode": (1, {}, 1, False, *_SMALL_PAGED),
+    "tp4-int8-verify": (4, {"dp_shard": 1, "tp": 4}, 5, True, *_SMALL_PAGED),
+    # the two serve cells at their published widths, so that pages a grid
+    # step which overflow VMEM, or a page the kernel cannot copy out of HBM,
+    # fail here and not on the chip. LFM2-8B-A1B: 128 slots, 8 KV heads of 64
+    # packed two a lane row, layer 1 of the stacked pool of its 3 K/V layers
+    "1chip-lfm2-cell": (1, {}, 1, False, (128, None, 32, 64), (3, 16384, 16, 4, 128), 544, 1),
+    # MiniMax-M2: 64 slots, 48 Q / 8 KV heads of 128, a one-layer pool
+    "1chip-minimax-cell": (1, {}, 1, False, (64, None, 48, 128), (20480, 16, 8, 128), 544, None),
+    # and its verify chunk of 5: 30 query rows a KV head in the same step
+    "1chip-minimax-verify": (1, {}, 5, False, (64, None, 48, 128), (20480, 16, 8, 128), 544, None),
+}
+
+
 @pytest.mark.parametrize(
-    "n,degrees,sq,int8",
-    [(1, {}, 1, False), (4, {"dp_shard": 1, "tp": 4}, 5, True)],
-    ids=["1chip-bf16-decode", "tp4-int8-verify"],
+    "n,degrees,sq,int8,q_shape,pool_shape,nbseq,layer",
+    list(_PAGED_CASES.values()), ids=list(_PAGED_CASES),
 )
-def test_paged_decode_lowers(n, degrees, sq, int8):
+def test_paged_decode_lowers(n, degrees, sq, int8, q_shape, pool_shape, nbseq, layer):
     ctx = _tpu_ctx(n, **degrees)
-    B, N, Nkv, H, NB, BS, NBseq = 4, 8, 4, 128, 64, 16, 8
+    B, _, N, H = q_shape
+    lead = (None,) * (len(pool_shape) - 4)
     q = _sds(ctx, (B, sq, N, H), jnp.bfloat16, None, None, "tensor", None)
     pool = _sds(
-        ctx, (NB, BS, Nkv, H), jnp.int8 if int8 else jnp.bfloat16,
-        None, None, "tensor", None,
+        ctx, pool_shape, jnp.int8 if int8 else jnp.bfloat16,
+        *lead, None, None, "tensor", None,
     )
-    args = [q, pool, pool, _sds(ctx, (B, NBseq), jnp.int32), _sds(ctx, (B,), jnp.int32)]
+    args = [q, pool, pool, _sds(ctx, (B, nbseq), jnp.int32), _sds(ctx, (B,), jnp.int32)]
     if int8:
-        scale = _sds(ctx, (NB, BS, Nkv), jnp.float32, None, None, "tensor")
+        scale = _sds(ctx, pool_shape[:-1], jnp.float32, *lead, None, None, "tensor")
         args += [scale, scale]
-    assert _compile(functools.partial(paged_attend, mesh_ctx=ctx), *args) == 1
+    kw = {} if layer is None else {"layer": layer}
+    assert _compile(functools.partial(paged_attend, mesh_ctx=ctx, **kw), *args) == 1
 
 
 @pytest.mark.parametrize(
