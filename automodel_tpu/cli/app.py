@@ -99,7 +99,7 @@ def main(argv: list[str] | None = None) -> int:
 def _run(argv: list[str] | None = None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
     # `report` takes a JSONL path, not a domain: validate + summarize a
-    # metrics file (telemetry/report.py — same linter bench.py uses)
+    # metrics file (telemetry/report.py)
     if argv and argv[0] == "report":
         from automodel_tpu.telemetry.report import main as report_main
 

@@ -163,25 +163,20 @@ def _pick_block(pref: int, s: int) -> int:
     return 128
 
 
-def _autotune_entry(head_dim: int, window: Optional[int], causal: bool):
-    """Per-shape backend + block selection from the per-chip autotune table
-    (ops/autotune.py; swept by tools/kernel_bench.py). The static 512/512
-    splash blocks are sized for head_dim 128 — at head_dim 64 the MXU runs
-    half-empty (PROFILE_MOE_r05: 59.3 TFLOP/s fwd+bwd ≈ 30% of v5e peak) —
-    so the table carries measured blocks per (head_dim, window, causal)
-    shape and, where the in-tree blockwise kernel (ops/ring_flash) wins the
-    race, routes the shape there. No entry → splash with the static
-    defaults (exactly the pre-table behavior)."""
-    from automodel_tpu.ops import autotune
-
-    entry = autotune.lookup(autotune.attn_key(head_dim, window, causal))
-    if entry is None:
-        return None
-    out = {"backend": entry.get("backend", "splash")}
-    blocks = autotune.valid_tiles(entry, ("block_q", "block_kv"), None)
-    if blocks is not None:
-        out["block_q"], out["block_kv"] = blocks
-    return out
+def _splash_blocks(head_dim: int, window: Optional[int]) -> tuple[int, int]:
+    """(block_q, block_kv) for causal splash by problem shape. The static
+    512/512 blocks are sized for head_dim 128. At head_dim 64 a block holds
+    half the VMEM, so shorter q blocks keep more kv resident per pass; a
+    128-token window under one 512 kv block wants kv blocks small enough
+    for LocalMask's block pruning to skip the dead tiles (and their DMAs).
+    The two head-64 rows are heuristics no chip run has timed (no cell of
+    the benchmark runs splash at head_dim 64)."""
+    if head_dim == 64:
+        if not window:
+            return 256, 512
+        if window == 128:
+            return 256, 128
+    return 512, 512
 
 
 @functools.partial(
@@ -330,38 +325,13 @@ def flash(
             sinks=sinks,
         )
     scale = scale if scale is not None else 1.0 / (h**0.5)
-    entry = _autotune_entry(h, sliding_window, causal)
-    entry_backend = entry.get("backend", "splash") if entry is not None else None
     # an explicit attn_block_q/attn_block_kv in the backend config wins
-    # outright: it pins the splash path with the caller's blocks (explicit
-    # tuning was done against splash — rerouting it to the block kernel
-    # would hand one kernel's blocks to the other). The table only acts on
-    # the STATIC 512/512 defaults; soft cap also forces splash (the
-    # blockwise kernels don't carry it).
-    default_blocks = (block_q, block_kv) == (512, 512)
-    take_block_path = (
-        entry_backend == "block" and logits_soft_cap is None and default_blocks
-    )
-    if entry is not None and default_blocks and (
-        take_block_path or entry_backend == "splash"
-    ):
-        # only the path the entry was raced on inherits its blocks — a
-        # block-backend entry forced onto splash (soft cap) keeps splash's
-        # static defaults rather than the other kernel's measured blocks
-        block_q = entry.get("block_q", block_q)
-        block_kv = entry.get("block_kv", block_kv)
+    # outright; the shape rule only acts on the STATIC 512/512 defaults
+    if (block_q, block_kv) == (512, 512):
+        block_q, block_kv = _splash_blocks(h, sliding_window)
     interpret = _interpret_requested()
 
     def kernel(q, k, v, segment_ids, sinks):
-        if take_block_path:
-            from automodel_tpu.ops import ring_flash
-
-            return ring_flash.flash_attention(
-                q, k, v,
-                causal=causal, scale=scale, segment_ids=segment_ids,
-                sliding_window=sliding_window, sinks=sinks,
-                block_q=block_q, block_kv=block_kv, interpret=interpret,
-            )
         return _splash_flash(
             q, k, v, segment_ids, sinks,
             causal=causal, scale=scale, logits_soft_cap=logits_soft_cap,

@@ -11,7 +11,7 @@ intermediate never materializes. Rows are lhs-masked (write-only outputs;
 boundary tiles accumulate across consecutive work units like
 ops/grouped_matmul._tgmm).
 
-Backward motivation (PROFILE_MOE_r05.md): the r5 backward composed generic
+Backward motivation (docs/history/PROFILE_MOE_r05.md): the r5 backward composed generic
 ``_tgmm``/transpose-GEMM calls and gave the forward win back (34.40 ms
 fused FWD+BWD vs 33.53 unfused; gmm2-class tiles ran 84.3 TFLOP/s vs
 gmm1's 107.0). The backward here is three purpose-tiled kernels that fold
@@ -23,8 +23,8 @@ and ``lhs`` is read once for both weight grads:
 - ``_bwd_dwd``  — dWd (+ ddb) with the activation mid recomputed in-kernel.
 - ``_bwd_dx``   — dlhs = dg·Wg^T + du·Wu^T fused over I-chunks.
 
-Tile shapes consult the per-chip autotune registry (ops/autotune.py, swept
-by tools/kernel_bench.py); the NaN-tail masking semantics from PR 5 are
+Tile shapes come from the pickers beside each kernel (the shape and
+`_VMEM_BUDGET`); the NaN-tail masking semantics from PR 5 are
 preserved bit-for-bit — every row outside a work unit's window (boundary
 rows of the neighbouring group AND the a2a sentinel tail) is zeroed on the
 ``dout`` side in-kernel, where 0·NaN can no longer survive.
@@ -148,7 +148,7 @@ def _col_off(fused: bool, I: int, block: int) -> int:
     """Column-BLOCK offset of the up half inside a fused [.., 2I] array."""
     if not fused:
         return 0
-    assert I % block == 0, (I, block)  # in_place_ok + the exact tile pickers
+    assert I % block == 0, (I, block)  # in_place_ok + the divisor-chunk pickers
     return I // block
 
 
@@ -399,18 +399,6 @@ def _act_grads(g, u, dmid, act_kind, limit):
 _VMEM_BUDGET = 12 * 1024 * 1024
 
 
-def _autotune_tiles(key, names, budget_fn, fallback):
-    from automodel_tpu.ops import autotune
-
-    tiles = autotune.valid_tiles(autotune.lookup(key), names, budget_fn)
-    return tiles if tiles is not None else fallback
-
-
-# the per-kernel VMEM-budget models are module-level so the sweep driver
-# (tools/kernel_bench.py) filters candidates with the SAME predicate the
-# kernel validates entries against — they can never drift apart
-
-
 def _bwd_gu_budget_ok(tm, tk, tn, itemsize):
     need = (
         2 * itemsize * tm * tk          # lhs block
@@ -439,56 +427,33 @@ def _bwd_dx_budget_ok(tm, tn, ic, itemsize):
     return need <= _VMEM_BUDGET
 
 
-# ``exact``: the operands are fused [.., 2I] arrays read in place, so a table
-# entry must also tile D and I with no remainder (nothing may be padded; the
-# divisor-chunk fallbacks always do at `in_place_ok` widths).
-
-
-def _bwd_gu_tiles(D, I, dtype, exact=False):
-    from automodel_tpu.ops import autotune
-
+def _bwd_tiles(budget_ok, a, b, dtype):
+    """(tm, divisor chunk of a, divisor chunk of b): tm 512, halved until
+    the kernel's VMEM model holds. The chunks divide the 128-padded dims, so
+    at `in_place_ok` widths (fused [.., 2I] operands read in place) nothing
+    but rows is ever padded."""
     it = jnp.dtype(dtype).itemsize
-    ok = lambda tm, tk, tn: _bwd_gu_budget_ok(tm, tk, tn, it) and not (
-        exact and (D % tk or I % tn)
-    )
-    fb_tk = _divisor_chunk(_round_up(D, 128))
-    fb_tn = _divisor_chunk(_round_up(I, 128))
-    fb = (512, fb_tk, fb_tn)
-    while not ok(*fb) and fb[0] > 128:
-        fb = (fb[0] // 2, fb_tk, fb_tn)
-    return _autotune_tiles(
-        autotune.moe_bwd_gu_key(D, I, dtype), ("tm", "tk", "tn"), ok, fb
-    )
+    ca = _divisor_chunk(_round_up(a, 128))
+    cb = _divisor_chunk(_round_up(b, 128))
+    tm = 512
+    while not budget_ok(tm, ca, cb, it) and tm > 128:
+        tm //= 2
+    return tm, ca, cb
 
 
-def _bwd_dwd_tiles(I, D, dtype, exact=False):
-    from automodel_tpu.ops import autotune
-
-    it = jnp.dtype(dtype).itemsize
-    ok = lambda tm, tk, tn: _bwd_dwd_budget_ok(tm, tk, tn, it) and not (
-        exact and I % tk
-    )
-    fb = (512, _divisor_chunk(_round_up(I, 128)), _divisor_chunk(_round_up(D, 128)))
-    while not ok(*fb) and fb[0] > 128:
-        fb = (fb[0] // 2, fb[1], fb[2])
-    return _autotune_tiles(
-        autotune.moe_bwd_dwd_key(I, D, dtype), ("tm", "tk", "tn"), ok, fb
-    )
+def _bwd_gu_tiles(D, I, dtype):
+    """(tm, tk over D, tn over I)."""
+    return _bwd_tiles(_bwd_gu_budget_ok, D, I, dtype)
 
 
-def _bwd_dx_tiles(D, I, dtype, exact=False):
-    from automodel_tpu.ops import autotune
+def _bwd_dwd_tiles(I, D, dtype):
+    """(tm, tk over I, tn over D)."""
+    return _bwd_tiles(_bwd_dwd_budget_ok, I, D, dtype)
 
-    it = jnp.dtype(dtype).itemsize
-    ok = lambda tm, tn, ic: _bwd_dx_budget_ok(tm, tn, ic, it) and not (
-        exact and (D % tn or I % ic)
-    )
-    fb = (512, _divisor_chunk(_round_up(D, 128)), _divisor_chunk(_round_up(I, 128)))
-    while not ok(*fb) and fb[0] > 128:
-        fb = (fb[0] // 2, fb[1], fb[2])
-    return _autotune_tiles(
-        autotune.moe_bwd_dx_key(D, I, dtype), ("tm", "tn", "ic"), ok, fb
-    )
+
+def _bwd_dx_tiles(D, I, dtype):
+    """(tm, tn over D, ic over I)."""
+    return _bwd_tiles(_bwd_dx_budget_ok, D, I, dtype)
 
 
 def _bwd_gu_kernel(wg, wt, ws, we, lhs_ref, g_ref, u_ref, dmid_ref,
@@ -544,7 +509,7 @@ def _bwd_gu(lhs, g, u, dmid, group_sizes, act_kind, limit, interpret,
     fused = u is None
     g, u, I = _halves(g, u)
     G = group_sizes.shape[0]
-    tm, tk, tn = _bwd_gu_tiles(D, I, lhs.dtype, exact=fused)
+    tm, tk, tn = _bwd_gu_tiles(D, I, lhs.dtype)
     Mp, Kp, Np = _round_up(M, tm), _round_up(D, tk), _round_up(I, tn)
     off = _col_off(fused, I, tn)
     if (Mp, Kp) != (M, D):
@@ -645,7 +610,7 @@ def _bwd_dwd(g, u, dy, group_sizes, act_kind, limit, interpret, want_db):
     g, u, I = _halves(g, u)
     _, D = dy.shape
     G = group_sizes.shape[0]
-    tm, tk, tn = _bwd_dwd_tiles(I, D, g.dtype, exact=fused)
+    tm, tk, tn = _bwd_dwd_tiles(I, D, g.dtype)
     Mp, Kp, Np = _round_up(M, tm), _round_up(I, tk), _round_up(D, tn)
     off = _col_off(fused, I, tk)
     if (Mp, Kp) != (M, I):
@@ -743,7 +708,7 @@ def _bwd_dx(g, u, dmid, gate, up, group_sizes, interpret, act_kind, limit):
     g, u, I = _halves(g, u)
     gate, up, _ = _halves(gate, up)
     G, D, _ = gate.shape
-    tm, tn, ic = _bwd_dx_tiles(D, I, g.dtype, exact=fused_m or fused_w)
+    tm, tn, ic = _bwd_dx_tiles(D, I, g.dtype)
     Mp, Np, Ip = _round_up(M, tm), _round_up(D, tn), _round_up(I, ic)
     off_m, off_w = _col_off(fused_m, I, ic), _col_off(fused_w, I, ic)
     if (Mp, Ip) != (M, I):
@@ -793,8 +758,7 @@ def _bwd_dx(g, u, dmid, gate, up, group_sizes, interpret, act_kind, limit):
 
 def _fused_bwd_enabled() -> bool:
     """AUTOMODEL_FUSED_BWD=0 falls back to the r5 composed-tgmm backward —
-    the A/B knob tools/kernel_bench.py races and a safety valve for a chip
-    where the purpose-tiled kernels regress."""
+    a safety valve for a chip where the purpose-tiled kernels regress."""
     return os.environ.get("AUTOMODEL_FUSED_BWD", "1") != "0"
 
 

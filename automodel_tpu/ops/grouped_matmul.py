@@ -99,51 +99,20 @@ def _pick_tiles(k: int, n: int, itemsize: int) -> tuple[int, int]:
     return 128, 128
 
 
-def _gmm_tiles(K: int, N: int, dtype, transpose_rhs: bool) -> tuple[int, int]:
-    """(tm, tn) for _gmm: the per-chip autotune entry when one exists and
-    fits the VMEM budget, else the static ladder above."""
-    from automodel_tpu.ops import autotune
-
-    it = jnp.dtype(dtype).itemsize
-    kp = _round_up(K, 128)
-
-    def ok(tm, tn):
-        return 2 * it * (tm * kp + kp * tn + tm * tn) <= 12 * 1024 * 1024
-
-    tiles = autotune.valid_tiles(
-        autotune.lookup(autotune.gmm_key(K, N, dtype, transpose_rhs)),
-        ("tm", "tn"), ok,
+def _gmm_tiles(K: int, N: int, dtype) -> tuple[int, int]:
+    """(tm, tn) for _gmm: the static ladder above over the padded dims."""
+    return _pick_tiles(
+        _round_up(K, 128), _round_up(N, 128), jnp.dtype(dtype).itemsize
     )
-    return tiles if tiles is not None else _pick_tiles(kp, _round_up(N, 128), it)
-
-
-def _tgmm_budget_ok(tm, tk, tn, itemsize):
-    """VMEM model for _tgmm blocks — module-level so tools/kernel_bench.py
-    filters sweep candidates with the same predicate."""
-    need = 2 * itemsize * (tm * tk + tm * tn) + 2 * 4 * tk * tn
-    return need <= 12 * 1024 * 1024
 
 
 def _tgmm_tiles(K: int, N: int, dtype) -> tuple[int, int, int]:
-    """(tm, tk, tn) for _tgmm. The contraction runs over the tm rows, so a
-    bigger tm means more MXU passes per [tk, tn] slab write-back — the
-    re-tiling lever PROFILE_MOE_r05 showed the default 512 leaving ~20% on
-    the table (gmm2-class 84.3 TFLOP/s vs gmm1's 107.0). Autotune entries
-    (tools/kernel_bench.py) win when feasible; the fallback keeps the
-    conservative 512 ladder."""
-    from automodel_tpu.ops import autotune
-
-    it = jnp.dtype(dtype).itemsize
-    ok = lambda tm, tk, tn: _tgmm_budget_ok(tm, tk, tn, it)
-
-    tiles = autotune.valid_tiles(
-        autotune.lookup(autotune.tgmm_key(K, N, dtype)), ("tm", "tk", "tn"), ok,
-    )
-    if tiles is not None:
-        return tiles
-    tm, tn = _pick_tiles(_round_up(K, 128), _round_up(N, 128), it)
-    tk = min(_round_up(K, 128), 512)
-    return tm, tk, tn
+    """(tm, tk, tn) for _tgmm: the contraction runs over the tm rows, so a
+    bigger tm means more MXU passes per [tk, tn] slab write-back. The
+    conservative 512 ladder: its blocks (two inputs double-buffered, one
+    fp32 slab) stay under 6 MB."""
+    tm, tn = _gmm_tiles(K, N, dtype)
+    return tm, min(_round_up(K, 128), 512), tn
 
 
 def _gmm_kernel(wg, wt, ws, we, lhs_ref, rhs_ref, out_ref, *, tm, tn,
@@ -180,7 +149,7 @@ def _gmm(lhs: jnp.ndarray, rhs: jnp.ndarray, group_sizes: jnp.ndarray,
     else:
         G, _, N = rhs.shape
     out_dtype = lhs.dtype
-    tm, tn = _gmm_tiles(K, N, lhs.dtype, transpose_rhs)
+    tm, tn = _gmm_tiles(K, N, lhs.dtype)
     Mp, Kp, Np = _round_up(M, tm), _round_up(K, 128), _round_up(N, tn)
     if (Mp, Kp) != (M, K):
         lhs = jnp.pad(lhs, ((0, Mp - M), (0, Kp - K)))

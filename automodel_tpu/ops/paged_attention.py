@@ -45,10 +45,9 @@ transform (quantize-on-scatter), the kernel (and the gather fallback)
 dequantize on read; quantize∘dequantize is exactly idempotent, so chunked
 prefill's rewrite-the-view scatter does not drift.
 
-The gather path stays in serving/paged.py as the fallback / A-B baseline
-(``AUTOMODEL_PAGED_DECODE=gather``); ``tools/kernel_bench.py`` races the
-two per (head_dim, block_size, kv dtype) into the autotune registry
-(``autotune.paged_key``).
+The gather path stays in serving/paged.py as the fallback where this
+kernel cannot run (``serving.decode_kernel: gather``, or ``auto`` off the
+TPU without interpret mode).
 """
 
 from __future__ import annotations
@@ -101,8 +100,8 @@ def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
     return (q.astype(jnp.float32) * scale[..., None].astype(jnp.float32)).astype(dtype)
 
 
-# -- pages a grid step, feasibility (shared with tools/kernel_bench.py and
-# -- the serving engine's grid counter) ---------------------------------------
+# -- pages a grid step, feasibility (shared with the serving engine's grid
+# -- counter) ------------------------------------------------------------------
 
 
 def _up(n: int, to: int) -> int:

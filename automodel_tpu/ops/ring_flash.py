@@ -216,8 +216,7 @@ def flash_block_fwd(q, k, v, q_pos, kv_pos, seg_q, seg_kv, *,
                     causal, window, scale, interpret=False,
                     block_q=None, block_kv=None):
     """q [B,Sq,N,H] × k/v [B,Sk,Nkv,H] → (out [B,Sq,N,H], lse [B,N,Sq]).
-    ``block_q``/``block_kv`` override the static preferences — the per-chip
-    autotune table (ops/autotune.py) threads through here."""
+    ``block_q``/``block_kv`` override the static preferences."""
     B, Sq, N, H = q.shape
     Sk, Nkv = k.shape[1], k.shape[2]
     bq = _pick_block(Sq, block_q or 512)
@@ -328,8 +327,8 @@ def flash_attention(
     with per-tile dead-tile skipping (a 128-token sliding window kills
     almost every kv tile), native GQA, packed-segment ids, gpt-oss sinks
     (folded post-merge exactly as parallel/cp.py does), and no head_dim
-    divisibility constraint — head_dim 64 runs as-is. `ops/attention.flash`
-    races this against splash per shape via the autotune table.
+    divisibility constraint — head_dim 64 runs as-is. Nothing in the
+    program calls it (tests/test_block_flash.py holds it to sdpa).
 
     q [B,S,N,H] × k/v [B,S,Nkv,H] → [B,S,N,H] in q.dtype; differentiable
     (custom_vjp on the flash identities, d_sinks included)."""

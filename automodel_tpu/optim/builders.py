@@ -110,8 +110,8 @@ def build_optimizer(
     regardless of grad dtype (torch AdamW parity — bf16 moments freeze nu,
     see scale_by_adam_fp32_moments). 'param' stores them in the param/grad
     dtype — HALVES optimizer memory; meant for memory-capacity-bound
-    benchmarking (bench.py documents this concession), not long training
-    runs."""
+    benchmarking (the benchmark's train configuration records it under
+    `assumed`), not long training runs."""
     # YAML 1.1 parses dotless scientific notation (`lr: 1e-2`) as a string;
     # coerce here so config-file values behave like `1.0e-2`
     lr, weight_decay, eps = float(lr), float(weight_decay), float(eps)

@@ -38,7 +38,7 @@ fwd/bwd-interleaved schedule (explicit per-chunk vjp in one scan) is the
 remaining true-1F1B design if profiling ever shows the AD wavefront
 leaving ICI idle between the two wavefronts.
 
-MEASURED (round 4, PROFILE_PP_r04.md): a pp=4 sweep of M∈{2..16} fits
+MEASURED (round 4, docs/history/PROFILE_PP_r04.md): a pp=4 sweep of M∈{2..16} fits
 t = T_work·(1 + (pp-1)/M) within 5% — the AD backward wavefront achieves
 the synchronous-SPMD schedule floor with no extra idle gap; 1F1B's bubble
 term is identical, and synchronous-tick interleaving measures strictly
@@ -49,7 +49,7 @@ the hand-split vjp in parallel/zero_bubble.py splits each stage backward
 into B (activation grads, stays on the ppermute wavefront) and W (weight
 grads, deferred into a bounded queue and contracted as flat bubble-free
 work after the B wave drains) — bubble 3(pp-1)/(4M+3(pp-1)), strictly
-below the GPipe law (pp-1)/(M+pp-1) at every M (PROFILE_PP_r06.md).
+below the GPipe law (pp-1)/(M+pp-1) at every M (docs/history/PROFILE_PP_r06.md).
 Select via MeshConfig.pp_schedule; bound stash memory with
 MeshConfig.pp_zb_queue.
 """

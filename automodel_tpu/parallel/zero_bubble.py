@@ -22,7 +22,7 @@ Per-rank idle drops from 3(pp-1) tick-equivalents (GPipe: fwd + AD backward
 at 3F/tick under remat) to 3(pp-1) out of a larger denominator with the W
 work bubble-free:   bubble = 3(pp-1) / (4M + 3(pp-1))  <  (pp-1)/(M+pp-1)
 for every M — strictly below the GPipe law (analytic model in
-utils/flops_utils.pipeline_bubble_fraction; measured in PROFILE_PP_r06.md).
+utils/flops_utils.pipeline_bubble_fraction; measured in docs/history/PROFILE_PP_r06.md).
 
 Mechanism for the B/W split without hand-writing the transformer backward:
 ``split_dot`` is a custom_vjp matmul whose backward returns dx immediately,

@@ -461,14 +461,11 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         # in-training eval generation (generation: YAML section,
         # docs/generation.md): sample completions at validation boundaries
         # through the KV-cache inference engine and log them to the JSONL
-        # (gen_samples + ttft_s/decode_tps). Never load-bearing: any skip
-        # reason is recorded and the benchmark recipe's decode leg reports
-        # it as a null-with-reason leg instead of a silent zero.
+        # (gen_samples + ttft_s/decode_tps). Never load-bearing: a skip is
+        # logged with its reason.
         self._gen_engine = None
         self._gen_prompts = None
         self._gen_prompt_ids = None
-        self._gen_section: dict = {}
-        self._gen_skip_reason: Optional[str] = None
         if cfg.get("generation") is not None:
             self._setup_eval_generation(dict(cfg.get("generation") or {}))
 
@@ -549,9 +546,7 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         )
 
         gcfg.pop("_target_", None)
-        self._gen_section = dict(gcfg)
         if gcfg.pop("enabled", True) is False:
-            self._gen_skip_reason = "generation.enabled: false"
             return
         prompts = gcfg.pop("prompts", None)
         prompt_ids = gcfg.pop("prompt_ids", None)
@@ -559,10 +554,9 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         if self.peft_config is not None:
             # the trainable tree is the adapter, not decodable weights;
             # merged-adapter generation is a follow-up
-            self._gen_skip_reason = (
-                "generation with peft adapters is not supported (merge first)"
+            logger.warning(
+                "generation: peft adapters are not supported (merge first)"
             )
-            logger.warning("generation: %s", self._gen_skip_reason)
             return
         # same resolution ladder as the generate CLI; the checkpoint
         # fallback only matters when text prompts are configured
@@ -577,7 +571,6 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                 self.auto, GenerationConfig.from_dict(gcfg), tokenizer=tokenizer
             )
         except GenerationUnsupported as e:
-            self._gen_skip_reason = str(e)
             logger.warning("generation: %s", e)
             return
         if prompts is not None and tokenizer is None:

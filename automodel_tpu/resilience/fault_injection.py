@@ -95,7 +95,7 @@ class FaultInjectionConfig:
     hang_at_step: Optional[int] = None
     hang_seconds: float = 3600.0  # bounded — the watchdog exits long before
     # per-batch collate delay (data/loader.py batch_for) — the input-
-    # pipeline overlap proof knob (bench.py input-pipeline A/B leg)
+    # pipeline overlap proof knob (tests/test_prefetch.py)
     slow_collate_ms: float = 0.0
     desync_batch_at_step: Optional[int] = None
     desync_on_host: int = 0  # process_index whose data hash is perturbed

@@ -485,12 +485,6 @@ class ServeConfig:
     # disaggregated pool and how much of its prefix cache it advertises
     role: str = "mixed"  # mixed | prefill | decode
     hot_prefix_advertise: int = 512  # cached chain heads exposed via /stats
-    # sustained-throughput bench knobs (recipes/benchmark.py serving leg)
-    bench_requests: int = 16
-    bench_rate: float = 8.0  # Poisson arrival rate, requests/second
-    bench_prompt_len_min: int = 8
-    bench_prompt_len_max: int = 48
-    bench_max_new_tokens: int = 16
     # production-hardening sections (docs/serving.md runbook)
     limits: LimitsConfig = dataclasses.field(default_factory=LimitsConfig)
     drain: DrainConfig = dataclasses.field(default_factory=DrainConfig)
@@ -837,7 +831,7 @@ class ServingEngine:
             )
         self._base_key = sampling_key(self.gen_config.seed)
         self._eos = set(self.gen_config.eos_ids)
-        # speculative accounting (accept-rate gauge + bench keys)
+        # speculative accounting (accept-rate gauge, /stats)
         self.spec_proposed_total = 0
         self.spec_accepted_total = 0
         self.spec_rounds = 0
@@ -941,27 +935,12 @@ class ServingEngine:
         self.program_costs: dict = {}
 
     def _resolve_decode_backend(self) -> str:
-        """fused (Pallas paged kernel) vs gather (XLA baseline):
-        ``AUTOMODEL_PAGED_DECODE`` env beats ``serving.decode_kernel``
-        beats the autotune table entry (``autotune.paged_key``, raced by
-        tools/kernel_bench.py) beats the platform default (fused wherever
-        the kernel can run — TPU or interpret mode — else gather)."""
-        import os
-
-        env = os.environ.get("AUTOMODEL_PAGED_DECODE", "").strip().lower()
-        mode = env if env in ("fused", "gather") else self.config.decode_kernel
-        if mode in ("fused", "gather"):
-            return mode
-        from automodel_tpu.ops import autotune
-
-        entry = autotune.lookup(
-            autotune.paged_key(
-                int(self.model.config.head_dim), self.config.block_size,
-                self.config.kv_cache_dtype,
-            )
-        )
-        if entry is not None and entry.get("backend") in ("fused", "gather"):
-            return entry["backend"]
+        """fused (Pallas paged kernel) vs gather (XLA path):
+        ``serving.decode_kernel`` when it names one, else the platform
+        default (fused wherever the kernel can run — TPU or interpret
+        mode — else gather)."""
+        if self.config.decode_kernel in ("fused", "gather"):
+            return self.config.decode_kernel
         from automodel_tpu.ops.platform_check import is_tpu_platform
 
         on_kernel_platform = self._interpret or is_tpu_platform(
@@ -997,9 +976,9 @@ class ServingEngine:
 
     def release_pools(self) -> None:
         """Drop the engine's HBM pool arrays (target + draft). For callers
-        that are DONE with this engine but keep the process alive — e.g.
-        the bench A/B sub-leg, which builds a second chip-sized engine and
-        must not hold two resident pools. The engine is unusable after."""
+        that are DONE with this engine but keep the process alive (the
+        benchmark harness frees the pool before its reference runs). The
+        engine is unusable after."""
         self._pool = None
         if self._spec_enabled:
             self._draft_pool = None
@@ -2767,65 +2746,3 @@ class ServingEngine:
             self.on_record(
                 {"event": "cost_attribution", "program": name, **cost}
             )
-
-    # -- workload driver (bench leg + sustained-throughput tests) -------------
-    def run_workload(
-        self, arrivals: Sequence[tuple[float, Sequence[int], Optional[int]]]
-    ) -> tuple[list[dict], dict]:
-        """Drive a timed workload: ``arrivals`` is [(offset_s, prompt_ids,
-        max_new_tokens|None)] sorted by offset. Requests are submitted when
-        their offset elapses (wall clock); the engine steps continuously in
-        between. → (completions, aggregate stats: sustained tokens/s, ttft
-        p50/p99, peak occupancy/queue depth)."""
-        arrivals = sorted(arrivals, key=lambda a: a[0])
-        t0 = time.perf_counter()
-        spec_proposed0 = self.spec_proposed_total
-        spec_accepted0 = self.spec_accepted_total
-        pending = deque(arrivals)
-        out: list[dict] = []
-        occ_peak, q_peak = 0.0, 0
-        while pending or not self.idle():
-            now = time.perf_counter() - t0
-            while pending and pending[0][0] <= now:
-                _, prompt, max_new = pending.popleft()
-                self.submit(prompt, max_new_tokens=max_new)
-            if self.idle():
-                if pending:
-                    time.sleep(min(0.001, max(pending[0][0] - now, 0.0)))
-                continue
-            out.extend(self.step())
-            occ_peak = max(occ_peak, self.pool.occupancy())
-            q_peak = max(q_peak, self.queue_depth)
-        dt = time.perf_counter() - t0
-        completions = [
-            r for r in out if r.get("completion_reason") in ("stop", "length")
-        ]
-        gen = sum(r["n_generated"] for r in completions)
-        from automodel_tpu.telemetry.report import percentile
-
-        ttfts = [
-            r["ttft_s"] for r in completions if isinstance(r.get("ttft_s"), float)
-        ]
-        stats = {
-            "requests": len(completions),
-            "gen_tokens": gen,
-            "wall_s": dt,
-            "sustained_tokens_per_s": gen / dt if dt > 0 else 0.0,
-            "ttft_p50_s": percentile(ttfts, 0.50),
-            "ttft_p99_s": percentile(ttfts, 0.99),
-            "block_occupancy_peak": round(occ_peak, 4),
-            "queue_depth_peak": q_peak,
-            "prefix_cache": dict(self.pool.counters),
-        }
-        if self._spec_enabled:
-            proposed = self.spec_proposed_total - spec_proposed0
-            accepted = self.spec_accepted_total - spec_accepted0
-            stats["spec_proposed"] = proposed
-            stats["spec_accepted"] = accepted
-            stats["accept_rate"] = (
-                round(accepted / proposed, 4) if proposed else None
-            )
-            stats["draft_tps"] = proposed / dt if dt > 0 else 0.0
-        if len(completions) != len(out):
-            stats["failed_requests"] = len(out) - len(completions)
-        return out, stats

@@ -178,9 +178,6 @@ class FleetConfig:
     disaggregate: Optional[bool] = None  # null = auto (prefill replicas seen)
     drain_grace_s: float = 10.0  # SIGTERM → in-flight forward budget
     seed: int = 0  # power-of-two-choices rng
-    # routed bench sub-leg knobs (recipes/benchmark.py _fleet_leg)
-    bench_replicas: int = 2
-    bench_num_blocks: Optional[int] = None  # default: serving.num_blocks // N
 
     def __post_init__(self):
         if self.retry_budget < 0:
@@ -196,12 +193,6 @@ class FleetConfig:
                 f"fleet.probe_backoff_max_s={self.probe_backoff_max_s} must "
                 f"be >= probe_interval_s={self.probe_interval_s} — a backoff "
                 "shorter than the sweep cadence is no backoff at all"
-            )
-        if self.bench_replicas < 2:
-            raise ValueError(
-                f"fleet.bench_replicas={self.bench_replicas} (want >= 2 — "
-                "a one-replica fleet measures nothing the serving leg "
-                "doesn't)"
             )
 
     @classmethod
@@ -503,7 +494,7 @@ class Router:
         self._stop = threading.Event()
         self._probe_thread: Optional[threading.Thread] = None
         self.draining = False
-        # plain-int mirrors of the /metrics counters for /stats + bench
+        # plain-int mirrors of the /metrics counters for /stats
         self.requests_total = 0
         self.completed_total = 0
         self.retries_total = 0
@@ -1583,14 +1574,14 @@ class Router:
             r.ready and r.role == "prefill" for r in self._replicas.values()
         )
 
-    # -- workload driver (routed bench sub-leg + chaos tests) ------------------
+    # -- workload driver (the fleet's end-to-end tests) -----------------------
     def run_workload(
         self, arrivals: Sequence[tuple[float, Sequence[int], Optional[int]]]
     ) -> tuple[list[dict], dict]:
-        """Drive the same timed-arrival workload shape as
-        ``ServingEngine.run_workload``, but through the ROUTER: one thread
-        per request submits at its offset and blocks on the routed
-        response. → (terminal bodies, aggregate stats)."""
+        """Drive a timed workload through the ROUTER: ``arrivals`` is
+        [(offset_s, prompt_ids, max_new_tokens|None)]; one thread per
+        request submits at its offset and blocks on the routed response.
+        → (terminal bodies, aggregate stats)."""
         arrivals = sorted(arrivals, key=lambda a: a[0])
         results: list[Optional[tuple[int, dict]]] = [None] * len(arrivals)
         req0 = {

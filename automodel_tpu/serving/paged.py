@@ -26,8 +26,8 @@ Programs, each compiled once per static shape and donating the pool:
   quantize-on-write. Chunking is what lets a long prompt interleave with
   the running decode wave.
 - **paged decode** — one token per active slot. Two backends, selected by
-  ``serving.decode_kernel`` / ``AUTOMODEL_PAGED_DECODE`` / the autotune
-  table (``autotune.paged_key``):
+  ``serving.decode_kernel`` (``auto``: fused wherever the kernel can run —
+  TPU or interpret mode — else gather):
 
   * ``fused`` — the model's attention runs the Pallas paged kernel
     (ops/paged_attention.py) that indexes the pool IN PLACE through the
@@ -36,13 +36,13 @@ Programs, each compiled once per static shape and donating the pool:
     gather → contiguous view → scatter-back round trip.
   * ``gather`` — the historical XLA path (block-table gather → the
     unchanged cached-attend → single-token scatter-back), kept as the
-    fallback and the A/B baseline ``tools/kernel_bench.py`` races the
-    kernel against.
+    fallback where the kernel cannot run (the CPU).
 
 - **draft propose / verify** — speculative decoding (Leviathan et al.
   2023): the draft model proposes ``spec_k`` tokens per slot (``spec_k``
   cheap decode steps over its OWN parallel pool, sharing the target's
-  block tables so rollback is shared bookkeeping), then ONE batched
+  block tables so rollback is shared bookkeeping, and one more forward
+  that only writes the last draft's K/V), then ONE batched
   verify forward pushes ``[cur, d_1..d_k]`` through the target —
   a chunk-shaped cached attend at per-slot offsets — and the rejection
   rule (generation.sampling.speculative_verify) commits the accepted
@@ -631,8 +631,10 @@ def build_draft_propose_fn(
     active, key, step_idx)`` → ``(draft_tokens [B, k], draft_logits
     [B, k, V] fp32, draft_pool)``: ``spec_k`` sequential draft decode
     steps inside one program, each writing the draft's K/V at the shared
-    block-table positions. Draft keys fold ``(step, 1 + i)`` so proposal
-    streams never collide with the verify correction stream."""
+    block-table positions, then one forward of ``d_k`` for its K/V alone
+    (a fully accepted round commits it, and the next round attends it).
+    Draft keys fold ``(step, 1 + i)`` so proposal streams never collide
+    with the verify correction stream."""
     forward = _make_forward(
         draft_apply, backend, block_size, compute_dtype, interpret
     )
@@ -654,6 +656,9 @@ def build_draft_propose_fn(
             logs.append(lg)
             length = length + 1
             c = nxt
+        # the last draft's own K/V: a round that accepts all k commits k + 1
+        # tokens, and the next round's proposals attend position len + k
+        _, pool = forward(params, pool, tables, length, c[:, None], active)
         return jnp.stack(toks, axis=1), jnp.stack(logs, axis=1), pool
 
     return propose

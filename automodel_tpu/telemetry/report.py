@@ -1,12 +1,7 @@
-"""Validate + summarize a train_metrics.jsonl; validate bench results.
+"""Validate + summarize a train_metrics.jsonl.
 
-Three consumers:
-- `automodel_tpu report <path.jsonl>` (cli/app.py) and tools/metrics_report.py
-  — human-facing lint + summary table.
-- bench.py — `validate_bench_result` enforces the VERDICT-r5 invariant:
-  a 0.0/None-valued leg with no recorded failure reason is a reporting bug
-  (a leg that never ran must never read as "measured zero") and fails the
-  bench loudly.
+Consumers: `automodel_tpu report <path.jsonl>` (cli/app.py) and
+tools/metrics_report.py — human-facing lint + summary table.
 
 The linter is deliberately strict about JSON: bare ``NaN``/``Infinity``
 tokens (which `json.dumps` emits by default and strict readers reject) are
@@ -25,9 +20,9 @@ from typing import Any, Iterable, Optional
 
 def percentile(values: Iterable[float], q: float) -> Optional[float]:
     """Linear-interpolation percentile (numpy's default method), shared by
-    every quantile consumer in the tree — engine/router workload stats, the
-    bench serving legs, and the report summaries — so a p50/p99 means the
-    same thing everywhere. ``q`` in [0, 1]; → None on an empty input."""
+    every quantile consumer in the tree — the router's workload stats and
+    the report summaries — so a p50/p99 means the same thing everywhere.
+    ``q`` in [0, 1]; → None on an empty input."""
     vals = sorted(float(v) for v in values)
     if not vals:
         return None
@@ -55,52 +50,28 @@ _NUMERIC_KEYS = (
     "prefetch_depth",
     "pp_bubble_fraction",
     "expert_load_imbalance",
-    # generation records (in-training eval sampling + the bench decode leg)
+    # generation records (in-training eval sampling)
     "ttft_s",
     "decode_tps",
     "gen_tokens",
     "gen_cache_bytes",
-    # serving records (serving/: per-request `serve_request` events + the
-    # sustained-throughput bench leg)
+    # serving records (serving/: per-request `serve_request` events)
     "queue_s",
     "queue_depth",
     "block_occupancy",
     "prefix_hit_tokens",
     "prefix_miss_tokens",
-    "serve_tokens_per_s",
-    "serve_ttft_p50_s",
-    "serve_ttft_p99_s",
-    "serve_block_occupancy_peak",
-    "serve_requests",
     # speculative decoding (serving.speculative:): per-request acceptance
-    # + the bench leg's aggregate accept-rate/draft-throughput keys
     "spec_proposed",
     "spec_accepted",
     "spec_accept_rate",
-    "serve_accept_rate",
-    "serve_draft_tps",
     # serving robustness (PR 9): drain/deadline/stall evidence
     "drain_duration_s",
     "requests_failed",
-    # fleet router (serving/fleet/): per-request `route_request` events +
-    # the routed bench sub-leg's aggregate keys
+    # fleet router (serving/fleet/): per-request `route_request` events
     "retries",
     "prefix_match_blocks",
     "route_s",
-    "serve_fleet_tokens_per_s",
-    "serve_route_prefix_hit_rate",
-    "serve_fleet_retries",
-    "serve_fleet_replicas",
-    "serve_fleet_requests",
-    "serve_fleet_kv_handoffs",
-    # hierarchical KV cache (serving.kv_spill:): the spill A/B bench
-    # sub-leg's aggregate keys — spill-on throughput/ttft on the replayed
-    # arrival schedule, the token-weighted effective hit rate, and how many
-    # admissions reloaded spilled blocks
-    "serve_spill_tokens_per_s",
-    "serve_spill_ttft_p50_s",
-    "serve_effective_hit_rate",
-    "serve_spill_reloads",
     # distributed guard (watchdog liveness, consensus/straggler attribution)
     "heartbeat_age_s",
     "deadline_s",
@@ -126,14 +97,6 @@ _NUMERIC_KEYS = (
     "ridge_intensity",
     "comm_fraction",
     "factor",
-    # kernel microbench records (tools/kernel_bench.py `kernel_bench`
-    # events): per-candidate timing + the per-program measured MFU that
-    # surfaces kernel regressions in the same JSONL pipeline as training
-    "kernel_ms",
-    "kernel_flops",
-    "kernel_tflops",
-    "kernel_mfu_measured_pct",
-    "kernel_bench_winners",
     # request tracing (telemetry/tracing.py `span` events)
     "duration_s",
     # fleet health plane (telemetry/slo.py `slo_alert` events): the measured
@@ -423,29 +386,6 @@ def summarize_metrics(records: list[dict]) -> dict[str, Any]:
             }
             for r in captures
         ]
-    # kernel sweep records (tools/kernel_bench.py): best TFLOP/s + measured
-    # MFU per kernel, so a tile regression reads off the same report as a
-    # training regression
-    kb = [r for r in records if r.get("event") == "kernel_bench"]
-    if kb:
-        out["kernel_bench_records"] = len(kb)
-        best: dict[str, float] = {}
-        for r in kb:
-            name = r.get("kernel")
-            tf = r.get("kernel_tflops")
-            if isinstance(name, str) and isinstance(tf, (int, float)):
-                best[name] = max(best.get(name, float("-inf")), tf)
-        if best:
-            out["kernel_tflops_best"] = dict(sorted(best.items()))
-        mfus = [
-            r["kernel_mfu_measured_pct"] for r in kb
-            if isinstance(r.get("kernel_mfu_measured_pct"), (int, float))
-        ]
-        if mfus:
-            out["kernel_mfu_measured_pct_max"] = max(mfus)
-        fails = [r for r in kb if r.get("ok") is False]
-        if fails:
-            out["kernel_bench_failures"] = len(fails)
     gens = [r for r in records if r.get("event") == "generation"]
     if gens:
         out["generation_records"] = len(gens)
@@ -708,67 +648,6 @@ def format_table(summary: dict[str, Any]) -> str:
             v = f"{v:.6g}"
         lines.append(f"{k:<{width}}  {v}")
     return "\n".join(lines)
-
-
-# -- bench-result validation (the VERDICT r5 failure mode) -------------------
-
-# (value key, failure-reason key) per bench leg — bench.py's output dict and
-# the benchmark recipe's generation (decode) leg
-_BENCH_LEGS = (
-    ("value", "dense_failure"),
-    ("qlora_8b_mfu_pct", "qlora_8b_failure"),
-    ("moe_mfu_pct", "moe_failures"),
-    ("gen_decode_tps", "gen_failure"),
-    ("serve_tokens_per_s", "serve_failure"),
-    # speculative sub-leg: a null accept rate must name why (spec disabled,
-    # engine failure, no round ran) — never read as "measured zero"
-    ("serve_accept_rate", "serve_spec_failure"),
-    # routed fleet sub-leg (serving/fleet/): same contract — absent fleet:
-    # section / any failure records its reason, never a silent null/zero
-    ("serve_fleet_tokens_per_s", "serve_fleet_failure"),
-    ("serve_route_prefix_hit_rate", "serve_fleet_failure"),
-    # hierarchical-KV-cache A/B sub-leg (spill-on vs spill-off on the same
-    # arrival schedule): a null throughput or hit rate must name why
-    ("serve_spill_tokens_per_s", "serve_spill_failure"),
-    ("serve_effective_hit_rate", "serve_spill_failure"),
-    # input-pipeline A/B sub-leg (sync vs prefetch under an injected collate
-    # delay): a null speedup must name why — never read as "measured zero"
-    ("input_pipeline_speedup", "input_pipeline_failure"),
-)
-
-# legs where a hard 0.0 IS a measurement (an accept rate of zero means the
-# draft never matched — real data, unlike a 0.0 MFU which means never-ran;
-# a 0.0 prefix-hit rate means the workload shared no prefixes — also real)
-_ZERO_VALID_LEGS = frozenset({
-    "serve_accept_rate",
-    "serve_route_prefix_hit_rate",
-    "serve_effective_hit_rate",
-})
-
-
-def validate_bench_result(result: dict[str, Any]) -> list[str]:
-    """A leg whose value is 0.0 or None MUST carry a recorded reason;
-    a hard 0.0 is additionally always suspect (an MFU of exactly zero is
-    not a measurement). → list of problems (empty = valid)."""
-    problems: list[str] = []
-    for value_key, failure_key in _BENCH_LEGS:
-        if value_key not in result:
-            continue
-        value = result[value_key]
-        reason = result.get(failure_key)
-        if (
-            isinstance(value, (int, float)) and not isinstance(value, bool)
-            and value == 0.0 and value_key not in _ZERO_VALID_LEGS
-        ):
-            problems.append(
-                f"{value_key} is 0.0 — a leg that never ran must report null "
-                f"+ a reason in {failure_key}, never a zero measurement"
-            )
-        elif value is None and not reason:
-            problems.append(
-                f"{value_key} is null but {failure_key} records no reason"
-            )
-    return problems
 
 
 def main(argv: Optional[list[str]] = None) -> int:

@@ -82,17 +82,6 @@ class Timer:
         return new
 
 
-def measured_bubble_fraction(step_s: float, work_s: float) -> float:
-    """Measured pipeline bubble: the fraction of a step spent idle given
-    the schedule-free work time ``work_s`` (e.g. the T_work intercept of a
-    microbatch sweep fit, tools/profile_pp.py, or a pp=1 run of the same
-    per-rank compute). Compare against the analytic laws in
-    utils/flops_utils.{gpipe,zero}_bubble_fraction per schedule."""
-    if step_s <= 0:
-        return 0.0
-    return max(0.0, 1.0 - work_s / step_s)
-
-
 class Timers:
     def __init__(self):
         self._timers: dict[str, Timer] = {}

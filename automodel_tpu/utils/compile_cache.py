@@ -9,7 +9,7 @@ cases:
   no path in code, so whoever launched the process decides where compiled
   programs live (and whether the next process finds them again);
 - otherwise: ``.jax_compile_cache/`` at the root of the checkout
-  (git-ignored), shared by the CLI, bench.py's workers, the tools and
+  (git-ignored), shared by the CLI, the benchmark's runs, the tools and
   chip_smoke.py's legs.
 """
 

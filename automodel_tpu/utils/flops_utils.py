@@ -183,7 +183,7 @@ def flops_per_token_for_config(cfg: Any, seq_len: int) -> float:
 def gpipe_bubble_fraction(pp: int, n_microbatches: int) -> float:
     """The GPipe-wavefront bubble law (S−1)/(m+S−1): fraction of a step a
     rank spends idle under the AD-transposed schedule (parallel/pp.py;
-    measured to ±5%, PROFILE_PP_r04.md)."""
+    measured to ±5%, docs/history/PROFILE_PP_r04.md)."""
     if pp <= 1:
         return 0.0
     return (pp - 1) / (n_microbatches + pp - 1)

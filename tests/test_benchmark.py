@@ -3,6 +3,7 @@
 import json
 
 import numpy as np
+import pytest
 
 from automodel_tpu.training.timers import Timers
 from automodel_tpu.utils.flops_utils import (
@@ -29,68 +30,8 @@ def test_dense_flops_sane():
     assert 0 < calculate_mfu(10_000, fpt, peak_tflops=459.0) < 1.5
 
 
-def test_bench_classify_env_failure():
-    """bench.py environment-failure detection: a libtpu client/terminal
-    version mismatch in the probe's stderr is a NAMED environment failure;
-    a dropped connection and a plain no-TPU host are not (an environment
-    failure must report as such, never as 0.0-valued legs)."""
-    import importlib.util
-    from pathlib import Path
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_module", Path(__file__).resolve().parent.parent / "bench.py"
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    mismatch = (
-        "RuntimeError: Invalid argument: The libtpu version mismatch: "
-        "client version 0.0.17 is incompatible with terminal version 0.0.21\n"
-    )
-    reason = bench.classify_env_failure(mismatch)
-    assert reason is not None and "libtpu" in reason
-    assert "0.0.17" in reason  # quotes the offending line
-
-    assert bench.classify_env_failure(
-        "TPU driver version skew detected\n"
-    ) is not None
-    assert bench.classify_env_failure(
-        "PJRT API version 0.40 is older than the framework's\n"
-    ) is not None
-
-    # NOT environment failures: a dropped connection / garden-variety no-TPU
-    assert bench.classify_env_failure("") is None
-    assert bench.classify_env_failure("Connection reset by peer") is None
-    assert bench.classify_env_failure(
-        "RuntimeError: Backend 'tpu' is not in the list of known backends"
-    ) is None
-
-
-def test_bench_oom_dump_records_leg_and_first_oom(tmp_path, monkeypatch):
-    """bench_oom_<leg>.json carries the leg name, a first_oom flag, and the
-    live-buffer census (the first dump sees the pristine failure state;
-    later dumps are cascade)."""
-    import importlib.util
-    import os
-    from pathlib import Path
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_module2", Path(__file__).resolve().parent.parent / "bench.py"
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    monkeypatch.chdir(tmp_path)
-    assert bench._first_oom_pending is True
-    p1 = bench._oom_memory_dump("dense_8b")
-    p2 = bench._oom_memory_dump("moe_ragged")
-    d1 = json.loads(Path(p1).read_text())
-    d2 = json.loads(Path(p2).read_text())
-    assert d1["leg"] == "dense_8b" and d1["first_oom"] is True
-    assert d2["leg"] == "moe_ragged" and d2["first_oom"] is False
-    assert "census" in d1 and "devices" in d1  # live-buffer HBM census
-
-
-def test_benchmark_recipe_cli(tmp_path):
+@pytest.mark.parametrize("with_serving", [False, True])
+def test_benchmark_recipe_cli(tmp_path, with_serving):
     from automodel_tpu.cli.app import main as cli_main
 
     recipe = {
@@ -125,6 +66,11 @@ def test_benchmark_recipe_cli(tmp_path):
             "output_json": str(tmp_path / "bench.json"),
         },
     }
+    if with_serving:
+        # a recipe that still carries serving / generation sections runs the
+        # TRAINING benchmark: the recipe has no other leg
+        recipe["serving"] = {"slots": 2, "num_blocks": 32, "max_seq_len": 64}
+        recipe["generation"] = {"max_new_tokens": 4, "greedy": True}
     import yaml
 
     cfg_path = tmp_path / "bench.yaml"
@@ -135,6 +81,7 @@ def test_benchmark_recipe_cli(tmp_path):
     assert result["tokens_per_second"] > 0
     assert np.isfinite(result["loss"])
     assert result["timers"]["step"]["count"] == 2
+    assert not [k for k in result if k.startswith(("serve_", "gen_"))]
 
 
 def _run_from_repo_root(script: str):
@@ -149,15 +96,14 @@ def _run_from_repo_root(script: str):
     )
 
 
-def test_chip_smoke_and_bench_refuse_to_run_without_a_tpu():
+def test_chip_smoke_refuses_to_run_without_a_tpu():
     """No chip is a fast non-zero exit that prints no result — never a CPU
     run written under a device metric's name (the suite pins
-    JAX_PLATFORMS=cpu, which the children inherit)."""
-    for script in ("chip_smoke.py", "bench.py"):
-        r = _run_from_repo_root(script)
-        assert r.returncode != 0, script
-        assert "no TPU found" in r.stderr, (script, r.stderr[-500:])
-        assert r.stdout.strip() == "", (script, r.stdout[-500:])
+    JAX_PLATFORMS=cpu, which the child inherits)."""
+    r = _run_from_repo_root("chip_smoke.py")
+    assert r.returncode != 0
+    assert "no TPU found" in r.stderr, r.stderr[-500:]
+    assert r.stdout.strip() == "", r.stdout[-500:]
 
 
 def test_compile_cache_is_placed_from_outside_when_the_env_says_so(monkeypatch):

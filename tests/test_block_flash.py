@@ -1,18 +1,15 @@
 """Non-ring single-chip entry over the blockwise flash kernels
-(ops/ring_flash.flash_attention) vs sdpa, and the per-shape autotune
-routing in ops/attention.flash.
+(ops/ring_flash.flash_attention) vs sdpa, and the per-shape splash blocks
+of ops/attention.flash.
 
 Interpret mode executes the REAL kernel code on CPU.
 """
-
-import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from automodel_tpu.ops import autotune
 from automodel_tpu.ops.attention import sdpa
 from automodel_tpu.ops.ring_flash import flash_attention
 
@@ -93,48 +90,31 @@ def test_block_flash_unpadded_seq():
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=5e-3)
 
 
-def test_flash_routes_block_backend_from_autotune_table(
-    tmp_path, monkeypatch
+@pytest.mark.parametrize(
+    "head_dim,window,given,want",
+    [
+        (128, None, {}, (512, 512)),
+        (64, None, {}, (256, 512)),
+        (64, 128, {}, (256, 128)),
+        (64, None, {"block_q": 128}, (128, 512)),  # an explicit block wins
+    ],
+)
+def test_flash_picks_splash_blocks_by_shape(
+    monkeypatch, head_dim, window, given, want
 ):
-    """A per-chip table entry with backend=block routes ops/attention.flash
-    (the model-facing entry point) onto the in-tree kernels — at head_dim 64
-    + window 128 this is the shape the library splash kernel on this build
-    cannot even run, so parity here proves the race wiring end-to-end."""
-    from automodel_tpu.ops.attention import flash
+    """ops/attention.flash hands splash the blocks of its shape rule
+    (head_dim, window), and an explicit attn_block_q / attn_block_kv keeps
+    the caller's blocks."""
+    from automodel_tpu.ops import attention
 
-    table = {
-        "format_version": 1,
-        "chips": {
-            autotune.chip_key(): {
-                autotune.attn_key(64, 128, True): {
-                    "backend": "block", "block_q": 128, "block_kv": 128,
-                }
-            }
-        },
-    }
-    path = tmp_path / "table.json"
-    path.write_text(json.dumps(table))
-    monkeypatch.setenv(autotune.ENV_TABLE, str(path))
+    seen = {}
+
+    def splash(q, k, v, segment_ids, sinks, *, block_q, block_kv, **kw):
+        seen["blocks"] = (block_q, block_kv)
+        return q
+
+    monkeypatch.setattr(attention, "_splash_flash", splash)
     monkeypatch.setenv("AUTOMODEL_FLASH_INTERPRET", "1")
-    autotune.clear_cache()
-    try:
-        rng = np.random.default_rng(3)
-        q, k, v = _qkv(rng, 1, 256, 2, 1, 64)
-        out = flash(q, k, v, causal=True, sliding_window=128)
-        ref = sdpa(q, k, v, causal=True, sliding_window=128)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-4)
-    finally:
-        autotune.clear_cache()
-
-
-def test_flash_without_table_entry_unchanged(monkeypatch):
-    """No table entry for the shape → flash keeps its pre-table behavior
-    (splash path / sdpa fallback off-TPU) — the committed defaults carry
-    only TPU chip kinds, so CPU flows are untouched."""
-    from automodel_tpu.ops.attention import _autotune_entry
-
-    autotune.clear_cache()
-    monkeypatch.delenv(autotune.ENV_TABLE, raising=False)
-    assert _autotune_entry(31337, None, True) is None
-    # committed defaults must never carry entries for the CPU chip kind
-    assert autotune.lookup(autotune.attn_key(64, 128, True), chip="cpu") is None
+    q, k, v = _qkv(np.random.default_rng(3), 1, 256, 2, 1, head_dim)
+    attention.flash(q, k, v, causal=True, sliding_window=window, **given)
+    assert seen["blocks"] == want

@@ -577,105 +577,6 @@ def test_k8s_fleet_manifest_roles_probes_and_router():
 
 
 # ---------------------------------------------------------------------------
-# routed bench sub-leg
-# ---------------------------------------------------------------------------
-
-
-def test_bench_fleet_leg_null_with_reason():
-    from automodel_tpu.config.loader import ConfigNode
-    from automodel_tpu.recipes.benchmark import (
-        BenchmarkingRecipeForNextTokenPrediction as Bench,
-    )
-    from automodel_tpu.telemetry.report import validate_bench_result
-
-    rec = Bench.__new__(Bench)
-    rec.cfg = ConfigNode({})
-    rec.peft_config = None
-    leg = rec._fleet_leg(None)
-    assert leg["serve_fleet_tokens_per_s"] is None
-    assert "fleet" in leg["serve_fleet_failure"]
-    assert validate_bench_result({"value": 1.0, **leg}) == []
-    bad = {"value": 1.0, "serve_fleet_tokens_per_s": None,
-           "serve_fleet_failure": None}
-    assert validate_bench_result(bad)
-    bad = {"value": 1.0, "serve_fleet_tokens_per_s": 0.0,
-           "serve_fleet_failure": None}
-    assert validate_bench_result(bad)
-    # a 0.0 prefix-hit rate is a real measurement, not a missing leg
-    ok = {"value": 1.0, "serve_route_prefix_hit_rate": 0.0,
-          "serve_fleet_failure": None}
-    assert validate_bench_result(ok) == []
-
-
-def test_bench_fleet_leg_end_to_end(cpu_devices, monkeypatch):
-    """The routed-vs-single A/B through the benchmark recipe surface:
-    router + 2 local replicas replay the single leg's exact Poisson
-    arrivals; both legs report, strict-valid."""
-    monkeypatch.setattr(jax, "devices", lambda *a: cpu_devices[:1])
-    from automodel_tpu.config.loader import ConfigNode
-    from automodel_tpu.recipes.benchmark import (
-        BenchmarkingRecipeForNextTokenPrediction as Bench,
-    )
-    from automodel_tpu.telemetry.report import validate_bench_result
-
-    cfg = ConfigNode(
-        {
-            "seed": 1,
-            "model": {
-                "hf_config": {
-                    "architectures": ["LlamaForCausalLM"],
-                    "model_type": "llama",
-                    "vocab_size": 128, "hidden_size": 32,
-                    "intermediate_size": 64, "num_hidden_layers": 2,
-                    "num_attention_heads": 4, "num_key_value_heads": 2,
-                    "head_dim": 8, "max_position_embeddings": 128,
-                },
-                "backend": {
-                    "attn": "sdpa", "param_dtype": "float32",
-                    "compute_dtype": "float32",
-                },
-            },
-            "distributed": {"dp_shard": 1},
-            "dataset": {
-                "_target_": "automodel_tpu.data.sft.MockSFTDataset",
-                "vocab_size": 128, "seq_length": 16, "num_samples": 16,
-            },
-            "dataloader": {"global_batch_size": 4},
-            "step_scheduler": {"max_steps": 2},
-            "optimizer": {"name": "adamw", "lr": 1e-3},
-            "benchmark": {"warmup_steps": 1, "measure_steps": 1},
-            "serving": {
-                "slots": 2, "block_size": 4, "num_blocks": 96,
-                "prefill_chunk": 8, "max_seq_len": 64,
-                "bench_requests": 4, "bench_rate": 50.0,
-                "bench_prompt_len_min": 2, "bench_prompt_len_max": 10,
-                "bench_max_new_tokens": 3,
-            },
-            "fleet": {"bench_replicas": 2, "block_size": 4,
-                      "retry_budget": 2},
-        }
-    )
-    recipe = Bench(cfg)
-    recipe.setup()
-    result = recipe.run_benchmark()
-    assert result["serve_failure"] is None
-    assert result["serve_fleet_failure"] is None, result.get(
-        "serve_fleet_failure"
-    )
-    assert result["serve_fleet_tokens_per_s"] > 0
-    assert result["serve_fleet_requests"] == 4
-    assert result["serve_fleet_retries"] == 0
-    assert result["serve_fleet_replicas"] == 2
-    ab = result["serve_fleet_ab"]
-    assert ab["single_tokens_per_s"] == result["serve_tokens_per_s"]
-    assert ab["fleet_tokens_per_s"] == result["serve_fleet_tokens_per_s"]
-    assert isinstance(
-        result["serve_route_prefix_hit_rate"], float
-    )
-    assert validate_bench_result(result) == []
-
-
-# ---------------------------------------------------------------------------
 # router records through the report pipeline
 # ---------------------------------------------------------------------------
 

@@ -448,37 +448,7 @@ def test_cli_app_routes_generate(tmp_path, monkeypatch, cpu_devices):
     assert rc == 0
 
 
-# -- benchmark decode leg / report schema -------------------------------------
-
-
-def test_bench_generation_leg_null_with_reason():
-    """A missing generation: section or a cache-less model yields a NULL
-    decode leg WITH a recorded reason that validate_bench_result accepts —
-    and a bare 0.0 leg still fails validation (the VERDICT r5 rule)."""
-    from automodel_tpu.recipes.benchmark import (
-        BenchmarkingRecipeForNextTokenPrediction as Bench,
-    )
-    from automodel_tpu.telemetry.report import validate_bench_result
-
-    rec = Bench.__new__(Bench)
-    rec._gen_engine = None
-    rec._gen_skip_reason = None
-    leg = rec._generation_leg()
-    assert leg["gen_decode_tps"] is None
-    assert "generation" in leg["gen_failure"]
-    assert validate_bench_result({"value": 1.0, **leg}) == []
-
-    rec._gen_skip_reason = "model has no KV-cache decode path"
-    leg = rec._generation_leg()
-    assert leg["gen_failure"] == "model has no KV-cache decode path"
-    assert validate_bench_result({"value": 1.0, **leg}) == []
-
-    # a 0.0-valued decode leg is never a measurement
-    bad = {"value": 1.0, "gen_decode_tps": 0.0, "gen_failure": None}
-    assert validate_bench_result(bad)
-    # and null WITHOUT a reason is flagged
-    bad = {"value": 1.0, "gen_decode_tps": None, "gen_failure": None}
-    assert validate_bench_result(bad)
+# -- report schema -------------------------------------------------------------
 
 
 def test_report_accepts_generation_keys(tmp_path):

@@ -728,67 +728,3 @@ def test_profile_cli_e2e_train_mode(tmp_path, monkeypatch):
     assert report["run_metrics"]["mfu_measured_pct"] > 0
     md = (tmp_path / "prof" / "profile" / "PROFILE.md").read_text()
     assert "## Decomposition" in md and "## Top ops by self time" in md
-
-
-# -- bench harness (subprocess legs) ------------------------------------------
-
-
-def _bench_module():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_module_profiling", Path(__file__).resolve().parent.parent / "bench.py"
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    return bench
-
-
-def test_bench_worker_writes_structured_result(tmp_path, monkeypatch):
-    """The worker contract: success → {ok, tps_chip, fpt, peak_tflops};
-    failure → {ok: false, error} — ALWAYS a result file, so the
-    orchestrator can never misread a dead leg as a measurement."""
-    bench = _bench_module()
-    monkeypatch.chdir(tmp_path)
-    hf = bench._dense_hf(("smoke", 64, 128, 2, 4, 2))
-    hf.update(vocab_size=256, head_dim=16)
-    spec = {
-        "leg": "t1", "hf": hf,
-        "backend": {"attn": "sdpa", "param_dtype": "float32", "compute_dtype": "float32"},
-        "batch": 8, "seq": 32, "steps": 1, "force_cpu": True,
-    }
-    out_path = tmp_path / "r.json"
-    rc = bench._worker_main(spec, str(out_path))
-    res = json.loads(out_path.read_text())
-    assert rc == 0 and res["ok"] and res["tps_chip"] > 0 and res["fpt"] > 0
-    assert "n_devices" in res and "platform" in res
-
-    bad = {k: v for k, v in spec.items() if k != "hf"}  # no model config
-    bad["leg"] = "t2"
-    rc = bench._worker_main(bad, str(tmp_path / "r2.json"))
-    res2 = json.loads((tmp_path / "r2.json").read_text())
-    assert rc == 1 and res2["ok"] is False and res2["error"]
-
-
-def test_bench_dense_ladder_includes_batch_fallback():
-    """The batch 4→2→1 ladder exists below the smallest dense shape (a chip
-    that cannot fit 0.9b@4 must report 0.9b@2 or @1, not a null round);
-    larger shapes try their single measured-default batch, and an explicit
-    BENCH_BATCH pins one attempt everywhere."""
-    bench = _bench_module()
-    assert bench.DENSE_SHAPES[-1][0] == "0.9b"
-    assert bench._dense_batches("0.9b", None) == [4, 2, 1]
-    assert bench._dense_batches("8b", None) == [1]
-    assert bench._dense_batches("3b", None) == [4]
-    assert bench._dense_batches("0.9b", "2") == [2]
-
-
-def test_bench_abstract_cost_summary_is_deviceless():
-    bench = _bench_module()
-    hf = bench._dense_hf(("smoke", 64, 128, 2, 4, 2))
-    hf.update(vocab_size=256, head_dim=16)
-    cost = bench._abstract_step_cost(
-        hf, {"attn": "sdpa", "param_dtype": "float32", "compute_dtype": "float32"},
-        batch=2, seq=32,
-    )
-    assert cost["flops"] > 0 and cost["dot_flops"] > 0 and cost["bytes_est"] > 0

@@ -404,8 +404,11 @@ def test_pool_exhaustion_queues_until_blocks_free():
 
 
 def test_sustained_poisson_workload():
-    """The bench-leg driver: Poisson arrivals of mixed-length prompts —
-    queue drains, stats come back coherent."""
+    """Poisson arrivals of mixed-length prompts, submitted as they come
+    due while the engine steps: the queue drains and every record is
+    coherent."""
+    from serving_driver import drive
+
     model, params = _tiny_llama()
     auto = _auto(model, params)
     srv = ServingEngine(
@@ -420,12 +423,13 @@ def test_sustained_poisson_workload():
         t += float(rng.exponential(0.002))
         n = int(rng.integers(2, 10))
         arrivals.append((t, rng.integers(1, 64, size=n).tolist(), 3))
-    done, stats = srv.run_workload(arrivals)
-    assert stats["requests"] == 8 and len(done) == 8
-    assert stats["gen_tokens"] == 24
-    assert stats["sustained_tokens_per_s"] > 0
-    assert 0 < stats["ttft_p50_s"] <= stats["ttft_p99_s"]
-    assert 0 < stats["block_occupancy_peak"] <= 1
+    occupancy = []
+    done = drive(srv, arrivals, lambda e: occupancy.append(e.pool.occupancy()))
+    assert len(done) == 8
+    assert {r["completion_reason"] for r in done} == {"length"}
+    assert sum(r["n_generated"] for r in done) == 24
+    assert all(r["ttft_s"] > 0 for r in done)
+    assert 0 < max(occupancy) <= 1
     assert srv.idle()
 
 
@@ -664,87 +668,6 @@ def test_serve_http_end_to_end(monkeypatch, cpu_devices):
         loop.close()
 
 
-# -- bench leg / report schema ------------------------------------------------
-
-
-def test_bench_serving_leg_null_with_reason():
-    """No serving: section → null leg WITH reason, accepted by
-    validate_bench_result; a 0.0 serve leg still fails validation."""
-    from automodel_tpu.config.loader import ConfigNode
-    from automodel_tpu.recipes.benchmark import (
-        BenchmarkingRecipeForNextTokenPrediction as Bench,
-    )
-    from automodel_tpu.telemetry.report import validate_bench_result
-
-    rec = Bench.__new__(Bench)
-    rec.cfg = ConfigNode({})
-    rec.peft_config = None
-    leg = rec._serving_leg()
-    assert leg["serve_tokens_per_s"] is None
-    assert "serving" in leg["serve_failure"]
-    assert validate_bench_result({"value": 1.0, **leg}) == []
-    bad = {"value": 1.0, "serve_tokens_per_s": 0.0, "serve_failure": None}
-    assert validate_bench_result(bad)
-    bad = {"value": 1.0, "serve_tokens_per_s": None, "serve_failure": None}
-    assert validate_bench_result(bad)
-
-
-def test_bench_serving_leg_end_to_end(cpu_devices, monkeypatch):
-    """The full serving leg on the tiny model through the benchmark recipe
-    surface: real Poisson workload, real keys, strict-valid result."""
-    monkeypatch.setattr(jax, "devices", lambda *a: cpu_devices[:1])
-    from automodel_tpu.config.loader import ConfigNode
-    from automodel_tpu.recipes.benchmark import (
-        BenchmarkingRecipeForNextTokenPrediction as Bench,
-    )
-    from automodel_tpu.telemetry.report import validate_bench_result
-
-    cfg = ConfigNode(
-        {
-            "seed": 1,
-            "model": {
-                "hf_config": {
-                    "architectures": ["LlamaForCausalLM"],
-                    "model_type": "llama",
-                    "vocab_size": 128, "hidden_size": 32,
-                    "intermediate_size": 64, "num_hidden_layers": 2,
-                    "num_attention_heads": 4, "num_key_value_heads": 2,
-                    "head_dim": 8, "max_position_embeddings": 128,
-                },
-                "backend": {
-                    "attn": "sdpa", "param_dtype": "float32",
-                    "compute_dtype": "float32",
-                },
-            },
-            "distributed": {"dp_shard": 1},
-            "dataset": {
-                "_target_": "automodel_tpu.data.sft.MockSFTDataset",
-                "vocab_size": 128, "seq_length": 16, "num_samples": 16,
-            },
-            "dataloader": {"global_batch_size": 4},
-            "step_scheduler": {"max_steps": 2},
-            "optimizer": {"name": "adamw", "lr": 1e-3},
-            "benchmark": {"warmup_steps": 1, "measure_steps": 1},
-            "serving": {
-                "slots": 2, "block_size": 4, "num_blocks": 48,
-                "prefill_chunk": 8, "max_seq_len": 64,
-                "bench_requests": 4, "bench_rate": 50.0,
-                "bench_prompt_len_min": 2, "bench_prompt_len_max": 10,
-                "bench_max_new_tokens": 3,
-            },
-        }
-    )
-    recipe = Bench(cfg)
-    recipe.setup()
-    result = recipe.run_benchmark()
-    assert result["serve_failure"] is None
-    assert result["serve_requests"] == 4
-    assert result["serve_tokens_per_s"] > 0
-    assert 0 < result["serve_ttft_p50_s"] <= result["serve_ttft_p99_s"]
-    assert 0 < result["serve_block_occupancy_peak"] <= 1
-    assert validate_bench_result(result) == []
-
-
 # -- robustness: deadlines / drain / shed / leak audit (PR 9) -----------------
 
 
@@ -771,6 +694,29 @@ def test_serve_config_nested_sections_parse_and_reject_unknown_keys():
     assert LimitsConfig.from_dict(None).deadline_s is None
     assert DrainConfig.from_dict(None).grace_s == 30.0
     assert StallConfig.from_dict(None).enabled is True
+
+
+@pytest.mark.parametrize(
+    "section,key",
+    [
+        ("serving", "bench_requests"),
+        ("serving", "bench_rate"),
+        ("serving", "bench_prompt_len_min"),
+        ("serving", "bench_prompt_len_max"),
+        ("serving", "bench_max_new_tokens"),
+        ("fleet", "bench_replicas"),
+        ("fleet", "bench_num_blocks"),
+    ],
+)
+def test_a_benchmark_s_traffic_is_no_key_of_the_server(section, key):
+    """The traffic a harness sends is the harness's (benchmarks/traffic/):
+    a YAML that still sets one of the old knobs is refused by name, not
+    silently ignored."""
+    from automodel_tpu.serving.fleet.router import FleetConfig
+
+    cls = {"serving": ServeConfig, "fleet": FleetConfig}[section]
+    with pytest.raises(TypeError, match=f"unknown {section} keys.*{key}"):
+        cls.from_dict({key: 8})
 
 
 def test_completion_reason_on_normal_completions():
@@ -1336,110 +1282,3 @@ def test_kv_spill_config_parse_validation_and_spec_exclusion():
             ),
             GenerationConfig(max_new_tokens=4, greedy=True),
         )
-
-
-def test_bench_spill_leg_null_with_reason():
-    """Degradation contract of the spill A/B sub-leg: no serving section
-    or spill disabled → null keys WITH a recorded reason, strict-valid;
-    a null or 0.0 leg with no reason fails validation."""
-    from automodel_tpu.config.loader import ConfigNode
-    from automodel_tpu.recipes.benchmark import (
-        BenchmarkingRecipeForNextTokenPrediction as Bench,
-    )
-    from automodel_tpu.telemetry.report import validate_bench_result
-
-    rec = Bench.__new__(Bench)
-    rec.cfg = ConfigNode({})
-    rec.peft_config = None
-    leg = rec._spill_leg()
-    assert leg["serve_spill_tokens_per_s"] is None
-    assert leg["serve_effective_hit_rate"] is None
-    assert "serving" in leg["serve_spill_failure"]
-    assert validate_bench_result({"value": 1.0, **leg}) == []
-    # serving present but the spill tier off: reason says exactly that
-    rec.cfg = ConfigNode({"serving": {"slots": 1, "num_blocks": 8}})
-    leg = rec._spill_leg()
-    assert leg["serve_spill_tokens_per_s"] is None
-    assert "kv_spill disabled" in leg["serve_spill_failure"]
-    assert validate_bench_result({"value": 1.0, **leg}) == []
-    bad = {"value": 1.0, "serve_spill_tokens_per_s": None,
-           "serve_spill_failure": None}
-    assert validate_bench_result(bad)
-    bad = {"value": 1.0, "serve_spill_tokens_per_s": 0.0,
-           "serve_spill_failure": None}
-    assert validate_bench_result(bad)
-    # 0.0 is a real measurement for a RATE, not a missing leg
-    ok = {"value": 1.0, "serve_effective_hit_rate": 0.0,
-          "serve_spill_failure": None}
-    assert validate_bench_result(ok) == []
-
-
-def test_bench_spill_leg_end_to_end(cpu_devices, monkeypatch):
-    """The spill-on vs spill-off A/B through the benchmark recipe surface:
-    same Poisson arrivals both legs, reloads actually happen, and the
-    effective hit rate improves with the tier on (acceptance: the sub-leg
-    reports the win)."""
-    monkeypatch.setattr(jax, "devices", lambda *a: cpu_devices[:1])
-    from automodel_tpu.config.loader import ConfigNode
-    from automodel_tpu.recipes.benchmark import (
-        BenchmarkingRecipeForNextTokenPrediction as Bench,
-    )
-    from automodel_tpu.telemetry.report import validate_bench_result
-
-    cfg = ConfigNode(
-        {
-            "seed": 1,
-            "model": {
-                "hf_config": {
-                    "architectures": ["LlamaForCausalLM"],
-                    "model_type": "llama",
-                    "vocab_size": 128, "hidden_size": 32,
-                    "intermediate_size": 64, "num_hidden_layers": 2,
-                    "num_attention_heads": 4, "num_key_value_heads": 2,
-                    "head_dim": 8, "max_position_embeddings": 128,
-                },
-                "backend": {
-                    "attn": "sdpa", "param_dtype": "float32",
-                    "compute_dtype": "float32",
-                },
-            },
-            "distributed": {"dp_shard": 1},
-            "dataset": {
-                "_target_": "automodel_tpu.data.sft.MockSFTDataset",
-                "vocab_size": 128, "seq_length": 16, "num_samples": 16,
-            },
-            "dataloader": {"global_batch_size": 4},
-            "step_scheduler": {"max_steps": 2},
-            "optimizer": {"name": "adamw", "lr": 1e-3},
-            "benchmark": {"warmup_steps": 1, "measure_steps": 1},
-            "serving": {
-                "slots": 2, "block_size": 4, "num_blocks": 48,
-                "prefill_chunk": 8, "max_seq_len": 64,
-                "bench_requests": 4, "bench_rate": 50.0,
-                "bench_prompt_len_min": 2, "bench_prompt_len_max": 10,
-                "bench_max_new_tokens": 3,
-                "kv_spill": {"enabled": True, "max_host_mb": 8.0},
-            },
-        }
-    )
-    recipe = Bench(cfg)
-    recipe.setup()
-    result = recipe.run_benchmark()
-    assert result["serve_failure"] is None
-    assert result["serve_spill_failure"] is None, result.get(
-        "serve_spill_failure"
-    )
-    assert result["serve_spill_tokens_per_s"] > 0
-    assert result["serve_spill_ttft_p50_s"] > 0
-    assert result["serve_spill_reloads"] > 0  # the workload forced evictions
-    ab = result["serve_spill_ab"]
-    assert ab["spilled_blocks"] >= ab["reloaded_blocks"] > 0
-    # the off leg recomputes every evicted prefix: its hit rate can
-    # legitimately be 0.0 under maximal churn — the WIN is the gap
-    assert 0 <= ab["effective_hit_rate_off"] < ab["effective_hit_rate_on"] <= 1
-    # ttft win: a reload (host->device scatter) beats re-prefilling the
-    # whole prefix even on CPU once compiles are excluded from the window
-    assert ab["spill_on_ttft_p50_s"] < ab["spill_off_ttft_p50_s"]
-    assert result["serve_effective_hit_rate"] == ab["effective_hit_rate_on"]
-    assert ab["spill_on_tokens_per_s"] > 0 and ab["spill_off_tokens_per_s"] > 0
-    assert validate_bench_result(result) == []

@@ -498,8 +498,9 @@ def test_spec_parity_fused_int8_compound(monkeypatch):
 def test_spec_self_draft_accepts_everything_and_stamps_records():
     """A draft with the TARGET's own weights agrees everywhere: accept
     rate 1.0, per-request records carry spec_accepted/spec_accept_rate,
-    run_workload reports accept_rate/draft_tps, /metrics exposes the
-    counters + gauge."""
+    /metrics exposes the counters + gauge."""
+    from serving_driver import drive
+
     model, params = _tiny_llama(num_layers=2)
     auto = _auto(model, params)
     draft = _draft_section(
@@ -509,14 +510,12 @@ def test_spec_self_draft_accepts_everything_and_stamps_records():
     srv = _serve(auto, max_new=9, spec_k=3, draft=draft)
     srv.draft_auto.params = params  # self-draft: identical proposals
     arrivals = [(0.0, [1, 2, 3, 4, 5], 9), (0.0, [7, 8, 9], 9)]
-    done, stats = srv.run_workload(arrivals)
+    done = drive(srv, arrivals)
     assert srv.spec_accept_rate == 1.0
-    assert stats["accept_rate"] == 1.0
-    assert stats["spec_proposed"] == stats["spec_accepted"] > 0
+    assert srv.spec_proposed_total == srv.spec_accepted_total > 0
     # rounds count propose+verify WAVES, not slot-rounds: with two slots
     # decoding concurrently, rounds must sit strictly below proposed / k
     assert 0 < srv.spec_rounds < srv.spec_proposed_total // 3
-    assert stats["draft_tps"] > 0
     for rec in done:
         assert rec["spec_accept_rate"] == 1.0
         assert rec["spec_accepted"] == rec["spec_proposed"]
@@ -606,34 +605,28 @@ def test_spec_config_validation_draft_mismatch():
         _serve(auto, spec_k=2, draft=bad_vocab)
 
 
-def test_decode_backend_resolution(monkeypatch, tmp_path):
-    """auto: env beats config beats autotune entry beats platform default
-    (gather on CPU without interpret; fused with interpret)."""
-    from automodel_tpu.ops import autotune
-
+@pytest.mark.parametrize(
+    "decode_kernel,interpret,env,want",
+    [
+        ("fused", False, None, "fused"),
+        ("gather", True, None, "gather"),
+        ("auto", False, None, "gather"),  # the CPU's only decode path
+        ("auto", True, None, "fused"),  # the kernel can run
+        ("auto", True, "gather", "fused"),  # no environment switch
+    ],
+)
+def test_decode_backend_resolution(monkeypatch, decode_kernel, interpret, env, want):
+    """serving.decode_kernel when it names a backend, else the platform's
+    default; nothing in the environment chooses."""
     model, params = _tiny_llama()
     auto = _auto(model, params)
     monkeypatch.delenv("AUTOMODEL_FLASH_INTERPRET", raising=False)
     monkeypatch.delenv("AUTOMODEL_PAGED_DECODE", raising=False)
-    assert _serve(auto).decode_backend == "gather"  # CPU default
-    monkeypatch.setenv("AUTOMODEL_FLASH_INTERPRET", "1")
-    assert _serve(auto).decode_backend == "fused"  # kernel can run
-    # an autotune entry for this (head_dim, block_size, dtype) wins over
-    # the platform default
-    table = tmp_path / "autotune.json"
-    autotune.save_table(
-        table, {autotune.paged_key(8, 4, "bf16"): {"backend": "gather"}}
-    )
-    monkeypatch.setenv(autotune.ENV_TABLE, str(table))
-    autotune.clear_cache()
-    try:
-        assert _serve(auto).decode_backend == "gather"
-        # explicit config and env still beat the table
-        assert _serve(auto, decode_kernel="fused").decode_backend == "fused"
-        monkeypatch.setenv("AUTOMODEL_PAGED_DECODE", "fused")
-        assert _serve(auto).decode_backend == "fused"
-    finally:
-        autotune.clear_cache()
+    if interpret:
+        monkeypatch.setenv("AUTOMODEL_FLASH_INTERPRET", "1")
+    if env:
+        monkeypatch.setenv("AUTOMODEL_PAGED_DECODE", env)
+    assert _serve(auto, decode_kernel=decode_kernel).decode_backend == want
 
 
 def test_fused_decode_refuses_kv_heads_the_mesh_does_not_divide(devices8):
@@ -651,87 +644,7 @@ def test_fused_decode_refuses_kv_heads_the_mesh_does_not_divide(devices8):
     assert _serve(auto, decode_kernel="gather").decode_backend == "gather"
 
 
-# -- bench leg + CLI wiring ---------------------------------------------------
-
-
-def test_bench_serving_leg_spec_ab_end_to_end(cpu_devices, monkeypatch):
-    """Acceptance: the Poisson serving bench leg runs e2e on CPU with
-    spec-decode ON and the interpret-gated fused kernel, reporting
-    serve_accept_rate + a spec-on/off A/B, strict-valid."""
-    monkeypatch.setattr(jax, "devices", lambda *a: cpu_devices[:1])
-    monkeypatch.setenv("AUTOMODEL_FLASH_INTERPRET", "1")
-    from automodel_tpu.config.loader import ConfigNode
-    from automodel_tpu.recipes.benchmark import (
-        BenchmarkingRecipeForNextTokenPrediction as Bench,
-    )
-    from automodel_tpu.telemetry.report import validate_bench_result
-
-    cfg = ConfigNode(
-        {
-            "seed": 1,
-            "model": {
-                "hf_config": {
-                    "architectures": ["LlamaForCausalLM"],
-                    "model_type": "llama",
-                    "vocab_size": 128, "hidden_size": 32,
-                    "intermediate_size": 64, "num_hidden_layers": 2,
-                    "num_attention_heads": 4, "num_key_value_heads": 2,
-                    "head_dim": 8, "max_position_embeddings": 128,
-                },
-                "backend": {
-                    "attn": "sdpa", "param_dtype": "float32",
-                    "compute_dtype": "float32",
-                },
-            },
-            "distributed": {"dp_shard": 1},
-            "dataset": {
-                "_target_": "automodel_tpu.data.sft.MockSFTDataset",
-                "vocab_size": 128, "seq_length": 16, "num_samples": 16,
-            },
-            "dataloader": {"global_batch_size": 4},
-            "step_scheduler": {"max_steps": 2},
-            "optimizer": {"name": "adamw", "lr": 1e-3},
-            "benchmark": {"warmup_steps": 1, "measure_steps": 1},
-            "serving": {
-                "slots": 2, "block_size": 4, "num_blocks": 64,
-                "prefill_chunk": 8, "max_seq_len": 64,
-                "kv_cache_dtype": "int8", "decode_kernel": "fused",
-                "bench_requests": 3, "bench_rate": 50.0,
-                "bench_prompt_len_min": 2, "bench_prompt_len_max": 8,
-                "bench_max_new_tokens": 3,
-                "speculative": {
-                    "enabled": True, "k": 2,
-                    "draft": {
-                        "hf_config": {
-                            "architectures": ["LlamaForCausalLM"],
-                            "model_type": "llama",
-                            "vocab_size": 128, "hidden_size": 16,
-                            "intermediate_size": 32, "num_hidden_layers": 1,
-                            "num_attention_heads": 2, "num_key_value_heads": 1,
-                            "head_dim": 8, "max_position_embeddings": 128,
-                        },
-                        "backend": {
-                            "attn": "sdpa", "param_dtype": "float32",
-                            "compute_dtype": "float32",
-                        },
-                    },
-                },
-            },
-        }
-    )
-    recipe = Bench(cfg)
-    recipe.setup()
-    result = recipe.run_benchmark()
-    assert result["serve_failure"] is None
-    assert result["serve_spec_failure"] is None
-    assert result["serve_tokens_per_s"] > 0
-    assert isinstance(result["serve_accept_rate"], float)
-    assert result["serve_draft_tps"] > 0
-    assert result["serve_decode_backend"] == "fused"
-    assert result["serve_kv_cache_dtype"] == "int8"
-    ab = result["serve_spec_ab"]
-    assert ab["spec_on_tokens_per_s"] > 0 and ab["spec_off_tokens_per_s"] > 0
-    assert validate_bench_result(result) == []
+# -- CLI wiring ---------------------------------------------------------------
 
 
 def test_serve_cli_spec_example_yaml_e2e(tmp_path, capsys, monkeypatch, cpu_devices):
@@ -778,27 +691,3 @@ def test_serve_cli_spec_example_yaml_e2e(tmp_path, capsys, monkeypatch, cpu_devi
     summary = summarize_metrics(records)
     assert summary["serve_requests"] == 2
     assert "serve_accept_rate" in summary
-
-
-def test_kernel_bench_paged_family_cpu_e2e(tmp_path, monkeypatch):
-    """tools/kernel_bench.py --skip-moe --skip-attention runs the paged
-    family through the interpreter: fused + gather candidates both gate,
-    rows carry the kernel_* keys, JSONL lints clean."""
-    monkeypatch.chdir(tmp_path)
-    import tools.kernel_bench as kb
-
-    rc = kb.main([
-        "--skip-moe", "--skip-attention", "--output-dir", str(tmp_path / "kb"),
-    ])
-    assert rc == 0
-    from automodel_tpu.telemetry.report import lint_metrics_jsonl
-
-    records, problems = lint_metrics_jsonl(str(tmp_path / "kb" / "kernel_bench.jsonl"))
-    assert problems == []
-    rows = [r for r in records if r.get("event") == "kernel_bench"]
-    backends = {r.get("kernel_backend") for r in rows}
-    assert {"fused", "gather"} <= backends
-    assert all(r["ok"] for r in rows), [r.get("error") for r in rows if not r["ok"]]
-    assert any(r["autotune_key"].startswith("paged:") for r in rows)
-    md = (tmp_path / "kb" / "KERNEL_BENCH.md").read_text()
-    assert "paged_attention" in md

@@ -20,7 +20,6 @@ from automodel_tpu.telemetry.memory import live_array_census, memory_snapshot
 from automodel_tpu.telemetry.report import (
     lint_metrics_jsonl,
     summarize_metrics,
-    validate_bench_result,
 )
 from automodel_tpu.training.timers import Timers
 from automodel_tpu.training.train_state import TrainState
@@ -378,24 +377,6 @@ def test_telemetry_disabled_is_inert(tmp_path):
     assert m == {"loss": 1.0}
     with tel.crash_guard():
         pass  # nullcontext
-
-
-# -- bench-result validation (satellite 6) -----------------------------------
-
-def test_validate_bench_result_catches_silent_zero():
-    bad = {"value": 0.0, "dense_failure": None, "moe_mfu_pct": None, "moe_failures": None}
-    problems = validate_bench_result(bad)
-    assert any("0.0" in p for p in problems)
-    assert any("moe_mfu_pct" in p for p in problems)
-    ok = {
-        "value": 61.2,
-        "dense_failure": None,
-        "qlora_8b_mfu_pct": None,
-        "qlora_8b_failure": "OOM: ...",
-        "moe_mfu_pct": 27.1,
-        "moe_failures": None,
-    }
-    assert validate_bench_result(ok) == []
 
 
 def test_lint_flags_bare_nan_tokens(tmp_path):

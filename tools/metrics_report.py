@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Validate + summarize a train_metrics.jsonl.
 
-Thin CLI wrapper over automodel_tpu/telemetry/report.py (which bench.py and
-`automodel_tpu report` also use): strict-JSON schema lint (bare NaN/Infinity
+Thin CLI wrapper over automodel_tpu/telemetry/report.py (which
+`automodel_tpu report` also uses): strict-JSON schema lint (bare NaN/Infinity
 tokens, null-without-marker, step monotonicity, request-tracing span schema
 and negative durations) plus a tps/step-time/loss summary table with
 per-stage span p50/p99 rollups. To JOIN span records across multiple
