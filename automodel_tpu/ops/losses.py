@@ -11,7 +11,10 @@ materializes full logits). TPU-native formulations:
 - linear CE: the chunked formulation but taking hidden states + lm_head and
   doing the final projection inside the chunk loop — the memory win of
   cut-cross-entropy without a custom kernel, letting XLA fuse projection and
-  log-softmax per chunk.
+  log-softmax per chunk. It carries its own differentiation rule: the same
+  loop forms dlogits, dH and dW while a chunk's logits are live (three
+  vocabulary-wide products a step, none recomputed), and its chunk width
+  comes from the shapes (``_chunk_count``).
 
 All losses return (summed_loss, num_valid_tokens) so callers can normalize
 globally across the dp_cp mesh group (reference: reduce_loss,
@@ -50,14 +53,20 @@ def _usable_chunks(t: int, requested: int) -> int:
     return nc
 
 
-def _ce_sum(logits: jnp.ndarray, labels: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Summed CE over valid tokens. logits [T, V] (any float dtype), labels [T]."""
+def _ce_rows(logits: jnp.ndarray, labels: jnp.ndarray):
+    """(valid [T], logsumexp [T], loss [T], zero where not valid) of logits
+    [T, V] (any float dtype) against labels [T]."""
     valid = labels != IGNORE_INDEX
     safe_labels = jnp.where(valid, labels, 0)
     logits32 = logits.astype(jnp.float32)
     lse = jax.nn.logsumexp(logits32, axis=-1)
     picked = jnp.take_along_axis(logits32, safe_labels[:, None], axis=-1)[:, 0]
-    loss = jnp.where(valid, lse - picked, 0.0)
+    return valid, lse, jnp.where(valid, lse - picked, 0.0)
+
+
+def _ce_sum(logits: jnp.ndarray, labels: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Summed CE over valid tokens. logits [T, V] (any float dtype), labels [T]."""
+    valid, _, loss = _ce_rows(logits, labels)
     return loss.sum(), valid.sum()
 
 
@@ -97,43 +106,130 @@ def chunked_cross_entropy(
     return loss, n
 
 
+# Token-chunk width of the fused linear CE, from the shapes (no setting).
+# A chunk's weight-gradient product is 2*Tc*D*V FLOPs and drags the carried
+# [D, V] accumulator through memory once (read + write: 2*D*V*itemsize bytes),
+# so product / traffic = Tc / itemsize FLOPs a byte whatever D and V are. A
+# TPU's peaks sit at 166-560 FLOPs a byte (v5e 197e12 / 819e9 = 240): at 512
+# bf16 tokens a chunk the two are the same size and the traffic cannot hide.
+# Timed on the v5e at D 2048, V 151,936, bf16 (PERF.md, PR 31), the dW
+# instruction a step: 38.3 ms at 512 tokens, 29.8 at 1024, 32.6 at 2048, 29.5
+# at 4096 against 25.9 for the product alone: twice the v5e's ratio hides it.
+_CHUNK_TOKENS_PER_ACC_BYTE = 512
+# ... capped by what a chunk keeps live: its f32 logits and the compute-dtype
+# dlogits, (4 + itemsize) * Tc * V bytes (0.93 GB at 1024 bf16 tokens and that
+# vocabulary). 2 GiB is what a one-layer 30B-A3B step leaves on a 16 GB chip;
+# it binds for an f32 head over 131 k entries or a bf16 one over 349 k.
+_CHUNK_LIVE_BYTES = 2 << 30
+
+
+def _chunk_count(t: int, v: int, compute_dtype, acc_dtype) -> int:
+    """Token chunks of the fused linear CE for T tokens over a V vocabulary:
+    the fewest that divide T with a chunk inside both bounds. A T with no such
+    divisor within twice that count (a prime) is left to ``_usable_chunks``:
+    fewer, larger chunks and its warning."""
+    wanted = _CHUNK_TOKENS_PER_ACC_BYTE * jnp.dtype(acc_dtype).itemsize
+    fits = _CHUNK_LIVE_BYTES // ((4 + jnp.dtype(compute_dtype).itemsize) * v)
+    need = -(-t // max(1, min(wanted, fits)))
+    return next((n for n in range(need, min(t, 2 * need) + 1) if t % n == 0), need)
+
+
+def _chunk_logits(h, kernel, soft_cap):
+    """f32 (capped) logits of one chunk, and tanh of the raw ones under a cap."""
+    z = (h @ kernel).astype(jnp.float32)
+    if soft_cap is None:
+        return z, None
+    th = jnp.tanh(z / soft_cap)
+    return soft_cap * th, th
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _fused_ce(h, kernel, labels, soft_cap):
+    """h [chunks, Tc, D], kernel [D, V], labels [chunks, Tc] -> (sum, count).
+    Undifferentiated: one product a chunk, nothing saved."""
+
+    def body(carry, chunk):
+        s, n = _ce_sum(_chunk_logits(chunk[0], kernel, soft_cap)[0], chunk[1])
+        return (carry[0] + s, carry[1] + n), None
+
+    return jax.lax.scan(body, (jnp.float32(0.0), jnp.int32(0)), (h, labels))[0]
+
+
+def _fused_ce_fwd(h, kernel, labels, soft_cap):
+    """The loss is the last op of the forward, so its backward starts when it
+    ends: form each chunk's dlogits = softmax - onehot while its logits are
+    live, and from them dH (stacked) and dW (carried). Residuals are the two
+    gradients of the loss SUM; nothing of size T x V outlives a chunk."""
+    want_dh, want_dw = h.perturbed, kernel.perturbed
+    h, kernel, labels = h.value, kernel.value, labels.value
+    dw0 = jnp.zeros(kernel.shape, kernel.dtype) if want_dw else None
+
+    def body(carry, chunk):
+        (s, n), dw = carry
+        hc, lb = chunk
+        z, th = _chunk_logits(hc, kernel, soft_cap)
+        valid, lse, loss = _ce_rows(z, lb)
+        out = (s + loss.sum(), n + valid.sum())
+        # IGNORE_INDEX matches no column; its rows are zeroed whole
+        hit = jax.lax.broadcasted_iota(jnp.int32, z.shape, 1) == lb[:, None]
+        dz = jnp.where(valid[:, None], jnp.exp(z - lse[:, None]) - hit, 0.0)
+        if th is not None:
+            dz = dz * (1.0 - th * th)
+        dz = dz.astype(jnp.result_type(hc.dtype, kernel.dtype))  # as autodiff casts it
+        dh = None
+        if want_dh:
+            dh = jax.lax.dot_general(
+                dz, kernel, (((1,), (1,)), ((), ())), preferred_element_type=hc.dtype)
+        if want_dw:
+            dw = dw + jax.lax.dot_general(
+                hc, dz, (((0,), (0,)), ((), ())), preferred_element_type=dw.dtype)
+        return (out, dw), dh
+
+    (out, dw), dh = jax.lax.scan(
+        body, ((jnp.float32(0.0), jnp.int32(0)), dw0), (h, labels))
+    return out, (dh, dw)
+
+
+def _fused_ce_bwd(soft_cap, res, cts):
+    """Both residuals times the scalar cotangent of the loss sum (the count
+    carries none): one pass over [T, D] and one over [D, V]."""
+    dh, dw = res
+    g = cts[0]
+    dh = None if dh is None else dh * g.astype(dh.dtype)
+    dw = None if dw is None else dw * g.astype(dw.dtype)
+    return dh, dw, None
+
+
+_fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd, symbolic_zeros=True)
+
+
 def fused_linear_cross_entropy(
     hidden: jnp.ndarray,
     lm_head_kernel: jnp.ndarray,
     labels: jnp.ndarray,
-    num_chunks: int = 16,
+    num_chunks: int | None = None,
     logits_soft_cap: float | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """CE from hidden states + lm_head without materializing [T, V] logits.
 
     hidden [..., D], lm_head_kernel [D, V], labels [...]. The projection runs
-    inside the chunk scan so peak memory is chunk×V (reference capability:
-    FusedLinearCrossEntropy via cut-cross-entropy, loss/linear_ce.py:119).
+    inside a scan over token chunks, so peak memory is chunk x V (reference
+    capability: FusedLinearCrossEntropy via cut-cross-entropy,
+    loss/linear_ce.py:119). The function carries its own differentiation rule
+    (``_fused_ce_fwd``): three [T, D] x [D, V]-sized products a step (logits,
+    dH, dW) and no recomputed one. The chunk count follows T, V and the dtypes
+    (``_chunk_count``) unless ``num_chunks`` is given.
     """
     d = hidden.shape[-1]
-    flat_h = hidden.reshape(-1, d)
-    flat_labels = labels.reshape(-1)
-    t = flat_h.shape[0]
+    t = labels.size
+    if num_chunks is None:
+        num_chunks = _chunk_count(
+            t, lm_head_kernel.shape[-1],
+            jnp.result_type(hidden.dtype, lm_head_kernel.dtype), lm_head_kernel.dtype)
     num_chunks = _usable_chunks(t, num_chunks)
-    flat_h = flat_h.reshape(num_chunks, t // num_chunks, d)
-    flat_labels = flat_labels.reshape(num_chunks, t // num_chunks)
-
-    # checkpoint the body, else scan's AD stacks every chunk's fp32 logits
-    # as residuals — f32[chunks, chunk_t, V] (4GB at the MoE bench shape,
-    # the round-5 OOM) — exactly the buffer this function exists to avoid.
-    # The backward recomputes h @ lm_head per chunk (cut-cross-entropy's
-    # trade: one extra [chunk, D]x[D, V] matmul per chunk).
-    @jax.checkpoint
-    def body(carry, chunk):
-        h, lb = chunk
-        logits = h @ lm_head_kernel
-        if logits_soft_cap is not None:
-            logits = logits_soft_cap * jnp.tanh(logits / logits_soft_cap)
-        s, n = _ce_sum(logits, lb)
-        return (carry[0] + s, carry[1] + n), None
-
-    (loss, n), _ = jax.lax.scan(body, (jnp.float32(0.0), jnp.int32(0)), (flat_h, flat_labels))
-    return loss, n
+    return _fused_ce(
+        hidden.reshape(num_chunks, t // num_chunks, d), lm_head_kernel,
+        labels.reshape(num_chunks, t // num_chunks), logits_soft_cap)
 
 
 def vocab_parallel_cross_entropy(

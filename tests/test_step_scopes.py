@@ -139,9 +139,18 @@ def test_most_ops_of_a_compiled_program_carry_a_scope(programs, program, must_ha
 def test_backward_and_recompute_are_told_apart(programs):
     names = [n for _, n in _instructions(programs["train"][1]) if n]
     seen = {(program_trace.scope_of(n), program_trace.direction_of(n)) for n in names}
-    for scope in ("attn", "moe/experts", "lm_head_ce"):
+    for scope in ("attn", "moe/experts"):
         assert {(scope, "fwd"), (scope, "bwd"), (scope, "remat")} <= seen, scope
     assert ("optimizer", "bwd") not in seen and ("optimizer", "remat") not in seen
+    # the fused loss carries its own rule (ops/losses._fused_ce_fwd): its three
+    # products run in the forward chunk loop, its backward is the multiply by
+    # the loss's cotangent, and NOTHING of it is recomputed. At this size the
+    # compiler folds that multiply into its consumers, so the backward is read
+    # where the program wrote it: the lowered module's locations
+    wrote = {(program_trace.scope_of(n), program_trace.direction_of(n)) for n in re.findall(
+        r'loc\("([^"/][^"]*)"', programs["train"][0].as_text(debug_info=True))}
+    assert ("lm_head_ce", "fwd") in seen and ("lm_head_ce", "bwd") in wrote
+    assert ("lm_head_ce", "remat") not in seen | wrote
 
 
 def test_path_segments_unwrap_transforms():

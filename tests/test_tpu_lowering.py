@@ -113,6 +113,34 @@ def test_paged_decode_lowers(n, degrees, sq, int8, q_shape, pool_shape, nbseq, l
     assert _compile(functools.partial(paged_attend, mesh_ctx=ctx, **kw), *args) == 1
 
 
+def test_fused_linear_ce_is_three_vocabulary_products():
+    """The train cell's loss at its widths (D 2048, V 151,936, bf16) over two
+    1024-token chunks: differentiated, the chunk loop holds the logits, dH and
+    dW products and no fourth (autodiff of a checkpointed scan recomputed the
+    logits: four)."""
+    from automodel_tpu.ops.losses import fused_linear_cross_entropy
+
+    ctx = _tpu_ctx(1)
+    t, d, v = 2048, 2048, 151936
+    h = _sds(ctx, (1, t, d), jnp.bfloat16)
+    w = _sds(ctx, (d, v), jnp.bfloat16)
+    labels = _sds(ctx, (1, t), jnp.int32)
+
+    def loss(h, w, labels):
+        s, n = fused_linear_cross_entropy(h, w, labels)
+        return s / n
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(h, w, labels).compile()
+    hlo = compiled.as_text()
+    assert hlo.count(" convolution(") == 3
+    # one body of the chunk loop, as the compiler counts it
+    chunk_product = 2 * 1024 * d * v
+    assert 3.0 <= compiled.cost_analysis()["flops"] / chunk_product < 3.1
+    # a chunk's f32 logits and bf16 dlogits, not the stacked [T, V]
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 1024 * v * 1.05
+
+
 @pytest.mark.parametrize(
     "n,degrees,backend",
     [
