@@ -21,9 +21,28 @@ The scheduler loop (one ``step()`` = one engine iteration):
    queued requests from stalling behind a single long prompt: the decode
    wave below still runs every iteration.
 4. **decode tick** — one jitted paged decode step over all slots; active
-   slots each advance one token — or, with ``serving.speculative:``, one
-   draft-propose + ONE batched verify forward advancing each slot by 1 to
-   k+1 tokens (rollback of rejected drafts is a host-side length
+   slots each advance one token. The tick LAUNCHES AHEAD: it dispatches
+   step n + 1 first and only then reads and records step n's tokens, so
+   the host's work of an iteration runs beside a decode program and the
+   device always has the next one queued. Step n + 1 needs nothing of
+   step n on the host: tables are fixed at admission, a slot with a row in
+   flight is one token longer, a spent budget is arithmetic (a slot is
+   never launched past ``max_new``), and ``cur`` is taken inside the
+   program from step n's token array, which never left the device. Only a
+   stop id needs the token: it is found one step late, and the row the
+   slot already has in step n + 1 is DISCARDED when that step is read
+   (never appended, no logprob; its K/V write lies inside the slot's own
+   budget, behind every position anyone reads, and before any write of a
+   later tenant in launch order). Every row of the step in flight carries
+   the ``_Slot`` it was launched for, so a slot that ended, was cancelled
+   or changed hands in between receives nothing. An iteration with nothing
+   to launch still reads what is in flight (a request's last token arrives
+   that way), and ``idle()`` is false until it has. The pipeline drains
+   where the host must wait for the device anyway: the first-token wait
+   after a prompt's last chunk. With ``serving.speculative:`` the tick
+   keeps its synchronous order (one draft-propose + ONE batched verify
+   forward advancing each slot by 1 to k+1 tokens, so the next lengths are
+   not host-known; rollback of rejected drafts is a host-side length
    decrement; no copies). Slots whose token hits a stop id or whose
    budget is spent COMPLETE: their blocks decref back to the pool (prompt
    blocks stay matchable in the prefix cache) and the slot refills from the
@@ -651,6 +670,18 @@ class _Slot:
     tier: str = "interactive"
 
 
+@dataclasses.dataclass
+class _DecodeInFlight:
+    """A decode step that was launched and whose tokens are not read yet.
+    ``slots[b]`` is the ``_Slot`` row ``b`` was launched for (None: the row
+    was not active in this step); the arrays are still on the device."""
+
+    tokens: Any  # [B] int32, the next step's ``prev_tokens``
+    logps: Any  # [B] fp32
+    units: Any  # [2] int32 (live, grid) expert work units
+    slots: list  # [B] of Optional[_Slot]
+
+
 def _tree_path_name(path) -> str:
     """The param-tree leaf naming rule — MUST match
     ``checkpoint.checkpointer.param_tree_signature`` exactly, so signature
@@ -787,6 +818,12 @@ class ServingEngine:
                     f"speculative.k={spec.k} exceeds the draft model's "
                     f"context limit {dmax}"
                 )
+        # launch-ahead (module docstring, step 4): a decode step's tokens stay
+        # on the device for the next step to read; on a mesh they are pinned
+        # replicated, where `_no_tokens` (_init_pool_arrays) is placed too
+        self._token_sharding = (
+            auto.mesh_ctx.replicated() if auto.mesh_ctx is not None else None
+        )
         self._init_pool_arrays()
         constrain = auto.constrain
 
@@ -808,7 +845,8 @@ class ServingEngine:
         self._decode = paged.build_paged_decode_fn(
             apply, self.gen_config.sampling,
             pad_id=self.gen_config.pad_token_id, with_logprobs=True,
-            expert_units=self._expert_units_fn(), **pk,
+            expert_units=self._expert_units_fn(),
+            token_sharding=self._token_sharding, **pk,
         )
         if self._spec_enabled:
             d_model = self.draft_auto.model
@@ -843,6 +881,8 @@ class ServingEngine:
         self._cur = np.full((B,), self.gen_config.pad_token_id, np.int32)
         self._active = np.zeros((B,), bool)
         self._slots: list[Optional[_Slot]] = [None] * B
+        # the decode step whose tokens the host has not read
+        self._in_flight: Optional[_DecodeInFlight] = None
         self._queue: deque[_Queued] = deque()
         self._ids = itertools.count()
         self._step_counter = 0
@@ -857,6 +897,10 @@ class ServingEngine:
         self._n_admitted = self._n_chunks = 0
         self._n_decoded = self._n_context_tokens = 0
         self._n_state_resets = 0  # prompts whose FIRST chunk ran this step
+        # launch-ahead: a decode program was dispatched this step; the one
+        # before it was still running then (the device saw no gap); rows of
+        # the step read whose slot had ended or changed hands
+        self._n_launched = self._n_launched_ahead = self._n_discarded_rows = 0
         # the fused decode kernel's grid over a K/V layer, and its steps that
         # hold a live page (ops/paged_attention.grid_steps)
         self._n_attn_grid_steps = self._n_attn_live_steps = 0
@@ -959,6 +1003,16 @@ class ServingEngine:
         speculative decoding the draft model's parallel pool (same block
         geometry, its own layer/head dims) rebuilds in the same breath —
         a stall mid-verify must never leave half-trusted draft state."""
+        # what the decode program is handed as "the previous step's tokens"
+        # while no step is unread: placed as a step's own tokens come back, so
+        # both are ONE compiled program
+        no_tokens = np.full(
+            (self.config.slots,), self.gen_config.pad_token_id, np.int32
+        )
+        self._no_tokens = (
+            jnp.asarray(no_tokens) if self._token_sharding is None
+            else jax.device_put(no_tokens, self._token_sharding)
+        )
         self._pool = paged.place_pool(
             paged.layout_pool(
                 self._layout, self.config.slots, self.config.num_blocks,
@@ -984,6 +1038,7 @@ class ServingEngine:
         benchmark harness frees the pool before its reference runs). The
         engine is unusable after."""
         self._pool = None
+        self._in_flight = None
         if self._spec_enabled:
             self._draft_pool = None
 
@@ -1081,7 +1136,13 @@ class ServingEngine:
         )
 
     def idle(self) -> bool:
-        return not self._queue and self.busy_slots == 0
+        return not self._queue and self._quiet
+
+    @property
+    def _quiet(self) -> bool:
+        """No request holds a slot and no decode step is unread (a slot
+        that ended by a stop id leaves one discarded row in flight)."""
+        return self.busy_slots == 0 and self._in_flight is None
 
     def note_ready(self) -> None:
         """Stamp ``time_to_ready_s`` at this replica's FIRST readiness
@@ -1195,10 +1256,11 @@ class ServingEngine:
         touched; a mismatch raises ``ValueError`` loudly with the old
         params bit-intact. A valid tree is device_put to the live leaves'
         shardings and staged; the scheduler applies it at a step boundary
-        with ZERO busy slots, so every in-flight request finishes under
-        the weights it started with, and new admissions hold (the queue
-        keeps absorbing — nothing drops) until the swap lands. If no
-        request is in flight the swap applies immediately. → the
+        with ZERO busy slots and no decode step unread (``_quiet``), so
+        every in-flight request finishes under the weights it started with,
+        and new admissions hold (the queue keeps absorbing — nothing drops)
+        until the swap lands. If no request is in flight the swap applies
+        immediately. → the
         ``weights_version`` the engine advertises once the swap is live.
 
         Same shapes/dtypes means the already-compiled prefill/decode
@@ -1239,7 +1301,7 @@ class ServingEngine:
         )
         self._pending_swap = staged
         target = self.weights_version + 1
-        if self.busy_slots == 0:
+        if self._quiet:
             self._apply_pending_swap()
         return target
 
@@ -2365,8 +2427,25 @@ class ServingEngine:
         return done
 
     def _decode_tick(self) -> list[dict]:
-        if not self._active.any():
+        if self._spec_enabled:
+            # a verify step commits 1 to k + 1 tokens a slot: the next
+            # step's lengths are not host-known, so nothing launches ahead
+            if not self._active.any():
+                return []
+            self._inject_decode_faults()
+            return self._spec_decode_tick()
+        read = self._in_flight
+        launch, ahead, slots = self._launch_rows(read)
+        if read is None and not launch.any():
             return []
+        self._inject_decode_faults()
+        self._in_flight = (
+            self._launch_decode(launch, ahead, slots, read)
+            if launch.any() else None
+        )
+        return self._read_decode(read) if read is not None else []
+
+    def _inject_decode_faults(self) -> None:
         from automodel_tpu.resilience.fault_injection import active_injector
 
         inj = active_injector()
@@ -2375,36 +2454,74 @@ class ServingEngine:
             # terminal), so the delay attributes to the decode stage
             inj.maybe_trace_delay("decode")
             inj.maybe_slo_breach("decode", self._step_counter)
-        if self._spec_enabled:
-            return self._spec_decode_tick()
-        params = self.auto.params
-        if self.collect_program_costs and "paged_decode" not in self.program_costs:
-            self._record_cost(
-                "paged_decode", self._decode,
-                params, self._pool,
-                jnp.asarray(self._tables), jnp.asarray(self._lengths),
-                jnp.asarray(self._cur), jnp.asarray(self._active),
-                self._base_key, jnp.int32(self._step_counter),
-            )
-        self._note_decode_wave()
+
+    def _launch_rows(self, read: Optional[_DecodeInFlight]):
+        """→ ``(launch [B] bool, ahead [B] bool, slots [B])``: the rows the
+        next decode step advances, those of them whose newest token is still
+        in ``read`` (the unread step: the row is one token longer than the
+        host has recorded and takes ``cur`` from that step's token array),
+        and the ``_Slot`` each launched row belongs to. A slot whose budget
+        its row in flight spends is not launched again."""
+        B = self.config.slots
+        launch, ahead = np.zeros((B,), bool), np.zeros((B,), bool)
+        slots: list[Optional[_Slot]] = [None] * B
+        for b in np.flatnonzero(self._active):
+            slot = self._slots[b]
+            in_flight = read is not None and read.slots[b] is slot
+            if len(slot.generated) + in_flight < slot.max_new:
+                launch[b], ahead[b], slots[b] = True, in_flight, slot
+        return launch, ahead, slots
+
+    def _launch_decode(
+        self, launch: np.ndarray, ahead: np.ndarray, slots: list,
+        read: Optional[_DecodeInFlight],
+    ) -> _DecodeInFlight:
+        """Dispatch one decode step over the ``launch`` rows and leave its
+        tokens on the device. Every host array handed over is this call's
+        own: the scheduler writes ``_tables``/``_cur`` while the program may
+        still be reading what it was given."""
+        lengths = self._lengths + ahead
+        self._note_decode_wave(lengths, launch)
         self.step_phase = "decode_dispatch"
         with TraceAnnotation("serve.decode_dispatch"):
-            tokens, logps, units, self._pool = self._decode(
-                params, self._pool,
-                jnp.asarray(self._tables), jnp.asarray(self._lengths),
-                jnp.asarray(self._cur), jnp.asarray(self._active),
+            args = (
+                self.auto.params, self._pool,
+                jnp.asarray(self._tables.copy()), jnp.asarray(lengths),
+                jnp.asarray(self._cur.copy()), jnp.asarray(launch),
                 self._base_key, jnp.int32(self._step_counter),
+                read.tokens if read is not None else self._no_tokens,
+                jnp.asarray(ahead),
             )
+            if self.collect_program_costs and "paged_decode" not in self.program_costs:
+                self._record_cost("paged_decode", self._decode, *args)
+            tokens, logps, units, self._pool = self._decode(*args)
+            self._n_launched = 1
+            # the step before is still running with this one queued behind
+            # it: the device goes from one to the other without the host
+            self._n_launched_ahead = int(
+                read is not None and not read.tokens.is_ready()
+            )
+        return _DecodeInFlight(tokens, logps, units, slots)
+
+    def _read_decode(self, step: _DecodeInFlight) -> list[dict]:
+        """Bring a launched step's tokens to the host and record them. A row
+        whose slot ended (a stop id found in the step before, a timeout, a
+        cancel) or changed hands since the launch is discarded."""
         self.step_phase = "decode_wait"
         with TraceAnnotation("serve.decode_wait"):
-            tokens, logps, units = jax.device_get((tokens, logps, units))
+            tokens, logps, units = jax.device_get(
+                (step.tokens, step.logps, step.units)
+            )
         self._n_expert_live_units, self._n_expert_grid_units = map(int, units)
         self.first_decode_done = True
         done: list[dict] = []
         self.step_phase = "record"
         with TraceAnnotation("serve.record"):
-            for b, slot in enumerate(self._slots):
-                if slot is None or not self._active[b]:
+            for b, slot in enumerate(step.slots):
+                if slot is None:
+                    continue
+                if self._slots[b] is not slot:
+                    self._n_discarded_rows += 1
                     continue
                 tok = int(tokens[b])
                 slot.generated.append(tok)
@@ -2418,16 +2535,16 @@ class ServingEngine:
                     done.append(self._terminate(b, "length"))
         return done
 
-    def _note_decode_wave(self) -> None:
+    def _note_decode_wave(self, lengths: np.ndarray, active: np.ndarray) -> None:
         """What the decode program is about to read, for `serve.counts`:
         the active slots and the context tokens their attention covers."""
-        self._n_decoded = int(self._active.sum())
-        self._n_context_tokens = int(self._lengths[self._active].sum())
+        self._n_decoded = int(active.sum())
+        self._n_context_tokens = int(lengths[active].sum())
         if self.decode_backend == "fused":
             # every slot's row, active or not: the kernel attends them all
             self._n_attn_grid_steps, self._n_attn_live_steps = (
                 paged_attention.grid_steps(
-                    self._lengths, self._tables.shape[1],
+                    lengths, self._tables.shape[1],
                     pages=self.attn_pages_per_step,
                     block_size=self.config.block_size, sq=self._attn_query_rows,
                 )
@@ -2447,7 +2564,7 @@ class ServingEngine:
         cur = jnp.asarray(self._cur)
         active = jnp.asarray(self._active)
         step = jnp.int32(self._step_counter)
-        self._note_decode_wave()
+        self._note_decode_wave(self._lengths, self._active)
         t_propose0 = time.perf_counter()
         self.step_phase = "spec_propose"
         with TraceAnnotation("serve.spec_propose"):
@@ -2539,6 +2656,7 @@ class ServingEngine:
         self.pool.clear_prefix_cache()
         self.pool.check_invariants()
         self._init_pool_arrays()
+        self._in_flight = None  # the step in flight goes with the wave it fails
         self._tables[:] = 0
         self._lengths[:] = 0
         self._active[:] = False
@@ -2628,8 +2746,9 @@ class ServingEngine:
 
         On the profiler's clock the iteration is one ``serve.step`` span;
         its phases (admit, each chunk's dispatch, the first-token wait, the
-        decode dispatch, the decode wait, the records) are its children,
-        and ``serve.counts`` closes it with the iteration's integers."""
+        decode dispatch of the NEXT step, the decode wait and the records of
+        the step launched an iteration ago) are its children, and
+        ``serve.counts`` closes it with the iteration's integers."""
         with TraceAnnotation(
             "serve.step", step=self._step_counter,
             queued=len(self._queue), busy=self.busy_slots,
@@ -2638,6 +2757,8 @@ class ServingEngine:
             self._n_decoded = self._n_context_tokens = 0
             self._n_attn_grid_steps = self._n_attn_live_steps = 0
             self._n_expert_grid_units = self._n_expert_live_units = 0
+            self._n_launched = self._n_launched_ahead = 0
+            self._n_discarded_rows = 0
             done = self._iterate()
             self.step_phase = None
             with TraceAnnotation(
@@ -2649,6 +2770,9 @@ class ServingEngine:
                 attn_live_steps=self._n_attn_live_steps,
                 expert_grid_units=self._n_expert_grid_units,
                 expert_live_units=self._n_expert_live_units,
+                decode_launched=self._n_launched,
+                decode_launched_ahead=self._n_launched_ahead,
+                discarded_rows=self._n_discarded_rows,
             ):
                 pass
         return done
@@ -2731,7 +2855,7 @@ class ServingEngine:
             )
         if not rebuilt:
             self._consecutive_rebuilds = 0
-        if self._pending_swap is not None and self.busy_slots == 0:
+        if self._pending_swap is not None and self._quiet:
             # the step that terminated the last in-flight request is the
             # swap boundary: everything before this line ran (and finished)
             # under the old weights, everything admitted after runs under

@@ -591,13 +591,20 @@ def build_paged_decode_fn(
     interpret: bool = False,
     with_logprobs: bool = False,
     expert_units: Optional[Callable] = None,
+    token_sharding=None,
 ) -> Callable:
     """→ jitted ``step(params, pool, tables [B, NBseq], lengths [B], cur
-    [B], active [B] bool, key, step_idx)`` → ``(next_tokens [B], units,
-    pool)``, or ``(next_tokens [B], logprobs [B] fp32, units, pool)`` when
-    ``with_logprobs`` — the sampled token's log-probability under the RAW
-    distribution (see ``sample_with_logprobs``), masked to 0.0 on
-    inactive slots. ``units`` ``[2] int32``: the (live, grid) work units of
+    [B], active [B] bool, key, step_idx, prev_tokens [B], take_prev [B]
+    bool)`` → ``(next_tokens [B], units, pool)``, or ``(next_tokens [B],
+    logprobs [B] fp32, units, pool)`` when ``with_logprobs`` — the sampled
+    token's log-probability under the RAW distribution (see
+    ``sample_with_logprobs``), masked to 0.0 on inactive slots. A row of
+    ``take_prev`` feeds ``prev_tokens[b]`` (an earlier step's
+    ``next_tokens``, which may never have left the device) in place of
+    ``cur[b]``: the scheduler launches a step before it has read the last
+    one's tokens. ``token_sharding`` (on a mesh) pins where ``next_tokens``
+    come back: what stands in for ``prev_tokens`` while no step is unread is
+    placed the same, so the two are ONE compiled program. ``units`` ``[2] int32``: the (live, grid) work units of
     the fused expert forward summed over this step's expert layers, by
     ``expert_units(counts [L_moe, E])`` from the rows the step routed each
     expert (ops/fused_expert_mlp.work_units); zeros without it or for a
@@ -614,23 +621,31 @@ def build_paged_decode_fn(
     forward = _make_forward(apply, backend, block_size, compute_dtype, interpret)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def step(params, pool, tables, lengths, cur, active, key, step_idx):
+    def step(
+        params, pool, tables, lengths, cur, active, key, step_idx,
+        prev_tokens, take_prev,
+    ):
+        cur = jnp.where(take_prev, prev_tokens, cur)
         logits, pool, counts = forward(
             params, pool, tables, lengths, cur[:, None], active
         )
         units = jnp.zeros((2,), jnp.int32)
         if expert_units is not None and counts is not None:
             units = jnp.stack(expert_units(counts)).astype(jnp.int32)
+        def placed(nxt):
+            nxt = jnp.where(active, nxt, jnp.int32(pad_id))
+            if token_sharding is None:
+                return nxt
+            return jax.lax.with_sharding_constraint(nxt, token_sharding)
+
         with jax.named_scope("sample"):
             skey = jax.random.fold_in(key, step_idx)
             if with_logprobs:
                 nxt, logp = sample_with_logprobs(logits[:, -1], skey, sampling)
-                nxt = jnp.where(active, nxt, jnp.int32(pad_id))
                 logp = jnp.where(active, logp, jnp.float32(0.0))
-                return nxt, logp, units, pool
+                return placed(nxt), logp, units, pool
             nxt = sample(logits[:, -1], skey, sampling)
-            nxt = jnp.where(active, nxt, jnp.int32(pad_id))
-            return nxt, units, pool
+            return placed(nxt), units, pool
 
     return step
 

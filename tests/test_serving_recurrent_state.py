@@ -295,7 +295,8 @@ def test_the_new_scopes_are_in_the_programs_op_names():
             jnp.int32(0), jnp.int32(5), jnp.int32(0)),
         "decode": eng._decode.lower(
             params, eng._pool, jnp.asarray(eng._tables), jnp.asarray(eng._lengths),
-            jnp.asarray(eng._cur), jnp.asarray(eng._active), eng._base_key, jnp.int32(0)),
+            jnp.asarray(eng._cur), jnp.asarray(eng._active), eng._base_key, jnp.int32(0),
+            eng._no_tokens, jnp.asarray(eng._active)),
     }
     for name, low in lowered.items():
         segments, scopes = set(), set()

@@ -76,7 +76,8 @@ def _lowered_programs() -> dict:
             jnp.int32(0), jnp.int32(5)),
         "decode": eng._decode.lower(
             params, eng._pool, jnp.asarray(eng._tables), jnp.asarray(eng._lengths),
-            jnp.asarray(eng._cur), jnp.asarray(eng._active), eng._base_key, jnp.int32(0)),
+            jnp.asarray(eng._cur), jnp.asarray(eng._active), eng._base_key, jnp.int32(0),
+            eng._no_tokens, jnp.asarray(eng._active)),
     }
 
 

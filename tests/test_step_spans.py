@@ -5,7 +5,9 @@ here depends on how long anything took).
 Ground truth is the engine's own state: every iteration is driven through a
 recorder that notes what ``step()`` returned, the arrays the decode program was
 handed, and ``_lengths``/``_active`` afterwards; ``serve.counts`` must say the
-same. The reader is the benchmark's (``benchmarks/harness/program_trace.py``),
+same. The decode tick launches ahead: an iteration dispatches the NEXT decode
+step and then reads the one launched an iteration ago, so a launch's tokens
+(and a request's end) show one iteration after it. The reader is the benchmark's (``benchmarks/harness/program_trace.py``),
 so the pair is tested together.
 """
 
@@ -75,10 +77,10 @@ def traced(tmp_path_factory):
     rows = []
     real_decode = eng._decode
 
-    def spy(params, pool, tables, lengths, cur, active, key, step_idx):
+    def spy(params, pool, tables, lengths, cur, active, *rest):
         rows[-1]["read"] = int(np.asarray(lengths)[np.asarray(active)].sum())
         rows[-1]["wave"] = int(np.asarray(active).sum())
-        return real_decode(params, pool, tables, lengths, cur, active, key, step_idx)
+        return real_decode(params, pool, tables, lengths, cur, active, *rest)
 
     eng._decode = spy
     trace_dir = tmp_path_factory.mktemp("trace")
@@ -133,7 +135,7 @@ def test_step_stats_are_the_engine_s_state_at_entry(traced):
 
 def test_counts_agree_with_the_records_and_the_arrays(traced):
     spans = traced["spans"]
-    admitted = 0
+    admitted = launched_before = read_before = wave_before = 0
     for (i, _), row in zip(_steps(spans), traced["rows"]):
         kids = program_trace.children_of(spans, i)
         counts = {k: int(v) for k, v in kids[-1]["stats"].items()}
@@ -144,14 +146,37 @@ def test_counts_agree_with_the_records_and_the_arrays(traced):
         # what the decode program was handed
         assert counts["decoded"] == row["wave"]
         assert counts["context_tokens"] == row["read"]
-        assert (counts["decoded"] > 0) == any(k["name"] == "serve.decode_wait" for k in kids)
+        names = [k["name"] for k in kids]
+        # launch-ahead: an iteration dispatches a step when a slot has budget
+        # left and reads the step launched the iteration before
+        assert counts["decode_launched"] == (counts["decoded"] > 0) == (
+            "serve.decode_dispatch" in names)
+        assert ("serve.decode_wait" in names) == bool(launched_before)
+        if "serve.decode_wait" in names and counts["decode_launched"]:
+            assert names.index("serve.decode_dispatch") < names.index("serve.decode_wait")
+        # ahead only of a step that was in flight (how often is the chip's to say)
+        assert counts["decode_launched_ahead"] <= min(counts["decode_launched"], launched_before)
+        assert counts["discarded_rows"] == 0  # no stop id, nothing cancelled
         # the relation to an after-step sample of _lengths * _active (the
-        # harness's): the step added one token an active slot and freed the
-        # slots it finished; a request that ends at its decode has
-        # prompt + n_generated - 1 tokens in the cache when it is freed
+        # harness's): the tokens of the step just launched are not recorded
+        # yet, and a slot whose budget the step in flight spent ended at this
+        # iteration's read, so what is left is what that step is reading
+        assert row["after"] == counts["context_tokens"]
+        # and the relation PR 25 pinned, moved by the iteration the read now
+        # lags: the step launched an iteration ago added one token a row, this
+        # iteration's read freed the slots that row finished (a request that
+        # ends at its decode held prompt + n_generated - 1 tokens then), and
+        # the prompts whose last chunk ran in this iteration started decoding
         freed = sum(r["prompt_tokens"] + r["n_generated"] - 1 for r in row["done"]
                     if r["n_generated"] >= 2)
-        assert row["after"] == counts["context_tokens"] + counts["decoded"] - freed
+        started = sum(
+            next(int(c["stats"]["pos"]) + int(c["stats"]["tokens"]) for c in chunks
+                 if c["stats"]["slot"] == w["stats"]["slot"])
+            for w in kids if w["name"] == "serve.first_token_wait"
+        ) - sum(r["prompt_tokens"] for r in row["done"] if r["n_generated"] == 1)
+        assert row["after"] == read_before + wave_before - freed + started
+        launched_before = counts["decode_launched"]
+        read_before, wave_before = row["read"], row["wave"]
         admitted += counts["admitted"]
     assert admitted == 5
     first_waits = [s for s in spans if s["name"] == "serve.first_token_wait"]
@@ -241,7 +266,7 @@ def test_counts_carry_the_fused_kernel_s_grid(monkeypatch):
     # ends the first group, 512 opens the second, an inactive slot counts too
     eng._lengths[:] = [0, 511, 512, 600]
     eng._active[:] = [False, True, True, False]
-    eng._note_decode_wave()
+    eng._note_decode_wave(eng._lengths, eng._active)
     assert (eng._n_attn_grid_steps, eng._n_attn_live_steps) == (4 * 3, 1 + 1 + 2 + 2)
     eng._lengths[:] = 0
     eng._active[:] = False
@@ -329,17 +354,20 @@ def test_counts_carry_the_fused_expert_forward_s_units(monkeypatch):
     eng.submit(list(range(1, 11)), request_id="a")
     eng.submit([3, 4, 5], request_id="b")
     eng.run()
-    decoding = [e for e in events if e["decoded"]]
-    assert decoding
+    # the units come to the host with the step's tokens: in the iteration
+    # AFTER the one that launched it (the decode tick launches ahead)
+    read = [e for e, before in zip(events[1:], events) if before["decode_launched"]]
+    assert read and sum(e["decode_launched"] for e in events) == len(read)
     # two expert layers (the first of three is dense), 3 slots x top-2 rows
     grid = 2 * fused_expert_mlp.work_units(np.zeros(16, int), 6, 128, 128)[1]
-    for e in decoding:
+    for e in read:
         assert e["expert_grid_units"] == grid == 2 * (1 + 16)
         # 6 rows a layer in one row tile: a live unit is an expert touched,
         # at least top-k of them a layer and at most one a row
         assert 2 * 2 <= e["expert_live_units"] <= 2 * 6
     assert all(e["expert_grid_units"] == e["expert_live_units"] == 0
-               for e in events if not e["decoded"])
+               for e, before in zip(events, [{"decode_launched": 0}] + events)
+               if not before["decode_launched"])
 
     dense = _engine()
     events.clear()

@@ -153,7 +153,9 @@ def serve_programs(cfg, ctx):
             "paged_decode", eng._decode,
             (eng.auto.params, eng._pool, sds((B, NB), jnp.int32),
              sds((B,), jnp.int32), sds((B,), jnp.int32), sds((B,), jnp.bool_),
-             key, i32),
+             key, i32,
+             # the step before's tokens, and the rows that take `cur` from them
+             sds((B,), jnp.int32), sds((B,), jnp.bool_)),
         ),
     ]
 
