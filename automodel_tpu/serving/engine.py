@@ -85,7 +85,6 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.profiler import TraceAnnotation
 
 from automodel_tpu.generation.engine import (
     GenerationConfig,
@@ -95,7 +94,7 @@ from automodel_tpu.generation.engine import (
 from automodel_tpu.generation import kv_cache
 from automodel_tpu.generation.sampling import sample
 from automodel_tpu.ops import paged_attention
-from automodel_tpu.serving import paged
+from automodel_tpu.serving import loop_account, paged
 from automodel_tpu.serving.block_pool import (
     BlockPool,
     HostSpillTier,
@@ -659,6 +658,9 @@ class _Slot:
     decoding: bool = False
     generated: Optional[list[int]] = None
     t_first: Optional[float] = None
+    # the loop's account at ``t_first`` (serving/loop_account.py): the
+    # terminal record carries its difference to the account at the end
+    account_first: Optional[loop_account.Snapshot] = None
     prefill_only: bool = False
     spec_proposed: int = 0  # draft tokens proposed for this request
     spec_accepted: int = 0  # draft tokens accepted by the verify rule
@@ -887,26 +889,14 @@ class ServingEngine:
         self._ids = itertools.count()
         self._step_counter = 0
         # step phases (docs/observability.md "Step phases and program
-        # scopes"): each phase of step() is a TraceAnnotation on the
-        # profiler's clock, so a device trace names what the host was doing
-        # in every idle gap. `step_phase` holds the name of the phase in
-        # progress (one store per phase; None between iterations) for the
-        # watchdog's stall evidence; the _n_* integers become the stats of
+        # scopes", "Loop account"): each phase of step() is one
+        # `with self._phase(...)`: a span on the profiler's clock, so a
+        # device trace names what the host was doing in every idle gap, and
+        # its perf_counter_ns duration in the loop's always-on account. The
+        # iteration's integers accumulate there too and become the stats of
         # the `serve.counts` event that closes each iteration.
-        self.step_phase: Optional[str] = None
-        self._n_admitted = self._n_chunks = 0
-        self._n_decoded = self._n_context_tokens = 0
-        self._n_state_resets = 0  # prompts whose FIRST chunk ran this step
-        # launch-ahead: a decode program was dispatched this step; the one
-        # before it was still running then (the device saw no gap); rows of
-        # the step read whose slot had ended or changed hands
-        self._n_launched = self._n_launched_ahead = self._n_discarded_rows = 0
-        # the fused decode kernel's grid over a K/V layer, and its steps that
-        # hold a live page (ops/paged_attention.grid_steps)
-        self._n_attn_grid_steps = self._n_attn_live_steps = 0
-        # the fused expert forward's work units over a decode step's expert
-        # layers, and those that hold rows (ops/fused_expert_mlp.work_units)
-        self._n_expert_grid_units = self._n_expert_live_units = 0
+        self._account = loop_account.LoopAccount()
+        self._phase = self._account.phase
         # live weight hot-swap (swap_weights): monotonic version tag
         # advertised on /stats + /metrics, and the validated replacement
         # tree staged until a step boundary with zero busy slots
@@ -1127,6 +1117,18 @@ class ServingEngine:
     @property
     def watchdog(self):
         return self._watchdog
+
+    @property
+    def step_phase(self) -> Optional[str]:
+        """The phase of ``step()`` in progress (None between iterations):
+        the stall watchdog's evidence."""
+        return self._account.step_phase
+
+    def loop_account(self) -> dict:
+        """Where the loop's thread has spent its time since the engine was
+        built, and what it did: {"s": seconds a phase, "n": counts}
+        (serving/loop_account.py; /stats and /metrics export it)."""
+        return self._account.totals()
 
     @property
     def last_step_age_s(self) -> Optional[float]:
@@ -1966,7 +1968,8 @@ class ServingEngine:
         "stop"/"length" is a completion; anything else is a failure whose
         blocks must still come back (the leak-audit contract)."""
         slot = self._slots[b]
-        now = time.perf_counter()
+        end = self._account.snapshot()
+        now = end.t
         gen = slot.generated or []
         self.pool.free(slot.blocks)
         self._slots[b] = None
@@ -2020,6 +2023,8 @@ class ServingEngine:
         # span covers submit→terminal and names how it ended — including
         # the cancel/stall/drain paths, which land here like completions
         if slot.decoding and slot.t_first is not None:
+            # what the loop did during this request's token gaps
+            rec["decode_account"] = loop_account.between(slot.account_first, end)
             self._child_span(
                 slot.trace, "decode", slot.t_first, now,
                 request_id=slot.request_id, tokens=max(len(gen) - 1, 0),
@@ -2166,8 +2171,7 @@ class ServingEngine:
         return best_i
 
     def _admit(self, done: list[dict]) -> None:
-        self.step_phase = "admit"
-        with TraceAnnotation("serve.admit"):
+        with self._phase("admit"):
             self._admit_free_slots(done)
 
     def _admit_free_slots(self, done: list[dict]) -> None:
@@ -2225,7 +2229,7 @@ class ServingEngine:
                         hit_tokens, max(matchable - hit_tokens, 0)
                     )
                     self._bind_slot(b, q, blocks, hit_tokens)
-                self._n_admitted += 1
+                self._account.n["admitted"] += 1
                 # queue wait and admission (prefix match + whole-budget
                 # block allocation + slot bind) as sibling stages under the
                 # request root — the two ways a slow admission can hide
@@ -2294,7 +2298,8 @@ class ServingEngine:
             request_id=q.rid, blocks=nb, prompt_tokens=p,
         )
         first = int(q.payload["first_token"])
-        now = time.perf_counter()
+        account_first = self._account.snapshot()
+        now = account_first.t
         self._tables[b] = row
         self._lengths[b] = p
         self._cur[b] = first
@@ -2303,7 +2308,8 @@ class ServingEngine:
             request_id=q.rid, prompt=q.prompt, max_new=q.max_new,
             blocks=blocks, hit_tokens=0, prefill_pos=p,
             t_submit=q.t_submit, t_admit=now, deadline_at=q.deadline_at,
-            decoding=True, generated=[first], t_first=now, trace=q.trace,
+            decoding=True, generated=[first], t_first=now,
+            account_first=account_first, trace=q.trace,
             tenant=q.tenant, tier=q.tier,
         )
         # the injected prefix is as matchable as a locally-computed one —
@@ -2328,18 +2334,16 @@ class ServingEngine:
             p = len(slot.prompt)
             start = slot.prefill_pos
             real = min(chunk_len, p - start)
-            self.step_phase = "prefill_dispatch"
-            with TraceAnnotation(
-                "serve.prefill_dispatch", slot=b, pos=start, tokens=real
-            ):
+            with self._phase(
+                "prefill_dispatch", slot=b, pos=start, tokens=real
+            ) as dispatch:
                 ids = np.full((chunk_len,), pad, np.int32)
                 ids[:real] = slot.prompt[start : start + real]
                 # a recurrent layout's chunk also names the slot's state row;
                 # a chunk at position 0 resets it inside the program
                 state_row = (jnp.int32(b),) if self._stateful else ()
                 if self._stateful and start == 0:
-                    self._n_state_resets += 1
-                t_chunk0 = time.perf_counter()
+                    self._account.n["state_resets"] += 1
                 if inj is not None:
                     inj.maybe_trace_delay("prefill")
                     inj.maybe_slo_breach("prefill", self._step_counter)
@@ -2365,11 +2369,11 @@ class ServingEngine:
                         jnp.asarray(self._tables[b]), jnp.asarray(ids),
                         jnp.int32(start), jnp.int32(real),
                     )
-            self._n_chunks += 1
+            self._account.n["chunks"] += 1
             # one span per chunk: a single long prompt's prefill shows as a
             # chunk train, and a stall inside one chunk names its offset
             self._child_span(
-                slot.trace, "prefill", t_chunk0,
+                slot.trace, "prefill", dispatch.start_s, dispatch.end_s,
                 request_id=slot.request_id, pos=start, tokens=real,
             )
             slot.prefill_pos = start + real
@@ -2378,8 +2382,8 @@ class ServingEngine:
                 continue
             # prompt fully in: sample the first token (charged to ttft) —
             # the host blocks here until the chunk program has run
-            self.step_phase = "first_token_wait"
-            with TraceAnnotation("serve.first_token_wait", slot=b):
+            self._account.n["first_token_waits"] += 1
+            with self._phase("first_token_wait", slot=b):
                 first = int(
                     sample(
                         last[None, :],
@@ -2388,8 +2392,7 @@ class ServingEngine:
                     )[0]
                 )
             # publish the prompt blocks to the prefix cache, flip to decode
-            self.step_phase = "record"
-            with TraceAnnotation("serve.record"):
+            with self._phase("record"):
                 if slot.logprobs is not None:
                     # same raw-logits rule as the decode program (the chunk
                     # already handed `last` to the host, so this is free)
@@ -2397,7 +2400,8 @@ class ServingEngine:
                         float(jax.nn.log_softmax(last.astype(jnp.float32))[first])
                     )
                 self.pool.register_prefix(slot.prompt, slot.blocks)
-                slot.t_first = time.perf_counter()
+                slot.account_first = self._account.snapshot()
+                slot.t_first = slot.account_first.t
                 slot.generated = [first]
                 if slot.prefill_only:
                     # disaggregated fleet: the prompt's block rows leave for
@@ -2435,12 +2439,16 @@ class ServingEngine:
             self._inject_decode_faults()
             return self._spec_decode_tick()
         read = self._in_flight
-        launch, ahead, slots = self._launch_rows(read)
+        with self._phase("decode_plan"):
+            launch, ahead, slots = self._launch_rows(read)
+            lengths = self._lengths + ahead
+            if launch.any():
+                self._note_decode_wave(lengths, launch)
         if read is None and not launch.any():
             return []
         self._inject_decode_faults()
         self._in_flight = (
-            self._launch_decode(launch, ahead, slots, read)
+            self._launch_decode(launch, ahead, lengths, slots, read)
             if launch.any() else None
         )
         return self._read_decode(read) if read is not None else []
@@ -2473,55 +2481,57 @@ class ServingEngine:
         return launch, ahead, slots
 
     def _launch_decode(
-        self, launch: np.ndarray, ahead: np.ndarray, slots: list,
-        read: Optional[_DecodeInFlight],
+        self, launch: np.ndarray, ahead: np.ndarray, lengths: np.ndarray,
+        slots: list, read: Optional[_DecodeInFlight],
     ) -> _DecodeInFlight:
-        """Dispatch one decode step over the ``launch`` rows and leave its
-        tokens on the device. Every host array handed over is this call's
-        own: the scheduler writes ``_tables``/``_cur`` while the program may
-        still be reading what it was given."""
-        lengths = self._lengths + ahead
-        self._note_decode_wave(lengths, launch)
-        self.step_phase = "decode_dispatch"
-        with TraceAnnotation("serve.decode_dispatch"):
-            args = (
-                self.auto.params, self._pool,
-                jnp.asarray(self._tables.copy()), jnp.asarray(lengths),
-                jnp.asarray(self._cur.copy()), jnp.asarray(launch),
-                self._base_key, jnp.int32(self._step_counter),
-                read.tokens if read is not None else self._no_tokens,
-                jnp.asarray(ahead),
-            )
+        """Dispatch one decode step over the ``launch`` rows (``lengths`` as
+        the program reads them) and leave its tokens on the device. Every
+        host array handed over is this call's own: the scheduler writes
+        ``_tables``/``_cur`` while the program may still be reading what it
+        was given."""
+        n = self._account.n
+        with self._phase("decode_dispatch"):
+            with self._phase("decode_h2d"):
+                args = (
+                    self.auto.params, self._pool,
+                    jnp.asarray(self._tables.copy()), jnp.asarray(lengths),
+                    jnp.asarray(self._cur.copy()), jnp.asarray(launch),
+                    self._base_key, jnp.int32(self._step_counter),
+                    read.tokens if read is not None else self._no_tokens,
+                    jnp.asarray(ahead),
+                )
             if self.collect_program_costs and "paged_decode" not in self.program_costs:
                 self._record_cost("paged_decode", self._decode, *args)
-            tokens, logps, units, self._pool = self._decode(*args)
-            self._n_launched = 1
-            # the step before is still running with this one queued behind
-            # it: the device goes from one to the other without the host
-            self._n_launched_ahead = int(
-                read is not None and not read.tokens.is_ready()
-            )
+            with self._phase("decode_launch"):
+                tokens, logps, units, self._pool = self._decode(*args)
+                n["decode_launched"] += 1
+                # the step before is still running with this one queued
+                # behind it: the device goes from one to the other without
+                # the host
+                n["decode_launched_ahead"] += int(
+                    read is not None and not read.tokens.is_ready()
+                )
         return _DecodeInFlight(tokens, logps, units, slots)
 
     def _read_decode(self, step: _DecodeInFlight) -> list[dict]:
         """Bring a launched step's tokens to the host and record them. A row
         whose slot ended (a stop id found in the step before, a timeout, a
         cancel) or changed hands since the launch is discarded."""
-        self.step_phase = "decode_wait"
-        with TraceAnnotation("serve.decode_wait"):
+        n = self._account.n
+        with self._phase("decode_wait"):
             tokens, logps, units = jax.device_get(
                 (step.tokens, step.logps, step.units)
             )
-        self._n_expert_live_units, self._n_expert_grid_units = map(int, units)
+        n["expert_live_units"] += int(units[0])
+        n["expert_grid_units"] += int(units[1])
         self.first_decode_done = True
         done: list[dict] = []
-        self.step_phase = "record"
-        with TraceAnnotation("serve.record"):
+        with self._phase("record"):
             for b, slot in enumerate(step.slots):
                 if slot is None:
                     continue
                 if self._slots[b] is not slot:
-                    self._n_discarded_rows += 1
+                    n["discarded_rows"] += 1
                     continue
                 tok = int(tokens[b])
                 slot.generated.append(tok)
@@ -2538,17 +2548,18 @@ class ServingEngine:
     def _note_decode_wave(self, lengths: np.ndarray, active: np.ndarray) -> None:
         """What the decode program is about to read, for `serve.counts`:
         the active slots and the context tokens their attention covers."""
-        self._n_decoded = int(active.sum())
-        self._n_context_tokens = int(lengths[active].sum())
+        n = self._account.n
+        n["decoded"] += int(active.sum())
+        n["context_tokens"] += int(lengths[active].sum())
         if self.decode_backend == "fused":
             # every slot's row, active or not: the kernel attends them all
-            self._n_attn_grid_steps, self._n_attn_live_steps = (
-                paged_attention.grid_steps(
-                    lengths, self._tables.shape[1],
-                    pages=self.attn_pages_per_step,
-                    block_size=self.config.block_size, sq=self._attn_query_rows,
-                )
+            grid, live = paged_attention.grid_steps(
+                lengths, self._tables.shape[1],
+                pages=self.attn_pages_per_step,
+                block_size=self.config.block_size, sq=self._attn_query_rows,
             )
+            n["attn_grid_steps"] += grid
+            n["attn_live_steps"] += live
 
     def _spec_decode_tick(self) -> list[dict]:
         """One speculative round for the whole decode wave: the draft
@@ -2564,17 +2575,14 @@ class ServingEngine:
         cur = jnp.asarray(self._cur)
         active = jnp.asarray(self._active)
         step = jnp.int32(self._step_counter)
-        self._note_decode_wave(self._lengths, self._active)
-        t_propose0 = time.perf_counter()
-        self.step_phase = "spec_propose"
-        with TraceAnnotation("serve.spec_propose"):
+        with self._phase("decode_plan"):
+            self._note_decode_wave(self._lengths, self._active)
+        with self._phase("spec_propose") as propose:
             drafts, draft_logits, self._draft_pool = self._propose(
                 self.draft_auto.params, self._draft_pool,
                 tables, lengths, cur, active, self._base_key, step,
             )
-        t_verify0 = time.perf_counter()
-        self.step_phase = "spec_verify"
-        with TraceAnnotation("serve.spec_verify"):
+        with self._phase("spec_verify") as verify:
             if self.collect_program_costs and "spec_verify" not in self.program_costs:
                 self._record_cost(
                     "spec_verify", self._verify,
@@ -2587,20 +2595,14 @@ class ServingEngine:
             )
             tokens = np.asarray(jax.device_get(tokens))
             n_commit = np.asarray(jax.device_get(n_commit))
-        t_wave_end = time.perf_counter()
         self.first_decode_done = True
         self.spec_rounds += 1  # one propose+verify round per WAVE, not per slot
         done: list[dict] = []
-        self.step_phase = "record"
-        with TraceAnnotation("serve.record"):
-            self._record_spec_wave(
-                done, tokens, n_commit, k, t_propose0, t_verify0, t_wave_end
-            )
+        with self._phase("record"):
+            self._record_spec_wave(done, tokens, n_commit, k, propose, verify)
         return done
 
-    def _record_spec_wave(
-        self, done, tokens, n_commit, k, t_propose0, t_verify0, t_wave_end
-    ) -> None:
+    def _record_spec_wave(self, done, tokens, n_commit, k, propose, verify) -> None:
         for b, slot in enumerate(self._slots):
             if slot is None or not self._active[b]:
                 continue
@@ -2608,11 +2610,11 @@ class ServingEngine:
             # served: the whole wave's wall time IS where this request's
             # time went (the calls are batched over the wave)
             self._child_span(
-                slot.trace, "spec_propose", t_propose0, t_verify0,
+                slot.trace, "spec_propose", propose.start_s, propose.end_s,
                 request_id=slot.request_id, k=k,
             )
             self._child_span(
-                slot.trace, "spec_verify", t_verify0, t_wave_end,
+                slot.trace, "spec_verify", verify.start_s, verify.end_s,
                 request_id=slot.request_id,
                 accepted=int(n_commit[b]) - 1,
             )
@@ -2746,35 +2748,20 @@ class ServingEngine:
 
         On the profiler's clock the iteration is one ``serve.step`` span;
         its phases (admit, each chunk's dispatch, the first-token wait, the
-        decode dispatch of the NEXT step, the decode wait and the records of
-        the step launched an iteration ago) are its children, and
-        ``serve.counts`` closes it with the iteration's integers."""
-        with TraceAnnotation(
-            "serve.step", step=self._step_counter,
+        plan and the dispatch of the NEXT decode step, the decode wait and
+        the records of the step launched an iteration ago) are its children,
+        and ``serve.counts`` closes it with the iteration's integers. The
+        same phases are the buckets of the loop's always-on account
+        (serving/loop_account.py)."""
+        with self._phase(
+            "step", step=self._step_counter,
             queued=len(self._queue), busy=self.busy_slots,
         ):
-            self._n_admitted = self._n_chunks = self._n_state_resets = 0
-            self._n_decoded = self._n_context_tokens = 0
-            self._n_attn_grid_steps = self._n_attn_live_steps = 0
-            self._n_expert_grid_units = self._n_expert_live_units = 0
-            self._n_launched = self._n_launched_ahead = 0
-            self._n_discarded_rows = 0
+            self._account.begin_iteration()
             done = self._iterate()
-            self.step_phase = None
-            with TraceAnnotation(
-                "serve.counts", admitted=self._n_admitted,
-                chunks=self._n_chunks, decoded=self._n_decoded,
-                context_tokens=self._n_context_tokens, finished=len(done),
-                state_resets=self._n_state_resets, state_slots=self.state_slots,
-                attn_grid_steps=self._n_attn_grid_steps,
-                attn_live_steps=self._n_attn_live_steps,
-                expert_grid_units=self._n_expert_grid_units,
-                expert_live_units=self._n_expert_live_units,
-                decode_launched=self._n_launched,
-                decode_launched_ahead=self._n_launched_ahead,
-                discarded_rows=self._n_discarded_rows,
-            ):
-                pass
+            self._account.close_iteration(
+                finished=len(done), state_slots=self.state_slots
+            )
         return done
 
     def _iterate(self) -> list[dict]:

@@ -169,6 +169,9 @@ STATS_METRIC_EQUIV = {
     # the router reads per-replica version skew off this during a rolling
     # update
     "weights_version": "automodel_serve_weights_version",
+    # the scheduler thread's account (an info dict: its numbers ride the
+    # labeled automodel_serve_loop_* families below)
+    "loop_account": None,
 }
 
 # Families deliberately absent from /stats: per-request distributions have
@@ -185,6 +188,9 @@ STATS_METRICS_ONLY = (
     "automodel_serve_tier_requests",
     "automodel_serve_tenant_requests",
     "automodel_serve_tier_ttft_seconds",
+    # the loop account by phase / by count: /stats has it as "loop_account"
+    "automodel_serve_loop_seconds",
+    "automodel_serve_loop_events",
 )
 
 
@@ -240,6 +246,10 @@ def stats_snapshot(engine: Any) -> dict:
         # queue and outcome breakdown (fleet-status renders these)
         "quota_total": engine.quota_total,
         "qos": engine.qos_snapshot(),
+        # where the scheduler thread's time went since the engine was built
+        # (seconds a step phase, `outside_step` = this front) and what it
+        # did (docs/observability.md "Loop account")
+        "loop_account": engine.loop_account(),
     }
 
 

@@ -180,6 +180,13 @@ class LabeledCounter(_Labeled):
         with self._lock:
             self.values[key] = self.values.get(key, 0.0) + v
 
+    def set_total(self, label_value, total: float) -> None:
+        """For a source that keeps its own cumulative value (``Counter
+        .set_total``'s contract: never backwards)."""
+        key = self._key(label_value)
+        with self._lock:
+            self.values[key] = max(self.values.get(key, 0.0), float(total))
+
     def render(self) -> list[str]:
         return self._lines("_total")
 
@@ -563,6 +570,23 @@ class ServingMetrics:
             "Monotonic weights generation currently being served "
             "(bumps on each applied hot-swap)",
         )
+        # the scheduler thread's own account (serving/loop_account.py),
+        # synced at scrape time: seconds in each step phase (`step` = the
+        # iteration's remainder, `outside_step` = the front between two
+        # iterations) and what the iterations did
+        self.loop_seconds = r.labeled_counter(
+            "automodel_serve_loop_seconds",
+            "Scheduler-thread seconds by step phase since engine start "
+            "(self time; the phases sum to the thread's wall time)",
+            "phase",
+        )
+        self.loop_events = r.labeled_counter(
+            "automodel_serve_loop_events",
+            "Scheduler-loop counts since engine start (iterations, chunk "
+            "programs, decode launches and those launched ahead, decoded "
+            "rows, live expert units, ...)",
+            "what",
+        )
         self._pool_counters = {
             key: r.counter(f"automodel_serve_block_{key}", help_text)
             for key, help_text in (
@@ -678,6 +702,11 @@ class ServingMetrics:
             self.spec_accept_rate.set(
                 accepted / proposed if proposed else 0.0
             )
+            account = engine.loop_account()
+            for phase, seconds in account["s"].items():
+                self.loop_seconds.set_total(phase, seconds)
+            for what, count in account["n"].items():
+                self.loop_events.set_total(what, count)
 
 
 # -- training-side metric set --------------------------------------------------

@@ -19,9 +19,10 @@ FIXTURES = ("train_xplane", "train_ops", "serve_xplane")
 # the trace reader, and the readers of the counters the engine writes into
 # ``serve.counts``: ``attn_grid_steps`` / ``attn_live_steps`` (PR 29),
 # ``expert_grid_units`` / ``expert_live_units`` (PR 34) and ``decode_launched``
-# / ``decode_launched_ahead`` / ``discarded_rows`` (PR 38)
+# / ``decode_launched_ahead`` / ``discarded_rows`` (PR 38); of the records'
+# ``decode_account`` and the ``serve.decode_h2d`` span (PR 39)
 for _name in ("program_trace", "paged_grid_live_pct", "expert_grid_live_pct",
-              "decode_launch_ahead_pct"):
+              "decode_launch_ahead_pct", "token_gap_account", "decode_h2d_ms"):
     # ``benchmarks_tests_program_trace`` is the name that file looks for to
     # bring the architecture-dispatch cases along
     _spec = importlib.util.spec_from_file_location(

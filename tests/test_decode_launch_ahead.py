@@ -18,7 +18,7 @@ import jax
 from automodel_tpu.auto_model import AutoModel
 from automodel_tpu.generation.engine import GenerationConfig, GenerationEngine
 from automodel_tpu.models.common.config import BackendConfig, TransformerConfig
-from automodel_tpu.serving import engine as engine_module
+from automodel_tpu.serving import loop_account
 from automodel_tpu.serving.engine import ServeConfig, ServingEngine, SpeculativeConfig
 
 FP32 = BackendConfig(attn="sdpa", param_dtype="float32", compute_dtype="float32")
@@ -68,7 +68,7 @@ def counts(monkeypatch):
         def __exit__(self, *exc):
             return False
 
-    monkeypatch.setattr(engine_module, "TraceAnnotation", Recorder)
+    monkeypatch.setattr(loop_account, "TraceAnnotation", Recorder)
     return events
 
 

@@ -32,6 +32,9 @@ _EXTRA_KEYS = (
     "boot_source",
     "direction",
     "trigger",
+    # serving/loop_account.py: a serve_request record's slice of the
+    # scheduler loop's account ({"s": seconds by phase, "n": counts})
+    "decode_account",
 )
 
 

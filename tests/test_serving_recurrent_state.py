@@ -209,13 +209,13 @@ def test_a_reused_slot_holds_nothing_of_its_previous_tenant(R):
     (x,) = eng.run()
     state_after_x = np.asarray(eng._pool.state)
     assert np.abs(state_after_x[:, 0]).max() > 0  # the row is dirty when Y arrives
-    resets = 0
+    resets_before = eng.loop_account()["n"]["state_resets"]
     eng.submit(second, request_id="y", max_new_tokens=6, return_logprobs=True)
     recs = []
     while not eng.idle():
         recs += eng.step()
-        resets += eng._n_state_resets
-    assert resets == 1  # Y's first chunk, and only that one
+    # Y's first chunk, and only that one
+    assert eng.loop_account()["n"]["state_resets"] - resets_before == 1
     _check_record(R, hf, auto.params, first, x)
     _check_record(R, hf, auto.params, second, recs[0])
 

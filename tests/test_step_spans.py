@@ -9,6 +9,10 @@ same. The decode tick launches ahead: an iteration dispatches the NEXT decode
 step and then reads the one launched an iteration ago, so a launch's tokens
 (and a request's end) show one iteration after it. The reader is the benchmark's (``benchmarks/harness/program_trace.py``),
 so the pair is tested together.
+
+The same primitive keeps the loop's always-on account
+(``serving/loop_account.py``): its totals and every record's ``decode_account``
+are held against the spans and the ``serve.counts`` rows of that trace.
 """
 
 import sys
@@ -27,12 +31,15 @@ if str(ROOT) not in sys.path:
 from automodel_tpu.auto_model import AutoModel
 from automodel_tpu.generation.engine import GenerationConfig
 from automodel_tpu.models.common.config import BackendConfig, TransformerConfig
+from automodel_tpu.serving import loop_account
 from automodel_tpu.serving.engine import ServeConfig, ServingEngine, StallConfig
 from benchmarks.harness import program_trace, trace
 
 FP32 = BackendConfig(attn="sdpa", param_dtype="float32", compute_dtype="float32")
-PHASES = ("serve.admit", "serve.prefill_dispatch", "serve.first_token_wait",
+PHASES = ("serve.admit", "serve.prefill_dispatch", "serve.first_token_wait", "serve.decode_plan",
           "serve.decode_dispatch", "serve.decode_wait", "serve.record", "serve.counts")
+# where the host's part of a decode dispatch is spent: children of the dispatch
+DISPATCH_PARTS = ("serve.decode_h2d", "serve.decode_launch")
 
 
 def _tiny_auto():
@@ -85,6 +92,8 @@ def traced(tmp_path_factory):
     eng._decode = spy
     trace_dir = tmp_path_factory.mktemp("trace")
     rng = np.random.default_rng(0)
+    first_token = {}  # request id -> (index of the iteration it came in, slot)
+    before = eng._account.snapshot()
     jax.profiler.start_trace(str(trace_dir))
     try:
         for _ in range(2):
@@ -98,10 +107,14 @@ def traced(tmp_path_factory):
                          "busy": eng.busy_slots, "read": 0, "wave": 0})
             done = eng.step()
             rows[-1].update(done=done, after=int((eng._lengths * eng._active).sum()))
+            for b, slot in enumerate(eng._slots):
+                if slot is not None and slot.t_first is not None:
+                    first_token.setdefault(slot.request_id, (len(rows) - 1, b))
     finally:
         jax.profiler.stop_trace()
     spans = program_trace.read_spans(trace.find_xplane(trace_dir))
-    return {"rows": rows, "spans": spans}
+    return {"rows": rows, "spans": spans, "first_token": first_token,
+            "account": (before, eng._account.snapshot())}
 
 
 def _steps(spans):
@@ -116,10 +129,14 @@ def test_every_phase_is_a_child_of_its_step(traced):
     for sp in spans:
         if sp["name"].startswith("serve.") and sp["name"] != "serve.step":
             parent = spans[sp["parent"]]
-            assert parent["name"] == "serve.step", sp
+            assert parent["name"] == (
+                "serve.decode_dispatch" if sp["name"] in DISPATCH_PARTS else "serve.step"), sp
             assert parent["start_s"] <= sp["start_s"] and sp["end_s"] <= parent["end_s"]
             seen.add(sp["name"])
-    assert seen == set(PHASES)
+    assert seen == set(PHASES + DISPATCH_PARTS)
+    for i, sp in enumerate(spans):
+        if sp["name"] == "serve.decode_dispatch":  # the transfers, then the launch
+            assert [k["name"] for k in program_trace.children_of(spans, i)] == list(DISPATCH_PARTS)
     # one thread, in order, and counts is the last child of every step
     for i, step in steps:
         kids = program_trace.children_of(spans, i)
@@ -183,6 +200,145 @@ def test_counts_agree_with_the_records_and_the_arrays(traced):
     assert len(first_waits) == 5  # one a prompt, after its last chunk
 
 
+def _iteration_kids(traced):
+    spans = traced["spans"]
+    return [program_trace.children_of(spans, i) for i, _ in _steps(spans)]
+
+
+def test_every_record_carries_its_slice_of_the_loop_s_account(traced):
+    """A request's ``decode_account`` is the account's difference between its
+    first token and its end: the seconds sum to its ``decode_s`` and the counts
+    are those of the ``serve.counts`` rows in between. The first token comes in
+    a prefill tick, BEFORE that iteration's decode tick and the chunks of the
+    slots after its own; the end comes at a decode read, AFTER that iteration's
+    chunks and launch."""
+    kids = _iteration_kids(traced)
+    counts = [{k: int(v) for k, v in ks[-1]["stats"].items()} for ks in kids]
+    decoded = 0
+    for j, row in enumerate(traced["rows"]):
+        for rec in row["done"]:
+            acct, n = rec["decode_account"], rec["n_generated"]
+            assert set(acct["n"]) == set(loop_account.REQUEST_COUNTS)
+            assert set(acct["s"]) <= set(loop_account.BUCKETS)
+            assert all(v > 0 for v in acct["s"].values())
+            if n == 1:  # ended where its first token was recorded
+                assert set(acct["s"]) == {"record"} and not any(acct["n"].values())
+                continue
+            decoded += 1
+            assert sum(acct["s"].values()) == pytest.approx(
+                (n - 1) / rec["decode_tps"], abs=1e-6)
+            i, b = traced["first_token"][rec["request_id"]]
+            own = lambda name: sum(k["name"] == name and int(k["stats"]["slot"]) > b
+                                   for k in kids[i])
+            want = {k: sum(c[k] for c in counts[i:j + 1])
+                    for k in ("decode_launched", "decode_launched_ahead", "decoded",
+                              "context_tokens", "expert_live_units", "expert_grid_units")}
+            want["chunks"] = own("serve.prefill_dispatch") + sum(
+                c["chunks"] for c in counts[i + 1:j + 1])
+            want["first_token_waits"] = own("serve.first_token_wait") + sum(
+                k["name"] == "serve.first_token_wait" for ks in kids[i + 1:j + 1] for k in ks)
+            want["iterations"] = j - i
+            assert {k: acct["n"][k] for k in want} == want, rec["request_id"]
+            assert acct["n"]["decode_launched"] >= n - 1  # one launch a token of its own
+            assert {"decode_wait", "decode_dispatch", "decode_launch", "record", "step",
+                    "outside_step"} <= set(acct["s"])
+    assert decoded == 4
+
+
+def test_account_totals_are_the_counts_rows_and_the_spans(traced):
+    before, after = traced["account"]
+    spans, kids = traced["spans"], _iteration_kids(traced)
+    counts = [{k: int(v) for k, v in ks[-1]["stats"].items()} for ks in kids]
+    n = {k: after.n[k] - before.n[k] for k in after.n}
+    assert {k: n[k] for k in loop_account.ITERATION_COUNTS} == {
+        k: sum(c[k] for c in counts) for k in loop_account.ITERATION_COUNTS}
+    named = lambda name: sum(s["name"] == name for s in spans)
+    assert n["iterations"] == named("serve.step") == len(traced["rows"])
+    assert n["first_token_waits"] == named("serve.first_token_wait")
+    # every nanosecond of the thread is in exactly one bucket
+    ns = {k: after.ns[k] - before.ns[k] for k in after.ns}
+    assert sum(ns.values()) == after.t_ns - before.t_ns
+    # a bucket got time exactly where the trace has a span of its name (the
+    # train spans and the trace's own start and stop fell in `outside_step`)
+    assert {k for k, v in ns.items() if v} == {"outside_step"} | {
+        s["name"].removeprefix("serve.") for s in spans
+        if s["name"].startswith("serve.") and s["name"] != "serve.counts"}
+
+
+def _spec_engine():
+    from automodel_tpu.serving.engine import SpeculativeConfig
+
+    draft = {
+        "hf_config": dict(
+            architectures=["LlamaForCausalLM"], model_type="llama", vocab_size=64,
+            hidden_size=16, intermediate_size=32, num_hidden_layers=1, num_attention_heads=2,
+            num_key_value_heads=1, head_dim=8, max_position_embeddings=128),
+        "backend": {"attn": "sdpa", "param_dtype": "float32", "compute_dtype": "float32"},
+    }
+    return _engine(speculative=SpeculativeConfig(enabled=True, k=2, draft=draft))
+
+
+def _recurrent_engine():
+    from tests.test_serving_recurrent_state import _auto
+    from tests.test_serving_recurrent_state import _engine as recurrent_engine
+
+    return recurrent_engine(_auto()[1])
+
+
+@pytest.mark.parametrize("build,waits", [
+    (_spec_engine, {"spec_propose", "spec_verify"}),
+    (_recurrent_engine, {"decode_wait"}),
+])
+def test_other_ticks_and_layouts_produce_the_slice(build, waits):
+    """The speculative tick's phases are buckets like any other, and a layout
+    with recurrent state goes through the same primitive."""
+    eng = build()
+    for prompt in ([5, 6, 7, 8, 9, 10, 11, 12, 13], [9, 8, 7]):
+        eng.submit(prompt, max_new_tokens=5)
+    recs = eng.run()
+    assert len(recs) == 2
+    for rec in recs:
+        acct = rec["decode_account"]
+        assert sum(acct["s"].values()) == pytest.approx(
+            (rec["n_generated"] - 1) / rec["decode_tps"], abs=1e-6)
+        assert waits <= set(acct["s"]) and acct["n"]["decoded"] > 0
+    totals = eng.loop_account()
+    assert set(totals["s"]) == set(loop_account.BUCKETS)
+    assert set(totals["n"]) == set(loop_account.COUNTS)
+    assert totals["n"]["chunks"] >= 3 and totals["n"]["first_token_waits"] == 2
+    assert (totals["n"]["decode_launched"] == 0) == ("spec_verify" in waits)
+
+
+def test_stats_and_metrics_show_the_loop_s_account():
+    """/stats and /metrics of ``automodel_tpu serve``: the totals, as the
+    operator's sink (``stats_snapshot`` and ``ServingMetrics.sync`` are what
+    the two handlers call under the engine lock)."""
+    from automodel_tpu.serving.server import stats_snapshot
+    from automodel_tpu.telemetry.federation import parse_exposition
+
+    eng = _engine()
+    for prompt in ([5, 6, 7, 8, 9], [9, 8, 7]):
+        eng.submit(prompt, max_new_tokens=4)
+    eng.run()
+    stats = stats_snapshot(eng)["loop_account"]
+    assert stats["n"]["iterations"] == eng._step_counter
+    assert stats["n"]["chunks"] == 3 and stats["n"]["decode_launched"] >= 3
+    eng.metrics.sync(eng)
+    fams = parse_exposition(eng.metrics.registry.render())
+    seconds = {k[0][1]: v for k, v in fams["automodel_serve_loop_seconds"].samples.items()}
+    events = {k[0][1]: v for k, v in fams["automodel_serve_loop_events"].samples.items()}
+    assert set(seconds) == set(loop_account.BUCKETS) and events == stats["n"]
+    # the same thread, a little later: every counter at or past /stats'
+    assert all(seconds[k] >= v for k, v in stats["s"].items())
+    assert seconds["decode_wait"] == stats["s"]["decode_wait"] > 0
+    eng.submit([1, 2, 3], max_new_tokens=2)
+    eng.run()
+    eng.metrics.sync(eng)  # a counter never goes back
+    later = parse_exposition(eng.metrics.registry.render())["automodel_serve_loop_seconds"]
+    assert all(later.samples[k] >= v for k, v in
+               fams["automodel_serve_loop_seconds"].samples.items())
+
+
 def test_train_input_path_spans(traced):
     names = [s["name"] for s in traced["spans"] if s["name"].startswith("train.")]
     assert names.count("train.collate") == 2 and names.count("train.place") == 2
@@ -243,7 +399,6 @@ def test_counts_carry_the_fused_kernel_s_grid(monkeypatch):
     ``length`` (the row written just before the attend)."""
     from automodel_tpu.models.llama import LlamaForCausalLM
     from automodel_tpu.ops import paged_attention
-    from automodel_tpu.serving import engine as engine_module
 
     monkeypatch.setenv("AUTOMODEL_FLASH_INTERPRET", "1")
     model = LlamaForCausalLM(
@@ -267,7 +422,8 @@ def test_counts_carry_the_fused_kernel_s_grid(monkeypatch):
     eng._lengths[:] = [0, 511, 512, 600]
     eng._active[:] = [False, True, True, False]
     eng._note_decode_wave(eng._lengths, eng._active)
-    assert (eng._n_attn_grid_steps, eng._n_attn_live_steps) == (4 * 3, 1 + 1 + 2 + 2)
+    counts = eng.loop_account()["n"]
+    assert (counts["attn_grid_steps"], counts["attn_live_steps"]) == (4 * 3, 1 + 1 + 2 + 2)
     eng._lengths[:] = 0
     eng._active[:] = False
 
@@ -292,7 +448,7 @@ def test_counts_carry_the_fused_kernel_s_grid(monkeypatch):
         return real_decode(params, pool, tables, lengths, *rest)
 
     eng._decode = spy
-    monkeypatch.setattr(engine_module, "TraceAnnotation", Recorder)
+    monkeypatch.setattr(loop_account, "TraceAnnotation", Recorder)
     eng.submit(list(range(1, 20)), request_id="a")
     eng.submit([3, 4, 5], request_id="b")
     eng.run()
@@ -313,7 +469,6 @@ def test_counts_carry_the_fused_expert_forward_s_units(monkeypatch):
     rows. A dense model's counts say 0 / 0."""
     from automodel_tpu.models.qwen3_moe import MoEForCausalLM, MoETransformerConfig
     from automodel_tpu.ops import fused_expert_mlp
-    from automodel_tpu.serving import engine as engine_module
 
     monkeypatch.setenv("AUTOMODEL_GMM_INTERPRET", "1")
     hf = {
@@ -350,7 +505,7 @@ def test_counts_carry_the_fused_expert_forward_s_units(monkeypatch):
         def __exit__(self, *exc):
             return False
 
-    monkeypatch.setattr(engine_module, "TraceAnnotation", Recorder)
+    monkeypatch.setattr(loop_account, "TraceAnnotation", Recorder)
     eng.submit(list(range(1, 11)), request_id="a")
     eng.submit([3, 4, 5], request_id="b")
     eng.run()
