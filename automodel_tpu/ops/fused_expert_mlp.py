@@ -32,6 +32,14 @@ and ``lhs`` is read once for both weight grads:
 - ``_bwd_dwd``  — dWd (+ ddb) with the activation mid recomputed in-kernel.
 - ``_bwd_dx``   — dlhs = dg·Wg^T + du·Wu^T fused over I-chunks.
 
+Each result leaves its kernel once, in the form the caller wants: a weight
+gradient is summed over its group's units in an fp32 VMEM scratch and
+written at the group's last unit in the WEIGHT's dtype (no fp32 slab in
+HBM, no cast pass), a fused [G, D, 2I] weight gets ONE [G, D, 2I] cotangent
+from `_bwd_gu` (no concatenate), an empty group's zeros are written by the
+one unit the backward's plan gives it (no select over [G, ·, ·]), and no
+operand is padded at 128-aligned widths (PERF.md, PR 40).
+
 Tile shapes come from the pickers beside each kernel (the shape and
 `_VMEM_BUDGET`); the NaN-tail masking semantics from PR 5 are
 preserved bit-for-bit — every row outside a work unit's window (boundary
@@ -61,6 +69,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from automodel_tpu.ops.grouped_matmul import (
+    _chunks,
+    _group_edges,
     _interpret_requested,
     _pallas_eligible,
     _plan,
@@ -445,24 +455,41 @@ def _act_grads(g, u, dmid, act_kind, limit):
 # mask: rows past sum(group_sizes) belong to no window, so their NaN/Inf
 # garbage is zeroed on the dout side BEFORE any contraction — the PR 5
 # semantics, now without the external [M, N] selects.
+#
+# Every result leaves its kernel once, in the form the caller wants. The two
+# weight-gradient kernels sum a group's units in an fp32 VMEM scratch and
+# write the group's slab at its LAST unit, rounded once to the weight's
+# dtype; their plan gives an empty group one unit (`_plan(...,
+# empty_units=True)`), which writes that group's zeros. `_bwd_gu`'s out block
+# spans both halves, so a fused [G, D, 2I] weight gets ONE [G, D, 2I]
+# cotangent straight from the kernel.
 
-_VMEM_BUDGET = 12 * 1024 * 1024
+# The scoped limit the three backward kernels ask Mosaic for, and the part of
+# it their pickers may fill with blocks (the rest is the kernels' own stack:
+# the activation chain's fp32 tiles, a product before it is added). A v5e
+# core has 128 MiB of VMEM; the 16 MiB scoped default cannot hold a weight
+# slab's fp32 scratch beside its double-buffered out block at the widths
+# that read each operand once (PERF.md, PR 40).
+_VMEM_LIMIT = 64 * 1024 * 1024
+_VMEM_BUDGET = 40 * 1024 * 1024
 
 
-def _bwd_gu_budget_ok(tm, tk, tn, itemsize):
+def _bwd_gu_budget_ok(tm, tk, tn, I, itemsize, out_itemsize):
     need = (
-        2 * itemsize * tm * tk          # lhs block
-        + 3 * 2 * itemsize * tm * tn    # g / u / dmid blocks
-        + 2 * 2 * 4 * tk * tn           # dWg / dWu fp32 slabs
+        2 * itemsize * tm * tk              # lhs block
+        + 3 * 2 * itemsize * tm * tn        # g / u / dmid chunks
+        + 4 * tk * 2 * I                    # fp32 scratch, both halves
+        + 2 * out_itemsize * tk * 2 * I     # the [tk, 2I] out block(s)
     )
     return need <= _VMEM_BUDGET
 
 
-def _bwd_dwd_budget_ok(tm, tk, tn, itemsize):
+def _bwd_dwd_budget_ok(tm, tk, tn, itemsize, out_itemsize):
     need = (
-        2 * 2 * itemsize * tm * tk      # g / u blocks
-        + 2 * itemsize * tm * tn        # dy block
-        + 2 * 4 * tk * tn               # dWd fp32 slab
+        2 * 2 * itemsize * tm * tk          # g / u blocks
+        + 2 * itemsize * tm * tn            # dy block
+        + 4 * tk * tn                       # fp32 scratch
+        + 2 * out_itemsize * tk * tn        # dWd out block
     )
     return need <= _VMEM_BUDGET
 
@@ -477,90 +504,148 @@ def _bwd_dx_budget_ok(tm, tn, ic, itemsize):
     return need <= _VMEM_BUDGET
 
 
-def _bwd_tiles(budget_ok, a, b, dtype):
-    """(tm, divisor chunk of a, divisor chunk of b): tm 512, halved until
-    the kernel's VMEM model holds. The chunks divide the 128-padded dims, so
-    at `in_place_ok` widths (fused [.., 2I] operands read in place) nothing
-    but rows is ever padded."""
-    it = jnp.dtype(dtype).itemsize
-    ca = _divisor_chunk(_round_up(a, 128))
-    cb = _divisor_chunk(_round_up(b, 128))
-    tm = 512
-    while not budget_ok(tm, ca, cb, it) and tm > 128:
-        tm //= 2
-    return tm, ca, cb
+def _bwd_tiles(budget_ok, passes, a, b):
+    """(tm, chunk of a, chunk of b): of the divisor chunks whose blocks fit
+    `_VMEM_BUDGET`, the pair with the fewest ``passes(ca, cb)`` over the
+    kernel's operands (ties: the wider blocks), at 256 rows and, if nothing
+    fits, at 128. The chunks divide the 128-padded dims, so at `in_place_ok`
+    widths (fused [.., 2I] operands read in place) nothing but rows is ever
+    padded.
+
+    Timed on one v5e at the train cell's shape (65,536 rows in 128 groups of
+    455–570, D 2048, I 768, bf16; PERF.md, PR 40), ms a call as (tm, a, b):
+    `_bwd_gu` (512, 512, 384) 7.22, (256, 512, 384) 6.48, (256, 512, 768)
+    5.55, (256, 1024, 768) 4.56, (512, 2048, 768) 4.86, **(256, 2048, 768)
+    4.14**, (128, 2048, 768) 4.10; `_bwd_dwd` (512, 384, 512) 5.15,
+    (256, 768, 512) 3.75, (256, 768, 1024) 2.90, (512, 768, 2048) 2.75,
+    **(256, 768, 2048) 2.40**, (128, 768, 2048) 2.27; `_bwd_dx`
+    (512, 512, 384) 6.51, (256, 512, 768) 4.57, (256, 1024, 768) 4.07,
+    (512, 2048, 768) 4.70, **(256, 2048, 768) 3.82**, (128, 2048, 768) 3.81.
+    A group there holds about 512 rows, so a 512-row tile multiplies as many
+    masked rows as real ones and a 256-row tile half as many; every wider
+    chunk is one pass fewer over the row operands."""
+    for tm in (256, 128):
+        fits = [
+            (passes(ca, cb), -ca * cb, ca, cb)
+            for ca in _chunks(a) for cb in _chunks(b) if budget_ok(tm, ca, cb)
+        ]
+        if fits:
+            return (tm, *min(fits)[2:])
+    return 128, 128, 128
 
 
-def _bwd_gu_tiles(D, I, dtype):
-    """(tm, tk over D, tn over I)."""
-    return _bwd_tiles(_bwd_gu_budget_ok, D, I, dtype)
+def _bwd_gu_tiles(D, I, dtype, out_dtype):
+    """(tm, tk over D, tn over I): g / u / dmid are read once a tk pass (and
+    their activation chain evaluated once a pass), then the widest I-chunk."""
+    it, ito = jnp.dtype(dtype).itemsize, jnp.dtype(out_dtype).itemsize
+    Dp, Ip = _round_up(D, 128), _round_up(I, 128)
+    return _bwd_tiles(
+        lambda tm, tk, tn: _bwd_gu_budget_ok(tm, tk, tn, Ip, it, ito),
+        lambda tk, tn: (Dp // tk, Ip // tn), D, I,
+    )
 
 
-def _bwd_dwd_tiles(I, D, dtype):
-    """(tm, tk over I, tn over D)."""
-    return _bwd_tiles(_bwd_dwd_budget_ok, I, D, dtype)
+def _bwd_dwd_tiles(I, D, dtype, out_dtype):
+    """(tm, tk over I, tn over D): g and u are read once a tn pass, dy once
+    a tk pass."""
+    it, ito = jnp.dtype(dtype).itemsize, jnp.dtype(out_dtype).itemsize
+    Ip, Dp = _round_up(I, 128), _round_up(D, 128)
+    return _bwd_tiles(
+        lambda tm, tk, tn: _bwd_dwd_budget_ok(tm, tk, tn, it, ito),
+        lambda tk, tn: 2 * Dp // tn + Ip // tk, I, D,
+    )
 
 
 def _bwd_dx_tiles(D, I, dtype):
-    """(tm, tn over D, ic over I)."""
-    return _bwd_tiles(_bwd_dx_budget_ok, D, I, dtype)
+    """(tm, tn over D, ic over I): g / u / dmid are read once a tn pass."""
+    it = jnp.dtype(dtype).itemsize
+    Dp, Ip = _round_up(D, 128), _round_up(I, 128)
+    return _bwd_tiles(
+        lambda tm, tn, ic: _bwd_dx_budget_ok(tm, tn, ic, it),
+        lambda tn, ic: (Dp // tn, Ip // ic), D, I,
+    )
 
 
-def _bwd_gu_kernel(wg, wt, ws, we, lhs_ref, g_ref, u_ref, dmid_ref,
-                   dwg_ref, dwu_ref, *rest, tm, act_kind, limit, has_bias):
+def _bwd_gu_kernel(wg, wt, ws, we, lhs_ref, g_ref, u_ref, dmid_ref, *rest,
+                   tm, tn, n_n, W, act_kind, limit, has_bias, fused):
+    """Grid (k over D, w, n over I). ``acc[c]`` / ``acc[n_n + c]`` hold the
+    group's [tk, tn] gate / up sums of I-chunk ``c``; the out block spans all
+    of I (of 2I when ``fused``) and is written at the group's last step."""
+    n_out = 1 if fused else 2
+    outs, rest = rest[:n_out], rest[n_out:]
     if has_bias:
-        dgb_ref, dub_ref = rest
-    w = pl.program_id(2)
-    rows = wt[w] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
-    mask = (rows >= ws[w]) & (rows < we[w])
-    dg, du = _act_grads(g_ref[...], u_ref[...], dmid_ref[...], act_kind, limit)
-    # dout mask folded in-kernel: rows outside this unit's window are the
-    # neighbouring group's rows (boundary tile) or the a2a sentinel tail —
-    # whose g/u/dmid can be NaN, which an lhs-only mask cannot neutralize
-    dg = jnp.where(mask, dg, 0.0)
-    du = jnp.where(mask, du, 0.0)
-    lhs = jnp.where(mask, lhs_ref[...], jnp.zeros_like(lhs_ref))
-    first = jnp.logical_or(w == 0, wg[jnp.maximum(w - 1, 0)] != wg[w])
-    acc_g = jax.lax.dot_general(
-        lhs, dg.astype(lhs_ref.dtype), (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    acc_u = jax.lax.dot_general(
-        lhs, du.astype(lhs_ref.dtype), (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    cur = dwg_ref[0]
-    dwg_ref[0] = acc_g + jnp.where(first, jnp.zeros_like(cur), cur)
-    cur = dwu_ref[0]
-    dwu_ref[0] = acc_u + jnp.where(first, jnp.zeros_like(cur), cur)
-    if has_bias:
-        # bias grads are the dg/du row sums — the [1, tn] accumulator rides
-        # the same first-visitor rule. Its block index ignores the k grid
-        # dim, so every k pass recomputes and rewrites the IDENTICAL totals
-        # (same rows, same dg) — the final write-back is always correct.
-        cur = dgb_ref[0]
-        dgb_ref[0] = dg.sum(axis=0, keepdims=True) + jnp.where(
-            first, jnp.zeros_like(cur), cur
+        dgb_ref, dub_ref, acc, bacc = rest
+    else:
+        (acc,) = rest
+    w = pl.program_id(1)
+    n = pl.program_id(2)
+    first, last = _group_edges(wg, w, W)
+
+    @pl.when(first)
+    def _():
+        acc[n] = jnp.zeros(acc.shape[1:], acc.dtype)
+        acc[n_n + n] = jnp.zeros(acc.shape[1:], acc.dtype)
+        if has_bias:
+            bacc[n] = jnp.zeros(bacc.shape[1:], bacc.dtype)
+            bacc[n_n + n] = jnp.zeros(bacc.shape[1:], bacc.dtype)
+
+    @pl.when(we[w] > ws[w])
+    def _():
+        rows = wt[w] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+        mask = (rows >= ws[w]) & (rows < we[w])
+        dg, du = _act_grads(
+            g_ref[...], u_ref[...], dmid_ref[...], act_kind, limit
         )
-        cur = dub_ref[0]
-        dub_ref[0] = du.sum(axis=0, keepdims=True) + jnp.where(
-            first, jnp.zeros_like(cur), cur
+        # dout mask folded in-kernel: rows outside this unit's window are the
+        # neighbouring group's rows (boundary tile) or the a2a sentinel tail —
+        # whose g/u/dmid can be NaN, which an lhs-only mask cannot neutralize
+        dg = jnp.where(mask, dg, 0.0)
+        du = jnp.where(mask, du, 0.0)
+        lhs = jnp.where(mask, lhs_ref[...], jnp.zeros_like(lhs_ref))
+        acc[n] += jax.lax.dot_general(
+            lhs, dg.astype(lhs_ref.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
+        acc[n_n + n] += jax.lax.dot_general(
+            lhs, du.astype(lhs_ref.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if has_bias:
+            # bias grads are the dg/du row sums. Their block ignores the k
+            # grid dim, so every k pass recomputes and rewrites the IDENTICAL
+            # totals (same rows, same dg)
+            bacc[n] += dg.sum(axis=0, keepdims=True)
+            bacc[n_n + n] += du.sum(axis=0, keepdims=True)
+
+    @pl.when(jnp.logical_and(last, n == n_n - 1))
+    def _():
+        up_ref, up_col = (outs[0], n_n * tn) if fused else (outs[1], 0)
+        for c in range(n_n):
+            outs[0][0, :, c * tn:(c + 1) * tn] = acc[c].astype(outs[0].dtype)
+            up_ref[0, :, up_col + c * tn:up_col + (c + 1) * tn] = (
+                acc[n_n + c].astype(up_ref.dtype)
+            )
+            if has_bias:
+                dgb_ref[0, :, c * tn:(c + 1) * tn] = bacc[c]
+                dub_ref[0, :, c * tn:(c + 1) * tn] = bacc[n_n + c]
 
 
 def _bwd_gu(lhs, g, u, dmid, group_sizes, act_kind, limit, interpret,
-            has_bias):
-    """One pass over lhs → (dWg [G,D,I] f32, dWu, dgb [G,I] f32 | None,
-    dub | None). The dgate·dup chain runs in-kernel on the g/u/dmid tiles.
-    u=None: ``g`` is the fused [M, 2I] product, read in place (`_halves`)."""
+            has_bias, out_dtype):
+    """One pass over lhs → (dWg [G,D,I], dWu, dgb [G,I] f32 | None,
+    dub | None), the weight gradients in ``out_dtype``. The dgate·dup chain
+    runs in-kernel on the g/u/dmid tiles. u=None: ``g`` is the fused [M, 2I]
+    product, read in place (`_halves`), and dWg is the ONE fused [G,D,2I]
+    cotangent (gate columns first), dWu None."""
     from automodel_tpu.ops.grouped_matmul import _out_sds
 
     M, D = lhs.shape
     fused = u is None
     g, u, I = _halves(g, u)
     G = group_sizes.shape[0]
-    tm, tk, tn = _bwd_gu_tiles(D, I, lhs.dtype)
+    tm, tk, tn = _bwd_gu_tiles(D, I, lhs.dtype, out_dtype)
     Mp, Kp, Np = _round_up(M, tm), _round_up(D, tk), _round_up(I, tn)
+    assert not fused or (Kp, Np) == (D, I), (D, I, tk, tn)  # `in_place_ok`
     off = _col_off(fused, I, tn)
     if (Mp, Kp) != (M, D):
         lhs = jnp.pad(lhs, ((0, Mp - M), (0, Kp - D)))
@@ -569,90 +654,111 @@ def _bwd_gu(lhs, g, u, dmid, group_sizes, act_kind, limit, interpret,
         g = jnp.pad(g, pad)
         u = g if fused else jnp.pad(u, pad)
         dmid = jnp.pad(dmid, pad)
-    wg, wt, ws, we = _plan(group_sizes, Mp, tm, G)
+    wg, wt, ws, we = _plan(group_sizes, Mp, tm, G, empty_units=True)
     W = Mp // tm + G
-    grid = (Kp // tk, Np // tn, W)
+    n_n = Np // tn
+    grid = (Kp // tk, W, n_n)
     in_specs = [
-        pl.BlockSpec((tm, tk), lambda k, n, w, wg, wt, ws, we: (wt[w], k)),
-        pl.BlockSpec((tm, tn), lambda k, n, w, wg, wt, ws, we: (wt[w], n)),
-        pl.BlockSpec((tm, tn), lambda k, n, w, wg, wt, ws, we: (wt[w], n + off)),
-        pl.BlockSpec((tm, tn), lambda k, n, w, wg, wt, ws, we: (wt[w], n)),
+        pl.BlockSpec((tm, tk), lambda k, w, n, wg, wt, ws, we: (wt[w], k)),
+        pl.BlockSpec((tm, tn), lambda k, w, n, wg, wt, ws, we: (wt[w], n)),
+        pl.BlockSpec((tm, tn), lambda k, w, n, wg, wt, ws, we: (wt[w], n + off)),
+        pl.BlockSpec((tm, tn), lambda k, w, n, wg, wt, ws, we: (wt[w], n)),
     ]
-    slab = pl.BlockSpec((1, tk, tn), lambda k, n, w, wg, wt, ws, we: (wg[w], k, n))
-    out_specs = [slab, slab]
+    width = 2 * Np if fused else Np
+    slab = pl.BlockSpec(
+        (1, tk, width), lambda k, w, n, wg, wt, ws, we: (wg[w], k, 0)
+    )
+    out_specs = [slab] if fused else [slab, slab]
     out_shapes = [
-        _out_sds((G, Kp, Np), jnp.float32, lhs, g, u, dmid),
-        _out_sds((G, Kp, Np), jnp.float32, lhs, g, u, dmid),
+        _out_sds((G, Kp, width), out_dtype, lhs, g, u, dmid)
+        for _ in out_specs
     ]
+    scratch = [pltpu.VMEM((2 * n_n, tk, tn), jnp.float32)]
     if has_bias:
-        brow = pl.BlockSpec((1, 1, tn), lambda k, n, w, wg, wt, ws, we: (wg[w], 0, n))
+        brow = pl.BlockSpec(
+            (1, 1, Np), lambda k, w, n, wg, wt, ws, we: (wg[w], 0, 0)
+        )
         out_specs += [brow, brow]
         out_shapes += [
             _out_sds((G, 1, Np), jnp.float32, g, dmid),
             _out_sds((G, 1, Np), jnp.float32, u, dmid),
         ]
+        scratch.append(pltpu.VMEM((2 * n_n, 1, tn), jnp.float32))
     outs = pl.pallas_call(
         functools.partial(
-            _bwd_gu_kernel, tm=tm, act_kind=act_kind, limit=limit,
-            has_bias=has_bias,
+            _bwd_gu_kernel, tm=tm, tn=tn, n_n=n_n, W=W, act_kind=act_kind,
+            limit=limit, has_bias=has_bias, fused=fused,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=grid,
             in_specs=in_specs,
             out_specs=out_specs,
+            scratch_shapes=scratch,
         ),
         out_shape=out_shapes,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
         name="fused_expert_mlp_bwd_gu",
     )(wg, wt, ws, we, lhs, g, u, dmid)
-    nz = (group_sizes > 0)
-    dwg = jnp.where(nz[:, None, None], outs[0][:, :D, :I], 0.0)
-    dwu = jnp.where(nz[:, None, None], outs[1][:, :D, :I], 0.0)
+    if fused:
+        dwg, dwu, bias = outs[0], None, outs[1:]
+    else:
+        dwg, dwu, bias = outs[0][:, :D, :I], outs[1][:, :D, :I], outs[2:]
     if not has_bias:
         return dwg, dwu, None, None
-    dgb = jnp.where(nz[:, None], outs[2][:, 0, :I], 0.0)
-    dub = jnp.where(nz[:, None], outs[3][:, 0, :I], 0.0)
-    return dwg, dwu, dgb, dub
+    return dwg, dwu, bias[0][:, 0, :I], bias[1][:, 0, :I]
 
 
 def _bwd_dwd_kernel(wg, wt, ws, we, g_ref, u_ref, dy_ref, dwd_ref, *rest,
-                    tm, act_kind, limit, want_db):
+                    tm, W, act_kind, limit, want_db):
     if want_db:
-        (ddb_ref,) = rest
+        ddb_ref, acc = rest
+    else:
+        (acc,) = rest
     w = pl.program_id(2)
-    rows = wt[w] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
-    mask = (rows >= ws[w]) & (rows < we[w])
-    mid = _act_core(
-        g_ref[...].astype(jnp.float32), u_ref[...].astype(jnp.float32),
-        act_kind, limit,
-    )
-    mid = jnp.where(mask, mid, 0.0)
-    # dy's sentinel tail is masked here, in-kernel — the external dy_m
-    # select the composed backward paid per [M, D] is gone
-    dy = jnp.where(mask, dy_ref[...], jnp.zeros_like(dy_ref))
-    first = jnp.logical_or(w == 0, wg[jnp.maximum(w - 1, 0)] != wg[w])
-    acc = jax.lax.dot_general(
-        mid.astype(dy_ref.dtype), dy, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    cur = dwd_ref[0]
-    dwd_ref[0] = acc + jnp.where(first, jnp.zeros_like(cur), cur)
-    if want_db:
-        # same rewrite-per-k-pass rule as the gu kernel's bias rows
-        cur = ddb_ref[0]
-        ddb_ref[0] = dy.astype(jnp.float32).sum(axis=0, keepdims=True) + jnp.where(
-            first, jnp.zeros_like(cur), cur
+    first, last = _group_edges(wg, w, W)
+
+    @pl.when(first)
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+        if want_db:
+            ddb_ref[...] = jnp.zeros_like(ddb_ref)
+
+    @pl.when(we[w] > ws[w])
+    def _():
+        rows = wt[w] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+        mask = (rows >= ws[w]) & (rows < we[w])
+        mid = _act_core(
+            g_ref[...].astype(jnp.float32), u_ref[...].astype(jnp.float32),
+            act_kind, limit,
         )
+        mid = jnp.where(mask, mid, 0.0)
+        # dy's sentinel tail is masked here, in-kernel — the external dy_m
+        # select the composed backward paid per [M, D] is gone
+        dy = jnp.where(mask, dy_ref[...], jnp.zeros_like(dy_ref))
+        acc[...] += jax.lax.dot_general(
+            mid.astype(dy_ref.dtype), dy, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if want_db:
+            # the [1, tn] row stays resident over a group's units (its block
+            # ignores k: every k pass rewrites the identical totals)
+            ddb_ref[0] += dy.astype(jnp.float32).sum(axis=0, keepdims=True)
+
+    @pl.when(last)
+    def _():
+        dwd_ref[0] = acc[...].astype(dwd_ref.dtype)
 
 
-def _bwd_dwd(g, u, dy, group_sizes, act_kind, limit, interpret, want_db):
+def _bwd_dwd(g, u, dy, group_sizes, act_kind, limit, interpret, want_db,
+             out_dtype):
     """Down-proj transpose GEMM with the activation mid recomputed in-kernel
-    → (dWd [G,I,D] f32, ddb [G,D] f32 | None). u=None: ``g`` is the fused
-    [M, 2I] product, read in place (`_halves`)."""
+    → (dWd [G,I,D] in ``out_dtype``, ddb [G,D] f32 | None). u=None: ``g`` is
+    the fused [M, 2I] product, read in place (`_halves`)."""
     from automodel_tpu.ops.grouped_matmul import _out_sds
 
     M = g.shape[0]
@@ -660,7 +766,7 @@ def _bwd_dwd(g, u, dy, group_sizes, act_kind, limit, interpret, want_db):
     g, u, I = _halves(g, u)
     _, D = dy.shape
     G = group_sizes.shape[0]
-    tm, tk, tn = _bwd_dwd_tiles(I, D, g.dtype)
+    tm, tk, tn = _bwd_dwd_tiles(I, D, g.dtype, out_dtype)
     Mp, Kp, Np = _round_up(M, tm), _round_up(I, tk), _round_up(D, tn)
     off = _col_off(fused, I, tk)
     if (Mp, Kp) != (M, I):
@@ -669,7 +775,7 @@ def _bwd_dwd(g, u, dy, group_sizes, act_kind, limit, interpret, want_db):
         u = g if fused else jnp.pad(u, pad)
     if (Mp, Np) != (M, D):
         dy = jnp.pad(dy, ((0, Mp - M), (0, Np - D)))
-    wg, wt, ws, we = _plan(group_sizes, Mp, tm, G)
+    wg, wt, ws, we = _plan(group_sizes, Mp, tm, G, empty_units=True)
     W = Mp // tm + G
     grid = (Kp // tk, Np // tn, W)
     in_specs = [
@@ -680,7 +786,7 @@ def _bwd_dwd(g, u, dy, group_sizes, act_kind, limit, interpret, want_db):
     out_specs = [
         pl.BlockSpec((1, tk, tn), lambda k, n, w, wg, wt, ws, we: (wg[w], k, n)),
     ]
-    out_shapes = [_out_sds((G, Kp, Np), jnp.float32, g, u, dy)]
+    out_shapes = [_out_sds((G, Kp, Np), out_dtype, g, u, dy)]
     if want_db:
         out_specs.append(
             pl.BlockSpec((1, 1, tn), lambda k, n, w, wg, wt, ws, we: (wg[w], 0, n))
@@ -688,7 +794,7 @@ def _bwd_dwd(g, u, dy, group_sizes, act_kind, limit, interpret, want_db):
         out_shapes.append(_out_sds((G, 1, Np), jnp.float32, dy))
     outs = pl.pallas_call(
         functools.partial(
-            _bwd_dwd_kernel, tm=tm, act_kind=act_kind, limit=limit,
+            _bwd_dwd_kernel, tm=tm, W=W, act_kind=act_kind, limit=limit,
             want_db=want_db,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -696,18 +802,17 @@ def _bwd_dwd(g, u, dy, group_sizes, act_kind, limit, interpret, want_db):
             grid=grid,
             in_specs=in_specs,
             out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
         ),
         out_shape=out_shapes,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
         name="fused_expert_mlp_bwd_dwd",
     )(wg, wt, ws, we, g, u, dy)
-    nz = (group_sizes > 0)
-    dwd = jnp.where(nz[:, None, None], outs[0][:, :I, :D], 0.0)
-    ddb = jnp.where(nz[:, None], outs[1][:, 0, :D], 0.0) if want_db else None
-    return dwd, ddb
+    return outs[0][:, :I, :D], outs[1][:, 0, :D] if want_db else None
 
 
 def _bwd_dx_kernel(wg, wt, ws, we, g_ref, u_ref, dmid_ref, gate_ref, up_ref,
@@ -799,6 +904,7 @@ def _bwd_dx(g, u, dmid, gate, up, group_sizes, interpret, act_kind, limit):
         out_shape=_out_sds((Mp, Np), g.dtype, g, u, dmid, gate, up),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
         name="fused_expert_mlp_bwd_dx",
@@ -891,30 +997,25 @@ def _vjp_bwd(act_kind, limit, platform, interpret, res, dy):
             u = u + bias_rows(ub)
 
     dmid = ragged_dot(dy, down, group_sizes, transpose_rhs=True, **kw)
+    # the weight gradients leave their kernels in the weight's dtype and
+    # layout (up=None: ONE [G, D, 2I] array, dWu None)
     dWd, ddb = _bwd_dwd(
-        g, u, dy, group_sizes, act_kind, limit, interpret, db is not None
+        g, u, dy, group_sizes, act_kind, limit, interpret, db is not None,
+        down.dtype,
     )
     dWg, dWu, dgb, dub = _bwd_gu(
         lhs, g, u, dmid, group_sizes, act_kind, limit, interpret,
-        gb is not None or ub is not None,
+        gb is not None or ub is not None, gate.dtype,
     )
     # dlhs tail rows stay zero/uninitialized — they ARE the sentinel tail,
     # and the a2a consumer never reads them (ragged_dot precondition)
     dlhs = _bwd_dx(g, u, dmid, gate, up, group_sizes, interpret, act_kind,
                    limit)
-    dWg = dWg.astype(gate.dtype)
-    if up is None:
-        # one [G, D, 2I] cotangent in the default layout; the concatenate is
-        # what the weight split's AD transpose cost before (writing the two
-        # halves in place from `_bwd_gu` is the follow-up)
-        dWg, dWu = jnp.concatenate([dWg, dWu.astype(gate.dtype)], axis=-1), None
-    else:
-        dWu = dWu.astype(up.dtype)
     return (
         mv(dlhs.astype(lhs.dtype), lhs),
         mv(dWg, gate),
         mv(dWu, up),
-        mv(dWd.astype(down.dtype), down),
+        mv(dWd, down),
         None,
         mv(dgb.astype(gb.dtype), gb) if gb is not None else None,
         mv(dub.astype(ub.dtype), ub) if ub is not None else None,

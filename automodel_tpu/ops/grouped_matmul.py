@@ -21,8 +21,11 @@ group boundary — dropless, no capacity factor, no padding per expert.
 
 The backward needs two more kernels: dlhs is just gmm against `rhs`
 transposed, and drhs is a transposed grouped matmul (`_tgmm`) accumulating
-`lhs_g^T @ dout_g` per group over that group's row tiles (same work-unit
-plan, output tile = the group's [K, N] slab, fp32 accumulation in place).
+`lhs_g^T @ dout_g` per group over that group's row tiles in an fp32 VMEM
+scratch, written once, in the weight's dtype, at the group's last unit. Its
+plan gives an EMPTY group one unit too (`_plan(..., empty_units=True)`), so
+the kernel writes that group's zero slab itself and no pass over
+``[G, K, N]`` follows the call.
 """
 
 from __future__ import annotations
@@ -61,16 +64,24 @@ def _out_sds(shape, dtype, *operands):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _plan(group_sizes: jnp.ndarray, m_padded: int, tm: int, num_groups: int):
+def _plan(group_sizes: jnp.ndarray, m_padded: int, tm: int, num_groups: int,
+          empty_units: bool = False):
     """Work-unit schedule: for each of W = m_padded/tm + G grid steps, the
     (group, m-tile, row-window) it computes. All jnp — `group_sizes` is a
-    traced value; the plan rides to the kernel as scalar prefetch."""
+    traced value; the plan rides to the kernel as scalar prefetch.
+
+    ``empty_units`` is the weight-gradient kernels' plan (`_tgmm`, the fused
+    backward's `_bwd_gu` / `_bwd_dwd`): an empty group holds ONE unit with an
+    empty row window, so the kernel that owns the group's output slab visits
+    it and writes zeros there (W budgets a unit a group). Every kernel that
+    produces ROWS leaves it off: an empty group then holds no unit, which is
+    what the forward's dead-unit skip and `work_units` count on."""
     gs = group_sizes.astype(jnp.int32)
     ends = jnp.cumsum(gs)
     starts = ends - gs
     first = starts // tm
     last = jnp.maximum(ends - 1, starts) // tm
-    ntiles = jnp.where(gs > 0, last - first + 1, 0)
+    ntiles = jnp.where(gs > 0, last - first + 1, 1 if empty_units else 0)
     wstart = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(ntiles)[:-1]])
     total = wstart[-1] + ntiles[-1]
 
@@ -82,37 +93,56 @@ def _plan(group_sizes: jnp.ndarray, m_padded: int, tm: int, num_groups: int):
     # groups) resolve to the run's last member, which is the non-empty one
     g = (jnp.searchsorted(wstart, j, side="right") - 1).astype(jnp.int32)
     tile = first[g] + (j - wstart[g])
+    if empty_units:
+        # an empty group past the last row starts AT m_padded: its (empty)
+        # window must still name a row tile the operands have
+        tile = jnp.minimum(tile, m_padded // tm - 1)
     # row window; invalid (clamped) units get an empty window → masked no-op
     row_s = jnp.where(valid, starts[g], 0)
     row_e = jnp.where(valid, ends[g], 0)
     return g, tile.astype(jnp.int32), row_s, row_e
 
 
-def _pick_tiles(k: int, n: int, itemsize: int) -> tuple[int, int]:
-    """(tm, tn) fitting lhs/rhs/out double-buffered blocks in ~12MB VMEM."""
+def _chunks(n: int, cap: int | None = None) -> list[int]:
+    """The 128-multiples (≤ ``cap``) that divide the 128-padded ``n``,
+    widest first. 128 always does."""
+    n128 = _round_up(n, 128)
+    return [
+        n128 // j for j in range(1, n128 // 128 + 1)
+        if n128 % j == 0 and (n128 // j) % 128 == 0
+        and (cap is None or n128 // j <= cap)
+    ]
+
+
+def _gmm_tiles(K: int, N: int, dtype) -> tuple[int, int]:
+    """(tm, tn) for _gmm: 256 rows and the widest column block whose
+    double-buffered lhs / rhs / out blocks (all of K a block) fit ~12 MB of
+    VMEM, 128 rows if none does. ``tn`` divides the 128-padded N: a column
+    block that does not pads the weight up to a multiple of itself, a copy
+    of ``[G, K, N]`` on every call and its padding as real matmul work.
+
+    Timed on one v5e at the fused backward's two products (65,536 rows in
+    128 groups of 455–570, bf16; PERF.md, PR 40), ms a call as (tm, tn):
+    ``lhs @ Wgu`` (K 2048, N 1536) (512, 512) 4.41, (512, 768) 4.36,
+    (256, 512) 3.78, **(256, 768) 3.66**, (128, 768) 3.69; ``dy @ Wd^T``
+    (K 2048, N 768) (512, 512 on a weight padded to 1024) 4.74, (512, 384)
+    2.27, (512, 768) 2.22, (256, 384) 1.99, **(256, 768) 1.86**,
+    (128, 768) 1.90. A group there holds about 512 rows: a 512-row tile
+    multiplies as many masked rows as real ones."""
+    Kp, it = _round_up(K, 128), jnp.dtype(dtype).itemsize
     budget = 12 * 1024 * 1024
-    for tm in (512, 256, 128):
-        for tn in (512, 256, 128):
-            need = 2 * itemsize * (tm * k + k * tn + tm * tn)
-            if need <= budget:
+    for tm in (256, 128):
+        for tn in _chunks(N):
+            if 2 * it * (tm * Kp + Kp * tn + tm * tn) <= budget:
                 return tm, tn
     return 128, 128
 
 
-def _gmm_tiles(K: int, N: int, dtype) -> tuple[int, int]:
-    """(tm, tn) for _gmm: the static ladder above over the padded dims."""
-    return _pick_tiles(
-        _round_up(K, 128), _round_up(N, 128), jnp.dtype(dtype).itemsize
-    )
-
-
 def _tgmm_tiles(K: int, N: int, dtype) -> tuple[int, int, int]:
-    """(tm, tk, tn) for _tgmm: the contraction runs over the tm rows, so a
-    bigger tm means more MXU passes per [tk, tn] slab write-back. The
-    conservative 512 ladder: its blocks (two inputs double-buffered, one
-    fp32 slab) stay under 6 MB."""
-    tm, tn = _gmm_tiles(K, N, dtype)
-    return tm, min(_round_up(K, 128), 512), tn
+    """(tm, tk, tn) for _tgmm: 256 rows like `_gmm_tiles`, and the widest
+    divisors of the 128-padded K and N up to 512: its blocks (two inputs and
+    the narrow out slab double-buffered, one fp32 scratch) stay under 4 MB."""
+    return 256, _chunks(K, 512)[0], _chunks(N, 512)[0]
 
 
 def _gmm_kernel(wg, wt, ws, we, lhs_ref, rhs_ref, out_ref, *, tm, tn,
@@ -191,26 +221,48 @@ def _gmm(lhs: jnp.ndarray, rhs: jnp.ndarray, group_sizes: jnp.ndarray,
     return out[:M, :N]
 
 
-def _tgmm_kernel(wg, wt, ws, we, lhs_ref, dout_ref, out_ref, *, tm):
+def _group_edges(wg, w, W: int):
+    """(first, last): whether unit ``w`` is the first / the last of its
+    group's run in a plan with ``empty_units`` (every group holds a unit and
+    the units past the plan's total stay on the last group). A kernel whose
+    output block is the GROUP's slab zeroes its accumulator at ``first`` and
+    writes the slab, once, at ``last``."""
+    g = wg[w]
+    first = jnp.logical_or(w == 0, wg[jnp.maximum(w - 1, 0)] != g)
+    last = jnp.logical_or(w == W - 1, wg[jnp.minimum(w + 1, W - 1)] != g)
+    return first, last
+
+
+def _tgmm_kernel(wg, wt, ws, we, lhs_ref, dout_ref, out_ref, acc, *, tm, W):
     w = pl.program_id(2)
-    rows = wt[w] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
-    mask = (rows >= ws[w]) & (rows < we[w])
-    lhs_tile = lhs_ref[...]
-    lhs = jnp.where(mask, lhs_tile, jnp.zeros_like(lhs_tile))
-    acc = jax.lax.dot_general(
-        lhs,
-        dout_ref[...],
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    first = jnp.logical_or(w == 0, wg[jnp.maximum(w - 1, 0)] != wg[w])
-    cur = out_ref[0]
-    out_ref[0] = acc + jnp.where(first, jnp.zeros_like(cur), cur)
+    first, last = _group_edges(wg, w, W)
+
+    @pl.when(first)
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+
+    @pl.when(we[w] > ws[w])
+    def _():
+        rows = wt[w] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+        mask = (rows >= ws[w]) & (rows < we[w])
+        lhs_tile = lhs_ref[...]
+        lhs = jnp.where(mask, lhs_tile, jnp.zeros_like(lhs_tile))
+        acc[...] += jax.lax.dot_general(
+            lhs,
+            dout_ref[...],
+            (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    @pl.when(last)
+    def _():
+        out_ref[0] = acc[...].astype(out_ref.dtype)
 
 
 def _tgmm(lhs: jnp.ndarray, dout: jnp.ndarray, group_sizes: jnp.ndarray,
-          interpret: bool = False) -> jnp.ndarray:
-    """Per-group lhs_g^T @ dout_g: [M, K] × [M, N] → [G, K, N] fp32."""
+          interpret: bool = False, out_dtype=jnp.float32) -> jnp.ndarray:
+    """Per-group lhs_g^T @ dout_g: [M, K] × [M, N] → [G, K, N], summed in
+    fp32 and rounded once to ``out_dtype``; an empty group's slab is zeros."""
     M, K = lhs.shape
     _, N = dout.shape
     G = group_sizes.shape[0]
@@ -221,12 +273,12 @@ def _tgmm(lhs: jnp.ndarray, dout: jnp.ndarray, group_sizes: jnp.ndarray,
     if (Mp, Np) != (M, N):
         dout = jnp.pad(dout, ((0, Mp - M), (0, Np - N)))
 
-    wg, wt, ws, we = _plan(group_sizes, Mp, tm, G)
+    wg, wt, ws, we = _plan(group_sizes, Mp, tm, G, empty_units=True)
     W = Mp // tm + G
     grid = (Kp // tk, Np // tn, W)
 
     out = pl.pallas_call(
-        functools.partial(_tgmm_kernel, tm=tm),
+        functools.partial(_tgmm_kernel, tm=tm, W=W),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=grid,
@@ -237,17 +289,16 @@ def _tgmm(lhs: jnp.ndarray, dout: jnp.ndarray, group_sizes: jnp.ndarray,
             out_specs=pl.BlockSpec(
                 (1, tk, tn), lambda k, n, w, wg, wt, ws, we: (wg[w], k, n)
             ),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
         ),
-        out_shape=_out_sds((G, Kp, Np), jnp.float32, lhs, dout),
+        out_shape=_out_sds((G, Kp, Np), out_dtype, lhs, dout),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
         name="tgmm",
     )(wg, wt, ws, we, lhs, dout)
-    # empty groups are never visited → force their slabs to zero
-    out = jnp.where((group_sizes > 0)[:, None, None], out[:, :K, :N], 0.0)
-    return out
+    return out[:, :K, :N]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -284,14 +335,12 @@ def _grouped_matmul_bwd(interpret, transpose_rhs, res, dout):
     # straight off the stored layout (no rhs.swapaxes materialization)
     dlhs = _gmm(dout, rhs, group_sizes, interpret=interpret,
                 transpose_rhs=not transpose_rhs)
-    if transpose_rhs:
-        # y = lhs @ rhs^T → drhs[g, n, k] = Σ_m dout[m, n] · lhs[m, k]
-        drhs = _tgmm(dout, lhs, group_sizes, interpret=interpret)
-    else:
-        drhs = _tgmm(lhs, dout, group_sizes, interpret=interpret)
+    # y = lhs @ rhs^T → drhs[g, n, k] = Σ_m dout[m, n] · lhs[m, k]
+    a, b = (dout, lhs) if transpose_rhs else (lhs, dout)
+    drhs = _tgmm(a, b, group_sizes, interpret=interpret, out_dtype=rhs.dtype)
     return (
         _match_vma(dlhs.astype(lhs.dtype), lhs),
-        _match_vma(drhs.astype(rhs.dtype), rhs),
+        _match_vma(drhs, rhs),
         None,
     )
 

@@ -327,3 +327,127 @@ def test_fused_weight_composed_backward_and_reference_paths_agree(monkeypatch):
         assert a.shape == b.shape == c.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
         np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=5e-4)
+
+
+# -- each result leaves its kernel once, in its final form (PR 40) ------------
+#
+# `_bwd_gu` / `_bwd_dwd` sum a group's units in an fp32 VMEM scratch and write
+# the slab at the group's LAST unit in the weight's dtype; `_bwd_gu`'s out
+# block spans both halves, so the fused weight's [G, D, 2I] cotangent is the
+# kernel's own output; an empty group holds one unit of the backward's plan,
+# which writes its zeros. Interpret mode fills an output nobody wrote with
+# NaN (tests/test_grouped_matmul.py checks that premise), so a slab the
+# kernels skipped would read NaN here.
+
+# a row tile is 256 rows: group 0 is summed over the units of more than three
+THREE_TILES = [1300, 0, 136, 100]
+
+
+def _dense_weight_grads(lhs, g, u, dmid, dy, sizes, act, limit):
+    """Per-group fp32 reference of the three weight gradients from the same
+    bf16-or-f32 operands: (dWg, dWu, dWd), each [G, ., .] fp32."""
+    from automodel_tpu.ops.fused_expert_mlp import _act_core, _act_grads
+
+    dg, du = _act_grads(g, u, dmid, act, limit)
+    mid = _act_core(g.astype(jnp.float32), u.astype(jnp.float32), act, limit)
+    cast = lambda a: np.asarray(a.astype(lhs.dtype), np.float32)
+    dg, du, mid = cast(dg), cast(du), cast(mid)
+    l32, dy32 = np.asarray(lhs, np.float32), np.asarray(dy, np.float32)
+    out = ([], [], [])
+    for e, size in zip(np.cumsum(sizes), sizes):
+        r = slice(e - size, e)
+        out[0].append(l32[r].T @ dg[r])
+        out[1].append(l32[r].T @ du[r])
+        out[2].append(mid[r].T @ dy32[r])
+    return tuple(np.stack(o) for o in out)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("fused", [False, True], ids=["two-array", "fused"])
+def test_weight_gradient_kernels_round_the_f32_sum_once(fused, dtype):
+    """bf16 and f32 weights: the kernel's narrow output IS its fp32 sum
+    rounded once (the old path's ``f32 slab → astype``), a group spanning
+    three row tiles is summed across its units, the fused form's one
+    [G, D, 2I] array is the two-array form's pair side by side, and the
+    NaN tail past the last group reaches nothing."""
+    from automodel_tpu.ops.fused_expert_mlp import _bwd_dwd, _bwd_gu
+
+    rng = np.random.default_rng(17)
+    sizes, tail, D, I = THREE_TILES, 64, 128, 256
+    G, M = len(sizes), sum(THREE_TILES) + 64
+    mk = lambda *s: jnp.asarray(rng.normal(size=s), dtype)
+    lhs, g, u, dmid, dy = mk(M, D), mk(M, I), mk(M, I), mk(M, I), mk(M, D)
+    lhs, dy = (a.at[M - tail:].set(jnp.nan) for a in (lhs, dy))
+    g, u, dmid = (a.at[M - tail:].set(jnp.nan) for a in (g, u, dmid))
+    gs = jnp.asarray(sizes, jnp.int32)
+    gu = (jnp.concatenate([g, u], axis=-1), None) if fused else (g, u)
+
+    def run(out_dtype):
+        dwg, dwu, _, _ = _bwd_gu(lhs, *gu, dmid, gs, "swiglu", 1.5, True,
+                                 False, out_dtype)
+        dwd, _ = _bwd_dwd(*gu, dy, gs, "swiglu", 1.5, True, False, out_dtype)
+        return dwg, dwu, dwd
+
+    narrow, wide = run(dtype), run(jnp.float32)
+    if fused:
+        assert narrow[1] is None and narrow[0].shape == (G, D, 2 * I)
+        narrow = (*jnp.split(narrow[0], 2, axis=-1), narrow[2])
+        wide = (*jnp.split(wide[0], 2, axis=-1), wide[2])
+    ref = _dense_weight_grads(lhs[:M - tail], g[:M - tail], u[:M - tail],
+                              dmid[:M - tail], dy[:M - tail], sizes,
+                              "swiglu", 1.5)
+    for n, a, w, r in zip(("dWg", "dWu", "dWd"), narrow, wide, ref):
+        assert a.dtype == dtype and w.dtype == jnp.float32, n
+        assert np.array_equal(np.asarray(a), np.asarray(w.astype(dtype))), n
+        w = np.asarray(w)
+        assert np.isfinite(w).all(), n
+        assert np.abs(w[1]).max() == 0.0 and np.abs(w[0]).max() > 0.0, n
+        np.testing.assert_allclose(w, r, atol=2e-3 * np.abs(r).max(), err_msg=n)
+
+
+EMPTY_GROUPS = {
+    "several-in-a-row": [50, 0, 0, 0, 78],
+    "first": [0, 0, 60, 68],
+    "last": [60, 68, 0, 0],
+    "all-but-one": [0, 0, 0, 128],
+    "every-group": [0, 0, 0],
+}
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["two-array", "fused"])
+@pytest.mark.parametrize("groups", list(EMPTY_GROUPS))
+def test_empty_groups_get_their_zeros_from_the_kernels(groups, fused):
+    """Every weight and bias gradient of an empty group is exactly zero and
+    nothing anywhere is NaN, with a NaN sentinel tail behind the real rows
+    (under expert parallelism most groups of a shard can be empty)."""
+    sizes = EMPTY_GROUPS[groups]
+    rng = np.random.default_rng(len(sizes))
+    M, D, I, G, n_real = 160, 128, 128, len(sizes), sum(sizes)
+    lhs, gate, up, down, gs, gb, ub, db, dy = _case(
+        rng, M, D, I, G, sizes, biased=True
+    )
+    lhs, dy = lhs.at[n_real:].set(jnp.nan), dy.at[n_real:].set(jnp.nan)
+    w = (jnp.concatenate([gate, up], axis=-1), None) if fused else (gate, up)
+
+    def f(wg_, wu_, d_, gb_, ub_, db_):
+        return fused_expert_mlp(lhs, wg_, wu_, d_, gs, gb_, ub_, db_,
+                                "swiglu_oai", None, None, True)
+
+    grads = jax.vjp(f, *w, down, gb, ub, db)[1](dy)
+    if fused:
+        assert grads[1] is None and grads[0].shape == (G, D, 2 * I)
+        grads = (*jnp.split(grads[0], 2, axis=-1), *grads[2:])
+
+    clean = lambda a: a.at[n_real:].set(0.0)
+    ref = jax.vjp(
+        lambda g_, u_, d_, gb_, ub_, db_: _reference(
+            clean(lhs), g_, u_, d_, gs, gb_, ub_, db_, "swiglu_oai", None, None
+        ),
+        gate, up, down, gb, ub, db,
+    )[1](clean(dy))
+    for n, a, b in zip(GRAD_NAMES[1:], grads, ref):
+        a = np.asarray(a)
+        assert np.isfinite(a).all(), n
+        for grp, size in enumerate(sizes):
+            assert (np.abs(a[grp]).max() > 0) == (size > 0), (n, grp, size)
+        np.testing.assert_allclose(a, np.asarray(b), atol=5e-4, err_msg=n)

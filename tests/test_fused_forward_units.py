@@ -233,3 +233,50 @@ def test_weight_blocks_change_only_in_live_units(M, D, I, G, routed, W):
     assert changes == max(live * n_ic - 1, 0)
     if live < W:
         assert (blocks[live * n_ic:] == blocks[max(live * n_ic - 1, 0)]).all()
+
+
+# -- the backward's plan must not leak into the forward (PR 40) ---------------
+#
+# The weight-gradient kernels give an empty group one unit so that they can
+# write its zero slab (`_plan(..., empty_units=True)`). The forward's
+# dead-unit skip, and `expert_grid_live_pct` through `work_units`, rest on
+# the opposite: an empty group holds NO unit.
+
+
+@pytest.mark.parametrize(
+    "sizes",
+    [[300, 0, 0, 212], [0, 0, 512, 0], [0, 512], [512, 0], [0, 0, 0], [100, 200, 212]],
+    ids=["run-inside", "all-but-one", "first", "last", "all", "none"],
+)
+def test_an_empty_group_holds_no_unit_in_the_forwards_plan(sizes):
+    tm = fem._fwd_tiles(128, 128)[0]
+    sizes = [s * tm // 256 for s in sizes]  # the ids' sizes are for tm 256
+    G, Mp = len(sizes), 2 * tm
+    gs = jnp.asarray(sizes, jnp.int32)
+    W = Mp // tm + G
+    wg, wt, ws, we = (np.asarray(a) for a in _plan(gs, Mp, tm, G))
+    live = we > ws
+    assert set(wg[live]) == {g for g, s in enumerate(sizes) if s > 0}
+    n_live, grid = fem.work_units(gs, Mp, 128, 128)
+    assert (int(n_live), int(grid)) == (int(live.sum()), W)
+    # every row is in exactly one live unit's window
+    covered = np.zeros(Mp, np.int32)
+    for w in np.flatnonzero(live):
+        lo, hi = max(ws[w], wt[w] * tm), min(we[w], (wt[w] + 1) * tm)
+        covered[lo:hi] += 1
+    assert (covered[: sum(sizes)] == 1).all() and (covered[sum(sizes):] == 0).all()
+
+    # the weight-gradient plan: the same live units, and one empty-window
+    # unit for each empty group, every group visited in order, tiles in range
+    bg, bt, bs, be = (np.asarray(a) for a in _plan(gs, Mp, tm, G, empty_units=True))
+    blive = be > bs
+    assert int(blive.sum()) == int(live.sum())
+    assert [tuple(r) for r in np.stack([bg, bt, bs, be], 1)[blive]] == [
+        tuple(r) for r in np.stack([wg, wt, ws, we], 1)[live]
+    ]
+    assert sorted(set(bg)) == list(range(G))
+    assert (np.diff(bg) >= 0).all() and (np.diff(bg) <= 1).all()
+    assert bt.min() >= 0 and bt.max() < Mp // tm
+    for g, s in enumerate(sizes):
+        if s == 0:
+            assert not blive[bg == g].any()

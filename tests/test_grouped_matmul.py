@@ -176,3 +176,131 @@ def test_fused_expert_mlp_nan_tail_weight_grads_finite():
                 f"{name} poisoned by NaN tail (biased={biased})"
             )
             assert float(jnp.abs(g).max()) > 0.0, f"{name} all-zero"
+
+
+# -- `_tgmm` writes each group's slab once, in its final form (PR 40) ---------
+#
+# The kernel sums a group's units in an fp32 VMEM scratch and writes the slab
+# at the group's last unit, rounded once to the weight's dtype; its plan
+# (`_plan(..., empty_units=True)`) gives an empty group one unit, which writes
+# that group's zeros. Interpret mode fills an output nobody wrote with NaN
+# (`test_interpret_mode_poisons_unwritten_outputs`), so a slab the kernel
+# skipped would read NaN here, as it would read garbage on the chip.
+
+
+def _old_tgmm(lhs, dout, group_sizes):
+    """`_tgmm` as it stood before PR 40, kept as the oracle: the fp32 slab
+    accumulated in place in the OUTPUT block, empty groups left unwritten
+    and zeroed by a select over [G, K, N] afterwards."""
+    import functools
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(wg, wt, ws, we, lhs_ref, dout_ref, out_ref, *, tm):
+        w = pl.program_id(2)
+        rows = wt[w] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+        mask = (rows >= ws[w]) & (rows < we[w])
+        lhs_tile = lhs_ref[...]
+        lhs_m = jnp.where(mask, lhs_tile, jnp.zeros_like(lhs_tile))
+        acc = jax.lax.dot_general(
+            lhs_m, dout_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        first = jnp.logical_or(w == 0, wg[jnp.maximum(w - 1, 0)] != wg[w])
+        cur = out_ref[0]
+        out_ref[0] = acc + jnp.where(first, jnp.zeros_like(cur), cur)
+
+    M, K = lhs.shape
+    N = dout.shape[1]
+    G = group_sizes.shape[0]
+    tm, tk, tn = gm._tgmm_tiles(K, N, lhs.dtype)
+    assert (M % tm, K % tk, N % tn) == (0, 0, 0)  # the oracle pads nothing
+    wg, wt, ws, we = gm._plan(group_sizes, M, tm, G)
+    out = pl.pallas_call(
+        functools.partial(kernel, tm=tm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(K // tk, N // tn, M // tm + G),
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda k, n, w, wg, wt, ws, we: (wt[w], k)),
+                pl.BlockSpec((tm, tn), lambda k, n, w, wg, wt, ws, we: (wt[w], n)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, tk, tn), lambda k, n, w, wg, wt, ws, we: (wg[w], k, n)
+            ),
+        ),
+        out_shape=jax.ShapeDtypeStruct((G, K, N), jnp.float32),
+        interpret=True,
+    )(wg, wt, ws, we, lhs, dout)
+    return jnp.where((group_sizes > 0)[:, None, None], out, 0.0)
+
+
+def test_interpret_mode_poisons_unwritten_outputs():
+    from jax._src.pallas.primitives import uninitialized_value
+
+    assert bool(jnp.isnan(uninitialized_value((2, 2), jnp.float32)).all())
+    assert bool(jnp.isnan(uninitialized_value((2, 2), jnp.bfloat16)).all())
+
+
+# a row tile is 256 rows (`_tgmm_tiles`): group 0 of "three-tiles" holds the
+# rows of more than three tiles and is summed over as many units
+TGMM_GROUPS = {
+    "three-tiles": [1300, 100, 0, 136],
+    "empty-run": [700, 0, 0, 0, 836],
+    "empty-first": [0, 0, 1000, 536],
+    "empty-last": [1000, 536, 0, 0],
+    "all-but-one-empty": [0, 0, 1536, 0],
+    "every-group-empty": [0, 0, 0],
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("groups", list(TGMM_GROUPS))
+def test_tgmm_writes_each_slab_once_in_the_weights_dtype(groups, dtype):
+    sizes = TGMM_GROUPS[groups]
+    rng = np.random.default_rng(len(sizes) + sum(sizes))
+    M, K, N = 1536, 128, 256
+    lhs = jnp.asarray(rng.normal(size=(M, K)), dtype)
+    dout = jnp.asarray(rng.normal(size=(M, N)), dtype)
+    gs = jnp.asarray(sizes, jnp.int32)
+
+    got = gm._tgmm(lhs, dout, gs, interpret=True, out_dtype=dtype)
+    assert got.dtype == dtype and got.shape == (len(sizes), K, N)
+    # the same fp32 sum as the old path's, rounded once
+    old = _old_tgmm(lhs, dout, gs)
+    assert np.array_equal(np.asarray(got), np.asarray(old.astype(dtype)))
+    wide = gm._tgmm(lhs, dout, gs, interpret=True)
+    assert wide.dtype == jnp.float32
+    assert np.array_equal(np.asarray(wide), np.asarray(old))
+    # an empty group's slab is zeros the kernel wrote; a live one's is not
+    got = np.asarray(got.astype(jnp.float32))
+    for g, size in enumerate(sizes):
+        assert (np.abs(got[g]).max() > 0) == (size > 0), (g, size)
+    # and the sums are the right ones
+    ends = np.cumsum(sizes)
+    l32, d32 = np.asarray(lhs, np.float32), np.asarray(dout, np.float32)
+    for g, (e, size) in enumerate(zip(ends, sizes)):
+        ref = l32[e - size:e].T @ d32[e - size:e]
+        np.testing.assert_allclose(
+            got[g], ref, atol=2e-2 * max(1.0, np.abs(ref).max()) if dtype == jnp.bfloat16 else 1e-3
+        )
+
+
+def test_grouped_matmul_weight_grad_comes_back_in_the_weights_dtype():
+    """bf16 weight, transposed or not: the cotangent is the kernel's own
+    output (no fp32 [G, K, N] value in the backward's program)."""
+    rng = np.random.default_rng(4)
+    lhs, rhs, gs = _random_case(rng, 256, 128, 128, 4, [100, 0, 56, 100])
+    lhs, rhs = lhs.astype(jnp.bfloat16), rhs.astype(jnp.bfloat16)
+    for transpose in (False, True):
+        f = lambda r: gm._grouped_matmul(lhs, r, gs, True, transpose).astype(jnp.float32).sum()
+        jaxpr = jax.make_jaxpr(jax.grad(f))(rhs)
+        grad = jax.grad(f)(rhs)
+        assert grad.dtype == jnp.bfloat16
+        assert float(jnp.abs(grad[1].astype(jnp.float32)).max()) == 0.0
+        f32_slabs = [
+            v.aval for e in jaxpr.jaxpr.eqns for v in e.outvars
+            if getattr(v.aval, "shape", None) == rhs.shape and v.aval.dtype == jnp.float32
+        ]
+        assert not f32_slabs, f32_slabs

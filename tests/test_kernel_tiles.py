@@ -16,8 +16,10 @@ from automodel_tpu.ops import fused_expert_mlp as fem
 from automodel_tpu.ops import grouped_matmul as gm
 
 BF16 = jnp.bfloat16
+F32 = jnp.float32
 IT = 2  # bytes an element
-BUDGET = 12 * 1024 * 1024
+GMM_BUDGET = 12 * 1024 * 1024  # under the 16 MiB scoped default
+V5E_VMEM = 128 * 1024 * 1024
 
 # (D, I) of sdar-30b-a3b.train-l1, minimax-m2.serve-l1, lfm2-8b-a1b.serve-l13
 SHAPES = [(2048, 768), (3072, 1536), (2048, 1792)]
@@ -27,15 +29,22 @@ def _aligned(*tiles):
     assert all(t > 0 and t % 128 == 0 for t in tiles), tiles
 
 
-def _gmm(D, I, want):
+def _gmm(D, I, want, products=None):
     # gate/up product [M, D] @ [G, D, 2I], down product [M, I] @ [G, I, D]:
     # the lhs row block and the rhs column block hold ALL of K
-    for (K, N), tiles in zip(((D, 2 * I), (I, D)), want):
+    for (K, N), tiles in zip(products or ((D, 2 * I), (I, D)), want):
         tm, tn = gm._gmm_tiles(K, N, BF16)
         assert (tm, tn) == tiles
         _aligned(tm, tn)
-        assert 2 * IT * (tm * K + K * tn + tm * tn) <= BUDGET
-        assert N % tn == 0  # no padded column block at these widths
+        assert 2 * IT * (tm * K + K * tn + tm * tn) <= GMM_BUDGET
+        assert N % tn == 0  # no padded column block: the weight is read in place
+
+
+def _gmm_transposed(D, I, want):
+    # the fused backward's ``dmid = dy @ Wd^T`` (`transpose_rhs`: K = D,
+    # N = I): at (2048, 768) a 512 column block padded ``down`` to
+    # [G, 1024, 2048] on every step and multiplied the padding
+    _gmm(D, I, [want], products=((D, I),))
 
 
 def _tgmm(D, I, want):
@@ -44,28 +53,43 @@ def _tgmm(D, I, want):
         tm, tk, tn = gm._tgmm_tiles(K, N, BF16)
         assert (tm, tk, tn) == tiles
         _aligned(tm, tk, tn)
-        # two input blocks double-buffered + the fp32 [tk, tn] slab
-        assert 2 * IT * (tm * tk + tm * tn) + 2 * 4 * tk * tn <= BUDGET
-        assert N % tn == 0 and tk <= 512  # K pads up to tk (I = 768, 1792)
+        # two input blocks and the out slab double-buffered + the fp32 scratch
+        # (an f32 weight's slab is the widest)
+        assert (2 * IT * (tm * tk + tm * tn) + 4 * tk * tn
+                + 2 * 4 * tk * tn) <= GMM_BUDGET
+        assert K % tk == 0 and N % tn == 0  # neither operand nor slab padded
+
+
+def _the_backward_asks_for_its_vmem():
+    # the pickers fill `_VMEM_BUDGET` with blocks; Mosaic is asked for
+    # `_VMEM_LIMIT`, the rest being the kernels' own stack; a v5e core has 128 MiB
+    assert fem._VMEM_BUDGET < fem._VMEM_LIMIT <= V5E_VMEM // 2
 
 
 def _bwd_gu(D, I, want):
-    tm, tk, tn = fem._bwd_gu_tiles(D, I, BF16)
+    _the_backward_asks_for_its_vmem()
+    tm, tk, tn = fem._bwd_gu_tiles(D, I, BF16, BF16)
     assert (tm, tk, tn) == want
-    _aligned(tm, tk, tn)
-    assert fem._bwd_gu_budget_ok(tm, tk, tn, IT)
-    assert D % tk == 0 and I % tn == 0  # `_col_off(fused, I, tn)`
+    for out_dtype in (BF16, F32):  # an f32 master weight widens the out block
+        tm, tk, tn = fem._bwd_gu_tiles(D, I, BF16, out_dtype)
+        _aligned(tm, tk, tn)
+        assert fem._bwd_gu_budget_ok(tm, tk, tn, I, IT, jnp.dtype(out_dtype).itemsize)
+        assert D % tk == 0 and I % tn == 0  # `_col_off(fused, I, tn)`
 
 
 def _bwd_dwd(D, I, want):
-    tm, tk, tn = fem._bwd_dwd_tiles(I, D, BF16)
+    _the_backward_asks_for_its_vmem()
+    tm, tk, tn = fem._bwd_dwd_tiles(I, D, BF16, BF16)
     assert (tm, tk, tn) == want
-    _aligned(tm, tk, tn)
-    assert fem._bwd_dwd_budget_ok(tm, tk, tn, IT)
-    assert I % tk == 0 and D % tn == 0  # `_col_off(fused, I, tk)`
+    for out_dtype in (BF16, F32):
+        tm, tk, tn = fem._bwd_dwd_tiles(I, D, BF16, out_dtype)
+        _aligned(tm, tk, tn)
+        assert fem._bwd_dwd_budget_ok(tm, tk, tn, IT, jnp.dtype(out_dtype).itemsize)
+        assert I % tk == 0 and D % tn == 0  # `_col_off(fused, I, tk)`
 
 
 def _bwd_dx(D, I, want):
+    _the_backward_asks_for_its_vmem()
     tm, tn, ic = fem._bwd_dx_tiles(D, I, BF16)
     assert (tm, tn, ic) == want
     _aligned(tm, tn, ic)
@@ -75,18 +99,19 @@ def _bwd_dx(D, I, want):
 
 WANT = {
     _gmm: [
-        ((512, 512), (512, 512)),
-        ((512, 256), (512, 512)),
-        ((512, 512), (512, 512)),
+        ((256, 768), (256, 2048)),
+        ((256, 512), (256, 1536)),
+        ((256, 896), (256, 1024)),
     ],
+    _gmm_transposed: [(256, 768), (256, 512), (256, 896)],
     _tgmm: [
-        ((512, 512, 512), (512, 512, 512)),
-        ((512, 512, 256), (512, 512, 512)),
-        ((512, 512, 512), (512, 512, 512)),
+        ((256, 512, 512), (256, 384, 512)),
+        ((256, 512, 512), (256, 512, 512)),
+        ((256, 512, 512), (256, 256, 512)),
     ],
-    _bwd_gu: [(512, 512, 384), (512, 512, 512), (512, 512, 256)],
-    _bwd_dwd: [(512, 384, 512), (512, 512, 512), (512, 256, 512)],
-    _bwd_dx: [(512, 512, 384), (512, 512, 512), (512, 512, 256)],
+    _bwd_gu: [(256, 2048, 768), (256, 1536, 768), (256, 1024, 1792)],
+    _bwd_dwd: [(256, 768, 2048), (256, 768, 3072), (256, 1792, 2048)],
+    _bwd_dx: [(256, 2048, 768), (256, 3072, 768), (256, 2048, 1792)],
 }
 
 

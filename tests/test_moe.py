@@ -533,8 +533,9 @@ def test_ragged_fused_makes_no_copy_of_the_expert_weight(
 
     halves, cuts = halves_and_cuts(loss)
     assert bool(halves or cuts) == copies, (halves, cuts)
-    # backward: the two halves of the weight's GRADIENT are [E, D, I] values
-    # (one concatenate joins them); the weight itself must still not be cut
+    # backward: the weight is not cut either, and its GRADIENT is the ONE
+    # [E, D, 2I] array `_bwd_gu` writes (PR 40): no [E, D, I] half, nothing
+    # to concatenate
     halves, cuts = halves_and_cuts(jax.grad(loss, argnums=(0, 1)))
     assert bool(cuts) == copies, cuts
-    assert halves
+    assert bool(halves) == copies, halves

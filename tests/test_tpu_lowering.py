@@ -159,9 +159,15 @@ _SMALL_MOE = 8, 2, 256, 256, (4, 128)
         # are mostly empty): the forward's `pl.when` on the plan's row window
         # and the `where` in its weight index maps, through Mosaic
         (1, {}, "ragged_fused", (256, 8, 1536, 3072, (64, 1)), False),
+        # train-30b-a3b's expert layer at its published widths (2 x 4096
+        # tokens x top-8 = 65,536 rows over 128 experts): the backward's wide
+        # tiles (a [2048, 1536] fp32 scratch beside its double-buffered out
+        # block) need the scoped VMEM the kernels ask for, and Mosaic alone
+        # says whether they got it
+        (1, {}, "ragged_fused", (128, 8, 768, 2048, (2, 4096)), True),
     ],
     ids=["1chip-ragged", "dp4-ragged", "ep4-a2a_fused", "1chip-ragged_fused",
-         "1chip-ragged_fused-minimax-decode"],
+         "1chip-ragged_fused-minimax-decode", "1chip-ragged_fused-30b-a3b-train"],
 )
 def test_expert_kernels_fwd_bwd_lower(n, degrees, backend, shape, grad):
     ctx = _tpu_ctx(n, **degrees)
