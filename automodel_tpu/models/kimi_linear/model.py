@@ -44,7 +44,7 @@ from automodel_tpu.models.qwen3_moe.model import MoEModelAux
 from automodel_tpu.moe.config import MoEConfig
 from automodel_tpu.moe.gate import update_gate_bias
 from automodel_tpu.moe.layer import init_moe_params, moe_block
-from automodel_tpu.ops.delta_rule import chunked_delta_rule, l2norm
+from automodel_tpu.ops.delta_rule import chunked_delta_rule
 from automodel_tpu.ops.norms import rms_norm
 from automodel_tpu.ops.rope import rope_table
 from automodel_tpu.ops.short_conv import causal_conv1d
@@ -200,27 +200,26 @@ def kda_block(cfg, backend, h, lp, norm_scale, segment_ids, constrain):
         q, k, v = proj("q_proj"), proj("k_proj"), proj("v_proj")
         with jax.named_scope("kda_conv"):
             conv = lambda a, name: jax.nn.silu(
-                causal_conv1d(a, lp[name]["weight"].astype(a.dtype), segment_ids)
-            ).reshape(B, S, H, dh)
+                causal_conv1d(a, lp[name]["weight"].astype(a.dtype), segment_ids))
             q, k, v = conv(q, "q_conv"), conv(k, "k_conv"), conv(v, "v_conv")
         with jax.named_scope("kda_gate"):
             f = proj("f_a_proj") @ lp["f_b_proj"]["kernel"].astype(x.dtype)
             f = f.astype(f32) + lp["dt_bias"].astype(f32)
-            g = -jnp.exp(lp["A_log"].astype(f32))[:, None] * jax.nn.softplus(
-                f.reshape(B, S, H, dh))
+            g = -jnp.repeat(jnp.exp(lp["A_log"].astype(f32)), dh) * jax.nn.softplus(f)
             beta = jax.nn.sigmoid(proj("b_proj").astype(f32))  # [B, S, H]
             gate = proj("g_a_proj") @ lp["g_b_proj"]["kernel"].astype(x.dtype)
         with jax.named_scope("kda_chunk"):
-            qn = (l2norm(q) * dh**-0.5).astype(x.dtype)
-            kn = l2norm(k).astype(x.dtype)
+            # q, k, v, g stay [B, S, H * dh] as the convs and the gate left
+            # them: the operator normalises q and k and forms beta k, beta v
+            # and the clamp a tile at a time (ops/delta_rule.py)
             o = chunked_delta_rule(
-                qn, kn, v, g, beta, segment_ids=segment_ids,
+                q, k, v, g, beta, segment_ids=segment_ids,
                 platform=backend.platform, mesh_ctx=backend.mesh_ctx,
             )
         with jax.named_scope("kda_norm"):
-            o = rms_norm(o, lp["o_norm"]["scale"], cfg.rms_eps).astype(f32)
-            o = o * jax.nn.sigmoid(gate.astype(f32).reshape(B, S, H, dh))
-            o = o.astype(x.dtype).reshape(B, S, H * dh)
+            o = rms_norm(o.reshape(B, S, H, dh), lp["o_norm"]["scale"], cfg.rms_eps)
+            o = o.reshape(B, S, H * dh).astype(f32) * jax.nn.sigmoid(gate.astype(f32))
+            o = o.astype(x.dtype)
         h = h + o @ lp["o_proj"]["kernel"].astype(x.dtype)
     return constrain(h, ("batch", "seq", None))
 

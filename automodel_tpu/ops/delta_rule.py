@@ -5,9 +5,28 @@ The recurrence, per head, with state ``S`` in R^{dk x dv}::
     S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
 
-``g`` is ``[B, S, H]`` (Qwen3-Next's gated DeltaNet: one decay a head) or
-``[B, S, H, dk]`` (Kimi's KDA: one a channel); a scalar decay is the
-per-channel case with every channel alike, so there is ONE algorithm.
+**The operator's boundary is where the projections (and convs) leave their
+results.** ``q``, ``k``, ``v`` come as ``[B, S, H * d]`` in the compute type,
+a head's channels contiguous, RAW; ``g`` as ``[B, S, H]`` (Qwen3-Next's gated
+DeltaNet: one decay a head) or ``[B, S, H * dk]`` (Kimi's KDA: one a
+channel), unclamped; ``beta`` as ``[B, S, H]``. A scalar decay is the
+per-channel case with every channel alike, so there is ONE algorithm; both
+families normalise ``q`` and ``k`` a head, so the operator always does. On a
+TPU ``[S, H * d] <-> [S, H, d]`` is no free view (an (8, 128) tile holds
+eight tokens of one head in the first and eight heads of one token in the
+second), so nothing here ever has the second shape.
+
+**Formed in the chunk body, from the ``[C, d]`` tile of a head it holds**
+(``_chunk_body``'s prologue; in VMEM inside the kernels), with these
+rounding points: ``qn = round((q / |q|) * dk^-0.5)`` and ``kn = round(k /
+|k|)`` from float32 (``rsqrt`` of the lane sum of squares plus 1e-6), then
+``kb = round(kn * beta)`` and ``vb = round(v * beta)`` from float32 again,
+``g = clip(g, G_MIN, 0)`` in float32 and ``g = 0`` on a document's first
+token; ``round`` is to the compute type (nothing for float32 operands). The
+backward differentiates the same prologue, so the gradients come back for
+the raw operands: ``dq``, ``dk``, ``dv`` in the compute type, ``dg`` float32
+(zero where the clamp holds and on a first token) and ``dbeta``; ``beta k``,
+``beta v``, the clamped ``g`` and their cotangents never reach HBM.
 
 **The chunked form.** With ``G`` the cumulative sum of ``g`` inside a chunk
 of ``C`` tokens and ``u_t = beta_t (v_t - (Diag(exp g_t) S_{t-1})^T k_t)``::
@@ -76,12 +95,17 @@ from jax.experimental.pallas import tpu as pltpu
 F32 = jnp.float32
 G_MIN = -10.0  # per token and channel; see the module docstring
 SUB = 16  # rows that share one reference point
-# Timed at 16,384 tokens x 32 heads of 128 x 128, bfloat16 (my chip run, PR 41;
-# forward / forward + backward ms a call): chunks of 64, 1 / 2 / 4 / 8 heads a
-# step: 18.3 / 61.0, 17.4 / 59.5, 16.9 / 58.6, 16.7 / 58.3; chunks of 128, 2 / 4
-# heads: 14.5 / 48.1, 14.3 / 47.8; chunks of 32: 26.2 / 84.7. Chunks a step (2,
-# 4, 8 at 64) read the same to 0.5 %. The same body under `lax.scan` (XLA,
-# chunks of 64): 10.8 / 49.5.
+# Timed at 16,384 tokens x 32 heads of 128 x 128, bfloat16, a per-channel decay,
+# from the raw flat operands to ``o`` and the five gradients (my chip run, PR 42;
+# forward / forward + backward ms a call; "h" operands on sublanes, see
+# ``_kernel_call``): chunks of 128, 2 / 4 / 8 heads a step: 9.50 / 34.70,
+# 9.11 / 34.16, 8.95 / 33.88; 4 chunks a step (4 heads): 9.08 / 34.11; chunks
+# of 64 (4 heads): 13.13 / 47.86. The same body under `lax.scan` (XLA, chunks
+# of 64): 14.36 / 56.35. PR 41's operator with its operands formed outside
+# (reshape to heads, two l2 norms, `beta k`, `beta v`, the clamp, flatten) from
+# the same arrays: 19.40 / 59.24 at chunks of 128, 23.41 / 73.18 at 64; the
+# kernels alone read 8.74 -> 8.95 (forward) and 23.62 -> 24.56 (backward) ms a
+# call in the cell's trace: the prologue costs 2.4 % and 4.0 % of them.
 _CHUNKS_PER_STEP = 2  # chunks a grid step walks
 _HEADS_PER_STEP = 4  # heads a grid step walks: independent chains for the scheduler to interleave
 _VMEM_LIMIT = 64 * 1024 * 1024  # a v5e core has 128 MiB; the default scoped limit is 16
@@ -180,13 +204,34 @@ _chunk_cumsum.defvjp(lambda g: (_tri_sum(g, reverse=False), None),
                      lambda _, d: (_tri_sum(d, reverse=True),))
 
 
-def _chunk_body(st0, q, k, kb, vb, g, segc=None, segr=None):
-    """One chunk of one head. ``st0`` [dv, dk] float32: the state BEFORE the
-    chunk, transposed (the decay then scales its columns); ``q, k`` [C, dk];
-    ``kb = beta k``, ``vb = beta v``; ``g`` [C, dk] float32: the clamped
-    log-decay (0 on a document's first token); ``segc`` [C, 1] / ``segr``
-    [1, C] int32: resets seen so far, or None for one document.
+def _chunk_body(st0, q, k, v, g, beta, segc=None, segr=None):
+    """One chunk of one head, from the operands as the projections left them.
+    ``st0`` [dv, dk] float32: the state BEFORE the chunk, transposed (the decay
+    then scales its columns); ``q, k`` [C, dk], ``v`` [C, dv]: raw, in the
+    compute type; ``g`` [C, dk] or [C, 1]: the log-decay, unclamped; ``beta``
+    [C, 1]; ``segc`` [C, 1] int32: 2 x the resets seen so far + 1 on a
+    document's first token, ``segr`` [1, C] int32: the resets seen so far; both
+    None for one document. What only feeds the rule is formed here, in float32,
+    and rounded to the compute type where the module docstring says.
     -> (o [C, dv] float32, the state after the chunk [dv, dk] float32)."""
+    cd, dk = q.dtype, q.shape[1]
+    beta = beta.astype(F32)
+    qn = (l2norm(q) * dk**-0.5).astype(cd)
+    kn = l2norm(k).astype(cd)
+    kb = (kn.astype(F32) * beta).astype(cd)
+    vb = (v.astype(F32) * beta).astype(cd)
+    g = jnp.clip(g.astype(F32), G_MIN, 0.0)
+    if segc is not None:
+        g = jnp.where((segc & 1) == 1, 0.0, g)
+        segc = segc >> 1
+    return _chunk_rule(st0, qn, kn, kb, vb, jnp.broadcast_to(g, q.shape), segc, segr)
+
+
+def _chunk_rule(st0, q, k, kb, vb, g, segc, segr):
+    """The chunked form of the module docstring on formed operands: ``q, k``
+    normalised, ``kb = beta k``, ``vb = beta v``, ``g`` [C, dk] float32 in
+    [G_MIN, 0] and 0 on a document's first token; ``segc`` [C, 1] / ``segr``
+    [1, C]: resets seen so far, or None."""
     c, dk = q.shape
     gc = _chunk_cumsum(g)  # inclusive, inside the chunk
     cd = q.dtype  # the matmuls' operand type; they accumulate in float32
@@ -232,22 +277,41 @@ def _chunk_body(st0, q, k, kb, vb, g, segc=None, segr=None):
     return o, st1
 
 
-def _chunk_grads(st0, q, k, kb, vb, g, segc, segr, do, dst1):
-    """The body recomputed and transposed: -> (dst0, dq, dk, dkb, dvb, dg)."""
+def _chunk_grads(st0, q, k, v, g, beta, segc, segr, do, dst1):
+    """The body recomputed and transposed: -> (dst0, dq, dk, dv, dg, dbeta),
+    each in its operand's shape and type."""
     body = lambda *a: _chunk_body(*a, segc, segr)
-    _, vjp = jax.vjp(body, st0, q, k, kb, vb, g)
+    _, vjp = jax.vjp(body, st0, q, k, v, g, beta)
     return vjp((do, dst1))
 
 
 # -- the Pallas kernels ------------------------------------------------------------
 
 
-def _fwd_kernel(*refs, c: int, n_sub: int, hb: int, dk: int, dv: int, has_seg: bool):
-    if has_seg:
-        q, k, kb, vb, g, segc, segr, o, states, st = refs
-    else:
-        q, k, kb, vb, g, o, states, st = refs
-        segc = segr = None
+def _diagonal(ref, i, c):
+    """Where lane ``l`` of a small operand's ``[hb, rows]`` block is row ``r`` of
+    chunk ``i``: ``[C, rows]`` bool."""
+    n = ref.shape[1]
+    r = lax.broadcasted_iota(jnp.int32, (c, n), 0)
+    l = lax.broadcasted_iota(jnp.int32, (c, n), 1)
+    return l == r + i * c
+
+
+def _head_column(ref, h, i, c):
+    """Head ``h``'s ``[C, 1]`` column of chunk ``i`` out of a small operand's
+    block (tokens on lanes): a select on the diagonal and a lane sum of one
+    term each, so exact."""
+    return jnp.sum(jnp.where(_diagonal(ref, i, c), ref[pl.ds(h, 1), :], 0.0), axis=1, keepdims=True)
+
+
+def _add_head_row(ref, h, i, c, col):
+    """The other way: a ``[C, 1]`` column into chunk ``i``'s lanes of head
+    ``h``'s row (the rest of the row gets + 0)."""
+    ref[pl.ds(h, 1), :] += jnp.sum(jnp.where(_diagonal(ref, i, c), col, 0.0), axis=0, keepdims=True)
+
+
+def _fwd_kernel(*refs, c: int, n_sub: int, hb: int, dk: int, dv: int, g_small: bool, has_seg: bool):
+    q, k, v, g, beta, *seg, o, states, st = refs
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -255,12 +319,13 @@ def _fwd_kernel(*refs, c: int, n_sub: int, hb: int, dk: int, dv: int, has_seg: b
 
     def one(i, _):
         rows = pl.ds(pl.multiple_of(i * c, c), c)
-        seg = (segc[rows, :], segr[i]) if has_seg else (None, None)
+        segs = (seg[0][rows, :], seg[1][i]) if has_seg else (None, None)
         for h in range(hb):  # independent chains: the scheduler interleaves them
             kc, vc = pl.ds(h * dk, dk), pl.ds(h * dv, dv)
             states[h, i] = st[h]
-            out, st1 = _chunk_body(st[h], q[rows, kc], k[rows, kc], kb[rows, kc], vb[rows, vc],
-                                   g[rows, kc], *seg)
+            out, st1 = _chunk_body(st[h], q[rows, kc], k[rows, kc], v[rows, vc],
+                                   _head_column(g, h, i, c) if g_small else g[rows, kc],
+                                   _head_column(beta, h, i, c), *segs)
             o[rows, vc] = out.astype(o.dtype)
             st[h] = st1
         return 0
@@ -268,57 +333,90 @@ def _fwd_kernel(*refs, c: int, n_sub: int, hb: int, dk: int, dv: int, has_seg: b
     lax.fori_loop(0, n_sub, one, 0)
 
 
-def _bwd_kernel(*refs, c: int, n_sub: int, hb: int, dk: int, dv: int, has_seg: bool):
-    if has_seg:
-        q, k, kb, vb, g, segc, segr, do, states, dq, dk_, dkb, dvb, dg, dst = refs
-    else:
-        q, k, kb, vb, g, do, states, dq, dk_, dkb, dvb, dg, dst = refs
-        segc = segr = None
+def _bwd_kernel(*refs, c: int, n_sub: int, hb: int, dk: int, dv: int, g_small: bool, has_seg: bool):
+    q, k, v, g, beta, *seg, do, states, dq, dk_, dv_, dg, dbeta, dst = refs
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         dst[...] = jnp.zeros_like(dst)
 
+    dbeta[...] = jnp.zeros_like(dbeta)  # a chunk adds its lanes to a head's row
+    if g_small:
+        dg[...] = jnp.zeros_like(dg)
+
     def one(j, _):
         i = n_sub - 1 - j
         rows = pl.ds(pl.multiple_of(i * c, c), c)
-        seg = (segc[rows, :], segr[i]) if has_seg else (None, None)
+        segs = (seg[0][rows, :], seg[1][i]) if has_seg else (None, None)
         for h in range(hb):
             kc, vc = pl.ds(h * dk, dk), pl.ds(h * dv, dv)
-            dst0, gq, gk, gkb, gvb, gg = _chunk_grads(
-                states[h, i], q[rows, kc], k[rows, kc], kb[rows, kc], vb[rows, vc], g[rows, kc],
-                *seg, do[rows, vc].astype(F32), dst[h])
-            dq[rows, kc] = gq.astype(dq.dtype)
-            dk_[rows, kc] = gk.astype(dk_.dtype)
-            dkb[rows, kc] = gkb.astype(dkb.dtype)
-            dvb[rows, vc] = gvb.astype(dvb.dtype)
-            dg[rows, kc] = gg
+            dst0, gq, gk, gv, gg, gb = _chunk_grads(
+                states[h, i], q[rows, kc], k[rows, kc], v[rows, vc],
+                _head_column(g, h, i, c) if g_small else g[rows, kc], _head_column(beta, h, i, c),
+                *segs, do[rows, vc].astype(F32), dst[h])
+            dq[rows, kc] = gq
+            dk_[rows, kc] = gk
+            dv_[rows, vc] = gv
+            if g_small:
+                _add_head_row(dg, h, i, c, gg)
+            else:
+                dg[rows, kc] = gg.astype(dg.dtype)
+            _add_head_row(dbeta, h, i, c, gb)
             dst[h] = dst0
         return 0
 
     lax.fori_loop(0, n_sub, one, 0)
 
 
-def _kernel_call(kernel, operands, outputs, *, heads, c, dk, dv, has_seg, reverse, interpret, name):
-    """Grid: batch x groups of ``_HEADS_PER_STEP`` heads x steps of
-    ``_CHUNKS_PER_STEP`` chunks (walked from the last when ``reverse``). An
-    operand or output is "k" (``[B, Sp, H * dk]``), "v" (``[B, Sp, H * dv]``),
-    "segc", "segr" or "states" (``[B, H, Sp / C, dv, dk]``)."""
+def _heads_per_step(heads: int) -> int:
+    return next(n for n in range(min(_HEADS_PER_STEP, heads), 0, -1) if heads % n == 0)
+
+
+def _by_group(x, hb):
+    """A value a head and token, ``[B, Sp, H]`` -> ``[B, H / hb, hb, Sp]``: the
+    layout the kernels read ``beta`` (and a scalar ``g``) in, see ``_kernel_call``."""
+    B, Sp, H = x.shape
+    return x.reshape(B, Sp, H // hb, hb).transpose(0, 2, 3, 1)
+
+
+def _from_groups(x):
+    B, n, hb, Sp = x.shape
+    return x.transpose(0, 3, 1, 2).reshape(B, Sp, n * hb)
+
+
+def _kernel_call(kernel, operands, outputs, *, heads, c, dk, dv, g_small, has_seg, reverse,
+                 interpret, name):
+    """Grid: batch x groups of ``hb`` heads x steps of ``_CHUNKS_PER_STEP``
+    chunks (walked from the last when ``reverse``). An operand or output is
+    "k" (``[B, Sp, H * dk]``), "v" (``[B, Sp, H * dv]``), "h" (a value a head
+    and token: ``beta``, a scalar ``g`` and their cotangents), "segc", "segr"
+    or "states" (``[B, H, Sp / C, dv, dk]``).
+
+    "h" travels as ``[B, H / hb, hb, Sp]``, tokens on lanes, and the body takes
+    a head's ``[C, 1]`` column out of its row by ``_head_column``. The other
+    candidate, ``[B, H / hb, Sp, hb]`` (tokens on sublanes: a head's column is
+    a plain lane slice of the block), pads ``hb`` = 4 lanes to 128 in HBM (64
+    MB an array at 16,384 x 32 against 4) and read slower (my chip run, PR 42;
+    the operator alone at 16,384 tokens x 32 heads of 128 x 128, bfloat16,
+    the 1 MB transposes outside included, forward / forward + backward ms a
+    call): sublanes 9.11 / 34.16, lanes **8.90 / 33.67**."""
     B, Sp, _ = operands[0][1].shape
     n_sub = _CHUNKS_PER_STEP
-    hb = next(n for n in range(min(_HEADS_PER_STEP, heads), 0, -1) if heads % n == 0)
+    hb = _heads_per_step(heads)
     n_steps = Sp // (n_sub * c)
     step = (lambda s: n_steps - 1 - s) if reverse else (lambda s: s)
     rows = n_sub * c
     specs = {
         "k": pl.BlockSpec((None, rows, hb * dk), lambda b, h, s: (b, step(s), h)),
         "v": pl.BlockSpec((None, rows, hb * dv), lambda b, h, s: (b, step(s), h)),
+        "h": pl.BlockSpec((None, None, hb, rows), lambda b, h, s: (b, h, 0, step(s))),
         "segc": pl.BlockSpec((None, rows, 1), lambda b, h, s: (b, step(s), 0)),
         "segr": pl.BlockSpec((None, n_sub, 1, c), lambda b, h, s: (b, step(s), 0, 0)),
         "states": pl.BlockSpec((None, hb, n_sub, dv, dk), lambda b, h, s: (b, h, step(s), 0, 0)),
     }
     return pl.pallas_call(
-        functools.partial(kernel, c=c, n_sub=n_sub, hb=hb, dk=dk, dv=dv, has_seg=has_seg),
+        functools.partial(kernel, c=c, n_sub=n_sub, hb=hb, dk=dk, dv=dv, g_small=g_small,
+                          has_seg=has_seg),
         grid=(B, heads // hb, n_steps),
         in_specs=[specs[kind] for kind, _ in operands],
         out_specs=[specs[kind] for kind, _ in outputs],
@@ -332,40 +430,45 @@ def _kernel_call(kernel, operands, outputs, *, heads, c, dk, dv, has_seg, revers
     )(*(a for _, a in operands))
 
 
-def _kernel_fwd(q, k, kb, vb, g, segc, segr, *, heads, c, interpret):
-    """Flat operands ``[B, Sp, H * d]`` (``g`` float32), ``Sp`` a whole number
-    of grid steps. -> (o [B, Sp, H * dv], boundary states [B, H, Sp / C, dv, dk])."""
+def _kernel_operands(q, k, v, g, beta, segc, segr, heads):
+    """-> (the kernels' operand list, their static arguments)."""
+    hb = _heads_per_step(heads)
+    dk, dv = q.shape[-1] // heads, v.shape[-1] // heads
+    g_small = g.shape[-1] == heads
+    seg = [("segc", segc), ("segr", segr)] if segc is not None else []
+    ops = [("k", q), ("k", k), ("v", v), ("h", _by_group(g, hb)) if g_small else ("k", g),
+           ("h", _by_group(beta, hb)), *seg]
+    return ops, dict(heads=heads, dk=dk, dv=dv, g_small=g_small, has_seg=bool(seg))
+
+
+def _kernel_fwd(q, k, v, g, beta, segc, segr, *, heads, c, interpret):
+    """Flat operands ``[B, Sp, H * d]``, ``beta`` (and a scalar ``g``)
+    ``[B, Sp, H]``, ``Sp`` a whole number of grid steps.
+    -> (o [B, Sp, H * dv], boundary states [B, H, Sp / C, dv, dk])."""
     B, Sp, _ = q.shape
-    dk, dv = q.shape[-1] // heads, vb.shape[-1] // heads
-    has_seg = segc is not None
-    seg = [("segc", segc), ("segr", segr)] if has_seg else []
+    ops, static = _kernel_operands(q, k, v, g, beta, segc, segr, heads)
     return _kernel_call(
-        _fwd_kernel,
-        [("k", q), ("k", k), ("k", kb), ("v", vb), ("k", g), *seg],
-        [("v", jax.ShapeDtypeStruct(vb.shape, vb.dtype)),
-         ("states", jax.ShapeDtypeStruct((B, heads, Sp // c, dv, dk), F32))],
-        heads=heads, c=c, dk=dk, dv=dv, has_seg=has_seg, reverse=False, interpret=interpret,
-        name="delta_rule_fwd")
+        _fwd_kernel, ops,
+        [("v", jax.ShapeDtypeStruct(v.shape, v.dtype)),
+         ("states", jax.ShapeDtypeStruct((B, heads, Sp // c, static["dv"], static["dk"]), F32))],
+        c=c, reverse=False, interpret=interpret, name="delta_rule_fwd", **static)
 
 
-def _kernel_bwd(q, k, kb, vb, g, segc, segr, do, states, *, heads, c, interpret):
-    dk, dv = q.shape[-1] // heads, vb.shape[-1] // heads
-    has_seg = segc is not None
-    seg = [("segc", segc), ("segr", segr)] if has_seg else []
-    like = lambda a, dt=None: jax.ShapeDtypeStruct(a.shape, dt or a.dtype)
-    return _kernel_call(
-        _bwd_kernel,
-        [("k", q), ("k", k), ("k", kb), ("v", vb), ("k", g), *seg, ("v", do), ("states", states)],
-        [("k", like(q)), ("k", like(k)), ("k", like(kb)), ("v", like(vb)), ("k", like(g, F32))],
-        heads=heads, c=c, dk=dk, dv=dv, has_seg=has_seg, reverse=True, interpret=interpret,
-        name="delta_rule_bwd")
+def _kernel_bwd(q, k, v, g, beta, segc, segr, do, states, *, heads, c, interpret):
+    ops, static = _kernel_operands(q, k, v, g, beta, segc, segr, heads)
+    like = lambda op, dt=None: (op[0], jax.ShapeDtypeStruct(op[1].shape, dt or op[1].dtype))
+    dq, dk_, dv_, dg, dbeta = _kernel_call(
+        _bwd_kernel, [*ops, ("v", do), ("states", states)],
+        [like(ops[0]), like(ops[1]), like(ops[2]), like(ops[3], F32), like(ops[4], F32)],
+        c=c, reverse=True, interpret=interpret, name="delta_rule_bwd", **static)
+    return dq, dk_, dv_, _from_groups(dg) if static["g_small"] else dg, _from_groups(dbeta)
 
 
 # -- the same body under a scan (off the TPU) ----------------------------------------
 
 
 def _to_chunks(x, heads, c):
-    """[B, Sp, H * d] -> [Sp / C, B, H, C, d]."""
+    """[B, Sp, H * d] (d = 1: a value a head) -> [Sp / C, B, H, C, d]."""
     B, Sp, hd = x.shape
     x = x.reshape(B, Sp // c, c, heads, hd // heads)
     return x.transpose(1, 0, 3, 2, 4)
@@ -383,7 +486,7 @@ def _seg_chunks(segc, segr, c):
 
 
 def _over_heads(fn, has_seg, n_tail=0):
-    """``fn(st0, q, k, kb, vb, g, segc, segr, *tail)`` of one head's chunk ->
+    """``fn(st0, q, k, v, g, beta, segc, segr, *tail)`` of one head's chunk ->
     of [B, H, ...] operands; the segment counts are a batch row's, shared by
     its heads."""
     seg = 0 if has_seg else None
@@ -391,11 +494,11 @@ def _over_heads(fn, has_seg, n_tail=0):
     return jax.vmap(per_head, in_axes=(0,) * 6 + (seg, seg) + (0,) * n_tail)
 
 
-def _scan_fwd(q, k, kb, vb, g, segc, segr, *, heads, c):
+def _scan_fwd(q, k, v, g, beta, segc, segr, *, heads, c):
     B, Sp, _ = q.shape
-    dk, dv = q.shape[-1] // heads, vb.shape[-1] // heads
+    dk, dv = q.shape[-1] // heads, v.shape[-1] // heads
     has_seg = segc is not None
-    xs = tuple(_to_chunks(a, heads, c) for a in (q, k, kb, vb, g))
+    xs = tuple(_to_chunks(a, heads, c) for a in (q, k, v, g, beta))
     segs = _seg_chunks(segc, segr, c) if has_seg else None
     batched = _over_heads(_chunk_body, has_seg)
 
@@ -406,12 +509,12 @@ def _scan_fwd(q, k, kb, vb, g, segc, segr, *, heads, c):
         return st1, (o, st)
 
     _, (o, states) = lax.scan(step, jnp.zeros((B, heads, dv, dk), F32), (xs, segs))
-    return _from_chunks(o).astype(vb.dtype), states.transpose(1, 2, 0, 3, 4)
+    return _from_chunks(o).astype(v.dtype), states.transpose(1, 2, 0, 3, 4)
 
 
-def _scan_bwd(q, k, kb, vb, g, segc, segr, do, states, *, heads, c):
+def _scan_bwd(q, k, v, g, beta, segc, segr, do, states, *, heads, c):
     has_seg = segc is not None
-    xs = tuple(_to_chunks(a, heads, c) for a in (q, k, kb, vb, g))
+    xs = tuple(_to_chunks(a, heads, c) for a in (q, k, v, g, beta))
     segs = _seg_chunks(segc, segr, c) if has_seg else None
     dos = _to_chunks(do.astype(F32), heads, c)
     batched = _over_heads(_chunk_grads, has_seg, n_tail=2)
@@ -424,36 +527,34 @@ def _scan_bwd(q, k, kb, vb, g, segc, segr, do, states, *, heads, c):
 
     states = states.transpose(2, 0, 1, 3, 4)  # a chunk's [B, H, dv, dk] a scan step
     _, grads = lax.scan(step, jnp.zeros_like(states[0]), (xs, segs, dos, states), reverse=True)
-    dq, dk_, dkb, dvb, dg = (_from_chunks(x) for x in grads)
-    return (dq.astype(q.dtype), dk_.astype(k.dtype), dkb.astype(kb.dtype),
-            dvb.astype(vb.dtype), dg)
+    return tuple(_from_chunks(x) for x in grads)
 
 
 # -- the custom_vjp over flat, padded operands -----------------------------------------
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def _chunked(q, k, kb, vb, g, segc, segr, heads, c, mode):
-    return _chunked_fwd(q, k, kb, vb, g, segc, segr, heads, c, mode)[0]
+def _chunked(q, k, v, g, beta, segc, segr, heads, c, mode):
+    return _chunked_fwd(q, k, v, g, beta, segc, segr, heads, c, mode)[0]
 
 
-def _chunked_fwd(q, k, kb, vb, g, segc, segr, heads, c, mode):
+def _chunked_fwd(q, k, v, g, beta, segc, segr, heads, c, mode):
     if mode == "scan":
-        o, states = _scan_fwd(q, k, kb, vb, g, segc, segr, heads=heads, c=c)
+        o, states = _scan_fwd(q, k, v, g, beta, segc, segr, heads=heads, c=c)
     else:
-        o, states = _kernel_fwd(q, k, kb, vb, g, segc, segr, heads=heads, c=c,
+        o, states = _kernel_fwd(q, k, v, g, beta, segc, segr, heads=heads, c=c,
                                 interpret=mode == "interpret")
-    return o, (q, k, kb, vb, g, segc, segr, states)
+    return o, (q, k, v, g, beta, segc, segr, states)
 
 
 def _chunked_bwd(heads, c, mode, res, do):
-    q, k, kb, vb, g, segc, segr, states = res
+    *ops, segc, segr, states = res
     if mode == "scan":
-        grads = _scan_bwd(q, k, kb, vb, g, segc, segr, do, states, heads=heads, c=c)
+        grads = _scan_bwd(*ops, segc, segr, do, states, heads=heads, c=c)
     else:
-        grads = _kernel_bwd(q, k, kb, vb, g, segc, segr, do, states, heads=heads, c=c,
+        grads = _kernel_bwd(*ops, segc, segr, do, states, heads=heads, c=c,
                             interpret=mode == "interpret")
-    return (*grads, None, None)
+    return (*(d.astype(a.dtype) for d, a in zip(grads, ops)), None, None)
 
 
 _chunked.defvjp(_chunked_fwd, _chunked_bwd)
@@ -463,10 +564,10 @@ _chunked.defvjp(_chunked_fwd, _chunked_bwd)
 
 
 def chunked_delta_rule(
-    q: jnp.ndarray,  # [B, S, H, dk], already normalised and scaled by the caller
-    k: jnp.ndarray,  # [B, S, H, dk]
-    v: jnp.ndarray,  # [B, S, H, dv]
-    g: jnp.ndarray,  # [B, S, H] or [B, S, H, dk]: log-decay, <= 0
+    q: jnp.ndarray,  # [B, S, H * dk]: raw, as the projection (and conv) left it
+    k: jnp.ndarray,  # [B, S, H * dk]
+    v: jnp.ndarray,  # [B, S, H * dv]
+    g: jnp.ndarray,  # [B, S, H] or [B, S, H * dk]: log-decay, unclamped
     beta: jnp.ndarray,  # [B, S, H]: write strength
     *,
     segment_ids: Optional[jnp.ndarray] = None,  # [B, S] packed-document ids
@@ -475,12 +576,18 @@ def chunked_delta_rule(
     mesh_ctx=None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """-> ``o`` [B, S, H, dv] in ``v``'s type. The products take their
-    operands in ``q``'s type (bfloat16 in training, float32 in the CPU
-    tests) and accumulate in float32; the state, the cumulative decay and the
-    triangular solve are float32 whatever the operands. Exact (to rounding)
-    for ``g`` in [-10, 0]; a smaller ``g`` is held at -10 (module docstring).
-    The state starts at zero and resets where ``segment_ids`` changes."""
+    """-> ``o`` [B, S, H * dv] in ``v``'s type; ``H`` is ``beta``'s last axis,
+    a head's channels are contiguous. The operator normalises ``q`` and ``k``
+    a head (l2, ``eps`` 1e-6), scales ``q`` by ``dk ** -0.5``, forms ``beta k``
+    and ``beta v`` and clamps ``g`` to [-10, 0] itself, a chunk's tile at a
+    time (module docstring): no ``[B, S, H, d]`` array goes in or comes out,
+    and the gradients come back for the raw operands. The products take their
+    operands in ``q``'s type (bfloat16 in training, float32 for Qwen3-Next and
+    in the CPU tests) and accumulate in float32; the norms, the state, the
+    cumulative decay and the triangular solve are float32 whatever the
+    operands. Exact (to rounding) for ``g`` in [-10, 0]; a smaller ``g`` is
+    held at -10 and its gradient is zero. The state starts at zero and resets
+    where ``segment_ids`` changes."""
     from automodel_tpu.ops.platform_check import is_tpu_platform, kernel_axes
 
     if interpret is None:
@@ -499,7 +606,8 @@ def chunked_delta_rule(
 
 def _delta_shard_map(block, mesh_ctx, q, k, v, g, beta, segment_ids):
     """The kernels per device block: batch over the data axes, heads over
-    ``tp``, the sequence whole (GSPMD cannot partition a Mosaic call)."""
+    ``tp`` (a shard of a flat operand is a contiguous block of heads), the
+    sequence whole (GSPMD cannot partition a Mosaic call)."""
     from jax.sharding import PartitionSpec as P
 
     from automodel_tpu.ops.platform_check import kernel_shard_map, sharded_axes
@@ -507,44 +615,32 @@ def _delta_shard_map(block, mesh_ctx, q, k, v, g, beta, segment_ids):
     if mesh_ctx.cp_size > 1:
         raise ValueError("the chunked delta rule walks a whole sequence a device: cp must be 1")
     batch = sharded_axes(mesh_ctx, "batch", (q.shape[0],), "delta-rule batch")
-    heads = sharded_axes(mesh_ctx, "tensor", (q.shape[2],), "delta-rule heads")
-    x4, x3 = P(batch, None, heads, None), P(batch, None, heads)
-    args = [q, k, v, g, beta]
-    specs = [x4, x4, x4, x4 if g.ndim == 4 else x3, x3]
+    heads = sharded_axes(mesh_ctx, "tensor", (beta.shape[2],), "delta-rule heads")
+    x = P(batch, None, heads)
+    args, specs = [q, k, v, g, beta], [x] * 5
     if segment_ids is not None:
         args.append(segment_ids)
         specs.append(P(batch, None))
-    return kernel_shard_map(mesh_ctx, block, tuple(specs), x4)(*args)
+    return kernel_shard_map(mesh_ctx, block, tuple(specs), x)(*args)
 
 
 def _delta_rule_block(q, k, v, g, beta, segment_ids, c, mode):
-    B, S, H, dk = q.shape
-    dv = v.shape[-1]
+    B, S, H = beta.shape
     cd = q.dtype
-    g = jnp.clip(g.astype(F32), G_MIN, 0.0)
-    if g.ndim == 3:
-        g = jnp.broadcast_to(g[..., None], (B, S, H, dk))
-    beta = beta.astype(F32)[..., None]
-    kb = (k.astype(F32) * beta).astype(cd)
-    vb = (v.astype(F32) * beta).astype(cd)
-
-    starts = None
-    if segment_ids is not None:
-        prev = jnp.pad(segment_ids, ((0, 0), (1, 0)), constant_values=-1)[:, :S]
-        starts = segment_ids != prev  # [B, S]; position 0 starts from zero anyway
-        g = jnp.where(starts[:, :, None, None], 0.0, g)
-
     step = c * (_CHUNKS_PER_STEP if mode != "scan" else 1)
     pad = (-S) % step
     Sp = S + pad
-    flat = lambda x: jnp.pad(x.reshape(B, S, -1), ((0, 0), (0, pad), (0, 0)))
     # padded rows: k = beta = 0 and g = 0, so they neither write nor decay
-    qf, kf, kbf, vbf = flat(q), flat(k.astype(cd)), flat(kb), flat(vb)
-    gf = flat(g)
+    padded = lambda x: jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
     segc = segr = None
-    if starts is not None:
-        seg = jnp.pad(starts.astype(jnp.int32), ((0, 0), (0, pad)))
-        seg = jnp.cumsum(seg.reshape(B, Sp // c, c), axis=2)
-        segc, segr = seg.reshape(B, Sp, 1), seg.reshape(B, Sp // c, 1, c)
-    o = _chunked(qf, kf, kbf, vbf, gf, segc, segr, H, c, mode)
-    return o[:, :S].reshape(B, S, H, dv).astype(v.dtype)
+    if segment_ids is not None:
+        prev = jnp.pad(segment_ids, ((0, 0), (1, 0)), constant_values=-1)[:, :S]
+        # [B, S]; position 0 starts from zero anyway
+        starts = jnp.pad((segment_ids != prev).astype(jnp.int32), ((0, 0), (0, pad)))
+        seg = jnp.cumsum(starts.reshape(B, Sp // c, c), axis=2)  # resets so far, a chunk
+        # the column also says which rows ARE a start (the body zeroes their g)
+        segc = (2 * seg + starts.reshape(seg.shape)).reshape(B, Sp, 1)
+        segr = seg.reshape(B, Sp // c, 1, c)
+    o = _chunked(padded(q), padded(k.astype(cd)), padded(v.astype(cd)), padded(g), padded(beta),
+                 segc, segr, H, c, mode)
+    return o[:, :S].astype(v.dtype)

@@ -14,9 +14,10 @@ ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from automodel_tpu.ops.delta_rule import G_MIN, chunked_delta_rule, l2norm
+from automodel_tpu.ops.delta_rule import G_MIN, _chunk_rule, chunked_delta_rule, l2norm
 
 B, H, DK, DV = 2, 2, 32, 16
+F32 = jnp.float32
 # float32 both sides: what differs is the order of the sums (one solve and a
 # few products a chunk against one update a token). Measured 1e-6 forward and
 # 2e-6 on the gradients at |g| ~ 1, 3e-5 at |g| ~ 4 (the factors exp(+-sum of
@@ -27,7 +28,8 @@ TOL, TOL_FAST_DECAY = 2e-5, 2e-4
 
 def recurrence(q, k, v, g, beta, segment_ids=None):
     """S_t = (I - beta k k^T) Diag(exp g) S_{t-1} + beta k v^T; o_t = S_t^T q_t;
-    S is zeroed where ``segment_ids`` changes."""
+    S is zeroed where ``segment_ids`` changes. ``[B, S, H, d]`` operands, ``q``
+    and ``k`` already normalised."""
     Bq, S, Hq, dk = q.shape
     if g.ndim == 3:
         g = jnp.broadcast_to(g[..., None], q.shape)
@@ -48,29 +50,54 @@ def recurrence(q, k, v, g, beta, segment_ids=None):
     return jnp.moveaxis(o, 0, 1)
 
 
-def operands(S, per_channel, g_scale, seed=0):
-    ks = jax.random.split(jax.random.key(seed + S), 6)
-    q = l2norm(jax.random.normal(ks[0], (B, S, H, DK))) * DK**-0.5
-    k = l2norm(jax.random.normal(ks[1], (B, S, H, DK)))
-    v = jax.random.normal(ks[2], (B, S, H, DV))
-    g = -g_scale * jax.nn.softplus(jax.random.normal(ks[3], (B, S, H, DK) if per_channel else (B, S, H)))
+def heads(x):
+    """The operator's flat ``[B, S, H * d]`` -> ``[B, S, H, d]``."""
+    return x.reshape(*x.shape[:2], H, -1)
+
+
+def reference(q, k, v, g, beta, segment_ids=None, clamp=True):
+    """The operator's contract, token by token: raw flat operands in, the
+    norms, the scale and the clamp formed here in jnp, flat ``o`` out."""
+    g = heads(g) if g.shape[-1] != H else g
+    if clamp:
+        g = jnp.clip(g, G_MIN, 0.0)
+    o = recurrence(l2norm(heads(q)) * DK**-0.5, l2norm(heads(k)), heads(v), g, beta, segment_ids)
+    return o.reshape(*q.shape[:2], -1)
+
+
+def operands(S, per_channel, g_scale, seed=0, norms=(1.0, 1.0)):
+    """Flat operands; a head's ``q`` and ``k`` rows have norms drawn
+    log-uniformly from ``norms``."""
+    ks = jax.random.split(jax.random.key(seed + S), 8)
+    lo, hi = jnp.log(norms[0]), jnp.log(norms[1])
+    size = lambda key: jnp.exp(jax.random.uniform(key, (B, S, H, 1), minval=lo, maxval=hi))
+    q = (l2norm(jax.random.normal(ks[0], (B, S, H, DK))) * size(ks[6])).reshape(B, S, -1)
+    k = (l2norm(jax.random.normal(ks[1], (B, S, H, DK))) * size(ks[7])).reshape(B, S, -1)
+    v = jax.random.normal(ks[2], (B, S, H * DV))
+    g = -g_scale * jax.nn.softplus(jax.random.normal(ks[3], (B, S, H * DK) if per_channel else (B, S, H)))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, H)))
-    return (q, k, v, g, beta), jax.random.normal(ks[5], (B, S, H, DV))
+    return (q, k, v, g, beta), jax.random.normal(ks[5], (B, S, H * DV))
 
 
 def rel(a, b):
     return float(jnp.abs(a - b).max() / jnp.abs(b).max())
 
 
+NAMES = "q k v g beta".split()
+
+
+def gradients(fn, args, w):
+    return dict(zip(NAMES, jax.grad(lambda *a: (fn(*a) * w).sum(), argnums=range(5))(*args)))
+
+
 def check(args, w, tol, segment_ids=None, **kw):
-    got = chunked_delta_rule(*args, segment_ids=segment_ids, **kw)
-    want = recurrence(*args, segment_ids)
-    assert rel(got, want) < tol
-    f = lambda *a: (chunked_delta_rule(*a, segment_ids=segment_ids, **kw) * w).sum()
-    r = lambda *a: (recurrence(*a, segment_ids) * w).sum()
-    for name, a, b in zip("q k v g beta".split(), jax.grad(f, argnums=range(5))(*args),
-                          jax.grad(r, argnums=range(5))(*args)):
-        assert rel(a, b) < tol, name
+    op = lambda *a: chunked_delta_rule(*a, segment_ids=segment_ids, **kw)
+    ref = lambda *a: reference(*a, segment_ids)
+    assert rel(op(*args), ref(*args)) < tol
+    got, want = gradients(op, args, w), gradients(ref, args, w)
+    for name in NAMES:
+        assert rel(got[name], want[name]) < tol, name
+    return got
 
 
 @pytest.mark.parametrize("mode", ["interpret", "scan"])
@@ -80,24 +107,57 @@ def test_forward_and_gradients_match_the_recurrence(mode, per_channel):
     check(args, w, TOL, interpret=mode == "interpret")
 
 
+@pytest.mark.parametrize("mode", ["interpret", "scan"])
+@pytest.mark.parametrize("per_channel", [True, False], ids=["per_channel", "scalar"])
+def test_raw_q_and_k_are_normalised_in_the_body(mode, per_channel):
+    """Rows of norm 0.1 to 30 a head: the norm and its transpose are the
+    operator's, so a unit-norm operand would hide a wrong one."""
+    args, w = operands(128, per_channel, 1.0, norms=(0.1, 30.0))
+    check(args, w, TOL, interpret=mode == "interpret")
+
+
 @pytest.mark.parametrize("S", [200, 37], ids=["S200", "S37"])
 def test_a_length_that_is_no_multiple_of_the_chunk(S):
     args, w = operands(S, True, 1.0)
     check(args, w, TOL, interpret=True)
 
 
+def packed(S):
+    # starts inside a chunk, on a chunk's first row, on adjacent tokens
+    cuts = jnp.array([[0, 37, 38, 100], [0, 64, 129, 130]])
+    return cuts, (jnp.arange(S)[None, :, None] >= cuts[:, None, :]).sum(-1)
+
+
 @pytest.mark.parametrize("mode", ["interpret", "scan"])
 def test_packed_documents_reset_the_state(mode):
     S = 200
     args, w = operands(S, True, 1.0)
-    # starts inside a chunk, on a chunk's first row, on adjacent tokens
-    cuts = jnp.array([[0, 37, 38, 100], [0, 64, 129, 130]])
-    seg = (jnp.arange(S)[None, :, None] >= cuts[:, None, :]).sum(-1)
+    _, seg = packed(S)
     check(args, w, TOL, segment_ids=seg, interpret=mode == "interpret")
     # a document's output is what it would be alone
     got = chunked_delta_rule(*args, segment_ids=seg, interpret=mode == "interpret")
     alone = chunked_delta_rule(*(a[:1, 38:100] for a in args), interpret=mode == "interpret")
     assert rel(got[:1, 38:100], alone) < TOL
+
+
+@pytest.mark.parametrize("mode", ["interpret", "scan"])
+@pytest.mark.parametrize("per_channel", [True, False], ids=["per_channel", "scalar"])
+def test_no_gradient_through_a_clamped_decay_or_a_first_token(mode, per_channel):
+    """``g`` below ``G_MIN`` on a third of the entries, raw ``q`` and ``k``,
+    packed documents: ``dg`` is zero where the clamp holds and on a document's
+    first token (its decay meets a state that was just reset), ``dbeta`` and
+    the rest match the recurrence on the clamped ``g``."""
+    S = 200
+    (q, k, v, g, beta), w = operands(S, per_channel, 1.0, norms=(0.1, 30.0))
+    low = jax.random.bernoulli(jax.random.key(7), 1 / 3, g.shape)
+    g = jnp.where(low, g - 12.0, g)
+    cuts, seg = packed(S)
+    # a third of the decays are exp(-10) a token: the fast-decay tolerance
+    got = check((q, k, v, g, beta), w, TOL_FAST_DECAY, segment_ids=seg, interpret=mode == "interpret")
+    assert float(jnp.abs(jnp.where(low, got["g"], 0.0)).max()) == 0.0
+    assert float(jnp.abs(jnp.where(low, 0.0, got["g"])).max()) > 0.0
+    for b in range(B):
+        assert float(jnp.abs(got["g"][b, cuts[b]]).max()) == 0.0
 
 
 @pytest.mark.parametrize("g_scale,tol", [(0.01, TOL), (4.0, TOL_FAST_DECAY)],
@@ -109,12 +169,11 @@ def test_decays_near_one_and_near_zero(g_scale, tol):
 
 def test_a_decay_below_the_clamp_is_held_at_it():
     (q, k, v, g, beta), _ = operands(64, True, 1.0)
-    g = g.at[:, 10].set(-40.0)  # exp(-40): the state is wiped but for 4e-18 of it
+    g = heads(g).at[:, 10].set(-40.0).reshape(g.shape)  # exp(-40): the state is wiped but for 4e-18 of it
     got = chunked_delta_rule(q, k, v, g, beta, interpret=True)
-    held = recurrence(q, k, v, jnp.maximum(g, G_MIN), beta)
-    assert rel(got, held) < TOL
+    assert rel(got, reference(q, k, v, g, beta)) < TOL
     # and what the clamp changes is exp(-10) of a state of order 1
-    assert rel(got, recurrence(q, k, v, g, beta)) < 2e-4
+    assert rel(got, reference(q, k, v, g, beta, clamp=False)) < 2e-4
 
 
 def test_bfloat16_operands_are_told_apart_by_the_tolerance():
@@ -122,7 +181,76 @@ def test_bfloat16_operands_are_told_apart_by_the_tolerance():
     q, k, v, g, beta = args
     low = chunked_delta_rule(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
                              v.astype(jnp.bfloat16), g, beta, interpret=True)
-    assert rel(low.astype(jnp.float32), recurrence(*args)) > 20 * TOL
+    assert rel(low.astype(jnp.float32), reference(*args)) > 20 * TOL
+
+
+def formed_outside(q, k, v, g, beta, segment_ids=None, c=128):
+    """The operator as it stood before the kernels formed their own operands:
+    ``l2norm``, ``round(k * beta)``, ``round(v * beta)``, the clamp and the
+    zero on a first token as jnp passes over ``[B, S, H, d]`` arrays, then the
+    same chunk rule (``_chunk_rule``) under a plain scan that autodiff walks.
+    ``S`` a whole number of chunks."""
+    Bq, S, _ = q.shape
+    cd = q.dtype
+    qn = (l2norm(heads(q)) * DK**-0.5).astype(cd)
+    kn = l2norm(heads(k)).astype(cd)
+    b = beta.astype(F32)[..., None]
+    kb = (kn.astype(F32) * b).astype(cd)
+    vb = (heads(v).astype(F32) * b).astype(cd)
+    g = jnp.clip(g.astype(F32), G_MIN, 0.0)
+    g = jnp.broadcast_to(g[..., None] if g.shape[-1] == H else heads(g), qn.shape)
+    seg = None
+    if segment_ids is not None:
+        prev = jnp.pad(segment_ids, ((0, 0), (1, 0)), constant_values=-1)[:, :S]
+        starts = segment_ids != prev
+        g = jnp.where(starts[:, :, None, None], 0.0, g)
+        seg = jnp.cumsum(starts.astype(jnp.int32).reshape(Bq, S // c, c), axis=2).swapaxes(0, 1)
+        seg = (seg[..., None], seg[:, :, None, :])  # [n, B, C, 1], [n, B, 1, C]
+    chunks = lambda x: x.reshape(Bq, S // c, c, H, -1).transpose(1, 0, 3, 2, 4)
+    s = None if seg is None else 0
+    rule = jax.vmap(jax.vmap(_chunk_rule, in_axes=(0,) * 6 + (None, None)), in_axes=(0,) * 6 + (s, s))
+
+    def step(st, x):
+        ops, sg = x
+        o, st1 = rule(st, *ops, *(sg if sg is not None else (None, None)))
+        return st1, o
+
+    xs = tuple(chunks(a) for a in (qn, kn, kb, vb, g))
+    _, o = jax.lax.scan(step, jnp.zeros((Bq, H, DV, DK), F32), (xs, seg))
+    return o.transpose(1, 0, 3, 2, 4).reshape(Bq, S, -1).astype(cd)
+
+
+# bfloat16 operands, both sides the same chunk rule on the same rounded
+# operands: the outputs are equal to the last bit. The gradients are rounded to
+# bfloat16 in other places: formed outside, ``dk`` is the sum of three bfloat16
+# arrays (the rule's own ``dk``, ``dkb * beta`` and the norm's transpose of
+# both, each rounded as it left a kernel or a jnp pass); formed inside, one
+# float32 sum rounded once. Read (all six cases): ``dv`` and, under the scan,
+# ``dg`` equal; ``dq`` 3e-6, ``dg`` 1e-6, ``dbeta`` < 5e-7 (float32 sums over d
+# in another order); ``dk`` 0.0039 to 0.0075 of its largest entry, one or two
+# last bits of bfloat16 (2^-8). The tolerance is four.
+TOL_BF16_GRADS = 2**-6
+
+
+@pytest.mark.parametrize("mode", ["interpret", "scan"])
+@pytest.mark.parametrize("case", ["per_channel", "scalar", "packed"])
+def test_bfloat16_rounding_points_are_those_of_the_formation_outside(mode, case):
+    S = 256
+    (q, k, v, g, beta), w = operands(S, case != "scalar", 1.0, norms=(0.1, 30.0))
+    g = jnp.where(jax.random.bernoulli(jax.random.key(7), 0.2, g.shape), g - 12.0, g)
+    seg = packed(S)[1] if case == "packed" else None
+    args = (*(a.astype(jnp.bfloat16) for a in (q, k, v)), g, beta)
+    op = lambda *a: chunked_delta_rule(*a, segment_ids=seg, interpret=mode == "interpret")
+    ref = lambda *a: formed_outside(*a, segment_ids=seg)
+    got, want = op(*args).astype(F32), ref(*args).astype(F32)
+    if mode == "scan":  # the same rounding points in the same XLA ops: equal to the last bit
+        assert bool((got == want).all())
+    else:  # (read equal in interpret mode too; a last bit of bfloat16 is allowed there)
+        assert rel(got, want) < 2**-7
+    grads, wants = gradients(op, args, w), gradients(ref, args, w)
+    for name in NAMES:
+        assert grads[name].dtype == args[NAMES.index(name)].dtype
+        assert rel(grads[name].astype(F32), wants[name].astype(F32)) < TOL_BF16_GRADS, name
 
 
 @pytest.mark.parametrize("chunk", [64, 128])
@@ -131,6 +259,6 @@ def test_a_run_of_equal_keys_written_at_full_strength(chunk):
     the diagonal, -1 under it), the powers of N are not (1e37 at N^64). The
     triangular inverse must never form them."""
     (q, k, v, g, beta), w = operands(256, True, 1.0)
-    k = jnp.broadcast_to(k[:, :1], k.shape)
+    k = jnp.broadcast_to(heads(k)[:, :1], heads(k).shape).reshape(k.shape)
     args = (q, k, v, jnp.full_like(g, -1e-3), jnp.ones_like(beta))
     check(args, w, TOL_FAST_DECAY, interpret=True, chunk_size=chunk)

@@ -91,15 +91,15 @@ def test_latent_attention_lowers_with_v_narrower_than_qk(n, degrees):
 )
 def test_delta_rule_kernels_fwd_bwd_lower(n, degrees, per_channel, packed):
     """ops/delta_rule.py at the cell's head shapes (32 heads of 128 x 128, a
-    per-channel decay) and Qwen3-Next's scalar decay: one forward and one
-    backward Mosaic call, on a mesh inside a shard_map."""
+    per-channel decay) and Qwen3-Next's scalar decay, flat operands as the
+    projections leave them: one forward and one backward Mosaic call, on a
+    mesh inside a shard_map (a ``tp`` shard is a contiguous block of heads)."""
     from automodel_tpu.ops.delta_rule import chunked_delta_rule
 
     ctx = _tpu_ctx(n, **degrees)
     B, S, H, d = 2, 1024, 32, 128
-    qkv = _sds(ctx, (B, S, H, d), jnp.bfloat16, "batch", None, "tensor", None)
-    g = (_sds(ctx, (B, S, H, d), jnp.float32, "batch", None, "tensor", None) if per_channel
-         else _sds(ctx, (B, S, H), jnp.float32, "batch", None, "tensor"))
+    qkv = _sds(ctx, (B, S, H * d), jnp.bfloat16, "batch", None, "tensor")
+    g = _sds(ctx, (B, S, H * d if per_channel else H), jnp.float32, "batch", None, "tensor")
     beta = _sds(ctx, (B, S, H), jnp.float32, "batch", None, "tensor")
     seg = _sds(ctx, (B, S), jnp.int32, "batch", None)
 
@@ -109,6 +109,88 @@ def test_delta_rule_kernels_fwd_bwd_lower(n, degrees, per_channel, packed):
         return out.astype(jnp.float32).sum()
 
     assert _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), qkv, qkv, qkv, g, beta, seg) == 2
+
+
+def _eqns(jaxpr, stop=lambda eqn: False):
+    """Every equation of a jaxpr and of the jaxprs in its parameters (not
+    those of an equation ``stop`` names)."""
+    from jax._src import core
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if not stop(eqn):
+            for sub in core.jaxprs_in_params(eqn.params):
+                yield from _eqns(sub, stop)
+
+
+def _delta_rule_boundary(fn, args, per_head):
+    """-> (ranks of the array operands and results of the operator's
+    ``custom_vjp`` call in ``fn``'s jaxpr, equations of ``fn``'s
+    value-and-grad whose result has the shape ``per_head`` = [B, S, H, d])."""
+    is_call = lambda e: e.primitive.name.startswith("custom_vjp_call")
+    calls = [e for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr, is_call) if is_call(e)]
+    assert len(calls) == 1  # the kernels' bodies hold one of their own (the cumulative sum)
+    ranks = {len(v.aval.shape) for v in (*calls[0].invars, *calls[0].outvars)}
+    grad = jax.make_jaxpr(jax.value_and_grad(fn, argnums=tuple(range(len(args)))))(*args)
+    shaped = [e for e in _eqns(grad.jaxpr)
+              if any(getattr(v.aval, "shape", None) == per_head for v in e.outvars)]
+    return ranks, shaped
+
+
+def test_kda_block_hands_the_delta_rule_what_the_convs_wrote():
+    """``kda_block`` at the cell's widths ([1, 512, 2304], 32 heads of 128,
+    bfloat16): every array the operator's ``custom_vjp`` takes or gives is
+    rank 3, and of the value-and-grad's equations 18 give a ``[B, S, H, dh]``
+    array, all of them ``kda_norm``'s per-head RMS norm of ``o`` and its
+    transpose (6 forward from the reshape of ``o`` on, 12 backward); q, k, v,
+    the decay and the output gate stay ``[B, S, H * dh]``. Before the kernels
+    formed their own operands the count was 137 (the reshapes of q, k, v and
+    both gates, the softplus on the reshaped ``f``, two ``l2norm``, ``beta k``,
+    ``beta v``, the clamp, and the transposes of each). The block compiles for
+    the chip with one forward and one backward Mosaic call."""
+    import types
+
+    from automodel_tpu.models.common.config import BackendConfig
+    from automodel_tpu.models.kimi_linear.model import init_kda_layer, kda_block
+
+    ctx = _tpu_ctx(1)
+    B, S, D, H, dh = 1, 512, 2304, 32, 128
+    cfg = types.SimpleNamespace(hidden_size=D, kda_num_heads=H, kda_head_dim=dh, kda_dim=H * dh,
+                                kda_conv_kernel=4, rms_eps=1e-5)
+    backend = BackendConfig(param_dtype="bfloat16", compute_dtype="bfloat16", platform="tpu",
+                            mesh_ctx=ctx)
+    lp = jax.tree.map(lambda a: _sds(ctx, a.shape[1:], a.dtype),
+                      jax.eval_shape(lambda: init_kda_layer(cfg, backend, jax.random.key(0), 1)))
+    h, scale = _sds(ctx, (B, S, D), jnp.bfloat16), _sds(ctx, (D,), jnp.bfloat16)
+
+    def loss(h, lp, scale):
+        out = kda_block(cfg, backend, h, lp, scale, None, lambda x, _: x)
+        return out.astype(jnp.float32).sum()
+
+    ranks, shaped = _delta_rule_boundary(loss, (h, lp, scale), (B, S, H, dh))
+    assert ranks == {3}
+    assert len(shaped) == 18, [e.primitive.name for e in shaped]
+    assert all("kda_norm" in str(e.source_info.name_stack) for e in shaped)
+    assert _compile(jax.grad(loss, argnums=(0, 1)), h, lp, scale) == 2
+
+
+def test_gated_delta_net_hands_the_delta_rule_flat_operands():
+    """Qwen3-Next's wrapper (``models/qwen3_next/delta.py``) takes and gives
+    ``[B, S, H, d]`` (its model's layout); between the two reshapes nothing
+    has that shape: FIVE equations of the value-and-grad (``o`` reshaped out,
+    its cotangent made, ``dq``, ``dk``, ``dv`` reshaped back), 35 before."""
+    from automodel_tpu.models.qwen3_next.delta import chunk_gated_delta_rule
+
+    B, S, H, d = 1, 256, 4, 128
+    x = jax.ShapeDtypeStruct((B, S, H, d), jnp.float32)
+    small = jax.ShapeDtypeStruct((B, S, H), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        return chunk_gated_delta_rule(q, k, v, g, beta, platform="tpu").sum()
+
+    ranks, shaped = _delta_rule_boundary(loss, (x, x, x, small, small), (B, S, H, d))
+    assert ranks == {3}
+    assert len(shaped) == 5, [e.primitive.name for e in shaped]
 
 
 def test_flash_refuses_heads_the_mesh_does_not_divide():
