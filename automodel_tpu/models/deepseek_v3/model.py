@@ -125,22 +125,21 @@ def init_mla_layer(cfg: DeepseekV3Config, backend: BackendConfig, key, L: int) -
     }
 
 
-def mla_block(
+def mla_branch(
     cfg: DeepseekV3Config,
     backend: BackendConfig,
-    h: jnp.ndarray,
-    lp: dict,
+    x: jnp.ndarray,
+    ap: dict,
     cos: jnp.ndarray,
     sin: jnp.ndarray,
     segment_ids: Optional[jnp.ndarray],
-    constrain: Constrain,
-    sliding_window: Optional[int] = None,
 ) -> jnp.ndarray:
-    B, S, D = h.shape
+    """The latent-attention branch: the NORMED input ``x`` [B, S, D] in, the
+    output projection's result out, no residual. ``mla_block`` adds it to the
+    single-stream residual; models/xing4 writes it into a multi-stream one."""
+    B, S, D = x.shape
     N = cfg.num_heads
     nope, rope, vdim = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    ap = lp["attn"]
-    x = rms_norm(h, lp["input_norm"]["scale"], cfg.rms_eps)
 
     if cfg.q_lora_rank:
         qa = x @ ap["q_a_proj"]["kernel"].astype(x.dtype)
@@ -184,7 +183,22 @@ def mla_block(
             else {}
         ),
     )
-    h = h + out.reshape(B, S, N * vdim) @ ap["o_proj"]["kernel"].astype(x.dtype)
+    return out.reshape(B, S, N * vdim) @ ap["o_proj"]["kernel"].astype(x.dtype)
+
+
+def mla_block(
+    cfg: DeepseekV3Config,
+    backend: BackendConfig,
+    h: jnp.ndarray,
+    lp: dict,
+    cos: jnp.ndarray,
+    sin: jnp.ndarray,
+    segment_ids: Optional[jnp.ndarray],
+    constrain: Constrain,
+    sliding_window: Optional[int] = None,
+) -> jnp.ndarray:
+    x = rms_norm(h, lp["input_norm"]["scale"], cfg.rms_eps)
+    h = h + mla_branch(cfg, backend, x, lp["attn"], cos, sin, segment_ids)
     return constrain(h, ("batch", "seq", None))
 
 

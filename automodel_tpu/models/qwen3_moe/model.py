@@ -105,6 +105,13 @@ class MoEModelAux(NamedTuple):
     # picks that landed on the experts this device holds, over all layers
     # (MoEConfig.held_experts); None where every expert is here
     held_expert_rows: Optional[jnp.ndarray] = None
+    # a multi-token-prediction module's final-normed hidden [B, S, D]: the
+    # loss runs the head on it against the labels shifted once more
+    # (models/xing4); None where a family has no such module
+    mtp_hidden: Optional[jnp.ndarray] = None
+    # the largest |row sum - 1| of any hyper-connection Hres in the forward
+    # (ops/hyper_connections.res_row_error); None without such a residual path
+    mhc_res_row_err: Optional[jnp.ndarray] = None
 
 
 def _init_attn_layer(cfg: TransformerConfig, backend: BackendConfig, key, L: int) -> dict:

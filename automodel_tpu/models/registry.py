@@ -29,7 +29,14 @@ def resolve_architecture(hf_config: Any) -> Callable:
         if isinstance(hf_config, dict)
         else getattr(hf_config, "architectures", None)
     ) or []
-    for a in archs:
+    # a family may also register its `model_type` (a config.json without
+    # `architectures` then still finds it)
+    model_type = (
+        hf_config.get("model_type")
+        if isinstance(hf_config, dict)
+        else getattr(hf_config, "model_type", None)
+    )
+    for a in (*archs, model_type):
         if a in _REGISTRY:
             return _REGISTRY[a]
     # generic llama-style fallback (SURVEY.md §7 hard part 6): any dense
@@ -113,6 +120,18 @@ def _kimi_linear_builder(hf_config: Any, backend: BackendConfig):
 
     cfg = KimiLinearConfig.from_hf(hf_config)
     return KimiLinearForCausalLM(cfg, backend), KimiLinearStateDictAdapter(cfg)
+
+
+@register_architecture("Xing4_0ForCausalLM", "xing4_0")
+def _xing4_builder(hf_config: Any, backend: BackendConfig):
+    from automodel_tpu.models.xing4 import (
+        Xing4Config,
+        Xing4ForCausalLM,
+        Xing4StateDictAdapter,
+    )
+
+    cfg = Xing4Config.from_hf(hf_config)
+    return Xing4ForCausalLM(cfg, backend), Xing4StateDictAdapter(cfg)
 
 
 @register_architecture("GptOssForCausalLM")

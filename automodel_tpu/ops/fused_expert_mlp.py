@@ -195,34 +195,61 @@ def in_place_ok(D: int, I: int) -> bool:
     return D % 128 == 0 and I % 128 == 0
 
 
+_FWD_STACK = 14 * 1024 * 1024  # what the forward's blocks may fill of the ~16MB default scoped stack
+
+
+def _fwd_vmem(tm: int, ic: int, Dp: int) -> int:
+    """The forward's VMEM: double-buffered input blocks + output + fp32
+    accumulator."""
+    return (
+        2 * (tm * Dp * 2)          # lhs
+        + 2 * (Dp * 2 * ic * 2)    # wgu chunk
+        + 2 * (ic * Dp * 2)        # wd chunk
+        + 2 * (tm * Dp * 2)        # out
+        + tm * Dp * 4              # acc scratch
+    )
+
+
 def _fwd_tiles(D: int, I: int) -> tuple[int, int]:
     """(tm, ic) of the forward: 512 rows and the largest divisor chunk of I,
-    rows halved to 256 and then the chunk shrunk until the blocks fit."""
-    tm = 512
+    rows halved to 256 and then the chunk shrunk until the blocks fit the
+    default scoped-vmem stack (Mosaic rejects the kernel at compile otherwise
+    — hit at D=1536 with the 512/512 tiles). A hidden size so wide that no
+    tile fits it (D=3584: 16.5MB at 256/128) is picked again under
+    `_VMEM_BUDGET`, and the call then asks Mosaic for `_VMEM_LIMIT` as the
+    backward kernels do (`_fwd_vmem_limit`). That pick, (256, 512) at 3584 x
+    1024, was made to fit and timed afterwards (TPU v5 lite, PR 43's review,
+    10,240 buffer rows over 8 experts, ms a forward at 512 live rows an
+    expert / at a full buffer): (256, 512) 0.661 / 1.496; (256, 256) 0.679 /
+    1.525; (256, 1024) 0.644 / 1.353; (512, 256) 0.563 / 1.514; (512, 512)
+    0.555 / 1.500 (42 MB by `_fwd_vmem`, over the budget); (128, 512) 1.121 /
+    2.631; (128, 1024) 0.722 / 1.437. 512 rows a tile are 16 % faster at the
+    balanced share, a whole-width chunk 10 % at a full buffer: ≈ 0.1 ms a
+    call either way, left to a `perf_opt` PR."""
     Dp = _round_up(D, 128)
     I128 = _round_up(I, 128)
-    ic = _divisor_chunk(I128)
 
-    def _vmem(tm_, ic_):
-        # double-buffered input blocks + output + fp32 accumulator; must stay
-        # under the ~16MB scoped-vmem stack (Mosaic rejects the kernel at
-        # compile otherwise — hit at D=1536 with the 512/512 tiles)
-        return (
-            2 * (tm_ * Dp * 2)          # lhs
-            + 2 * (Dp * 2 * ic_ * 2)    # wgu chunk
-            + 2 * (ic_ * Dp * 2)        # wd chunk
-            + 2 * (tm_ * Dp * 2)        # out
-            + tm_ * Dp * 4              # acc scratch
-        )
+    def pick(budget: int) -> tuple[int, int]:
+        tm, ic = 512, _divisor_chunk(I128)
+        while _fwd_vmem(tm, ic, Dp) > budget and tm > 256:
+            tm //= 2
+        while _fwd_vmem(tm, ic, Dp) > budget:
+            smaller = [c for c in _IC_CANDS if c < ic and I128 % c == 0]
+            if not smaller:
+                break
+            ic = smaller[0]
+        return tm, ic
 
-    while _vmem(tm, ic) > 14 * 1024 * 1024 and tm > 256:
-        tm //= 2
-    while _vmem(tm, ic) > 14 * 1024 * 1024:
-        smaller = [c for c in _IC_CANDS if c < ic and I128 % c == 0]
-        if not smaller:
-            break
-        ic = smaller[0]
+    tm, ic = pick(_FWD_STACK)
+    if _fwd_vmem(tm, ic, Dp) > _FWD_STACK:
+        tm, ic = pick(_VMEM_BUDGET)
     return tm, ic
+
+
+def _fwd_vmem_limit(tm: int, ic: int, Dp: int) -> dict:
+    """The compiler parameter a forward whose blocks overflow the default
+    stack needs; nothing for every shape that fits it."""
+    return {"vmem_limit_bytes": _VMEM_LIMIT} if _fwd_vmem(tm, ic, Dp) > _FWD_STACK else {}
 
 
 def work_units(group_sizes, M: int, D: int, I: int):
@@ -329,6 +356,7 @@ def _fwd(lhs, gate, up, down, group_sizes, gb, ub, db, act_kind, limit,
         out_shape=out_sds,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
+            **_fwd_vmem_limit(tm, ic, Dp),
         ),
         interpret=interpret,
         name="fused_expert_mlp_fwd",

@@ -111,6 +111,10 @@ def _attention_factor(cfg: RopeConfig) -> float:
         return 1.0
     if cfg.attention_factor is not None:
         return cfg.attention_factor
+    if cfg.mscale and cfg.mscale_all_dim:
+        # HF _compute_yarn_parameters: both given -> their ratio (1 for
+        # DeepSeek-style configs, whose correction is in the softmax scale)
+        return yarn_mscale(cfg)
     if cfg.factor > 1.0:
         return 0.1 * math.log(cfg.factor) + 1.0
     return 1.0

@@ -67,6 +67,13 @@ class _RollbackRequested(Exception):
         self.fail_step = fail_step
 
 
+# step metrics that ``train.counts`` reports beside the step's input tokens:
+# the picks on held experts (``MoEConfig.held_experts``), a multi-token-
+# prediction module's own loss and the largest error of a hyper-connection
+# residual map's row sums (models/xing4)
+COUNTED_METRICS = ("held_expert_rows", "mtp_loss", "mhc_res_row_err")
+
+
 class _StepWithCounts:
     """The jitted train step (every attribute of it: ``lower``, ``trace``),
     which on a call also hands the recipe what ``train.counts`` reports."""
@@ -76,9 +83,9 @@ class _StepWithCounts:
 
     def __call__(self, state, batch):
         out = self._step(state, batch)
-        held = out[1].get("held_expert_rows")
-        if held is not None:
-            self._recipe._counts_pending = (batch["input_ids"].size, held)
+        counts = {k: out[1][k] for k in COUNTED_METRICS if k in out[1]}
+        if counts:
+            self._recipe._counts_pending = (batch["input_ids"].size, counts)
         return out
 
     def __getattr__(self, name):
@@ -789,16 +796,17 @@ class TrainFinetuneRecipeForNextTokenPrediction:
 
     def _write_counts(self) -> None:
         """``train.counts`` on the profiler's clock: the PREVIOUS step's input
-        tokens and the picks that landed on this device's held experts
-        (``MoEConfig.held_experts``), once that step's metrics are ready.
-        Never a barrier: a step still running keeps its counts for the next
-        group. A model with every expert here writes nothing."""
+        tokens and those of ``COUNTED_METRICS`` its model reports, once that
+        step's metrics are ready. Never a barrier: a step still running keeps
+        its counts for the next group. A model that reports none of them
+        writes nothing."""
         pending = getattr(self, "_counts_pending", None)
-        if pending is None or not pending[1].is_ready():
+        if pending is None or not all(v.is_ready() for v in pending[1].values()):
             return
         self._counts_pending = None
-        with TraceAnnotation("train.counts", tokens=int(pending[0]),
-                             held_expert_rows=int(pending[1])):
+        counts = {k: int(v) if np.issubdtype(v.dtype, np.integer) else float(v)
+                  for k, v in pending[1].items()}
+        with TraceAnnotation("train.counts", tokens=int(pending[0]), **counts):
             pass
 
     def _close_prefetch(self) -> None:

@@ -261,6 +261,14 @@ def build_train_step(
             metrics["expert_load_imbalance_per_layer"] = per_layer
         if "held_expert_rows" in extras_sum:
             metrics["held_expert_rows"] = extras_sum["held_expert_rows"]
+        if "mtp_loss_sum" in extras_sum:
+            # the multi-token-prediction loss alone: a mean over ITS targets
+            metrics["mtp_loss"] = extras_sum["mtp_loss_sum"] / jnp.maximum(
+                extras_sum["mtp_tokens"], 1
+            ).astype(jnp.float32)
+        if "mhc_res_row_err" in extras_sum:
+            # each microbatch's largest, averaged over the microbatches
+            metrics["mhc_res_row_err"] = extras_sum["mhc_res_row_err"] / batch_size(batch)
         if lr_schedule is not None:
             metrics["lr"] = lr_schedule(state.step)
         new_state = TrainState(
@@ -306,6 +314,21 @@ def build_eval_step(
     return lambda state, batch: jitted(state, batch, bound_params)
 
 
+def shift_labels(labels: jnp.ndarray, segment_ids: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """``labels`` [B, S] shifted left once more (position t takes t + 1's
+    target): a multi-token-prediction module's targets. A row's last position
+    and the last position of a packed document (``segment_ids`` change after
+    it) have none and are ignored."""
+    ignore = jnp.full_like(labels[:, :1], -100)
+    shifted = jnp.concatenate([labels[:, 1:], ignore], axis=1)
+    if segment_ids is not None:
+        same = jnp.concatenate(
+            [segment_ids[:, 1:] == segment_ids[:, :-1], jnp.zeros_like(ignore, bool)], axis=1
+        )
+        shifted = jnp.where(same, shifted, -100)
+    return shifted
+
+
 def make_causal_lm_loss(
     model: Any,
     loss: str = "masked_ce",
@@ -336,21 +359,42 @@ def make_causal_lm_loss(
             mesh_ctx = getattr(constrain, "mesh_ctx", None)
             with jax.named_scope("lm_head_ce"):  # head and loss, chunk scan included
                 kernel = model.lm_head(params).astype(hidden.dtype)
-                if loss == "vocab_parallel_ce" and mesh_ctx is not None:
-                    loss_sum, n = L.vocab_parallel_cross_entropy(
-                        hidden, kernel, mb["labels"], mesh_ctx,
+
+                def head_loss(h, labels):
+                    if loss == "vocab_parallel_ce" and mesh_ctx is not None:
+                        return L.vocab_parallel_cross_entropy(
+                            h, kernel, labels, mesh_ctx,
+                            logits_soft_cap=model.config.logits_soft_cap, **loss_kwargs,
+                        )
+                    return L.fused_linear_cross_entropy(
+                        h, kernel, labels,
                         logits_soft_cap=model.config.logits_soft_cap, **loss_kwargs,
                     )
-                else:
-                    loss_sum, n = L.fused_linear_cross_entropy(
-                        hidden, kernel, mb["labels"],
-                        logits_soft_cap=model.config.logits_soft_cap, **loss_kwargs,
-                    )
+
+                loss_sum, n = head_loss(hidden, mb["labels"])
         else:
             out = model(params, mb["input_ids"], constrain=constrain, **kw)
             logits, maux = out if isinstance(out, tuple) else (out, None)
+
+            loss_of_logits = L.build_loss(loss, **loss_kwargs)
+
+            def head_loss(h, labels):
+                return loss_of_logits(h @ model.lm_head(params).astype(h.dtype), labels)
+
             with jax.named_scope("lm_head_ce"):
-                loss_sum, n = L.build_loss(loss, **loss_kwargs)(logits, mb["labels"])
+                loss_sum, n = loss_of_logits(logits, mb["labels"])
+        mtp_hidden = getattr(maux, "mtp_hidden", None)
+        if mtp_hidden is not None:
+            # a second pass of the same head over the module's hidden state,
+            # against the token after the next; each loss is a mean over its
+            # own targets, so the module's sum is weighted to the main count
+            # (the step divides the whole by it)
+            with jax.named_scope("lm_head_ce"):
+                mtp_sum, mtp_n = head_loss(
+                    mtp_hidden, shift_labels(mb["labels"], mb.get("segment_ids")))
+            loss_sum = loss_sum + model.config.mtp_loss_weight * mtp_sum * (
+                n.astype(jnp.float32) / jnp.maximum(mtp_n, 1).astype(jnp.float32)
+            )
         if maux is None:
             return loss_sum, n
         # MoE models return (output, aux). The aux loss is a per-batch mean;
@@ -364,6 +408,10 @@ def make_causal_lm_loss(
         }
         if getattr(maux, "held_expert_rows", None) is not None:
             extras["held_expert_rows"] = maux.held_expert_rows
+        if mtp_hidden is not None:
+            extras["mtp_loss_sum"], extras["mtp_tokens"] = mtp_sum, mtp_n
+        if getattr(maux, "mhc_res_row_err", None) is not None:
+            extras["mhc_res_row_err"] = maux.mhc_res_row_err
         return loss_sum, n, extras
 
     # pipelined models advertise their schedule so the step metrics (and the

@@ -99,4 +99,12 @@ SCOPES = (
     # block; benchmarks/metrics/kda_ms.train.py and kda_chunk_roofline.py read them
     "attn/kda", "attn/kda/kda_conv", "attn/kda/kda_gate", "attn/kda/kda_chunk",
     "attn/kda/kda_norm", "attn/mla",
+    # models/xing4: the hyper-connection residual path of a sublayer, inside
+    # `norm` (where the frozen vocabulary files it), with its segments (the
+    # coefficients with their Sinkhorn rounds, the pre-mix, the post-mix:
+    # ops/hyper_connections.py), and the multi-token-prediction module, an
+    # OUTER scope: its block's ops keep `attn`, `moe/...`, `norm/mhc`
+    # innermost, its projection `mlp`; benchmarks/metrics/mhc_ms.train.py,
+    # mhc_stream_roofline.py and mtp_ms.train.py read them
+    "norm/mhc", "norm/mhc/mhc_coeff", "norm/mhc/mhc_pre", "norm/mhc/mhc_post", "mtp",
 )

@@ -29,9 +29,13 @@ WHOLE = MoEConfig(num_experts=E, num_experts_per_tok=K, moe_intermediate_size=I,
 ACT = jax.nn.silu
 
 
-def _setup():
-    params = init_moe_params(jax.random.key(0), WHOLE, D, jnp.float32)
-    params["router"]["bias"] = 0.05 * jax.random.normal(jax.random.key(1), (E,))
+# the Xing4.0 cell's router: 64 experts, top-4, weights renormalised x 2, in 8 shares of 8
+WHOLE_64 = dataclasses.replace(WHOLE, num_experts=64, route_scale=2.0)
+
+
+def _setup(whole=WHOLE):
+    params = init_moe_params(jax.random.key(0), whole, D, jnp.float32)
+    params["router"]["bias"] = 0.05 * jax.random.normal(jax.random.key(1), (whole.num_experts,))
     x = jax.random.normal(jax.random.key(2), (2, 24, D))
     return params, x
 
@@ -44,16 +48,17 @@ def _share(params, lo, hi):
 
 
 @pytest.mark.parametrize("backend", ["ragged", "ragged_fused"])
-def test_the_shares_add_up_to_the_uncut_layer(backend, monkeypatch):
+@pytest.mark.parametrize("whole", [WHOLE, WHOLE_64], ids=["32-experts", "64-experts-top4-x2"])
+def test_the_shares_add_up_to_the_uncut_layer(backend, whole, monkeypatch):
     monkeypatch.setenv("AUTOMODEL_GMM_INTERPRET", "1")
-    params, x = _setup()
-    whole, aux = moe_block(x, params, WHOLE, ACT, experts_backend="dense")
+    params, x = _setup(whole)
+    out_whole, aux = moe_block(x, params, whole, ACT, experts_backend="dense")
     no_shared = {k: v for k, v in params.items() if k != "shared"}
-    shared = whole - moe_block(x, no_shared, WHOLE, ACT, experts_backend="dense")[0]
-    n = E // SHARES
+    shared = out_whole - moe_block(x, no_shared, whole, ACT, experts_backend="dense")[0]
+    n = whole.num_experts // SHARES
     total, rows = shared, 0
     for s in range(SHARES):
-        cfg = dataclasses.replace(WHOLE, held_experts=(s * n, (s + 1) * n))
+        cfg = dataclasses.replace(whole, held_experts=(s * n, (s + 1) * n))
         out, a = moe_block(x, _share(no_shared, s * n, (s + 1) * n), cfg, ACT,
                            experts_backend=backend)
         total = total + out
@@ -61,7 +66,7 @@ def test_the_shares_add_up_to_the_uncut_layer(backend, monkeypatch):
         assert jnp.array_equal(a.expert_counts, aux.expert_counts)
         rows += int(a.expert_counts[s * n:(s + 1) * n].sum())
     # float32, the same products in another order: 1e-6 of the output's scale
-    assert float(jnp.abs(total - whole).max() / jnp.abs(whole).max()) < 1e-5
+    assert float(jnp.abs(total - out_whole).max() / jnp.abs(out_whole).max()) < 1e-5
     assert rows == x.shape[0] * x.shape[1] * K  # every pick lands on exactly one share
 
 

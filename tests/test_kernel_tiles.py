@@ -119,3 +119,26 @@ WANT = {
 @pytest.mark.parametrize("picker", list(WANT), ids=lambda f: f.__name__.lstrip("_"))
 def test_tiles_of_the_cells_expert_shapes(picker, shape):
     picker(*SHAPES[shape], WANT[picker][shape])
+
+
+# (D, I) -> (tm, ic) of the fused forward, and whether it asks Mosaic for
+# `_VMEM_LIMIT`: the three shapes above and kimi-linear's (2304, 1024) fit the
+# default scoped stack at the tiles they have always had; xing4.0's hidden
+# size of 3584 fits it at no tile (16.5 MB at 256 / 128), so its tiles are
+# picked under `_VMEM_BUDGET` and the call asks for the limit, as the backward
+# kernels do
+FWD = [((2048, 768), (256, 256), False), ((3072, 1536), (256, 128), False),
+       ((2048, 1792), (256, 256), False), ((2304, 1024), (256, 256), False),
+       ((3584, 1024), (256, 512), True)]
+
+
+@pytest.mark.parametrize("shape,want,asks", FWD, ids=[f"{d}x{i}" for (d, i), _, _ in FWD])
+def test_forward_tiles_and_the_vmem_they_ask_for(shape, want, asks):
+    D, I = shape
+    tm, ic = fem._fwd_tiles(D, I)
+    assert (tm, ic) == want
+    _aligned(tm, ic)
+    assert I % ic == 0  # `_col_off(fused, I, ic)`: the fused weight is blocked in place
+    need = fem._fwd_vmem(tm, ic, D)
+    assert need <= (fem._VMEM_BUDGET if asks else fem._FWD_STACK)
+    assert fem._fwd_vmem_limit(tm, ic, D) == ({"vmem_limit_bytes": fem._VMEM_LIMIT} if asks else {})

@@ -117,9 +117,12 @@ def test_every_scope_is_in_the_programs_op_names(programs):
     # (tests/test_serving_recurrent_state.py finds those in its programs)
     assert found - {program_trace.UNSCOPED} == set(program_trace.VOCABULARY)
     # ... and kimi_linear's two mixers (tests/test_kimi_linear.py finds those)
+    # ... and xing4's residual path and its multi-token-prediction module
+    # (tests/test_xing4.py finds those)
     assert set(SCOPES) - set(program_trace.VOCABULARY) == {
         "conv", "kv_write/state_write", "attn/kda", "attn/kda/kda_conv", "attn/kda/kda_gate",
-        "attn/kda/kda_chunk", "attn/kda/kda_norm", "attn/mla"}
+        "attn/kda/kda_chunk", "attn/kda/kda_norm", "attn/mla",
+        "norm/mhc", "norm/mhc/mhc_coeff", "norm/mhc/mhc_pre", "norm/mhc/mhc_post", "mtp"}
 
 
 @pytest.mark.parametrize("program,must_have", [

@@ -269,6 +269,49 @@ def test_fused_linear_ce_is_three_vocabulary_products():
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 1024 * v * 1.05
 
 
+@pytest.mark.parametrize(
+    "n_dev,degrees,B,T",
+    [(1, {}, 1, 8192), (4, {"dp_shard": 4}, 4, 2048), (4, {"dp_shard": 2, "cp": 2}, 2, 4104)],
+    ids=["one_chip_cell_shape", "dp4", "dp2_cp2_rows_padded"],
+)
+def test_hyper_connection_residual_path_lowers_unpadded(n_dev, degrees, B, T):
+    """train-8k-xing4.0-29b-a4b's residual path at its shape, [8192, 4 x 3584]
+    bfloat16, forward and backward through XLA:TPU; and the same kernels on
+    the 2x2 mesh under their shard_map (batch over the data axes, the sequence
+    over cp; 2052 rows a device are padded to a tile): the TPU has one path.
+    The stream is carried flat (ops/hyper_connections.py): as [T, 4, C] its 4
+    rows would be padded to a tile of 16 and every copy of the stream would
+    be four times its size."""
+    from automodel_tpu.ops import hyper_connections as hc
+
+    ctx = _tpu_ctx(n_dev, **degrees)
+    n, D = 4, 3584
+    x = _sds(ctx, (B, T, n * D), jnp.bfloat16, "batch", "seq", None)
+    y = _sds(ctx, (B, T, D), jnp.bfloat16, "batch", "seq", None)
+    phi = _sds(ctx, (n * D, hc.n_coefficients(n)), jnp.bfloat16, None, None)
+    b = _sds(ctx, (hc.n_coefficients(n),), jnp.float32, None)
+    alpha = _sds(ctx, (3,), jnp.float32, None)
+
+    def loss(x, y, phi, b, alpha):
+        co = hc.coefficients(x, phi, b, alpha, n=n, norm_eps=1e-6, sinkhorn_iters=20,
+                             sinkhorn_eps=1e-6, clamp=(-30.0, 30.0))
+        u = hc.pre_mix(x, co.pre)
+        out = hc.post_mix(x, y + u, co.post, co.res, platform="tpu", mesh_ctx=ctx)
+        return out.astype(jnp.float32).sum() + hc.res_row_error(co.res)
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(x, y, phi, b, alpha).compile()
+    # the post-mix's two kernels (its forward is dead here: the loss needs only
+    # the backward's outputs, which read x, y and the coefficients)
+    assert 1 <= mosaic_calls(compiled) <= 2
+    stream = B * T * n * D * 2 // n_dev
+    m = compiled.memory_analysis()
+    # out: the stream's gradient (+ the small ones); temporaries: a few
+    # float32 copies of the stream at most. A padded layout alone is 4 x.
+    assert m.output_size_in_bytes < 1.3 * stream
+    assert m.temp_size_in_bytes < 6 * stream, m.temp_size_in_bytes / stream
+
+
 # experts, top-k, expert width, hidden, x [B, S]
 _SMALL_MOE = 8, 2, 256, 256, (4, 128)
 
@@ -293,14 +336,22 @@ _SMALL_MOE = 8, 2, 256, 256, (4, 128)
         # block) need the scoped VMEM the kernels ask for, and Mosaic alone
         # says whether they got it
         (1, {}, "ragged_fused", (128, 8, 768, 2048, (2, 4096)), True),
+        # train-8k-xing4.0-29b-a4b's held-expert layer at its published widths
+        # (8 of 64 experts, top-4, a buffer of 8,192 rows): at a hidden size
+        # of 3584 no tile of the forward fits the default scoped stack, and
+        # Mosaic alone says whether the limit it asks for instead is granted
+        (1, {}, "a2a_fused", (64, 4, 1024, 3584, (1, 8192),
+                              {"held_experts": (0, 8), "held_capacity_factor": 2.0}), True),
     ],
     ids=["1chip-ragged", "dp4-ragged", "ep4-a2a_fused", "1chip-ragged_fused",
-         "1chip-ragged_fused-minimax-decode", "1chip-ragged_fused-30b-a3b-train"],
+         "1chip-ragged_fused-minimax-decode", "1chip-ragged_fused-30b-a3b-train",
+         "1chip-a2a_fused-xing4-held-train"],
 )
 def test_expert_kernels_fwd_bwd_lower(n, degrees, backend, shape, grad):
     ctx = _tpu_ctx(n, **degrees)
-    E, K, I, D, tokens = shape
-    cfg = MoEConfig(num_experts=E, num_experts_per_tok=K, moe_intermediate_size=I)
+    E, K, I, D, tokens, *extra = shape
+    cfg = MoEConfig(num_experts=E, num_experts_per_tok=K, moe_intermediate_size=I,
+                    **(extra[0] if extra else {}))
     mp = jax.eval_shape(
         lambda: init_moe_params(jax.random.key(0), cfg, D, jnp.bfloat16)
     )
