@@ -68,8 +68,23 @@ chunk's boundary (``[B, H, S/C, dv, dk]`` float32) and no chunk's
 intermediates; the backward walks the chunks in reverse, carries ``dS`` in
 VMEM, and for each chunk recomputes the body from its boundary state and
 differentiates THAT ONE BODY (``jax.vjp`` of ``_chunk_body`` inside the
-kernel: the body is matmuls and elementwise ops, so its transpose is too).
-Nothing differentiates through the scan over chunks.
+kernel: the body is matmuls and elementwise ops, so its transpose is too)
+EXCEPT the triangular inverse, which brings its own cotangent
+(``_unit_lower_inverse``): with ``T = (I + N)^-1``, ``dN = -T^T dT T^T``, two
+products in the forward's arithmetic on the ``T`` the body holds anyway, where
+the transpose of the block-merge construction runs each of its twelve products
+backwards (and rounds every cotangent to bfloat16 on the way). Nothing
+differentiates through the scan over chunks.
+
+**What a chunk-head costs** is its count of 128 x 128 x 128 products on the
+MXU, 39 to 44 ns each (``dot_general`` in ``jax.make_jaxpr`` at C = dk = dv =
+128, bfloat16; ``tests/test_delta_rule.py`` pins them): forward **52** (36 the
+inverse's twelve in three passes, 13 the rule's, 3 the cumulative sum);
+backward **87** = those 52 recomputed + 26 the rule's transposed + 3 the
+cumulative sum's + 6 the identity's two. With ``jax.vjp`` through the inverse
+it was 153 (72 for the twelve transposed). Float32 operands: 28 and 59 (81).
+My chip run, PR 44, 16,384 tokens x 32 heads: ``delta_rule_fwd`` 8.85 ms a
+call, the backward 24.77 -> 15.55 (14.2 with the identity's products left out).
 
 On a TPU both passes are Pallas kernels (grid: batch x groups of
 ``_HEADS_PER_STEP`` heads x steps of ``_CHUNKS_PER_STEP`` chunks, the states
@@ -96,16 +111,20 @@ F32 = jnp.float32
 G_MIN = -10.0  # per token and channel; see the module docstring
 SUB = 16  # rows that share one reference point
 # Timed at 16,384 tokens x 32 heads of 128 x 128, bfloat16, a per-channel decay,
-# from the raw flat operands to ``o`` and the five gradients (my chip run, PR 42;
-# forward / forward + backward ms a call; "h" operands on sublanes, see
-# ``_kernel_call``): chunks of 128, 2 / 4 / 8 heads a step: 9.50 / 34.70,
-# 9.11 / 34.16, 8.95 / 33.88; 4 chunks a step (4 heads): 9.08 / 34.11; chunks
-# of 64 (4 heads): 13.13 / 47.86. The same body under `lax.scan` (XLA, chunks
-# of 64): 14.36 / 56.35. PR 41's operator with its operands formed outside
-# (reshape to heads, two l2 norms, `beta k`, `beta v`, the clamp, flatten) from
-# the same arrays: 19.40 / 59.24 at chunks of 128, 23.41 / 73.18 at 64; the
-# kernels alone read 8.74 -> 8.95 (forward) and 23.62 -> 24.56 (backward) ms a
-# call in the cell's trace: the prologue costs 2.4 % and 4.0 % of them.
+# from the raw flat operands to ``o`` and the five gradients (forward / forward
+# + backward ms a call; "h" operands on lanes, see ``_kernel_call``). My chip
+# run, PR 44 (the inverse's backward by its identity, 87 products a chunk-head):
+# chunks of 128, 2 chunks a step, 2 / 4 / 8 heads a step: 9.08 / 24.74,
+# **8.86 / 24.40**, 8.74 / 24.90 (a step runs the forward twice under `remat:
+# full`: 4 heads 33.3, 8 heads 33.7); 4 heads, 1 / 4 chunks a step: 8.85 /
+# 24.43, 8.87 / 24.49; chunks of 64: 12.79 / 33.88. Before it (153 products; my
+# chip run, PR 42, "h" on sublanes): 2 / 4 / 8 heads 9.50 / 34.70, 9.11 / 34.16,
+# 8.95 / 33.88; 4 chunks a step 9.08 / 34.11; chunks of 64 13.13 / 47.86; the
+# same body under `lax.scan` (XLA, chunks of 64): 14.36 / 56.35. PR 41's
+# operator with its operands formed outside (reshape to heads, two l2 norms,
+# `beta k`, `beta v`, the clamp, flatten) from the same arrays: 19.40 / 59.24 at
+# chunks of 128, 23.41 / 73.18 at 64; the prologue costs the kernels 2.4 % and
+# 4.0 % (8.74 -> 8.95 and 23.62 -> 24.56 ms a call in the cell's trace, PR 42).
 _CHUNKS_PER_STEP = 2  # chunks a grid step walks
 _HEADS_PER_STEP = 4  # heads a grid step walks: independent chains for the scheduler to interleave
 _VMEM_LIMIT = 64 * 1024 * 1024  # a v5e core has 128 MiB; the default scoped limit is 16
@@ -128,21 +147,28 @@ def _dot(a, b, dims, precision=None):
                            preferred_element_type=F32)
 
 
-def _mm_split(a, b):
+def _mm_split(a, b, dims=((1,), (0,))):
     """float32 ``a @ b`` from three bfloat16 passes on the MXU (hi/lo split:
     hi.hi + hi.lo + lo.hi, 2^-16 relative; the compiler's ``HIGHEST`` takes
-    six)."""
-    dims = ((1,), (0,))
+    six). ``dims``: the contracting axes, for a transposed operand."""
     bf = jnp.bfloat16
     ah, bh = a.astype(bf), b.astype(bf)
     al, bl = (a - ah.astype(F32)).astype(bf), (b - bh.astype(F32)).astype(bf)
     return _dot(ah, bh, dims) + _dot(ah, bl, dims) + _dot(al, bh, dims)
 
 
+def _inverse_mm(exact: bool):
+    """The product of the triangular inverse and of its cotangent: float32
+    (``HIGHEST``) for float32 operands, three bfloat16 passes otherwise."""
+    if exact:
+        return lambda a, b, dims=((1,), (0,)): _dot(a, b, dims, lax.Precision.HIGHEST)
+    return _mm_split
+
+
 _INVERSE_BASE = 8  # diagonal blocks inverted by their (short) Neumann product
 
 
-def _unit_lower_inverse(n: jnp.ndarray, exact: bool) -> jnp.ndarray:
+def _block_merge_inverse(n: jnp.ndarray, exact: bool) -> jnp.ndarray:
     """(I + n)^-1 for a strictly lower triangular float32 ``n`` [C, C], from
     products only. The 8 x 8 diagonal blocks by ``(I - n)(I + n^2)(I + n^4)``
     (nilpotent: exact), then pairs of blocks merged level by level with
@@ -158,7 +184,7 @@ def _unit_lower_inverse(n: jnp.ndarray, exact: bool) -> jnp.ndarray:
     16,384 tokens x 32 heads, chunks of 64: six passes 74.1, three 62.9, one
     56.2 with 2^-9 compounding over the products)."""
     c = n.shape[0]
-    mm = (lambda a, b: _dot(a, b, ((1,), (0,)), lax.Precision.HIGHEST)) if exact else _mm_split
+    mm = _inverse_mm(exact)
     row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
     col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
     same_block = lambda b: (row // b) == (col // b)
@@ -175,6 +201,35 @@ def _unit_lower_inverse(n: jnp.ndarray, exact: bool) -> jnp.ndarray:
         x = x - mm(mm(x, lower_left), x)
         b *= 2
     return x
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _unit_lower_inverse(n: jnp.ndarray, exact: bool) -> jnp.ndarray:
+    """``T = (I + n)^-1`` by ``_block_merge_inverse`` (12 products at C = 128,
+    36 ``dot_general`` in three passes), with the inverse's own cotangent in
+    place of the construction's transpose: ``d(T) = -T d(n) T``, so
+
+        dn = -T^T dT T^T
+
+    two products in the forward's own arithmetic (``_inverse_mm``: 6
+    ``dot_general`` in three passes, 2 at ``HIGHEST``) where ``jax.vjp`` of the
+    construction transposes each of its twelve (72, every transposed pass with
+    its cotangent rounded to bfloat16 on the way in). The residual is ``T``,
+    which the caller holds anyway. Against float64 the identity reads 4.8e-6
+    to 1.7e-5 of the largest entry where the transposed construction reads
+    3.1e-3 to 3.1e-2 (``tests/test_delta_rule.py``; float32 operands: both at
+    float32's rounding). The caller masks ``n`` to its strictly lower entries,
+    and with it ``dn``."""
+    return _block_merge_inverse(n, exact)
+
+
+def _unit_lower_inverse_bwd(exact, t, dt):
+    mm = _inverse_mm(exact)
+    return (-mm(mm(t, dt, ((0,), (0,))), t, ((1,), (1,))),)
+
+
+_unit_lower_inverse.defvjp(lambda n, exact: (_block_merge_inverse(n, exact),) * 2,
+                           _unit_lower_inverse_bwd)
 
 
 def _tri_sum(x: jnp.ndarray, reverse: bool) -> jnp.ndarray:

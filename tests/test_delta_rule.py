@@ -5,6 +5,7 @@ kernels in interpret mode and through the same body under ``lax.scan``."""
 import sys
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 import jax
@@ -14,7 +15,16 @@ ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from automodel_tpu.ops.delta_rule import G_MIN, _chunk_rule, chunked_delta_rule, l2norm
+from automodel_tpu.ops.delta_rule import (
+    G_MIN,
+    _block_merge_inverse,
+    _chunk_body,
+    _chunk_grads,
+    _chunk_rule,
+    _unit_lower_inverse,
+    chunked_delta_rule,
+    l2norm,
+)
 
 B, H, DK, DV = 2, 2, 32, 16
 F32 = jnp.float32
@@ -262,3 +272,78 @@ def test_a_run_of_equal_keys_written_at_full_strength(chunk):
     k = jnp.broadcast_to(heads(k)[:, :1], heads(k).shape).reshape(k.shape)
     args = (q, k, v, jnp.full_like(g, -1e-3), jnp.ones_like(beta))
     check(args, w, TOL_FAST_DECAY, interpret=True, chunk_size=chunk)
+
+
+# -- the triangular inverse's own cotangent ---------------------------------------------
+
+
+def inverse_operands(case, c):
+    """-> (n [C, C] float32 under ``mask``, a float32 cotangent of T, mask):
+    ``random``: entries uniform in +-1/4; ``equal_keys``: all ones (the run of
+    equal keys written at full strength); ``packed``: ``beta_t k_t . k_j`` of
+    unit keys that share a direction, inside the documents of ``packed``."""
+    rng = np.random.default_rng(c)
+    mask = np.tril(np.ones((c, c), bool), -1)
+    if case == "random":
+        n = rng.uniform(-0.25, 0.25, (c, c))
+    elif case == "equal_keys":
+        n = np.ones((c, c))
+    else:
+        k = rng.normal(size=(c, DK)) + 2.0 * rng.normal(size=(1, DK))
+        k /= np.linalg.norm(k, axis=1, keepdims=True)
+        n = (k @ k.T) / (1.0 + np.exp(-rng.normal(size=(c, 1))))
+        seg = np.asarray(packed(c)[1][0])
+        mask &= seg[:, None] == seg[None, :]
+    return np.where(mask, n, 0.0).astype(np.float32), rng.normal(size=(c, c)).astype(np.float32), mask
+
+
+# Read (all six operands a mode, error on the masked entries over the largest):
+# three bfloat16 passes: the identity 4.8e-6 to 1.7e-5, ``jax.vjp`` of the
+# construction 3.1e-3 to 3.1e-2 (every transposed pass takes its cotangent
+# rounded to bfloat16); float32: both at float32's rounding, the identity 6.6e-8
+# to 3.4e-7, the construction 1.4e-7 to 1.4e-6, either ahead by up to 1.2e-7
+# (the identity sums C terms an entry, a merge's block-sparse factors fewer),
+# so "no further" is held to 2^-20 of the largest entry there.
+@pytest.mark.parametrize("case", ["random", "equal_keys", "packed"])
+@pytest.mark.parametrize("c", [64, 128])
+@pytest.mark.parametrize("exact", [False, True], ids=["split", "exact"])
+def test_the_inverse_is_differentiated_by_its_identity(exact, c, case):
+    """``dn = -T^T dT T^T`` in the forward's own arithmetic against float64, and
+    against what ``jax.vjp`` of the block-merge construction gave before it."""
+    n, dt, mask = inverse_operands(case, c)
+    t = np.linalg.inv(np.eye(c) + n.astype(np.float64))
+    want = np.where(mask, -t.T @ dt.astype(np.float64) @ t.T, 0.0)
+
+    def error(inverse):
+        out, vjp = jax.vjp(lambda x: inverse(x, exact), jnp.asarray(n))
+        got = np.where(mask, np.asarray(vjp(jnp.asarray(dt))[0], np.float64), 0.0)
+        return out, float(np.abs(got - want).max() / np.abs(want).max())
+
+    (out, new), (out_plain, old) = error(_unit_lower_inverse), error(_block_merge_inverse)
+    assert bool((out == out_plain).all())  # the forward IS the construction
+    assert new < (1e-5 if exact else 1e-4)
+    assert new <= (max(old, 2.0**-20) if exact else old)
+
+
+def dot_generals(jaxpr) -> int:
+    from jax._src import core
+
+    return sum((eqn.primitive.name == "dot_general")
+               + sum(dot_generals(sub) for sub in core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("cd,forward,backward", [(jnp.bfloat16, 52, 87), (F32, 28, 59)],
+                         ids=["bfloat16", "float32"])
+def test_products_a_chunk_head(cd, forward, backward):
+    """The kernels are bound by their count of 128 x 128 x 128 products (module
+    docstring): at the cell's shapes a chunk-head is 52 forward (36 the
+    inverse's twelve in three passes) and 87 backward: the body recomputed, its
+    transpose, and 6 for the inverse's identity. ``jax.vjp`` let back into the
+    inverse reads 153 (float32 operands: 81)."""
+    c, dk, dv = 128, 128, 128
+    st0, rows = jnp.zeros((dv, dk), F32), jnp.zeros((c, dk), cd)
+    args = (st0, rows, rows, jnp.zeros((c, dv), cd), jnp.zeros((c, dk), F32), jnp.zeros((c, 1), F32))
+    assert dot_generals(jax.make_jaxpr(_chunk_body)(*args).jaxpr) == forward
+    grads = lambda *a: _chunk_grads(*a[:6], None, None, *a[6:])
+    assert dot_generals(jax.make_jaxpr(grads)(*args, jnp.zeros((c, dv), F32), st0).jaxpr) <= backward
