@@ -51,9 +51,14 @@ class BackendConfig:
     fake_balanced_gate: bool = False  # deterministic routing for benchmarks
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
-    # none | full | selective | full_save_dispatch (full remat but the MoE
-    # sort permutations survive — skips re-argsorting T*K picks per layer
-    # in the recompute pass; memory cost 2 int32 [T*K] leaves per layer)
+    # What a layer keeps for its backward (models/common/stacking.remat_wrap):
+    # none: every residual. full: the layer's input; recomputes everything
+    # XLA computes and never re-runs the attention kernel (splash's output
+    # and logsumexp stay: S x N x (2 Dv + 4) bytes an attention layer and
+    # sequence). full_save_dispatch: full, and the MoE sort permutations
+    # (skips re-argsorting T*K picks per layer in the recompute pass; 2 to 4
+    # int32 [T*K] leaves per layer). selective: full, and every product with no
+    # batch dimension (the projections' and the MLP's outputs).
     remat: str = "none"
     scan_layers: bool = True
     # fp8 matmul recipe for dense projections (e4m3 fwd / e5m2 grads,

@@ -20,26 +20,48 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from automodel_tpu.moe.experts import SORT_CHECKPOINT_NAMES
+from automodel_tpu.ops.attention import SPLASH_RESIDUAL_NAME
+
+
+# What survives a checkpoint under each recomputing policy, by the name it
+# was given where it is computed: splash's ``out`` and ``logsumexp`` under
+# every one, the MoE sort permutations under full_save_dispatch besides.
+_KEPT_NAMES = {
+    "full": (SPLASH_RESIDUAL_NAME,),
+    "full_save_dispatch": (*SORT_CHECKPOINT_NAMES, SPLASH_RESIDUAL_NAME),
+    "selective": (SPLASH_RESIDUAL_NAME,),
+}
+
 
 def remat_wrap(f: Callable, remat: str) -> Callable:
-    if remat == "full":
-        return jax.checkpoint(f, policy=jax.checkpoint_policies.nothing_saveable)
-    if remat == "full_save_dispatch":
-        # full remat, but the tagged MoE sort permutations survive the
-        # boundary (moe/experts.py _name_ckpt) — the recompute pass skips
-        # the per-layer argsorts over T*K picks
-        return jax.checkpoint(
-            f,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                "moe_sort_order", "moe_sort_inv", "moe_sort_order_inv",
-                "moe_sort_inv2",
-            ),
-        )
+    """``jax.checkpoint`` over one layer, by ``BackendConfig.remat``:
+
+    - ``none``: ``f`` as it is; autodiff keeps every residual.
+    - ``full``: recomputes everything XLA computes in the backward; never
+      re-runs the attention kernel. Kept a layer: its input, and splash's
+      bfloat16 ``out`` and float32 ``logsumexp``, ``S x N x (2 Dv + 4)``
+      bytes an attention layer and sequence (68 MB at 8,192 tokens x 32
+      heads x 128: ``N Dv / H`` of the layer's input at every length, while
+      the forward kernel it spares grows with the length squared).
+    - ``full_save_dispatch``: ``full``, and the MoE sort permutations
+      (moe/experts.py _name_ckpt; 2 to 4 int32 ``[T*K]`` arrays a layer),
+      so the recompute skips the argsorts over the T*K picks.
+    - ``selective``: keeps every product with no batch dimension as well
+      (the projections' and MLP's outputs); recomputes the elementwise ops
+      between them.
+
+    Off the TPU ``attn: flash`` falls back to sdpa, nothing carries the
+    name, and each policy keeps what it kept without it."""
+    if remat not in _KEPT_NAMES:
+        return f
+    policies = jax.checkpoint_policies
+    policy = policies.save_only_these_names(*_KEPT_NAMES[remat])
     if remat == "selective":
-        return jax.checkpoint(
-            f, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        policy = policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable, policy
         )
-    return f
+    return jax.checkpoint(f, policy=policy)
 
 
 # cap for the aperiodic P == num_layers fallback in _flag_period: beyond

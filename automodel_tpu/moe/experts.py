@@ -167,12 +167,20 @@ def gspmd_experts(
         return out.astype(x.dtype)
 
 
+# every tag _name_ckpt gives; models/common/stacking.remat_wrap keeps them
+# under remat='full_save_dispatch'
+SORT_CHECKPOINT_NAMES = (
+    "moe_sort_order", "moe_sort_inv", "moe_sort_order_inv", "moe_sort_inv2",
+)
+
+
 def _name_ckpt(x: jnp.ndarray, name: str) -> jnp.ndarray:
     """checkpoint_name tag: under remat='full_save_dispatch' these values
     are SAVED across the remat boundary (policy save_only_these_names), so
     the recompute pass skips re-argsorting the T·K picks."""
     from jax.ad_checkpoint import checkpoint_name
 
+    assert name in SORT_CHECKPOINT_NAMES, name  # a tag no policy keeps
     return checkpoint_name(x, name)
 
 

@@ -185,6 +185,13 @@ def _splash_blocks(head_dim: int, window: Optional[int]) -> tuple[int, int]:
     return 512, 512
 
 
+# The name splash's ``out`` and ``logsumexp`` carry inside its custom_vjp's
+# forward. Every recomputing policy of models/common/stacking.remat_wrap
+# keeps it, so a layer's backward reads the two arrays instead of running
+# the forward kernel a second time.
+SPLASH_RESIDUAL_NAME = "splash_residuals"
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -250,6 +257,7 @@ def _splash_flash(
         head_shards=1,
         q_seq_shards=1,
         attn_logits_soft_cap=logits_soft_cap,
+        residual_checkpoint_name=SPLASH_RESIDUAL_NAME,
         interpret=interpret,
     )
     seg = (
