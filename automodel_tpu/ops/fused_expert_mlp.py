@@ -20,8 +20,8 @@ whose 512 rows touch 110 of 256 experts streams those 110 experts' weights,
 not 258 x one expert's (PERF.md, PR 34). `work_units` counts both for the
 serving engine's ``serve.counts``.
 
-Backward motivation (docs/history/PROFILE_MOE_r05.md): the r5 backward composed generic
-``_tgmm``/transpose-GEMM calls and gave the forward win back (34.40 ms
+Backward motivation (docs/history/PROFILE_MOE_r05.md): a backward composed
+of generic ``_tgmm``/transpose-GEMM calls gives the forward win back (34.40 ms
 fused FWD+BWD vs 33.53 unfused; gmm2-class tiles ran 84.3 TFLOP/s vs
 gmm1's 107.0). The backward here is three purpose-tiled kernels that fold
 the dgate·dup activation-backward elementwise chain (and the sentinel-tail
@@ -61,7 +61,6 @@ capability: the fused SwiGLU+GEMM epilogues TE/DeepEP provide on GPU).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -765,8 +764,8 @@ def _bwd_dwd_kernel(wg, wt, ws, we, g_ref, u_ref, dy_ref, dwd_ref, *rest,
             act_kind, limit,
         )
         mid = jnp.where(mask, mid, 0.0)
-        # dy's sentinel tail is masked here, in-kernel — the external dy_m
-        # select the composed backward paid per [M, D] is gone
+        # dy's sentinel tail is masked here, in-kernel: no [M, D] select
+        # outside the kernel
         dy = jnp.where(mask, dy_ref[...], jnp.zeros_like(dy_ref))
         acc[...] += jax.lax.dot_general(
             mid.astype(dy_ref.dtype), dy, (((0,), (0,)), ((), ())),
@@ -940,12 +939,6 @@ def _bwd_dx(g, u, dmid, gate, up, group_sizes, interpret, act_kind, limit):
     return out[:M, :D]
 
 
-def _fused_bwd_enabled() -> bool:
-    """AUTOMODEL_FUSED_BWD=0 falls back to the r5 composed-tgmm backward —
-    a safety valve for a chip where the purpose-tiled kernels regress."""
-    return os.environ.get("AUTOMODEL_FUSED_BWD", "1") != "0"
-
-
 def _vjp_bwd(act_kind, limit, platform, interpret, res, dy):
     from automodel_tpu.ops.grouped_matmul import (
         _match_vma,
@@ -971,26 +964,12 @@ def _vjp_bwd(act_kind, limit, platform, interpret, res, dy):
             mv(dgb, gb), mv(dub, ub), mv(ddb, db),
         )
 
-    if not _fused_bwd_enabled():
-        if up is None:
-            # the A/B baseline keeps its two-array form: split the fused
-            # residual for it and join its two weight gradients
-            halves = tuple(jnp.split(gate, 2, axis=-1))
-            res = (lhs, *halves, down, group_sizes, gb, ub, db)
-        out = _vjp_bwd_composed(
-            act_kind, limit, platform, interpret, res, dy, mv
-        )
-        if up is None:
-            out = (out[0], jnp.concatenate(out[1:3], axis=-1), None) + out[3:]
-        return out
-
     # purpose-tiled manual backward: recompute the cheap gate_up GEMMs
     # (g, u) and the dmid transpose GEMM, then run the three fused kernels.
-    # vs the r5 composed backward this never materializes mid/dg/du (or
-    # their masked copies), reads lhs once for both weight grads, and folds
-    # the sentinel-tail dout mask + the bias-grad row sums in-kernel:
-    # 6 grouped passes total vs 8 + five [M, N]-sized selects/elementwise
-    # round trips. With the fused weight (up=None) g and u are ONE
+    # mid/dg/du (and masked copies of them) never materialize, lhs is read
+    # once for both weight grads, and the sentinel-tail dout mask + the
+    # bias-grad row sums are folded in-kernel: 6 grouped passes in all.
+    # With the fused weight (up=None) g and u are ONE
     # [M, 2I] product of one pass over lhs, and the three kernels block
     # their g/u/gate/up operands out of the fused arrays in place.
     kw = dict(platform=platform, interpret=interpret)
@@ -1048,74 +1027,6 @@ def _vjp_bwd(act_kind, limit, platform, interpret, res, dy):
         mv(dgb.astype(gb.dtype), gb) if gb is not None else None,
         mv(dub.astype(ub.dtype), ub) if ub is not None else None,
         mv(ddb.astype(db.dtype), db) if db is not None else None,
-    )
-
-
-def _vjp_bwd_composed(act_kind, limit, platform, interpret, res, dy, mv):
-    """The r5 manual backward: generic _tgmm/ragged_dot composition with
-    external tail masks. Kept verbatim behind AUTOMODEL_FUSED_BWD=0 as the
-    kernel-bench A/B baseline."""
-    from automodel_tpu.ops.grouped_matmul import _tgmm
-
-    lhs, gate, up, down, group_sizes, gb, ub, db = res
-    kw = dict(platform=platform, interpret=interpret)
-    M = lhs.shape[0]
-    G = gate.shape[0]
-    g = ragged_dot(lhs, gate, group_sizes, **kw)
-    u = ragged_dot(lhs, up, group_sizes, **kw)
-    # rows past sum(group_sizes) (the a2a sentinel tail) are uninitialized
-    # in every ragged_dot/_tgmm output AND in the a2a cotangents (dy). Zero
-    # one-hot rows and the _tgmm kernel's in-tile lhs mask both rely on
-    # 0·x = 0 with FINITE x — NaN/Inf garbage survives them (0·NaN = NaN),
-    # so every contraction that reduces over rows (seg_sum, and the dout
-    # operand of each _tgmm) gets an explicit zero-mask first. The mask is
-    # one [M, 1] compare broadcast into the selects — backward-only cost.
-    bounds = jnp.cumsum(group_sizes.astype(jnp.int32))
-    valid = (jnp.arange(M, dtype=jnp.int32) < bounds[-1])[:, None]
-    has_bias = gb is not None or ub is not None or db is not None
-    if has_bias:
-        row_g = jnp.searchsorted(
-            bounds, jnp.arange(M, dtype=jnp.int32), side="right"
-        )
-        row_gc = jnp.minimum(row_g, G - 1)
-        onehot = jax.nn.one_hot(row_g, G, dtype=lhs.dtype)  # [M, G]
-    if gb is not None:
-        g = g + jnp.where(valid, gb.astype(g.dtype)[row_gc], 0)
-    if ub is not None:
-        u = u + jnp.where(valid, ub.astype(u.dtype)[row_gc], 0)
-
-    mid, act_vjp = jax.vjp(
-        lambda g_, u_: _act_fn(g_, u_, act_kind, limit), g, u
-    )
-    dy_m = jnp.where(valid, dy, 0)
-    dmid = ragged_dot(dy, down, group_sizes, transpose_rhs=True, **kw)
-    dWd = _tgmm(mid, dy_m, group_sizes, interpret=interpret)
-    dg_, du_ = act_vjp(dmid)
-    dg_m = jnp.where(valid, dg_, 0)
-    du_m = jnp.where(valid, du_, 0)
-    dlhs = (
-        ragged_dot(dg_, gate, group_sizes, transpose_rhs=True, **kw)
-        + ragged_dot(du_, up, group_sizes, transpose_rhs=True, **kw)
-    )
-    dWg = _tgmm(lhs, dg_m, group_sizes, interpret=interpret)
-    dWu = _tgmm(lhs, du_m, group_sizes, interpret=interpret)
-
-    def seg_sum(ct):  # [M, N] (tail pre-masked) → per-expert sums [G, N]
-        return jax.lax.dot_general(
-            onehot, ct, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    dgb = seg_sum(dg_m).astype(gb.dtype) if gb is not None else None
-    dub = seg_sum(du_m).astype(ub.dtype) if ub is not None else None
-    ddb = seg_sum(dy_m).astype(db.dtype) if db is not None else None
-    return (
-        mv(dlhs.astype(lhs.dtype), lhs),
-        mv(dWg.astype(gate.dtype), gate),
-        mv(dWu.astype(up.dtype), up),
-        mv(dWd.astype(down.dtype), down),
-        None,
-        mv(dgb, gb), mv(dub, ub), mv(ddb, db),
     )
 
 

@@ -23,10 +23,14 @@ _cpus = jax.devices("cpu")
 
 import pytest  # noqa: E402
 
-# per-test wall budget for the tier-1 (non-slow) suite: the whole suite
-# must fit a 870s standalone single-CPU window, so one runaway non-slow
-# test is a CI outage, not a slow test. Anything that legitimately needs
-# longer belongs behind `-m slow` (multi-subprocess elasticity e2es are).
+# per-test wall budget for the tier-1 (non-slow) suite. The driver gives the
+# whole suite 1,470 s over six workers (`-n 6 --dist loadfile`) and a cut run
+# is a refused PR; the suite is kept near 1,000 s on an idle machine (PR 46),
+# so one runaway non-slow test is a CI outage, not a slow test. 180 s a case,
+# written for tests that run in this process. A case that is one subprocess
+# compiling whole programs into a cold cache (a cell's rehearsal) states its
+# own with `@pytest.mark.budget(seconds)`; anything else that legitimately
+# needs longer belongs behind `-m slow` (multi-subprocess elasticity e2es are).
 TIER1_TEST_BUDGET_S = float(os.environ.get("AUTOMODEL_TEST_BUDGET_S", "180"))
 
 
@@ -34,18 +38,21 @@ TIER1_TEST_BUDGET_S = float(os.environ.get("AUTOMODEL_TEST_BUDGET_S", "180"))
 def pytest_runtest_makereport(item, call):
     outcome = yield
     report = outcome.get_result()
+    own = item.get_closest_marker("budget")
+    budget = float(own.args[0]) if own else TIER1_TEST_BUDGET_S
     if (
         report.when == "call"
         and report.passed
         and item.get_closest_marker("slow") is None
-        and report.duration > TIER1_TEST_BUDGET_S
+        and report.duration > budget
     ):
         report.outcome = "failed"
         report.longrepr = (
-            f"{item.nodeid} took {report.duration:.1f}s — over the "
-            f"{TIER1_TEST_BUDGET_S:.0f}s tier-1 per-test budget "
-            "(AUTOMODEL_TEST_BUDGET_S). Mark it @pytest.mark.slow or make "
-            "it fit: the whole non-slow suite must fit one 870s window."
+            f"{item.nodeid} took {report.duration:.1f}s — over its "
+            f"{budget:.0f}s tier-1 budget (180 s a case unless the test is "
+            "marked `budget(seconds)`; AUTOMODEL_TEST_BUDGET_S moves the "
+            "default). Mark it @pytest.mark.slow or make it fit: the whole "
+            "non-slow suite has 1,470 s at the driver."
         )
 
 

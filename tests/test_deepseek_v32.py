@@ -170,14 +170,14 @@ def test_adapter_round_trip():
 
 
 def test_train_step_learns():
-    from automodel_tpu.optim.builders import build_optimizer
+    from automodel_tpu.optim.builders import build_optimizer, init_opt_state
     from automodel_tpu.training.train_state import TrainState
     from automodel_tpu.training.train_step import build_train_step, make_causal_lm_loss
 
     auto = _build()
     loss_fn = make_causal_lm_loss(auto.model)
     opt = build_optimizer(name="adamw", lr=5e-3)
-    state = TrainState.create(auto.params, jax.jit(opt.init)(auto.params))
+    state = TrainState.create(auto.params, init_opt_state(opt, auto.params, auto.mesh_ctx))
     step = build_train_step(loss_fn, opt)
     ids = np.random.default_rng(3).integers(0, 128, size=(1, 2, 12)).astype(np.int32)
     batch = {"input_ids": jnp.asarray(ids), "labels": jnp.asarray(ids)}

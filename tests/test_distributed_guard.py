@@ -588,8 +588,9 @@ def test_e2e_watchdog_catches_injected_hang(tmp_path, devices8, monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def _clean_env():
-    env = dict(os.environ)
+def _clean_env(tmp_path):
+    # a compile cache of the test's own, as in tests/test_goodput.py
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
     for k in ("XLA_FLAGS", "JAX_PLATFORMS", "JAX_COORDINATOR_ADDRESS",
               "JAX_NUM_PROCESSES", "JAX_PROCESS_ID", fi.ENV_VAR):
         env.pop(k, None)
@@ -640,7 +641,7 @@ def test_hang_subprocess_requeue_exit_with_evidence(tmp_path):
 
     out = subprocess.run(
         [sys.executable, _WORKER, "finetune", "llm", "-c", str(cfg_path)],
-        env=_clean_env(), capture_output=True, text=True, timeout=500,
+        env=_clean_env(tmp_path), capture_output=True, text=True, timeout=500,
     )
     # detected within the adaptive deadline → hard exit with the requeue
     # code (a committed checkpoint exists to resume from)

@@ -38,7 +38,7 @@ def test_fp8_dot_grads_flow():
 def test_llama_trains_with_fp8(devices8):
     from automodel_tpu import auto_model
     from automodel_tpu.data.loader import place_batch
-    from automodel_tpu.optim.builders import build_optimizer
+    from automodel_tpu.optim.builders import build_optimizer, init_opt_state
     from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
     from automodel_tpu.training.train_state import TrainState
     from automodel_tpu.training.train_step import build_train_step, make_causal_lm_loss
@@ -57,7 +57,7 @@ def test_llama_trains_with_fp8(devices8):
         seed=0,
     )
     opt = build_optimizer(name="adamw", lr=5e-3, grad_clip_norm=1.0)
-    state = TrainState.create(auto.params, jax.jit(opt.init)(auto.params))
+    state = TrainState.create(auto.params, init_opt_state(opt, auto.params, auto.mesh_ctx))
     step = build_train_step(
         make_causal_lm_loss(auto.model, constrain=auto.constrain), opt
     )

@@ -34,9 +34,14 @@ def _case(rng, M, D, I, G, sizes, biased, dtype=jnp.float32):
     return lhs, gate, up, down, gs, gb, ub, db, dy
 
 
-def _grads(fn, args, biased, dy):
-    y, vjp = jax.vjp(fn, *args)
-    return y, vjp(dy)
+def _pulled(fn, args, dy):
+    """(``fn``'s output, its cotangents for ``dy``) as ONE compiled program:
+    called op by op, the glue around the kernels is a program a primitive."""
+    def run(args, dy):
+        y, vjp = jax.vjp(fn, *args)
+        return y, vjp(dy)
+
+    return jax.jit(run)(tuple(args), dy)
 
 
 def _both(lhs, gate, up, down, gs, gb, ub, db, dy, act, limit):
@@ -52,8 +57,8 @@ def _both(lhs, gate, up, down, gs, gb, ub, db, dy, act, limit):
         b = a[4:] if biased else (None, None, None)
         return _reference(a[0], a[1], a[2], a[3], gs, *b, act, limit, None)
 
-    y1, g1 = _grads(f_new, args, biased, dy)
-    y2, g2 = _grads(f_ref, args, biased, dy)
+    y1, g1 = _pulled(f_new, args, dy)
+    y2, g2 = _pulled(f_ref, args, dy)
     return y1, g1, y2, g2
 
 
@@ -111,10 +116,8 @@ def test_manual_backward_parity_garbage_tail():
         return _reference(l, g_, u_, d_, gs, gb_, ub_, db_,
                           "swiglu_oai", None, None)
 
-    _, vjp1 = jax.vjp(f_new, lhs_dirty, gate, up, down, gb, ub, db)
-    g1 = vjp1(dy_dirty)
-    _, vjp2 = jax.vjp(f_ref, lhs_clean, gate, up, down, gb, ub, db)
-    g2 = vjp2(dy_clean)
+    _, g1 = _pulled(f_new, (lhs_dirty, gate, up, down, gb, ub, db), dy_dirty)
+    _, g2 = _pulled(f_ref, (lhs_clean, gate, up, down, gb, ub, db), dy_clean)
     for n, a, b in zip(GRAD_NAMES, g1, g2):
         a, b = np.asarray(a), np.asarray(b)
         if n == "dlhs":
@@ -134,40 +137,12 @@ def test_empty_expert_grads_zero():
         return fused_expert_mlp(lhs, g_, u_, d_, gs, gb_, ub_, db_,
                                 "swiglu", None, None, True)
 
-    _, vjp = jax.vjp(f, gate, up, down, gb, ub, db)
-    grads = vjp(dy)
+    _, grads = _pulled(f, (gate, up, down, gb, ub, db), dy)
     for n, g in zip(GRAD_NAMES[1:], grads):
         g = np.asarray(g)
         assert np.abs(g[1]).max() == 0.0, f"{n}[empty expert 1] nonzero"
         assert np.abs(g[3]).max() == 0.0, f"{n}[empty expert 3] nonzero"
         assert np.abs(g[0]).max() > 0.0, f"{n}[expert 0] all-zero"
-
-
-def test_fused_vs_composed_backward_paths_agree(monkeypatch):
-    """AUTOMODEL_FUSED_BWD=0 (the r5 composed-tgmm backward, kept as the
-    kernel-bench A/B baseline) and the default purpose-tiled path must
-    produce the same grads."""
-    rng = np.random.default_rng(5)
-    lhs, gate, up, down, gs, gb, ub, db, dy = _case(
-        rng, 96, 64, 48, 3, [30, 26, 40], biased=True
-    )
-
-    def run():
-        def f(l, g_, u_, d_, gb_, ub_, db_):
-            return fused_expert_mlp(l, g_, u_, d_, gs, gb_, ub_, db_,
-                                    "swiglu", 1.5, None, True)
-
-        _, vjp = jax.vjp(f, lhs, gate, up, down, gb, ub, db)
-        return vjp(dy)
-
-    monkeypatch.setenv("AUTOMODEL_FUSED_BWD", "0")
-    composed = run()
-    monkeypatch.delenv("AUTOMODEL_FUSED_BWD")
-    fused = run()
-    for n, a, b in zip(GRAD_NAMES, fused, composed):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), atol=5e-4, err_msg=n
-        )
 
 
 def test_manual_backward_bfloat16_smoke():
@@ -182,14 +157,14 @@ def test_manual_backward_bfloat16_smoke():
         return fused_expert_mlp(l, g_, u_, d_, gs, None, None, None,
                                 "swiglu", None, None, True)
 
-    _, vjp = jax.vjp(f, lhs, gate, up, down)
-    grads = vjp(dy)
-    ref32 = jax.vjp(
+    _, grads = _pulled(f, (lhs, gate, up, down), dy)
+    _, ref32 = _pulled(
         lambda l, g_, u_, d_: _reference(
             l, g_, u_, d_, gs, None, None, None, "swiglu", None, None
         ),
-        *(a.astype(jnp.float32) for a in (lhs, gate, up, down)),
-    )[1](dy.astype(jnp.float32))
+        tuple(a.astype(jnp.float32) for a in (lhs, gate, up, down)),
+        dy.astype(jnp.float32),
+    )
     for n, a, b in zip(GRAD_NAMES, grads, ref32):
         a = np.asarray(a.astype(jnp.float32))
         assert np.isfinite(a).all(), n
@@ -264,8 +239,7 @@ def test_fused_weight_read_in_place(D, I, dtype, act, biased, tail, path):
             return fused_expert_mlp(l, g_, u_, d, gs, *(b or (None,) * 3),
                                     act, limit, None, True)
 
-        y, vjp = jax.vjp(f, lhs, gate_up, down, *bias)
-        return f, y, vjp(dy)
+        return f, *_pulled(f, (lhs, gate_up, down, *bias), dy)
 
     f_new, y_new, g_new = run(lambda w: _fused_gate_up(w, cfg))
     _, y_old, g_old = run(lambda w: _split_gate_up(w, interleaved))
@@ -300,10 +274,10 @@ def _all_shapes(jaxpr) -> set:
     return out
 
 
-def test_fused_weight_composed_backward_and_reference_paths_agree(monkeypatch):
-    """The other two routes of the same custom VJP take the fused operand
-    too: AUTOMODEL_FUSED_BWD=0 (splits the residual itself) and the
-    non-Pallas `_reference` composition (one grouped matmul, product split)."""
+def test_fused_weight_kernel_and_reference_backward_paths_agree():
+    """The other route of the same custom VJP takes the fused operand too:
+    the non-Pallas `_reference` composition (one grouped matmul, product
+    split)."""
     rng = np.random.default_rng(13)
     D, I, G, sizes = 128, 128, 3, [30, 26, 40]
     lhs, gate, up, down, gs, gb, ub, db, dy = _case(
@@ -316,16 +290,12 @@ def test_fused_weight_composed_backward_and_reference_paths_agree(monkeypatch):
             return fused_expert_mlp(l, w, None, d, gs, gb_, ub_, db_,
                                     "swiglu", 1.5, None, interpret)
 
-        return jax.vjp(f, lhs, gate_up, down, gb, ub, db)[1](dy)
+        return _pulled(f, (lhs, gate_up, down, gb, ub, db), dy)[1]
 
     fused = grads(True)
-    monkeypatch.setenv("AUTOMODEL_FUSED_BWD", "0")
-    composed = grads(True)
-    monkeypatch.delenv("AUTOMODEL_FUSED_BWD")
     reference = grads(False)  # CPU, no interpret: the XLA composition
-    for a, b, c in zip(fused, composed, reference):
-        assert a.shape == b.shape == c.shape
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+    for a, c in zip(fused, reference):
+        assert a.shape == c.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=5e-4)
 
 
@@ -433,18 +403,18 @@ def test_empty_groups_get_their_zeros_from_the_kernels(groups, fused):
         return fused_expert_mlp(lhs, wg_, wu_, d_, gs, gb_, ub_, db_,
                                 "swiglu_oai", None, None, True)
 
-    grads = jax.vjp(f, *w, down, gb, ub, db)[1](dy)
+    grads = _pulled(f, (*w, down, gb, ub, db), dy)[1]
     if fused:
         assert grads[1] is None and grads[0].shape == (G, D, 2 * I)
         grads = (*jnp.split(grads[0], 2, axis=-1), *grads[2:])
 
     clean = lambda a: a.at[n_real:].set(0.0)
-    ref = jax.vjp(
+    ref = _pulled(
         lambda g_, u_, d_, gb_, ub_, db_: _reference(
             clean(lhs), g_, u_, d_, gs, gb_, ub_, db_, "swiglu_oai", None, None
         ),
-        gate, up, down, gb, ub, db,
-    )[1](clean(dy))
+        (gate, up, down, gb, ub, db), clean(dy),
+    )[1]
     for n, a, b in zip(GRAD_NAMES[1:], grads, ref):
         a = np.asarray(a)
         assert np.isfinite(a).all(), n

@@ -154,7 +154,7 @@ def test_vlm_train_step_frozen_tower(devices8):
     the reference's freeze-config path (recipes/vlm/finetune.py:469)."""
     from automodel_tpu import auto_model
     from automodel_tpu.data.loader import place_batch
-    from automodel_tpu.optim.builders import build_optimizer
+    from automodel_tpu.optim.builders import build_optimizer, init_opt_state
     from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
     from automodel_tpu.training.train_state import TrainState
     from automodel_tpu.training.train_step import build_train_step, make_causal_lm_loss
@@ -185,7 +185,7 @@ def test_vlm_train_step_frozen_tower(devices8):
     )
     mask = freeze_mask(auto.params, ["vision/*"])
     opt = apply_freeze(build_optimizer(name="adamw", lr=2e-3, grad_clip_norm=1.0), mask)
-    state = TrainState.create(auto.params, jax.jit(opt.init)(auto.params))
+    state = TrainState.create(auto.params, init_opt_state(opt, auto.params, auto.mesh_ctx))
     step = build_train_step(make_causal_lm_loss(auto.model, constrain=auto.constrain), opt)
 
     rng = np.random.default_rng(0)

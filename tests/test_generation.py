@@ -85,9 +85,13 @@ def _tiny_moe():
     return model, model.init(jax.random.key(2))
 
 
+def _primary(out):
+    return out[0] if isinstance(out, tuple) else out
+
+
 def _full_logits(model, params, seq):
-    out = model(params, jnp.asarray([seq]))
-    logits = out[0] if isinstance(out, tuple) else out
+    # one program a length (op by op it is a program a primitive a length)
+    logits = jax.jit(lambda p, i: _primary(model(p, i)))(params, jnp.asarray([seq]))
     return np.asarray(logits[0], np.float32)
 
 
@@ -107,14 +111,14 @@ def _cached_stepwise_logits(model, params, prompt, n_steps, capacity=None, windo
     step_logits = [np.asarray(last[0], np.float32)]
     tok = int(jnp.argmax(last[0]))
     tokens = [tok]
-    for _ in range(n_steps - 1):
+    @jax.jit
+    def decode(params, tok, cache):
         kvc, ctx = kv_cache.decode_ctx(cache)
-        out = model(
-            params, jnp.asarray([[tok]], jnp.int32),
-            position_ids=ctx.q_pos[:, None], cache=(kvc, ctx),
-        )
-        primary, cache = out
-        logits = primary[0] if isinstance(primary, tuple) else primary
+        primary, cache = model(params, tok, position_ids=ctx.q_pos[:, None], cache=(kvc, ctx))
+        return _primary(primary), cache
+
+    for _ in range(n_steps - 1):
+        logits, cache = decode(params, jnp.asarray([[tok]], jnp.int32), cache)
         step_logits.append(np.asarray(logits[0, -1], np.float32))
         tok = int(jnp.argmax(logits[0, -1]))
         tokens.append(tok)

@@ -73,7 +73,7 @@ def test_logits_parity(setup):
     ids = rng.integers(0, 96, size=(2, 12)).astype(np.int64)
     with torch.no_grad():
         hf_logits = hf_model(input_ids=torch.from_numpy(ids)).logits.numpy()
-    logits, aux = model(params, jnp.asarray(ids))
+    logits, aux = jax.jit(lambda p, i: model(p, i))(params, jnp.asarray(ids))
     np.testing.assert_allclose(
         np.asarray(logits), hf_logits, atol=3e-4, rtol=2e-3
     )
@@ -90,7 +90,7 @@ def test_roundtrip(setup):
 def test_train_step_on_mesh(setup, devices8):
     from automodel_tpu import auto_model
     from automodel_tpu.data.loader import place_batch
-    from automodel_tpu.optim.builders import build_optimizer
+    from automodel_tpu.optim.builders import build_optimizer, init_opt_state
     from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
     from automodel_tpu.training.train_state import TrainState
     from automodel_tpu.training.train_step import build_train_step, make_causal_lm_loss
@@ -112,7 +112,7 @@ def test_train_step_on_mesh(setup, devices8):
         seed=0,
     )
     opt = build_optimizer(name="adamw", lr=2e-3, grad_clip_norm=1.0)
-    state = TrainState.create(auto.params, jax.jit(opt.init)(auto.params))
+    state = TrainState.create(auto.params, init_opt_state(opt, auto.params, auto.mesh_ctx))
     step = build_train_step(
         make_causal_lm_loss(auto.model, constrain=auto.constrain), opt,
         post_step_fn=auto.model.post_step_fn,

@@ -494,8 +494,11 @@ def test_report_flags_restart_count_regression(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def _clean_env():
-    env = dict(os.environ)
+def _clean_env(tmp_path):
+    # a compile cache of the test's own: the checkout's `.jax_compile_cache`
+    # may hold XLA:CPU results another machine type compiled, which abort
+    # this host's child (rc -6)
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
     for k in ("XLA_FLAGS", "JAX_PLATFORMS", "JAX_COORDINATOR_ADDRESS",
               "JAX_NUM_PROCESSES", "JAX_PROCESS_ID", fi.ENV_VAR):
         env.pop(k, None)
@@ -559,7 +562,7 @@ def test_sigterm_requeue_resume_yields_one_joined_ledger(tmp_path):
 
     argv = [sys.executable, _WORKER, "finetune", "llm", "-c", str(cfg_path)]
     proc = subprocess.Popen(
-        argv, env=_clean_env(), stdout=subprocess.PIPE,
+        argv, env=_clean_env(tmp_path), stdout=subprocess.PIPE,
         stderr=subprocess.PIPE, text=True,
     )
     deadline = time.time() + 300
@@ -606,7 +609,7 @@ def test_sigterm_requeue_resume_yields_one_joined_ledger(tmp_path):
     # requeue: resume and run a couple more steps to a clean exit
     out2 = subprocess.run(
         argv + [f"--step_scheduler.max_steps={last_commit + 2}"],
-        env=_clean_env(), capture_output=True, text=True, timeout=300,
+        env=_clean_env(tmp_path), capture_output=True, text=True, timeout=300,
     )
     assert out2.returncode == 0, out2.stderr[-2000:]
 
@@ -663,7 +666,7 @@ def test_hang_watchdog_exit_reads_as_unattributed_idle(tmp_path):
     cfg_path.write_text(json.dumps(cfg))
     out = subprocess.run(
         [sys.executable, _WORKER, "finetune", "llm", "-c", str(cfg_path)],
-        env=_clean_env(), capture_output=True, text=True, timeout=500,
+        env=_clean_env(tmp_path), capture_output=True, text=True, timeout=500,
     )
     assert out.returncode == REQUEUE_EXIT_CODE, (
         out.stdout[-2000:], out.stderr[-2000:]

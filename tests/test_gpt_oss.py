@@ -66,7 +66,7 @@ def test_logits_parity_with_hf():
             cfg, BackendConfig(attn="sdpa", experts=backend,
                                param_dtype="float32", compute_dtype="float32")
         )
-        out, aux = model(params, jnp.asarray(ids))
+        out, aux = jax.jit(lambda p, i: model(p, i))(params, jnp.asarray(ids))
         np.testing.assert_allclose(np.asarray(out), ref, atol=5e-4, rtol=3e-3)
     assert int(aux.expert_counts.sum()) == 2 * 2 * 16 * 2
 
@@ -87,7 +87,7 @@ def test_hf_roundtrip():
 def test_train_step_learns(devices8):
     from automodel_tpu import auto_model
     from automodel_tpu.data.loader import place_batch
-    from automodel_tpu.optim.builders import build_optimizer
+    from automodel_tpu.optim.builders import build_optimizer, init_opt_state
     from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
     from automodel_tpu.training.train_state import TrainState
     from automodel_tpu.training.train_step import build_train_step, make_causal_lm_loss
@@ -111,7 +111,7 @@ def test_train_step_learns(devices8):
         hf, ctx, {"attn": "sdpa", "param_dtype": "float32", "compute_dtype": "float32"}, seed=0
     )
     opt = build_optimizer(name="adamw", lr=2e-3, grad_clip_norm=1.0)
-    state = TrainState.create(auto.params, jax.jit(opt.init)(auto.params))
+    state = TrainState.create(auto.params, init_opt_state(opt, auto.params, auto.mesh_ctx))
     step = build_train_step(make_causal_lm_loss(auto.model, constrain=auto.constrain), opt)
     ids = np.random.default_rng(0).integers(0, 128, size=(1, 4, 16)).astype(np.int32)
     batch = place_batch(ctx, {"input_ids": ids, "labels": ids})

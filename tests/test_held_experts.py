@@ -52,15 +52,17 @@ def _share(params, lo, hi):
 def test_the_shares_add_up_to_the_uncut_layer(backend, whole, monkeypatch):
     monkeypatch.setenv("AUTOMODEL_GMM_INTERPRET", "1")
     params, x = _setup(whole)
-    out_whole, aux = moe_block(x, params, whole, ACT, experts_backend="dense")
+    # one program a configuration: op by op a layer is a program a primitive
+    block = lambda p, cfg, kind: jax.jit(
+        lambda x, p: moe_block(x, p, cfg, ACT, experts_backend=kind))(x, p)
+    out_whole, aux = block(params, whole, "dense")
     no_shared = {k: v for k, v in params.items() if k != "shared"}
-    shared = out_whole - moe_block(x, no_shared, whole, ACT, experts_backend="dense")[0]
+    shared = out_whole - block(no_shared, whole, "dense")[0]
     n = whole.num_experts // SHARES
     total, rows = shared, 0
     for s in range(SHARES):
         cfg = dataclasses.replace(whole, held_experts=(s * n, (s + 1) * n))
-        out, a = moe_block(x, _share(no_shared, s * n, (s + 1) * n), cfg, ACT,
-                           experts_backend=backend)
+        out, a = block(_share(no_shared, s * n, (s + 1) * n), cfg, backend)
         total = total + out
         # every share routes over all the experts and counts the same picks
         assert jnp.array_equal(a.expert_counts, aux.expert_counts)

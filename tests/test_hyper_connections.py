@@ -4,6 +4,7 @@ Sinkhorn rounds' convergence, the clamp and the single-stream limit. (Its
 lowering for the TPU at the benchmark cell's shape is with the other
 compile-only tests, tests/test_tpu_lowering.py.)"""
 
+import functools
 import math
 
 import numpy as np
@@ -56,8 +57,10 @@ def _token_loop(w, iters=ITERS, clamp=CLAMP):
 
 def test_forward_matches_the_token_loop():
     w = _weights()
-    co, u, out = _operator(w)
-    u_ref, out_ref, maps = _token_loop(w)
+    # each side one program: op by op, the loop is 10 tokens x 20 rounds of
+    # single primitives
+    co, u, out = jax.jit(_operator)(w)
+    u_ref, out_ref, maps = jax.jit(_token_loop)(w)
     np.testing.assert_allclose(u, u_ref, rtol=2e-5, atol=2e-6)
     np.testing.assert_allclose(out, out_ref, rtol=2e-5, atol=2e-6)
     # tokens on the minor axis: [n, T] and [n, n, T], float32
@@ -66,17 +69,25 @@ def test_forward_matches_the_token_loop():
     np.testing.assert_allclose(jnp.moveaxis(co.res, -1, 0), maps, rtol=2e-5, atol=1e-7)
 
 
-@pytest.mark.parametrize("leaf", ["x", "y", "phi", "b", "alpha"])
-def test_every_gradient_matches_the_token_loop(leaf):
+@functools.cache
+def _gradients():
+    """(the operator's, the token loop's) gradient by every leaf: two compiled
+    programs, run once; the five cases below read a leaf each."""
     w = _weights(1)
     mix = jax.random.normal(jax.random.key(9), (2, T, N * C))
 
     def scalar(u, out):  # both outputs, weighted so that nothing cancels
         return jnp.sum(u * mix[0, :, :C]) + jnp.sum(out * mix[1])
 
-    got = jax.grad(lambda v: scalar(*_operator({**w, leaf: v})[1:]))(w[leaf])
-    want = jax.grad(lambda v: scalar(*_token_loop({**w, leaf: v})[:2]))(w[leaf])
-    assert float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want)) < 2e-5
+    got = jax.jit(jax.grad(lambda v: scalar(*_operator(v)[1:])))(w)
+    want = jax.jit(jax.grad(lambda v: scalar(*_token_loop(v)[:2])))(w)
+    return jax.device_get((got, want))
+
+
+@pytest.mark.parametrize("leaf", ["x", "y", "phi", "b", "alpha"])
+def test_every_gradient_matches_the_token_loop(leaf):
+    got, want = (g[leaf] for g in _gradients())
+    assert float(np.linalg.norm(got - want) / np.linalg.norm(want)) < 2e-5
 
 
 def test_rows_and_columns_sum_to_one_after_20_rounds_and_not_after_2():
@@ -154,11 +165,10 @@ def test_the_post_mix_kernels_match_the_jnp_sums(dtype, tol, monkeypatch):
     res = hc.sinkhorn(jnp.exp(jax.random.normal(k[3], (n, n, T_))), 20, 1e-6)
     w = jax.random.normal(k[4], x.shape)
     loss = lambda f: (lambda *a: jnp.sum(f(*a).astype(jnp.float32) * w))
-    want = hc._post_mix_jnp(x, y, post, res)
-    want_g = jax.grad(loss(hc._post_mix_jnp), argnums=(0, 1, 2, 3))(x, y, post, res)
+    both = lambda f, w: jax.jit(lambda *a: (f(*a), jax.grad(w(f), argnums=(0, 1, 2, 3))(*a)))
+    want, want_g = both(hc._post_mix_jnp, loss)(x, y, post, res)
     monkeypatch.setenv("AUTOMODEL_MHC_INTERPRET", "1")
-    got = hc.post_mix(x, y, post, res)
-    got_g = jax.grad(loss(hc.post_mix), argnums=(0, 1, 2, 3))(x, y, post, res)
+    got, got_g = both(hc.post_mix, loss)(x, y, post, res)
     assert got.dtype == dtype and got.shape == x.shape
     rel = lambda a, b: float(jnp.linalg.norm(a.astype(jnp.float32) - b.astype(jnp.float32))
                              / jnp.linalg.norm(b.astype(jnp.float32)))
@@ -168,9 +178,10 @@ def test_the_post_mix_kernels_match_the_jnp_sums(dtype, tol, monkeypatch):
         assert rel(g, wg) < tol
     # rows no token tile divides are padded to one: the same kernels
     x5, y5, post5, res5 = x[:, :5], y[:, :5], post[:, :10], res[:, :, :10]
-    assert rel(hc.post_mix(x5, y5, post5, res5), hc._post_mix_jnp(x5, y5, post5, res5)) < tol
-    g5 = jax.grad(lambda *a: hc.post_mix(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3))(x5, y5, post5, res5)
-    w5 = jax.grad(lambda *a: hc._post_mix_jnp(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3))(x5, y5, post5, res5)
+    total = lambda f: (lambda *a: f(*a).astype(jnp.float32).sum())
+    got5, g5 = both(hc.post_mix, total)(x5, y5, post5, res5)
+    want5, w5 = both(hc._post_mix_jnp, total)(x5, y5, post5, res5)
+    assert rel(got5, want5) < tol
     for g, wg in zip(g5, w5):
         assert g.shape == wg.shape and rel(g, wg) < tol
 

@@ -214,13 +214,13 @@ def test_muon_optimizer_runs():
     import jax
 
     from automodel_tpu import auto_model
-    from automodel_tpu.optim.builders import build_optimizer
+    from automodel_tpu.optim.builders import build_optimizer, init_opt_state
     from automodel_tpu.training.train_state import TrainState
     from automodel_tpu.training.train_step import build_train_step, make_causal_lm_loss
 
     auto = auto_model.from_config(TINY, None, FP32, seed=0)
     opt = build_optimizer(name="muon", lr=1e-3)
-    state = TrainState.create(auto.params, jax.jit(opt.init)(auto.params))
+    state = TrainState.create(auto.params, init_opt_state(opt, auto.params, auto.mesh_ctx))
     step = build_train_step(make_causal_lm_loss(auto.model), opt)
     ids = np.random.default_rng(0).integers(0, 128, size=(1, 4, 16)).astype(np.int32)
     batch = {"input_ids": ids, "labels": ids}

@@ -116,7 +116,7 @@ def built():
     from automodel_tpu.models.registry import resolve_architecture
 
     model, adapter = resolve_architecture(hf)(hf, FP32)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     return model, adapter, params
 
 
@@ -153,7 +153,7 @@ def test_multimodal_train_smoke(built):
         )
         return jnp.mean(logits.astype(jnp.float32) ** 2) + aux.aux_loss
 
-    val, g = jax.value_and_grad(loss)(params)
+    val, g = jax.jit(jax.value_and_grad(loss))(params)
     assert bool(jnp.isfinite(val))
     for part in ("vision", "projector", "text"):
         gn = jax.tree_util.tree_reduce(

@@ -7,10 +7,14 @@ holds experts [4, 8) of 16, as the cell's configuration holds 8 of 256, and
 the reference is given the same share.
 """
 
+import functools
 import json
+import os
 import re
 import sys
 from pathlib import Path
+from types import SimpleNamespace
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -30,14 +34,16 @@ from benchmarks.harness import weights as W
 HF = {
     "architectures": ["KimiLinearForCausalLM"], "model_type": "kimi_linear",
     "vocab_size": 96, "hidden_size": 48, "intermediate_size": 64, "moe_intermediate_size": 32,
-    "num_hidden_layers": 5, "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 12,
+    # one layer of each kind and nothing twice: delta rule and a dense MLP, delta
+    # rule and experts, latent attention and experts
+    "num_hidden_layers": 3, "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 12,
     "kv_lora_rank": 24, "q_lora_rank": None, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
     "v_head_dim": 16, "mla_use_nope": True, "first_k_dense_replace": 1, "moe_layer_freq": 1,
     "moe_renormalize": True, "moe_router_activation_func": "sigmoid", "num_expert_group": 1,
     "topk_group": 1, "num_experts": 16, "num_experts_per_token": 4, "num_shared_experts": 1,
     "routed_scaling_factor": 2.446, "rms_norm_eps": 1e-5, "rope_theta": 10000,
     "rope_scaling": None, "tie_word_embeddings": False, "hidden_act": "silu",
-    "linear_attn_config": {"full_attn_layers": [4], "kda_layers": [1, 2, 3, 5], "head_dim": 16,
+    "linear_attn_config": {"full_attn_layers": [3], "kda_layers": [1, 2], "head_dim": 16,
                            "num_heads": 4, "short_conv_kernel_size": 4},
     "held_experts": [4, 8],
 }
@@ -54,8 +60,22 @@ LOGITS_TOL = 5e-5
 GRAD_TOL = 2e-4
 
 
+def _program_loss(model, params, ids, labels):
+    logits, _ = model(params, ids)
+    lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+    picked = jnp.take_along_axis(logits, jnp.maximum(labels, 0)[..., None], axis=-1)[..., 0]
+    keep = labels >= 0
+    return jnp.sum(jnp.where(keep, lse - picked, 0.0)) / jnp.sum(keep)
+
+
 @pytest.fixture(scope="module")
 def setup():
+    """The model, its weights, one batch, and what the program and the
+    reference make of that batch: each a whole program under ONE ``jax.jit``,
+    compiled and run the first time a test reads it (called op by op, the
+    model dispatched hundreds of programs of one primitive each, every test
+    over again). The program's two are traced with the delta-rule kernels in
+    interpret mode."""
     R = loader.load_module("reference", "kimi_linear")
     init = json.loads(CONFIG_FILE.read_text())["reference"]["init"]
     model, adapter = resolve_architecture(HF)(HF, F32)
@@ -63,24 +83,48 @@ def setup():
     params = W.make(abstract, 7, init=init)
     ref_hf = dict(HF, num_experts=4)  # the file's key counts the experts HELD
     spec = R.spec(ref_hf, {"published_experts": 16, "held_experts": [4, 8]})
-    # key 9: the closest call of any top-4-of-16 pick on these ids is 2.7e-4 of
-    # a score (test_no_routing_tie_...); at key 1, which this file used until
-    # PR 43, it was 1.2e-5, some ten times the float32 noise between the
-    # chunked rule and the token loop: near enough for a differently ordered
-    # CPU reduction (six busy workers) to flip a pick now and then, and one
-    # flipped pick moves the gradients by far more than GRAD_TOL
-    ids = jax.random.randint(jax.random.key(9), (2, 72), 0, HF["vocab_size"])
+    # key 0: the closest call of any top-4-of-16 pick on these ids is 5.4e-4 of
+    # a score (test_no_routing_tie_...), the widest of keys 0-39 (key 9, which
+    # the five-layer model of PR 43 used, reads 3.0e-4 on these three layers).
+    # A margin some ten times the float32 noise between the chunked rule and
+    # the token loop (1.2e-5, key 1, until PR 43) is near enough for a
+    # differently ordered CPU reduction (six busy workers) to flip a pick now
+    # and then, and one flipped pick moves the gradients by far more than
+    # GRAD_TOL
+    ids = jax.random.randint(jax.random.key(0), (2, 72), 0, HF["vocab_size"])
     labels = jnp.where(jax.random.uniform(jax.random.key(2), ids.shape) < 0.25, -100,
                        jnp.roll(ids, -1, axis=1))
-    return R, model, adapter, params, spec, ids, labels
+    ref = R.to_reference(params)
+    kernels = mock.patch.dict(os.environ, AUTOMODEL_DELTA_INTERPRET="1")
 
+    @functools.cache
+    def forward():
+        """(logits, the model's counters)."""
+        with kernels:
+            return jax.jit(lambda p, i: model(p, i))(params, ids)
 
-def _program_loss(model, params, ids, labels):
-    logits, _ = model(params, ids)
-    lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-    picked = jnp.take_along_axis(logits, jnp.maximum(labels, 0)[..., None], axis=-1)[..., 0]
-    keep = labels >= 0
-    return jnp.sum(jnp.where(keep, lse - picked, 0.0)) / jnp.sum(keep)
+    @functools.cache
+    def program():
+        """(the loss, its gradient by every leaf)."""
+        with kernels:
+            return jax.jit(jax.value_and_grad(lambda p: _program_loss(model, p, ids, labels)))(params)
+
+    @functools.cache
+    def reference():
+        """(the loss, its gradient by every leaf of the reference's tree)."""
+        def loss(p):
+            total, n = R.loss_sum(p, ids, labels, spec)
+            return total / n
+
+        return jax.jit(jax.value_and_grad(loss))(ref)
+
+    @functools.cache
+    def reference_logits():
+        return jnp.stack([R.rows_logits(ref, row, 0, spec, "f32", ids.shape[1]) for row in ids])
+
+    return SimpleNamespace(R=R, model=model, adapter=adapter, params=params, ref=ref, spec=spec,
+                           ids=ids, labels=labels, forward=forward, program=program,
+                           reference=reference, reference_logits=reference_logits)
 
 
 def test_resolves_through_the_registry_with_the_published_keys():
@@ -102,41 +146,29 @@ def test_resolves_through_the_registry_with_the_published_keys():
     assert shapes["lm_head"]["kernel"].shape == (2304, 20480)
 
 
-def test_logits_and_loss_match_the_reference(setup, monkeypatch):
-    monkeypatch.setenv("AUTOMODEL_DELTA_INTERPRET", "1")
-    R, model, _, params, spec, ids, labels = setup
-    logits, aux = model(params, ids)
-    ref_params = R.to_reference(params)
-    ref = jnp.stack([R.rows_logits(ref_params, row, 0, spec, "f32", ids.shape[1]) for row in ids])
+def test_logits_and_loss_match_the_reference(setup):
+    logits, aux = setup.forward()
+    ref = setup.reference_logits()
     scale = float(jnp.abs(ref).max())
     assert float(jnp.abs(logits - ref).max()) / scale < LOGITS_TOL
-    total, n = R.loss_sum(ref_params, ids, labels, spec)
-    assert abs(float(_program_loss(model, params, ids, labels)) - float(total / n)) < 1e-5
+    assert abs(float(setup.program()[0]) - float(setup.reference()[0])) < 1e-5
     # the counter: picks that landed on the held experts, against a direct count
-    assert aux.expert_counts.shape == (4, 16)
+    assert aux.expert_counts.shape == (2, 16)
     assert int(aux.held_expert_rows) == int(aux.expert_counts[:, 4:8].sum())
 
 
-def test_every_leaf_gradient_matches_the_reference(setup, monkeypatch):
-    monkeypatch.setenv("AUTOMODEL_DELTA_INTERPRET", "1")
-    R, model, _, params, spec, ids, labels = setup
-    got = jax.grad(lambda p: _program_loss(model, p, ids, labels))(params)
-    ref_params = R.to_reference(params)
-
-    def ref_loss(p):
-        total, n = R.loss_sum(p, ids, labels, spec)
-        return total / n
-
-    want = jax.grad(ref_loss)(ref_params)
-    names = jax.tree.leaves(R.program_names(ref_params), is_leaf=lambda x: isinstance(x, tuple))
+def test_every_leaf_gradient_matches_the_reference(setup):
+    # on the host: a norm of a difference is numpy's, not a program a leaf shape
+    (_, got), (_, want) = jax.device_get((setup.program(), setup.reference()))
+    names = jax.tree.leaves(setup.R.program_names(setup.ref), is_leaf=lambda x: isinstance(x, tuple))
     by_name = {W.path_name(p): g for p, g in jax.tree_util.tree_flatten_with_path(got)[0]}
     assert sorted(n for n, _ in names) == sorted(by_name)  # every leaf, once
     for (name, _), w in zip(names, jax.tree.leaves(want)):
         g = by_name[name]
         if name == "moe/router/bias":  # selects, never weighs: no gradient on either side
-            assert not np.any(np.asarray(g)) and not np.any(np.asarray(w))
+            assert not np.any(g) and not np.any(w)
             continue
-        err = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+        err = float(np.linalg.norm(g - w) / np.linalg.norm(w))
         assert err < GRAD_TOL, (name, err)
 
 
@@ -146,28 +178,28 @@ def test_no_routing_tie_sits_near_float32_noise(setup):
     best unpicked score is within the float32 noise of two orders of summation
     flips, and a flip is no rounding. The margins on these ids are two orders
     above that noise (logits agree to 3e-6 of their scale)."""
-    R, _, _, params, spec, ids, labels = setup
+    s, R = setup, setup.R
     margins = []
     real_route = R.route
 
-    def spy_route(x, lp, s):
+    def spy_route(x, lp, sp):
         scores = jax.nn.sigmoid(x @ lp["router"]) + lp["router_bias"]
-        top = jax.lax.top_k(scores, s.top_k + 1)[0]
+        top = jax.lax.top_k(scores, sp.top_k + 1)[0]
         jax.debug.callback(lambda m: margins.append(float(m)),
-                           jnp.min(top[:, s.top_k - 1] - top[:, s.top_k]))
-        return real_route(x, lp, s)
+                           jnp.min(top[:, sp.top_k - 1] - top[:, sp.top_k]))
+        return real_route(x, lp, sp)
 
     R.route = spy_route
-    try:
-        jax.block_until_ready(R.loss_sum(R.to_reference(params), ids, labels, spec))
+    try:  # traced while the spy stands in
+        jax.block_until_ready(jax.jit(lambda p: R.loss_sum(p, s.ids, s.labels, s.spec))(s.ref))
     finally:
         R.route = real_route
     jax.effects_barrier()
-    assert len(margins) == 4 and min(margins) > 1e-4, margins  # four expert layers
+    assert len(margins) == 2 and min(margins) > 4e-4, margins  # two expert layers
 
 
 def test_state_dict_round_trip(setup):
-    _, _, adapter, params, *_ = setup
+    adapter, params = setup.adapter, setup.params
     sd = dict(adapter.to_hf(params))
     assert sorted(sd) == sorted(adapter.hf_keys())
     # held experts keep their published numbers; the router keeps every column
@@ -187,13 +219,14 @@ def test_the_train_step_writes_both_mixers_scopes_and_the_counter(setup):
     from automodel_tpu.training.train_step import build_train_step, make_causal_lm_loss
     from automodel_tpu.utils.profiler import SCOPES
 
-    _, model, _, params, _, ids, labels = setup
+    model, params, ids, labels = setup.model, setup.params, setup.ids, setup.labels
     opt = build_optimizer(lr=1e-3, grad_clip_norm=1.0)
     step = build_train_step(make_causal_lm_loss(model, loss="fused_linear_ce", num_chunks=2),
                             opt, donate=False)
-    state = TrainState.create(params, opt.init(params))
+    state = TrainState.create(params, jax.jit(opt.init)(params))
     batch = {"input_ids": ids[None], "labels": labels[None]}
-    text = step.lower(state, batch).as_text(debug_info=True)
+    lowered = step.lower(state, batch)  # traced once: its text is read, and it is run
+    text = lowered.as_text(debug_info=True)
     segments = set()
     for name in re.findall(r'loc\("([^"/][^"]*)"', text):
         segs = program_trace.path_segments(name)
@@ -203,15 +236,15 @@ def test_the_train_step_writes_both_mixers_scopes_and_the_counter(setup):
             assert program_trace.scope_of(name) == "attn", name
     assert {"attn/kda", "attn/kda/kda_conv", "attn/kda/kda_gate", "attn/kda/kda_chunk",
             "attn/kda/kda_norm", "attn/mla"} <= segments
-    _, metrics = step(state, batch)
-    _, aux = model(params, ids)
+    _, metrics = lowered.compile()(state, batch)
+    _, aux = setup.forward()
     assert int(metrics["held_expert_rows"]) == int(aux.expert_counts[:, 4:8].sum()) > 0
 
 
 def test_serving_refuses_the_family(setup):
     from automodel_tpu.serving.engine import ServeConfig
 
-    _, model, *_ = setup
+    model = setup.model
     with pytest.raises(ValueError, match="delta-rule state is not served yet"):
         ServeConfig().check_layout(model.cache_layout(), type(model).__name__)
 
@@ -220,13 +253,11 @@ def test_bfloat16_is_far_outside_the_tolerances(setup):
     """What makes the limits above tight: the same weights through the same
     path with bfloat16 compute (delta rule, router input, every product) miss
     the float32 reference by orders more than ``LOGITS_TOL``."""
-    R, _, _, params, spec, ids, _ = setup
     bf16 = BackendConfig(attn="sdpa", experts="ragged", param_dtype="float32",
                          compute_dtype="bfloat16", remat="none")
     model, _ = resolve_architecture(HF)(HF, bf16)
-    logits, _ = model(params, ids)
-    ref = jnp.stack([R.rows_logits(R.to_reference(params), row, 0, spec, "f32", ids.shape[1])
-                     for row in ids])
+    logits, _ = jax.jit(lambda p, i: model(p, i))(setup.params, setup.ids)
+    ref = setup.reference_logits()
     gap = float(jnp.abs(logits.astype(jnp.float32) - ref).max() / jnp.abs(ref).max())
     assert gap > 20 * LOGITS_TOL, gap
 

@@ -85,7 +85,7 @@ def built():
     from automodel_tpu.models.registry import resolve_architecture
 
     model, adapter = resolve_architecture(hf)(hf, FP32)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     return model, adapter, params
 
 
@@ -130,7 +130,7 @@ def test_multimodal_train_smoke(built):
         )
         return jnp.mean(logits.astype(jnp.float32) ** 2) + aux.aux_loss
 
-    val, g = jax.value_and_grad(loss)(params)
+    val, g = jax.jit(jax.value_and_grad(loss))(params)
     assert bool(jnp.isfinite(val))
     for part in ("vision", "projector", "text"):
         gn = jax.tree_util.tree_reduce(
@@ -146,8 +146,7 @@ def test_count_mismatch_poisons(built):
     ids = rng.integers(0, 100, size=(1, 12)).astype(np.int64)
     ids[0, 2:4] = IMG_TOKEN  # 2 placeholders but 4 features
     pix = rng.normal(size=(16, cfg.vision.patch_dim)).astype(np.float32)
-    logits, _ = model(
-        params, jnp.asarray(ids), pixel_values=jnp.asarray(pix),
-        grid_hws=((4, 4),),
-    )
+    logits, _ = jax.jit(
+        lambda p, i, x: model(p, i, pixel_values=x, grid_hws=((4, 4),))
+    )(params, jnp.asarray(ids), jnp.asarray(pix))
     assert bool(jnp.isnan(logits).any())

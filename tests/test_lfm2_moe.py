@@ -50,12 +50,17 @@ def R():
 def setup():
     cfg = Lfm2MoeConfig.from_hf(TINY)
     model = Lfm2MoeForCausalLM(cfg, FP32)
-    params = model.init(jax.random.key(0))
-    # biases and norms off their init values, so that a skipped one shows
-    leaves, treedef = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.key(1), len(leaves))
-    leaves = [a + 0.05 * jax.random.normal(k, a.shape, a.dtype) for a, k in zip(leaves, keys)]
-    return cfg, model, jax.tree.unflatten(treedef, leaves)
+
+    def weights(key, noise_key):
+        # biases and norms off their init values, so that a skipped one shows
+        leaves, treedef = jax.tree.flatten(model.init(key))
+        keys = jax.random.split(noise_key, len(leaves))
+        leaves = [a + 0.05 * jax.random.normal(k, a.shape, a.dtype) for a, k in zip(leaves, keys)]
+        return jax.tree.unflatten(treedef, leaves)
+
+    # every program of this file runs under one jit: called op by op, the model
+    # is hundreds of programs of one primitive each
+    return cfg, model, jax.jit(weights)(jax.random.key(0), jax.random.key(1))
 
 
 def _ids(shape, seed=0):
@@ -64,10 +69,13 @@ def _ids(shape, seed=0):
 
 def _ref_logits(R, params, ids, precision="f32"):
     spec = R.spec(TINY, {})
-    ref = R.to_reference(params)
-    h = R.hidden_states(ref, ids, spec, precision)
-    return jnp.einsum("bsd,vd->bsv", h, ref["embed"].astype(jnp.float32),
-                      precision=jax.lax.Precision.HIGHEST)
+
+    def logits(ref):
+        h = R.hidden_states(ref, ids, spec, precision)
+        return jnp.einsum("bsd,vd->bsv", h, ref["embed"].astype(jnp.float32),
+                          precision=jax.lax.Precision.HIGHEST)
+
+    return jax.jit(logits)(R.to_reference(params))
 
 
 def test_registry_and_config():
@@ -89,7 +97,7 @@ def test_registry_and_config():
 def test_forward_matches_the_reference(setup, R):
     _, model, params = setup
     ids = _ids((2, 40))
-    logits, aux = model(params, ids)
+    logits, aux = jax.jit(lambda p, i: model(p, i))(params, ids)
     want = _ref_logits(R, params, ids)
     assert aux.expert_counts.shape == (4, 4)  # the four expert layers
     assert float(jnp.max(jnp.abs(logits - want))) < TOL
@@ -119,10 +127,12 @@ def test_loss_and_gradients_match_the_reference(setup, R):
         total, n = R.loss_sum(R.to_reference(p), inputs, labels, spec)
         return total / n
 
-    (lp, gp), (lr, gr) = (jax.value_and_grad(f)(params) for f in (program_loss, reference_loss))
+    (lp, gp), (lr, gr) = jax.device_get(
+        [jax.jit(jax.value_and_grad(f))(params) for f in (program_loss, reference_loss)]
+    )
     assert abs(float(lp) - float(lr)) < 1e-5
     worst = max(
-        float(jnp.max(jnp.abs(a - b))) / (float(jnp.max(jnp.abs(b))) + 1e-8)
+        float(np.max(np.abs(a - b))) / (float(np.max(np.abs(b))) + 1e-8)
         for a, b in zip(jax.tree.leaves(gp), jax.tree.leaves(gr))
     )
     assert worst < 2e-3  # relative to each leaf's largest gradient
@@ -137,13 +147,13 @@ def test_packed_documents_do_not_leak_through_the_conv(setup):
     packed = jnp.concatenate([a, b], axis=1)
     seg = jnp.concatenate([jnp.zeros((1, 11), jnp.int32), jnp.ones((1, 13), jnp.int32)], axis=1)
     pos = jnp.concatenate([jnp.arange(11), jnp.arange(13)])[None, :].astype(jnp.int32)
-    got, _ = model(params, packed, segment_ids=seg, position_ids=pos)
-    alone_a, _ = model(params, a)
-    alone_b, _ = model(params, b)
+    run = jax.jit(lambda p, i, **kw: model(p, i, **kw)[0])
+    got = run(params, packed, segment_ids=seg, position_ids=pos)
+    alone_a, alone_b = run(params, a), run(params, b)
     assert float(jnp.max(jnp.abs(got[:, :11] - alone_a))) < TOL
     assert float(jnp.max(jnp.abs(got[:, 11:] - alone_b))) < TOL
     # and without the boundary the second document does see the first
-    leaky, _ = model(params, packed, position_ids=pos)
+    leaky = run(params, packed, position_ids=pos)
     assert float(jnp.max(jnp.abs(leaky[:, 11:] - alone_b))) > 100 * TOL
 
 

@@ -207,7 +207,7 @@ def test_vocab_parallel_ce_matches_masked(devices8):
     # e2e: train a tiny llama with loss_fn name=vocab_parallel_ce
     from automodel_tpu import auto_model
     from automodel_tpu.data.loader import place_batch
-    from automodel_tpu.optim.builders import build_optimizer
+    from automodel_tpu.optim.builders import build_optimizer, init_opt_state
     from automodel_tpu.training.train_state import TrainState
     from automodel_tpu.training.train_step import build_train_step, make_causal_lm_loss
 
@@ -222,7 +222,7 @@ def test_vocab_parallel_ce_matches_masked(devices8):
         seed=0,
     )
     opt = build_optimizer(name="adamw", lr=2e-3, grad_clip_norm=1.0)
-    state = TrainState.create(auto.params, jax.jit(opt.init)(auto.params))
+    state = TrainState.create(auto.params, init_opt_state(opt, auto.params, auto.mesh_ctx))
     step = build_train_step(
         make_causal_lm_loss(auto.model, loss="vocab_parallel_ce", constrain=auto.constrain),
         opt,

@@ -54,7 +54,7 @@ def test_config_mapping():
 def test_flat_qk_norm_shapes_and_numerics():
     cfg = MiniMaxM2Config.from_hf(_hf_cfg())
     model = MiniMaxM2ForCausalLM(cfg, FP32)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     qn = params["moe_layers"]["attn"]["q_norm"]["scale"]
     kn = params["moe_layers"]["attn"]["k_norm"]["scale"]
     assert qn.shape == (2, cfg.q_dim)  # flattened dims, not head_dim
@@ -123,14 +123,14 @@ def test_registry_train_smoke():
     hf = _hf_cfg()
     model, _ = resolve_architecture(hf)(hf, FP32)
     assert isinstance(model, MiniMaxM2ForCausalLM)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     ids = jnp.asarray(np.random.default_rng(2).integers(0, 128, (2, 12)))
 
     def loss(p):
         logits, aux = model(p, ids)
         return jnp.mean(logits.astype(jnp.float32) ** 2) + aux.aux_loss
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     gn = jax.tree_util.tree_reduce(
         lambda a, x: a + jnp.sum(jnp.abs(x.astype(jnp.float32))), g, 0.0
     )

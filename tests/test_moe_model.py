@@ -66,7 +66,7 @@ def test_logits_parity_with_hf(experts_backend):
     ids = np.random.default_rng(0).integers(0, 128, size=(2, 16))
     with torch.no_grad():
         ref = hf_model(torch.tensor(ids)).logits.numpy()
-    out, aux = model(params, jnp.asarray(ids))
+    out, aux = jax.jit(lambda p, i: model(p, i))(params, jnp.asarray(ids))
     np.testing.assert_allclose(np.asarray(out), ref, atol=3e-4, rtol=3e-3)
     assert int(aux.expert_counts.sum()) == 2 * 2 * 16 * 2  # L*B*S*K
 
@@ -86,7 +86,7 @@ def test_train_step_ep_sharded(devices8):
     """Full jitted train step with EP+FSDP+aux-free bias on the 8-dev mesh."""
     from automodel_tpu import auto_model
     from automodel_tpu.data.loader import place_batch
-    from automodel_tpu.optim.builders import build_optimizer
+    from automodel_tpu.optim.builders import build_optimizer, init_opt_state
     from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
     from automodel_tpu.training.train_state import TrainState
     from automodel_tpu.training.train_step import build_train_step, make_causal_lm_loss
@@ -111,7 +111,7 @@ def test_train_step_ep_sharded(devices8):
     ctx = build_mesh(MeshConfig(dp_shard=4, ep=2, tp=2), devices=devices8)
     auto = auto_model.from_config(hf, ctx, {"attn": "sdpa", **FP32}, seed=0)
     opt = build_optimizer(name="adamw", lr=1e-3, grad_clip_norm=1.0)
-    state = TrainState.create(auto.params, jax.jit(opt.init)(auto.params))
+    state = TrainState.create(auto.params, init_opt_state(opt, auto.params, auto.mesh_ctx))
     loss_fn = make_causal_lm_loss(auto.model, constrain=auto.constrain)
     step = build_train_step(
         loss_fn, opt, post_step_fn=auto.model.post_step_fn

@@ -39,7 +39,7 @@ def _tiny_cfg():
 @pytest.fixture(scope="module")
 def built():
     model = NemotronParseForConditionalGeneration(_tiny_cfg(), FP32)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     return model, params
 
 
@@ -62,7 +62,7 @@ def test_decoder_parity_with_hf_mbart():
 
     cfg = _tiny_cfg()
     model = NemotronParseForConditionalGeneration(cfg, FP32)
-    params = model.init(jax.random.PRNGKey(1))
+    params = jax.jit(model.init)(jax.random.PRNGKey(1))
 
     # map HF weights into the native decoder subtree via the adapter plans
     sd = {("decoder." + k): v.detach().numpy() for k, v in dec.state_dict().items()}
@@ -221,7 +221,7 @@ def test_train_smoke_with_family_loss(built):
         s, n = loss_fn(logits, labels)
         return s / jnp.maximum(n, 1)
 
-    val, g = jax.value_and_grad(loss)(params)
+    val, g = jax.jit(jax.value_and_grad(loss))(params)
     assert bool(jnp.isfinite(val))
     for part in ("vision", "decoder", "lm_head"):
         gn = jax.tree_util.tree_reduce(

@@ -108,7 +108,7 @@ def built():
 
     hf = _hf_cfg()
     model, adapter = resolve_architecture(hf)(hf, FP32)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     return model, adapter, params
 
 
@@ -121,7 +121,7 @@ def test_hybrid_train_smoke(built):
         logits, aux = model(p, ids)
         return jnp.mean(logits.astype(jnp.float32) ** 2)
 
-    val, g = jax.value_and_grad(loss)(params)
+    val, g = jax.jit(jax.value_and_grad(loss))(params)
     assert bool(jnp.isfinite(val))
     for part in ("mamba", "attn", "mlp", "moe", "embed"):
         gn = jax.tree_util.tree_reduce(
@@ -154,12 +154,12 @@ def test_packed_segments_forward(built):
     la, lb = 10, 14
     doc_a = rng.integers(0, 128, (1, la))
     doc_b = rng.integers(0, 128, (1, lb))
-    ref_a, _ = model(params, jnp.asarray(doc_a))
-    ref_b, _ = model(params, jnp.asarray(doc_b))
+    run = jax.jit(lambda p, i, **kw: model(p, i, **kw)[0])  # one program a shape
+    ref_a, ref_b = run(params, jnp.asarray(doc_a)), run(params, jnp.asarray(doc_b))
     packed = jnp.asarray(np.concatenate([doc_a, doc_b], 1))
     seg = jnp.asarray(np.concatenate(
         [np.zeros((1, la)), np.ones((1, lb))], 1), jnp.int32)
-    got, _ = model(params, packed, segment_ids=seg)
+    got = run(params, packed, segment_ids=seg)
     np.testing.assert_allclose(
         np.asarray(got[:, :la]), np.asarray(ref_a), atol=2e-4, rtol=2e-3
     )

@@ -121,7 +121,7 @@ def test_cost_walker_sees_explicit_collectives(devices8):
 
 def _step_cost_for(hf, backend, batch=2, seq=32):
     from automodel_tpu import auto_model
-    from automodel_tpu.optim.builders import build_optimizer
+    from automodel_tpu.optim.builders import build_optimizer, init_opt_state
     from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
     from automodel_tpu.training.train_state import TrainState
     from automodel_tpu.training.train_step import (
@@ -134,7 +134,7 @@ def _step_cost_for(hf, backend, batch=2, seq=32):
     auto = auto_model.from_config(hf, ctx, backend, seed=0)
     loss = make_causal_lm_loss(auto.model, loss="masked_ce", constrain=auto.constrain)
     opt = build_optimizer(name="adamw", lr=1e-3)
-    state = TrainState.create(auto.params, jax.jit(opt.init)(auto.params))
+    state = TrainState.create(auto.params, init_opt_state(opt, auto.params, auto.mesh_ctx))
     step = build_train_step(loss, opt)
     ids = jax.ShapeDtypeStruct((1, batch, seq), jnp.int32)
     cost = trace_cost(step, state, {"input_ids": ids, "labels": ids})

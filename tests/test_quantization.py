@@ -52,7 +52,7 @@ def test_fake_quant_levels_and_ste():
 
 
 def test_qat_delayed_enablement_and_training():
-    from automodel_tpu.optim.builders import build_optimizer
+    from automodel_tpu.optim.builders import build_optimizer, init_opt_state
     from automodel_tpu.training.train_state import TrainState
     from automodel_tpu.training.train_step import build_train_step, make_causal_lm_loss
 
@@ -74,7 +74,7 @@ def test_qat_delayed_enablement_and_training():
 
     # end-to-end: train step consumes the step-threaded loss and learns
     opt = build_optimizer(name="adamw", lr=5e-3)
-    state = TrainState.create(auto.params, jax.jit(opt.init)(auto.params))
+    state = TrainState.create(auto.params, init_opt_state(opt, auto.params, auto.mesh_ctx))
     step = build_train_step(qat_loss, opt)
     batch = {"input_ids": jnp.asarray(ids)[None], "labels": jnp.asarray(ids)[None]}
     losses = []

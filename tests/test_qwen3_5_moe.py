@@ -85,13 +85,13 @@ def test_split_matches_fused():
     cfg = _tiny_cfg()
     next_model = Qwen3NextForCausalLM(cfg, FP32)
     model35 = Qwen3_5MoeForConditionalGeneration(cfg, FP32)
-    p_next = next_model.init(jax.random.PRNGKey(0))
+    p_next = jax.jit(next_model.init)(jax.random.PRNGKey(0))
     p35 = dict(p_next)
     p35["linear_attn"] = _split_from_fused(cfg, p_next["linear_attn"])
 
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 128, (2, 16)))
-    ref, _ = next_model(p_next, ids)
-    got, _ = model35(p35, ids)
+    ref, _ = jax.jit(lambda p, i: next_model(p, i))(p_next, ids)
+    got, _ = jax.jit(lambda p, i: model35(p, i))(p35, ids)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
 
 
@@ -142,7 +142,7 @@ def test_registry_train_smoke():
         logits, aux = model(p, ids)
         return jnp.mean(logits.astype(jnp.float32) ** 2) + aux.aux_loss
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     gn = jax.tree_util.tree_reduce(
         lambda a, x: a + jnp.sum(jnp.abs(x.astype(jnp.float32))), g, 0.0
     )

@@ -70,7 +70,7 @@ def test_logits_parity(setup):
     ids = rng.integers(0, 96, size=(2, 20)).astype(np.int64)
     with torch.no_grad():
         hf_logits = hf_model(input_ids=torch.from_numpy(ids)).logits.numpy()
-    logits, aux = model(params, jnp.asarray(ids))
+    logits, aux = jax.jit(lambda p, i: model(p, i))(params, jnp.asarray(ids))
     np.testing.assert_allclose(
         np.asarray(logits), hf_logits, atol=5e-4, rtol=2e-3
     )
@@ -88,7 +88,7 @@ def test_roundtrip(setup):
 def test_train_step_on_mesh(devices8):
     from automodel_tpu import auto_model
     from automodel_tpu.data.loader import place_batch
-    from automodel_tpu.optim.builders import build_optimizer
+    from automodel_tpu.optim.builders import build_optimizer, init_opt_state
     from automodel_tpu.parallel.mesh import MeshConfig, build_mesh
     from automodel_tpu.training.train_state import TrainState
     from automodel_tpu.training.train_step import build_train_step, make_causal_lm_loss
@@ -115,7 +115,7 @@ def test_train_step_on_mesh(devices8):
         seed=0,
     )
     opt = build_optimizer(name="adamw", lr=2e-3, grad_clip_norm=1.0)
-    state = TrainState.create(auto.params, jax.jit(opt.init)(auto.params))
+    state = TrainState.create(auto.params, init_opt_state(opt, auto.params, auto.mesh_ctx))
     step = build_train_step(
         make_causal_lm_loss(auto.model, constrain=auto.constrain), opt
     )
@@ -138,8 +138,9 @@ def test_packed_matches_unpacked(setup):
     doc_a = rng.integers(0, 96, (1, la))
     doc_b = rng.integers(0, 96, (1, lb))
 
-    ref_a, _ = model(params, jnp.asarray(doc_a))
-    ref_b, _ = model(params, jnp.asarray(doc_b))
+    # one program a shape: op by op, each of the three calls is hundreds
+    run = jax.jit(lambda p, i, **kw: model(p, i, **kw)[0])
+    ref_a, ref_b = run(params, jnp.asarray(doc_a)), run(params, jnp.asarray(doc_b))
 
     packed = jnp.asarray(np.concatenate([doc_a, doc_b], axis=1))
     seg = jnp.asarray(
@@ -149,7 +150,7 @@ def test_packed_matches_unpacked(setup):
         np.concatenate([np.arange(la)[None], np.arange(lb)[None]], axis=1),
         jnp.int32,
     )
-    got, _ = model(params, packed, segment_ids=seg, position_ids=pos)
+    got = run(params, packed, segment_ids=seg, position_ids=pos)
     np.testing.assert_allclose(
         np.asarray(got[:, :la]), np.asarray(ref_a), atol=2e-4, rtol=2e-3
     )

@@ -137,14 +137,14 @@ def test_registry_train_smoke():
     }
     model, adapter = resolve_architecture(hf)(hf, FP32)
     assert isinstance(model, Qwen3OmniMoeThinkerForCausalLM)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     ids = jnp.asarray(np.random.default_rng(2).integers(0, 128, (1, 12)))
 
     def loss(p):
         logits, aux = model(p, ids)
         return jnp.mean(logits.astype(jnp.float32) ** 2) + aux.aux_loss
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     gn = jax.tree_util.tree_reduce(
         lambda a, x: a + jnp.sum(jnp.abs(x.astype(jnp.float32))), g, 0.0
     )

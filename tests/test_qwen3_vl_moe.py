@@ -124,13 +124,10 @@ def test_logits_parity_with_images(parity_setup):
     )
     np.testing.assert_array_equal(pos, hf_pos.numpy())
 
-    logits, aux = model(
-        params,
-        jnp.asarray(ids_t.numpy()),
-        pixel_values=jnp.asarray(pix_t.numpy()),
-        image_grid_thw=tuple(tuple(g) for g in grid_t.numpy()),
-        position_ids=jnp.asarray(pos),
-    )
+    grid = tuple(tuple(int(v) for v in g) for g in grid_t.numpy())
+    logits, aux = jax.jit(
+        lambda p, i, x, pos: model(p, i, pixel_values=x, image_grid_thw=grid, position_ids=pos)
+    )(params, jnp.asarray(ids_t.numpy()), jnp.asarray(pix_t.numpy()), jnp.asarray(pos))
     np.testing.assert_allclose(np.asarray(logits), out, atol=2e-4, rtol=2e-3)
 
 
@@ -142,7 +139,7 @@ def test_logits_parity_text_only(parity_setup):
     ids = rng.integers(0, 100, size=(2, 12)).astype(np.int64)
     with torch.no_grad():
         out = hf_model(input_ids=torch.tensor(ids)).logits.numpy()
-    logits, _ = model(params, jnp.asarray(ids))
+    logits, _ = jax.jit(lambda p, i: model(p, i))(params, jnp.asarray(ids))
     np.testing.assert_allclose(np.asarray(logits), out, atol=2e-4, rtol=2e-3)
 
 

@@ -989,8 +989,11 @@ def test_e2e_preemption_emergency_checkpoint_in_process(tmp_path, devices8, monk
 # ---------------------------------------------------------------------------
 
 
-def _clean_env():
-    env = dict(os.environ)
+def _clean_env(tmp_path):
+    # a compile cache of the test's own: the checkout's `.jax_compile_cache`
+    # may hold XLA:CPU results another machine type compiled, which abort
+    # this host's child (rc -6)
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
     for k in ("XLA_FLAGS", "JAX_PLATFORMS", "JAX_COORDINATOR_ADDRESS",
               "JAX_NUM_PROCESSES", "JAX_PROCESS_ID", fi.ENV_VAR):
         env.pop(k, None)
@@ -1035,7 +1038,7 @@ def test_sigterm_subprocess_requeue_exit_and_resume(tmp_path):
 
     argv = [sys.executable, _WORKER, "finetune", "llm", "-c", str(cfg_path)]
     proc = subprocess.Popen(
-        argv, env=_clean_env(), stdout=subprocess.PIPE,
+        argv, env=_clean_env(tmp_path), stdout=subprocess.PIPE,
         stderr=subprocess.PIPE, text=True,
     )
     deadline = time.time() + 300
@@ -1065,7 +1068,7 @@ def test_sigterm_subprocess_requeue_exit_and_resume(tmp_path):
     # checkpoint, not from scratch
     out2 = subprocess.run(
         argv + [f"--step_scheduler.max_steps={last_step + 2}"],
-        env=_clean_env(), capture_output=True, text=True, timeout=300,
+        env=_clean_env(tmp_path), capture_output=True, text=True, timeout=300,
     )
     assert out2.returncode == 0, out2.stderr[-2000:]
     new = [

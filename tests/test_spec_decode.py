@@ -9,8 +9,10 @@ The exactness contracts pinned here:
   engine, for ragged batches across the cache-capable families;
 - **fused == gather** — the paged kernel indexing the pool in place
   equals the gather → ``sdpa_decode`` view path, bf16/fp32 and int8;
-- **int8 == fp32 tokens** — the quantized pool decodes the same greedy
-  tokens as the full-precision pool on the tiny models;
+- **int8 within its rounding of fp32** — along the full-precision greedy
+  path the quantized pool's log-probabilities stay within a stated
+  tolerance, and its greedy token is the full-precision one wherever the
+  top two candidates are further apart than that;
 - **rollback is leak-free** — ``BlockPool.check_invariants()`` holds
   after every engine step of a randomized accept/reject schedule,
   including rollbacks across a block boundary (``spec_k > block_size``).
@@ -316,16 +318,50 @@ def test_fused_engine_greedy_parity_sliding_window(monkeypatch):
 # -- int8 KV-cache pool -------------------------------------------------------
 
 
+# What an int8 K/V row can promise: each value within 1/254 of its row's
+# largest, which three layers of attention turn into a log-probability within
+# 0.016 of the full-precision one on this model (read over the 13 positions
+# compared below; the full-precision pool reads 0.000000 on all 18).
+INT8_LOGPROB_TOL = 0.03
+
+
 def test_int8_pool_greedy_tokens_match_fp32():
-    """The quantized pool decodes IDENTICAL greedy tokens to the
-    full-precision pool on the tiny model (per-row scales keep the
-    attention outputs well inside the argmax margin)."""
+    """Along the full-precision greedy path the quantized pool's
+    log-probability of its pick is within ``INT8_LOGPROB_TOL`` of the full
+    forward's, and its pick IS the full-precision token wherever that one
+    leads the runner-up by more than twice the tolerance. Token equality
+    everywhere, which this test asked until PR 46, is not a claim a quantized
+    pool can make: two of these three requests meet a position whose top two
+    are 0.013 and 0.011 apart, inside int8's rounding, and from there on the
+    two engines continue different sequences."""
     model, params = _tiny_llama(seed=1)
     auto = _auto(model, params)
     prompts = [[1, 2, 3, 4, 5], [9, 10, 11], [20, 21, 22, 23, 24, 25]]
     refs = _greedy_refs(auto, prompts, 6)
-    int8 = _run(_serve(auto, kv_cache_dtype="int8", decode_kernel="gather"), prompts)
-    assert [r["tokens"] for r in int8] == refs
+    srv = _serve(auto, kv_cache_dtype="int8", decode_kernel="gather")
+    ids = [srv.submit(p, return_logprobs=True) for p in prompts]
+    done = {r["request_id"]: r for r in srv.run()}
+    forward = jax.jit(lambda ids: model(params, ids))
+    compared = decided = 0
+    for prompt, want, i in zip(prompts, refs, ids):
+        got, got_logp = done[i]["tokens"], done[i]["logprobs"]
+        # full precision's log-probabilities at every position of ITS path
+        logits = forward(jnp.asarray([list(prompt) + list(want)]))
+        logits = logits[0] if isinstance(logits, tuple) else logits
+        logp = np.asarray(jax.nn.log_softmax(logits[0], axis=-1))[len(prompt) - 1:-1]
+        for t, row in enumerate(logp):
+            # the two engines have read the same tokens so far
+            assert abs(got_logp[t] - row[got[t]]) < INT8_LOGPROB_TOL, (prompt, t)
+            compared += 1
+            first, second = np.sort(row)[::-1][:2]
+            if first - second > 2 * INT8_LOGPROB_TOL:
+                decided += 1
+                assert got[t] == want[t], (prompt, t, first - second)
+            if got[t] != want[t]:
+                break  # a near-tie went the other way: other sequences from here
+    # the claim is no empty one: most positions are compared, and more than
+    # half of all 18 are decided by a margin the tolerance cannot cross
+    assert compared >= 12 and 2 * decided > sum(len(r) for r in refs), (compared, decided)
 
 
 def test_int8_pool_fused_matches_gather(monkeypatch):

@@ -81,7 +81,7 @@ def built():
 
     hf = _hf_cfg()
     model, adapter = resolve_architecture(hf)(hf, FP32)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     return model, adapter, params
 
 
@@ -101,7 +101,7 @@ def test_shapes_and_train_smoke(built):
         logits, aux = model(p, ids)
         return jnp.mean(logits.astype(jnp.float32) ** 2)
 
-    val, g = jax.value_and_grad(loss)(params)
+    val, g = jax.jit(jax.value_and_grad(loss))(params)
     assert bool(jnp.isfinite(val))
     for part in ("attn_full", "attn_sliding", "mlp", "moe", "share_expert"):
         gn = jax.tree_util.tree_reduce(

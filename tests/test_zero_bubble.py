@@ -58,7 +58,7 @@ def test_zero_bubble_moe_parity_and_gate_bias_update(devices8):
     gate-bias update (post_step_fn, driven by the forward-accumulated
     expert counts) produces the same bias trajectory under both schedules."""
     from automodel_tpu.data.loader import place_batch
-    from automodel_tpu.optim.builders import build_optimizer
+    from automodel_tpu.optim.builders import build_optimizer, init_opt_state
     from automodel_tpu.training.train_state import TrainState
     from automodel_tpu.training.train_step import (
         build_train_step,
@@ -81,7 +81,7 @@ def test_zero_bubble_moe_parity_and_gate_bias_update(devices8):
         g = _grad_tree(auto.model, auto.params, ids)
 
         opt = build_optimizer(name="adamw", lr=1e-3, grad_clip_norm=1.0)
-        state = TrainState.create(auto.params, jax.jit(opt.init)(auto.params))
+        state = TrainState.create(auto.params, init_opt_state(opt, auto.params, auto.mesh_ctx))
         loss_fn = make_causal_lm_loss(auto.model, constrain=auto.constrain)
         assert loss_fn.pipeline_info["schedule"] == sched
         step = build_train_step(loss_fn, opt, post_step_fn=auto.model.post_step_fn)
