@@ -118,10 +118,10 @@ class GenerationEngine:
                 "families: llama-generic (llama/qwen2/qwen3/mistral/phi3), "
                 "gpt2, qwen3_moe, lfm2_moe (paged serving only)"
             )
-        if kv_cache.recurrent_kinds(layout):
+        if kv_cache.recurrent_kinds(layout, held=("kv",)):
             raise GenerationUnsupported(
                 f"{type(auto.model).__name__} keeps "
-                f"{'/'.join(kv_cache.recurrent_kinds(layout))} state beside K/V: "
+                f"{'/'.join(kv_cache.recurrent_kinds(layout, held=('kv',)))} state beside K/V: "
                 "the contiguous generation cache holds per-head K/V alone "
                 "(no recurrent-state row a sequence); serve it through the "
                 "paged path (`automodel serve`, serving/engine.py)"

@@ -34,11 +34,14 @@ ring (equal-length batches, or ragged ones fitting the window, are exact).
 
 Cache layout: a model states, per layer, WHAT it keeps between a sequence's
 tokens (``model.cache_layout()`` -> one :class:`LayerCache` a layer): per-head
-K/V (``kv``), or the last ``taps - 1`` inputs of a short causal conv
+K/V (``kv``), ONE latent row a token that every head shares (``latent``:
+multi-head latent attention's normed compression beside its rotated shared
+key, 512 + 64 wide), or the last ``taps - 1`` inputs of a short causal conv
 (``conv``), a fixed-size recurrent state. The engines read that layout, never
-a family name. The paged serving path (serving/) holds K/V for the ``kv``
-layers only and, beside it, one state row a slot for the others
-(``KVCache.state``); this contiguous cache holds K/V alone, so the
+a family name. The paged serving path (serving/) holds blocks of K/V for
+``kv`` layers or of latent rows for ``latent`` layers (``PAGED_KINDS``: what
+grows with the sequence) and, beside them, one state row a slot for the
+others (``KVCache.state``); this contiguous cache holds K/V alone, so the
 in-training GenerationEngine refuses a layout with any other kind.
 """
 
@@ -56,17 +59,26 @@ class LayerCache:
     """What ONE layer keeps for a sequence. ``kv``: ``heads`` x ``head_dim``
     keys and values a token (grows with the sequence, paged). ``conv``: the
     ``taps - 1`` last inputs of a depthwise conv over ``channels`` (one fixed
-    size a sequence, indexed by slot)."""
+    size a sequence, indexed by slot). ``latent``: ONE row of ``head_dim``
+    numbers a token (``heads`` 1), shared by every query head (paged); its
+    first ``rank`` numbers are the compression the values are read from."""
 
-    kind: str  # "kv" | "conv"
+    kind: str  # "kv" | "latent" | "conv" (| "delta": stated, not served)
     heads: int = 0
     head_dim: int = 0
     channels: int = 0
     taps: int = 0
+    rank: int = 0
 
 
 def kv_layer(heads: int, head_dim: int) -> LayerCache:
     return LayerCache("kv", heads=int(heads), head_dim=int(head_dim))
+
+
+def latent_layer(width: int, rank: int) -> LayerCache:
+    """One row a token: ``width`` = the latent ``rank`` + the shared rotary
+    key (DeepSeek-style latent attention, 512 + 64)."""
+    return LayerCache("latent", heads=1, head_dim=int(width), rank=int(rank))
 
 
 def conv_layer(channels: int, taps: int) -> LayerCache:
@@ -88,10 +100,17 @@ def layers_of(layout, kind: str) -> list:
     return [c for c in layout if c.kind == kind]
 
 
-def recurrent_kinds(layout) -> list[str]:
-    """The kinds of fixed-size per-sequence state in a layout (not K/V):
-    what "state = blocks + a length" does not describe."""
-    return sorted({c.kind for c in layout if c.kind != "kv"})
+# what grows with the sequence and lives in blocks: "blocks + a length" IS
+# the sequence's state
+PAGED_KINDS = ("kv", "latent")
+
+
+def recurrent_kinds(layout, held: tuple = PAGED_KINDS) -> list[str]:
+    """The kinds of fixed-size per-sequence state in a layout (neither K/V
+    nor latent rows): what "state = blocks + a length" does not describe.
+    ``held=("kv",)``: every kind that is not per-head K/V, which is what the
+    contiguous generation cache and a speculative draft's pool do not hold."""
+    return sorted({c.kind for c in layout if c.kind not in held})
 
 
 def one_geometry(layout, kind: str) -> Optional[LayerCache]:
@@ -238,6 +257,9 @@ class CacheContext:
     write_block: Optional[jnp.ndarray] = None  # [B, S] int32
     write_off: Optional[jnp.ndarray] = None  # [B, S] int32
     paged_interpret: bool = False  # run the Pallas kernel interpreted (CPU)
+    # a latent pool's decode attention through an XLA gather of the tables'
+    # rows instead of the kernel (``serving.decode_kernel: gather``)
+    paged_gather: bool = False
     # a stack that is NOT scanned (layers of several kinds, models/lfm2_moe)
     # hands ``write``/``attend`` the WHOLE stacked sides and names the K/V
     # layer here (``at_layer``): the paged write scatters into the stacked
@@ -359,6 +381,22 @@ class CacheContext:
             write(ck, k.astype(ck.dtype), self.slots),
             write(cv, v.astype(cv.dtype), self.slots),
         )
+
+    def write_latent(self, pool: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
+        """Latent rows ``[B, S, W]`` (the normed compression beside the
+        rotated shared key) into layer ``layer`` of the stacked latent pool
+        ``[L, NB, BS, W]``, in place at the paged write targets. One side:
+        keys and values are both read from this row."""
+        if self.mode != "paged" or self.layer is None:
+            raise NotImplementedError(
+                f"cache mode {self.mode!r} holds no latent rows: a latent "
+                "layer decodes through the paged serving path only"
+            )
+        with jax.named_scope("kv_write"), jax.named_scope("latent_write"):
+            at = (self.layer, self.write_block, self.write_off)
+            pad = pool.shape[-1] - rows.shape[-1]  # the pool's lane padding
+            rows = jnp.pad(rows.astype(pool.dtype), ((0, 0), (0, 0), (0, pad)))
+            return pool.at[at].set(rows)
 
     # -- attend --------------------------------------------------------------
     def attend(
@@ -508,6 +546,14 @@ def packed_heads(
     ) != usable_axes(mesh_ctx, heads, "tensor"):
         return heads, head_dim
     return heads // pack, head_dim * pack
+
+
+def lane_padded(width: int) -> int:
+    """The minor dim a latent pool is ALLOCATED with: ``width`` up to whole
+    lane rows (576 -> 640). The chip stores the padding whatever the shape
+    says, and the decode kernel copies a page out of HBM only in whole tiles;
+    the padding lanes hold zeros and meet zeros in the query."""
+    return -(-int(width) // LANES) * LANES
 
 
 def pack_rows(new: jnp.ndarray, like) -> jnp.ndarray:

@@ -133,10 +133,21 @@ def mla_branch(
     cos: jnp.ndarray,
     sin: jnp.ndarray,
     segment_ids: Optional[jnp.ndarray],
-) -> jnp.ndarray:
+    cache: Optional[jnp.ndarray] = None,
+    cache_ctx: Any = None,
+):
     """The latent-attention branch: the NORMED input ``x`` [B, S, D] in, the
     output projection's result out, no residual. ``mla_block`` adds it to the
-    single-stream residual; models/xing4 writes it into a multi-stream one."""
+    single-stream residual; models/xing4 writes it into a multi-stream one.
+
+    ``cache`` / ``cache_ctx`` (serving/): the stacked LATENT pool ``[L, NB, BS,
+    W]`` and the paged plan with this layer named (``CacheContext.at_layer``).
+    What is cached a token is ``[c | rotated k_rot]`` (``kv_lora_rank`` +
+    ``qk_rope_head_dim`` numbers, every head's keys and values in one row);
+    the return becomes ``(out, pool)``. A decode step's one query a sequence
+    attends ABSORBED, ``kv_b_proj`` folded into the query and the output; a
+    prompt's chunk reads its prefix back and expands it through ``kv_b_proj``
+    (ops/latent_attention.py)."""
     B, S, D = x.shape
     N = cfg.num_heads
     nope, rope, vdim = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -147,20 +158,48 @@ def mla_branch(
         q = qa @ ap["q_b_proj"]["kernel"].astype(x.dtype)
     else:
         q = x @ ap["q_proj"]["kernel"].astype(x.dtype)
+    if cache_ctx is not None:
+        # a serving program's few rows: without the barrier the compiler moves the
+        # split of the 192-wide heads below into the WEIGHT's layout and copies
+        # the whole q projection (100 MB at 4096 x 64 x 192) a layer a call
+        q = jax.lax.optimization_barrier(q)
     q = q.reshape(B, S, N, nope + rope)
     q_pass, q_rot = q[..., :nope], q[..., nope:]
 
     ckv = x @ ap["kv_a_proj"]["kernel"].astype(x.dtype)  # [B,S,kvr+rope]
     k_pass_c, k_rot = ckv[..., : cfg.kv_lora_rank], ckv[..., cfg.kv_lora_rank :]
     k_pass_c = rms_norm(k_pass_c, ap["kv_a_norm"]["scale"], cfg.rms_eps)
-    kv = (k_pass_c @ ap["kv_b_proj"]["kernel"].astype(x.dtype)).reshape(
-        B, S, N, nope + vdim
-    )
-    k_pass, v = kv[..., :nope], kv[..., nope:]
+    if cache_ctx is None:
+        kv = (k_pass_c @ ap["kv_b_proj"]["kernel"].astype(x.dtype)).reshape(
+            B, S, N, nope + vdim
+        )
+        k_pass, v = kv[..., :nope], kv[..., nope:]
 
     k_rot = k_rot[:, :, None, :]  # single shared rope head [B,S,1,rope]
     if cfg.use_rope:
         q_rot, k_rot = apply_rope(q_rot, k_rot, cos, sin, interleave=cfg.rope_interleave)
+
+    if cache_ctx is not None:
+        from automodel_tpu.ops import latent_attention
+
+        pool = cache_ctx.write_latent(
+            cache, jnp.concatenate([k_pass_c, k_rot[:, :, 0, :]], axis=-1)
+        )
+        kw = dict(layer=cache_ctx.layer, scale=cfg.mla_attn_scale, v_dim=vdim)
+        w_kvb = ap["kv_b_proj"]["kernel"]
+        if S == 1:
+            out = latent_attention.absorbed_attend(
+                q_pass, q_rot, pool, w_kvb, cache_ctx.tables, cache_ctx.q_pos,
+                interpret=cache_ctx.paged_interpret, gather=cache_ctx.paged_gather,
+                **kw,
+            )
+        else:
+            out = latent_attention.chunk_attend(
+                q_pass, q_rot, pool, w_kvb, cache_ctx.tables, cache_ctx.q_pos, **kw
+            )
+        out = out.reshape(B, S, N * vdim) @ ap["o_proj"]["kernel"].astype(x.dtype)
+        return out, pool
+
     k_rot = jnp.broadcast_to(k_rot, (B, S, N, rope))
 
     qh = jnp.concatenate([q_pass, q_rot], axis=-1)

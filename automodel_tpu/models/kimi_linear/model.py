@@ -22,7 +22,9 @@ stacked by kind (``kda`` / ``mla``), the MLPs likewise (``dense_mlp`` /
 routing, as models/qwen3_next does.
 
 Not served: a delta-rule state has no slot in serving/'s cache layouts yet
-(``cache_layout`` says so, ``ServeConfig.check_layout`` refuses it).
+(``cache_layout`` says so, ``ServeConfig.check_layout`` refuses the family for
+its ``delta`` layers alone; its latent layers' rows would live in the latent
+pool that models/sarvam_mla is served from).
 """
 
 from __future__ import annotations
@@ -358,16 +360,18 @@ class KimiLinearForCausalLM:
         return h @ self.lm_head(params).astype(h.dtype), aux
 
     def cache_layout(self) -> tuple:
-        """What a serving cache would keep a layer: a delta-rule state
-        ``[heads, dk, dv]`` (and three conv windows) for a KDA layer, the
-        latent row for an MLA layer. serving/ holds neither yet
-        (``ServeConfig.check_layout`` refuses the family)."""
+        """What a serving cache keeps a layer: a delta-rule state ``[heads, dk,
+        dv]`` (and three conv windows) for a KDA layer, one latent row a token
+        for an MLA layer. serving/ holds latent rows (the latent pool,
+        serving/paged.py) and no delta-rule state yet:
+        ``ServeConfig.check_layout`` refuses the family for its ``delta``
+        layers alone."""
         from automodel_tpu.generation import kv_cache
 
         c = self.config
         return tuple(
             kv_cache.LayerCache("delta", c.kda_num_heads, c.kda_head_dim) if k == "kda"
-            else kv_cache.LayerCache("latent", 1, c.kv_lora_rank + c.qk_rope_head_dim)
+            else kv_cache.latent_layer(c.kv_lora_rank + c.qk_rope_head_dim, c.kv_lora_rank)
             for k in c.layer_kinds
         )
 

@@ -283,6 +283,18 @@ def _kimi_k25_vl_builder(hf_config: Any, backend: BackendConfig):
     )
 
 
+@register_architecture("SarvamMlaForCausalLM", "sarvam_mla")
+def _sarvam_mla_builder(hf_config: Any, backend: BackendConfig):
+    from automodel_tpu.models.sarvam_mla import (
+        SarvamMlaConfig,
+        SarvamMlaForCausalLM,
+        SarvamMlaStateDictAdapter,
+    )
+
+    cfg = SarvamMlaConfig.from_hf(hf_config)
+    return SarvamMlaForCausalLM(cfg, backend), SarvamMlaStateDictAdapter(cfg)
+
+
 @register_architecture("Lfm2MoeForCausalLM")
 def _lfm2_moe_builder(hf_config: Any, backend: BackendConfig):
     from automodel_tpu.models.lfm2_moe import (

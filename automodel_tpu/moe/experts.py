@@ -879,6 +879,8 @@ def held_experts(
         flat = gate_out.topk_idx.reshape(-1)  # [T*K]
         held = (flat >= lo) & (flat < hi)
         order = jnp.argsort(jnp.where(held, flat - lo, Eh))[:cap]  # stable; held picks first
+        if cap > order.size:  # cap rounded up past T*K: the padding is never valid
+            order = jnp.pad(order, (0, cap - order.size))
         ends = jnp.minimum(jnp.cumsum(gate_out.expert_counts[lo:hi].astype(jnp.int32)), cap)
         group_sizes = jnp.diff(ends, prepend=0)
         valid = jnp.arange(cap) < ends[-1]

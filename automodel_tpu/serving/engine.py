@@ -93,7 +93,7 @@ from automodel_tpu.generation.engine import (
 )
 from automodel_tpu.generation import kv_cache
 from automodel_tpu.generation.sampling import sample
-from automodel_tpu.ops import paged_attention
+from automodel_tpu.ops import latent_attention, paged_attention
 from automodel_tpu.serving import loop_account, paged
 from automodel_tpu.serving.block_pool import (
     BlockPool,
@@ -492,6 +492,10 @@ class ServeConfig:
     block_size: int = 16  # tokens per KV block
     num_blocks: int = 512  # pool size (block 0 is scratch)
     prefill_chunk: int = 64  # prompt tokens per engine iteration per slot
+    # chunk programs one engine iteration runs at most, the oldest admissions
+    # first; 0: one for EVERY prefilling slot, however many (a decoding slot's
+    # next token then waits for all of them)
+    max_prefill_chunks_per_step: int = 0
     max_seq_len: int = 1024  # per-request prompt + generated cap
     max_queue: int = 4096
     prefix_cache: bool = True
@@ -527,6 +531,11 @@ class ServeConfig:
             )
         if self.max_seq_len < 2:
             raise ValueError(f"serving.max_seq_len={self.max_seq_len}")
+        if self.max_prefill_chunks_per_step < 0:
+            raise ValueError(
+                "serving.max_prefill_chunks_per_step="
+                f"{self.max_prefill_chunks_per_step} (want 0 = no bound, or >= 1)"
+            )
         if self.kv_cache_dtype not in ("bf16", "int8"):
             raise ValueError(
                 f"serving.kv_cache_dtype={self.kv_cache_dtype!r} "
@@ -571,37 +580,59 @@ class ServeConfig:
         return cls(**d)
 
     def check_layout(self, layout, model_name: str) -> None:
-        """Refuse, before anything is built, every feature that takes a
-        sequence's state to be "blocks + a length" when the model's cache
-        layout (``cache_layout()``, generation/kv_cache.py) also keeps a
-        fixed-size recurrent state a slot: none of them is silently off."""
+        """Refuse, before anything is built, what the model's cache layout
+        (``cache_layout()``, generation/kv_cache.py) cannot be served with:
+        none of it is silently off. A layout that also keeps a fixed-size
+        recurrent state a slot loses every feature that takes a sequence's
+        state to be "blocks + a length"; a LATENT layout (one shared row a
+        token, paged like K/V: prefix reuse works on its blocks as it stands)
+        loses those that ship block rows or need a second pool."""
         kinds = kv_cache.recurrent_kinds(layout)
-        if not kinds:
-            return
         unserved = [k for k in kinds if k != "conv"]
         if unserved:
             raise ValueError(
                 f"{model_name} keeps {'/'.join(unserved)} state a layer (a delta-rule "
-                "state [heads, dk, dv] a slot, a latent row a token): serving/ holds "
-                "per-head K/V and conv windows only, so a delta-rule state is not "
-                "served yet; the family trains (recipes/train_ft.py)"
+                "state [heads, dk, dv] a slot): serving/ holds per-head K/V, latent "
+                "rows and conv windows, so a delta-rule state is not served yet; "
+                "the family trains (recipes/train_ft.py)"
             )
-        what = f"{model_name} keeps {'/'.join(kinds)} state beside K/V"
-        refused = [
-            (self.prefix_cache, "prefix_cache: true",
-             "a prefix hit resumes at a block boundary and would need the "
-             "recurrent state AT that boundary, which is kept only for a "
-             "slot's newest position (set serving.prefix_cache: false)"),
-            (self.kv_spill.enabled, "kv_spill.enabled",
-             "spilled and peer-fetched prefixes are K/V blocks by chain hash; "
-             "the recurrent state at their boundary is not among them"),
-            (self.speculative.enabled, "speculative.enabled",
-             "rollback of rejected drafts is a length decrement, which does "
-             "not undo the inputs already shifted into a recurrent state"),
-            (self.role != "mixed", f"role: {self.role}",
-             "the prefill->decode handoff ships K/V block rows only; the "
-             "slot's recurrent state would stay behind"),
-        ]
+        if kv_cache.layers_of(layout, "latent"):
+            what = f"{model_name} keeps one latent row a token (a pool of one side, no V)"
+            refused = [
+                (self.kv_cache_dtype != "bf16", f"kv_cache_dtype: {self.kv_cache_dtype}",
+                 "a latent row has no per-head scale to quantize under"),
+                (self.kv_spill.enabled, "kv_spill.enabled",
+                 "spilled and peer-fetched prefixes are (k, v) block rows; a "
+                 "latent block's rows are not shipped yet"),
+                (self.speculative.enabled, "speculative.enabled",
+                 "the draft's parallel pool and the verify forward over a "
+                 "latent pool are not built or tested"),
+                (self.role != "mixed", f"role: {self.role}",
+                 "the prefill->decode handoff ships (k, v) block rows; a latent "
+                 "block's rows are not shipped yet"),
+                (bool(self.kv_transfer.enabled), "kv_transfer.enabled",
+                 "its listener receives (k, v) block rows; a latent block's "
+                 "rows are not shipped yet"),
+            ]
+        elif kinds:
+            what = f"{model_name} keeps {'/'.join(kinds)} state beside K/V"
+            refused = [
+                (self.prefix_cache, "prefix_cache: true",
+                 "a prefix hit resumes at a block boundary and would need the "
+                 "recurrent state AT that boundary, which is kept only for a "
+                 "slot's newest position (set serving.prefix_cache: false)"),
+                (self.kv_spill.enabled, "kv_spill.enabled",
+                 "spilled and peer-fetched prefixes are K/V blocks by chain hash; "
+                 "the recurrent state at their boundary is not among them"),
+                (self.speculative.enabled, "speculative.enabled",
+                 "rollback of rejected drafts is a length decrement, which does "
+                 "not undo the inputs already shifted into a recurrent state"),
+                (self.role != "mixed", f"role: {self.role}",
+                 "the prefill->decode handoff ships K/V block rows only; the "
+                 "slot's recurrent state would stay behind"),
+            ]
+        else:
+            return
         for on, key, why in refused:
             if on:
                 raise ValueError(f"serving.{key} is refused: {what}, and {why}")
@@ -730,10 +761,11 @@ class ServingEngine:
             raise GenerationUnsupported(
                 f"{type(auto.model).__name__} states no cache layout "
                 "(cache_layout(): what each layer keeps for a sequence — `kv` "
-                "heads x head_dim, or `conv` channels x taps), so it has no "
-                "decode path; families that state one: llama-generic (llama/"
-                "qwen2/qwen3/mistral/phi3), gpt2, qwen3_moe (all `kv`), "
-                "lfm2_moe (`kv` + `conv`)"
+                "heads x head_dim, `latent` one row a token, or `conv` channels "
+                "x taps), so it has no decode path; families that state one: "
+                "llama-generic (llama/qwen2/qwen3/mistral/phi3), gpt2, "
+                "qwen3_moe (all `kv`), lfm2_moe (`kv` + `conv`), sarvam_mla "
+                "(`latent`)"
             )
         self.auto = auto
         self.model = auto.model
@@ -741,6 +773,8 @@ class ServingEngine:
         self.config.check_layout(self._layout, type(auto.model).__name__)
         # a layout with a recurrent kind: the pool carries one state row a slot
         self._stateful = bool(kv_cache.recurrent_kinds(self._layout))
+        # a latent layout: the pool has one side and its own decode kernel
+        self._latent = bool(kv_cache.layers_of(self._layout, "latent"))
         self.gen_config = gen_config or GenerationConfig()
         self.on_record = on_record
         mcfg = self.model.config
@@ -762,7 +796,16 @@ class ServingEngine:
 
         self._interpret = _interpret_requested()
         self.decode_backend = self._resolve_decode_backend()
-        if self.decode_backend == "fused" and auto.mesh_ctx is not None:
+        if self._latent:
+            from automodel_tpu.ops.platform_check import kernel_axes
+
+            if kernel_axes(auto.mesh_ctx) is not None:
+                raise ValueError(
+                    f"{type(auto.model).__name__} keeps latent rows: the latent "
+                    "pool and its decode kernel run on one device (no shard_map "
+                    "over a mesh is built for them yet)"
+                )
+        elif self.decode_backend == "fused" and auto.mesh_ctx is not None:
             # the paged kernel runs per TP shard on whole heads
             # (ops/paged_attention.paged_attend); refuse here, not at the
             # first decode step — where step() would catch the error, fail
@@ -802,7 +845,7 @@ class ServingEngine:
                 spec.draft, auto.mesh_ctx, seed=self.gen_config.seed
             )
             draft_layout = kv_cache.layout_of(self.draft_auto.model)
-            if draft_layout is None or kv_cache.recurrent_kinds(draft_layout):
+            if draft_layout is None or kv_cache.recurrent_kinds(draft_layout, held=("kv",)):
                 raise GenerationUnsupported(
                     "serving.speculative.draft model "
                     f"{type(self.draft_auto.model).__name__} must state a "
@@ -847,7 +890,8 @@ class ServingEngine:
             interpret=self._interpret,
         )
         self._chunk = paged.build_chunk_prefill_fn(
-            apply, self.config.prefill_chunk, self._compute_dtype
+            apply, self.config.prefill_chunk, self._compute_dtype,
+            interpret=self._interpret, gather=self.decode_backend == "gather",
         )
         # the decode program always computes the sampled token's logprob
         # beside the token (one extra gather off logits already in hand);
@@ -1064,7 +1108,15 @@ class ServingEngine:
         """Pool pages the fused decode kernel attends a grid step
         (ops/paged_attention.pages_per_step), from what the kernel itself
         sees: one TP shard's page of the pool as allocated (packed heads)
-        and the query rows a KV head."""
+        and the query rows a KV head. A latent pool: its own kernel's picker
+        (ops/latent_attention.pages_per_step), every head's queries against
+        one page of rows."""
+        if self._latent:
+            block_size, width = self._pool.k.shape[-2:]
+            return latent_attention.pages_per_step(
+                block_size, width, self._layout[0].rank,
+                int(self.model.config.num_heads), self._pool.k.dtype.itemsize,
+            )
         k = self._pool.k
         values = k[0] if self._quantized else k
         block_size, nkv, width = values.shape[-3:]
@@ -1096,9 +1148,23 @@ class ServingEngine:
             or not _pallas_eligible(ctx.platform if ctx is not None else backend.platform)
         ):
             return None
-        rows = self.config.slots * int(moe.num_experts_per_tok)
+        K = int(moe.num_experts_per_tok)
         D, I = int(mcfg.hidden_size), int(moe.moe_intermediate_size)
-        return lambda counts: fused_expert_mlp.work_units(counts, rows, D, I)
+        if moe.held_experts is None:
+            rows = self.config.slots * K
+            return lambda counts: fused_expert_mlp.work_units(counts, rows, D, I)
+        # a chip that holds a share of each layer's experts: the kernel's plan
+        # is over the held experts' groups in the held picks' row buffer
+        # (moe/experts.held_experts), and the picks that landed there are
+        # counted beside its units
+        lo, hi = moe.held_experts
+        rows = -(-self.config.slots * min(K, hi - lo) // 8) * 8
+
+        def held_units(counts):
+            held = counts[..., lo:hi]
+            return (*fused_expert_mlp.work_units(held, rows, D, I), held.sum())
+
+        return held_units
 
     @property
     def _attn_query_rows(self) -> int:
@@ -1533,6 +1599,7 @@ class ServingEngine:
     def kv_geometry(self) -> dict:
         """The pool geometry a KV-transfer peer must match exactly — the
         handshake header both sides validate before any block row moves."""
+        paged._refuse_latent(self._pool, "kv_geometry")
         L, _, BS, Nkv, H = self._pool.values_shape
         return {
             "layers": int(L),
@@ -2336,9 +2403,15 @@ class ServingEngine:
         done: list[dict] = []
         chunk_len = self.config.prefill_chunk
         pad = self.gen_config.pad_token_id
-        for b, slot in enumerate(self._slots):
-            if slot is None or slot.decoding:
-                continue
+        prefilling = [
+            (b, s) for b, s in enumerate(self._slots) if s is not None and not s.decoding
+        ]
+        cap = self.config.max_prefill_chunks_per_step
+        if cap and len(prefilling) > cap:
+            # a bounded iteration: the rest keep their slots and wait a turn, so
+            # the decode step that follows is at most ``cap`` chunks away
+            prefilling = sorted(prefilling, key=lambda bs: bs[1].t_admit)[:cap]
+        for b, slot in prefilling:
             p = len(slot.prompt)
             start = slot.prefill_pos
             real = min(chunk_len, p - start)
@@ -2532,6 +2605,8 @@ class ServingEngine:
             )
         n["expert_live_units"] += int(units[0])
         n["expert_grid_units"] += int(units[1])
+        if len(units) > 2:  # a held share's picks (_expert_units_fn)
+            n["held_expert_rows"] += int(units[2])
         self.first_decode_done = True
         done: list[dict] = []
         with self._phase("record"):
@@ -2559,9 +2634,16 @@ class ServingEngine:
         n = self._account.n
         n["decoded"] += int(active.sum())
         n["context_tokens"] += int(lengths[active].sum())
+        if self._latent:
+            # rows the decode attention has to read, every layer's: each slot
+            # the call is handed, active or not (the kernel attends them all)
+            n["latent_context_rows"] += len(self._layout) * latent_attention.context_rows(
+                lengths
+            )
         if self.decode_backend == "fused":
             # every slot's row, active or not: the kernel attends them all
-            grid, live = paged_attention.grid_steps(
+            steps = latent_attention.grid_steps if self._latent else paged_attention.grid_steps
+            grid, live = steps(
                 lengths, self._tables.shape[1],
                 pages=self.attn_pages_per_step,
                 block_size=self.config.block_size, sq=self._attn_query_rows,

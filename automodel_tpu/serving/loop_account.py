@@ -45,6 +45,9 @@ ITERATION_COUNTS = (
     "attn_grid_steps", "attn_live_steps", "expert_grid_units",
     "expert_live_units", "decode_launched", "decode_launched_ahead",
     "discarded_rows",
+    # a latent layout's rows the decode attention had to read (all layers); a
+    # held share's picks that landed on the experts this chip holds
+    "latent_context_rows", "held_expert_rows",
 )
 COUNTS = ("iterations", "first_token_waits") + ITERATION_COUNTS
 # what of them a request's slice keeps (docs/serving.md "decode_account")

@@ -10,7 +10,13 @@ head) fp32 scales ``[L_kv, NB, BS, N_kv]`` riding alongside
 token, so ~2× the sequences per chip on the same HBM budget); and for the
 ``L_conv`` layers that keep a short conv's last inputs, ONE slot-indexed
 array ``state [L_conv, slots, taps - 1, C]`` beside them (None when every
-layer keeps K/V). Recurrent state is not paged: it has one fixed size a slot.
+layer keeps K/V). A model whose layers keep ONE latent row a token
+(multi-head latent attention, ``latent``) gets a pool of ONE side instead:
+``k`` ``[L, NB, BS, W]`` (``W`` = 512 + 64 padded to whole lanes), ``v`` None;
+its chunk program and its decode program both write and read that pool in
+place through the tables (``ops/latent_attention``), no gathered view ever
+exists, and the block tables and their allocation are the K/V pool's.
+Recurrent state is not paged: it has one fixed size a slot.
 A prompt's chunk reads its slot's row as the positions before its first
 token (zeros when that token is position 0: the reset on slot reuse is that
 test, on the traced offset) and writes back the inputs at its last REAL
@@ -95,7 +101,9 @@ class PagedKV:
     ``(values int8, scales fp32 [L, NB, BS, N_kv])`` pair — the same pytree
     shape the model's layer scan slices per layer. ``state`` covers the
     layers that keep a short conv's last inputs: ``[L_conv, slots, taps -
-    1, C]``, one row a slot, or None for a K/V-only layout."""
+    1, C]``, one row a slot, or None for a K/V-only layout. A LATENT pool
+    (every paged layer keeps one shared row a token) has one side: ``k``
+    ``[L, NB, BS, W]`` and ``v`` None."""
 
     k: Any
     v: Any
@@ -104,6 +112,10 @@ class PagedKV:
     @property
     def quantized(self) -> bool:
         return isinstance(self.k, tuple)
+
+    @property
+    def latent(self) -> bool:
+        return self.v is None
 
     @property
     def values_shape(self) -> tuple:
@@ -148,8 +160,20 @@ def layout_pool(
     ``mesh_ctx``: the mesh ``place_pool`` will shard it over."""
     kv = kv_cache.one_geometry(layout, "kv")
     conv = kv_cache.one_geometry(layout, "conv")
+    latent = kv_cache.one_geometry(layout, "latent")
+    if latent is not None:
+        if kv is not None or conv is not None or quantized:
+            raise NotImplementedError(
+                "a latent pool holds latent rows alone, in the model's compute "
+                "dtype: no K/V or conv layer beside them, no int8"
+            )
+        shape = (
+            len(kv_cache.layers_of(layout, "latent")), num_blocks, block_size,
+            kv_cache.lane_padded(latent.head_dim),
+        )
+        return PagedKV(k=jnp.zeros(shape, dtype), v=None)
     if kv is None:
-        raise NotImplementedError("cache layout without a K/V layer")
+        raise NotImplementedError("cache layout without a K/V or latent layer")
     return init_pool(
         len(kv_cache.layers_of(layout, "kv")), num_blocks, block_size,
         # heads narrower than a lane row are packed side by side
@@ -187,6 +211,8 @@ def place_pool(pool: PagedKV, mesh_ctx) -> PagedKV:
     """Shard the pool onto the mesh as ``pool_shardings`` says."""
     if mesh_ctx is None:
         return pool
+    if pool.latent:  # one row for every head: each shard reads it whole
+        return jax.device_put(pool, mesh_ctx.replicated())
     return jax.device_put(
         pool,
         pool_shardings(
@@ -272,6 +298,15 @@ _write_targets = kv_cache.paged_write_targets
 # -- KV extraction / injection (disaggregated prefill→decode handoff) --------
 
 
+def _refuse_latent(pool: PagedKV, what: str) -> None:
+    if pool.latent:
+        raise NotImplementedError(
+            f"{what}: block rows of a latent pool (one side, no V) are not "
+            "shipped yet; spill, hand-off and peer fetch are refused for a "
+            "latent layout (ServeConfig.check_layout)"
+        )
+
+
 def extract_blocks(pool: PagedKV, blocks) -> tuple:
     """Pull one request's block rows out of the pool to host memory —
     ``(k, v)``, each ``[L, nb, BS, Nkv, H]`` (or ``(int8 values, fp32
@@ -281,6 +316,7 @@ def extract_blocks(pool: PagedKV, blocks) -> tuple:
     the next row before it is ever attended)."""
     import numpy as np
 
+    _refuse_latent(pool, "extract_blocks")
     idx = jnp.asarray(np.asarray(blocks, np.int32))
 
     def side(s):
@@ -329,6 +365,7 @@ def inject_blocks(pool: PagedKV, blocks, kv: dict) -> PagedKV:
     cells for the same table."""
     import numpy as np
 
+    _refuse_latent(pool, "inject_blocks")
     table = jnp.asarray(np.asarray(blocks, np.int32))
     fn = _inject_fn(int(table.shape[0]), pool.quantized)
     return fn(pool, table, kv["k"], kv["v"])
@@ -469,13 +506,15 @@ def _gather_forward(
 
 def _fused_forward(
     apply: Callable, params, pool: PagedKV, tables, lengths, tokens, active,
-    block_size: int, interpret: bool,
+    block_size: int, interpret: bool, gather: bool = False,
 ):
     """tokens [B, S] through the paged-mode cache: per-layer writes scatter
     the S rows straight into the pool slices (quantize-on-write) and
     attention runs the fused Pallas kernel over the pool via the tables —
     no view is ever materialized. → (logits [B,S,V] fp32, new pool, expert
-    counts [L_moe, E] | None)."""
+    counts [L_moe, E] | None). A LATENT pool always comes this way, its
+    prompts' chunks too (``S`` = the chunk); ``gather`` then has its decode
+    attention gather the tables' rows in XLA instead of running the kernel."""
     B, S = tokens.shape
     lengths = lengths.astype(jnp.int32)
     kvc = kv_cache.KVCache(
@@ -486,6 +525,7 @@ def _fused_forward(
         kvc, ctx = kv_cache.paged_ctx(
             kvc, tables, lengths, S, active, block_size, interpret=interpret
         )
+        ctx = dataclasses.replace(ctx, paged_gather=bool(gather))
     if pool.state is not None:  # batch row b is slot b; inactive slots feed nothing
         ctx = kv_cache.with_state_plan(
             ctx, lengths, jnp.where(active, S, 0).astype(jnp.int32)
@@ -507,21 +547,31 @@ def _make_forward(
     apply: Callable, backend: str, block_size: int, compute_dtype,
     interpret: bool,
 ) -> Callable:
+    fused = functools.partial(
+        _fused_forward, apply, block_size=block_size, interpret=interpret
+    )
     if backend == "fused":
-        return functools.partial(
-            _fused_forward, apply, block_size=block_size, interpret=interpret
-        )
-    return functools.partial(
+        return fused
+    gathered = functools.partial(
         _gather_forward, apply,
         compute_dtype=compute_dtype, block_size=block_size,
     )
+
+    def forward(params, pool, *args):
+        # a latent pool has no gathered VIEW: the gather is its attention's own
+        if pool.latent:
+            return fused(params, pool, *args, gather=True)
+        return gathered(params, pool, *args)
+
+    return forward
 
 
 # -- programs ----------------------------------------------------------------
 
 
 def build_chunk_prefill_fn(
-    apply: Callable, chunk_len: int, compute_dtype=None
+    apply: Callable, chunk_len: int, compute_dtype=None, *,
+    interpret: bool = False, gather: bool = False,
 ) -> Callable:
     """→ jitted ``chunk(params, pool, table [NBseq], chunk_ids [chunk_len],
     start, real_len[, slot])`` → ``(last_logits [V] fp32, pool)`` for ONE
@@ -533,10 +583,22 @@ def build_chunk_prefill_fn(
     the sequence owns: read as the positions before ``start`` (zeros when
     ``start == 0``), written back at the chunk's last real positions.
     Always the gathered-view path: prefill is compute-bound and one
-    compiled program serves every offset."""
+    compiled program serves every offset. A LATENT pool has no view: its
+    chunk writes its rows through the table and attends the prefix in place
+    (``_fused_forward`` with one sequence of ``chunk_len`` tokens; rows past
+    ``real_len`` land past the prompt's end, where no query of the chunk and
+    no later one looks before a decode step has overwritten them);
+    ``interpret`` / ``gather`` are that path's, as the decode program's."""
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def chunk(params, pool: PagedKV, table, chunk_ids, start, real_len, slot=None):
+        if pool.latent:
+            logits, pool, _ = _fused_forward(
+                apply, params, pool, table[None, :], start[None], chunk_ids[None, :],
+                jnp.ones((1,), bool), block_size=pool.values_shape[2],
+                interpret=interpret, gather=gather,
+            )
+            return logits[0, real_len - 1], pool
         L, _, BS, Nkv, H = pool.values_shape
         NBseq = table.shape[0]
         cd = compute_dtype or (

@@ -107,4 +107,11 @@ SCOPES = (
     # innermost, its projection `mlp`; benchmarks/metrics/mhc_ms.train.py,
     # mhc_stream_roofline.py and mtp_ms.train.py read them
     "norm/mhc", "norm/mhc/mhc_coeff", "norm/mhc/mhc_pre", "norm/mhc/mhc_post", "mtp",
+    # the latent block served from a latent pool (models/sarvam_mla,
+    # ops/latent_attention.py): the decode step's absorption, absorbed attention
+    # and value expansion, a prompt chunk's prefix expansion and attention,
+    # and the row's write; benchmarks/metrics/mla_ms.serve.py,
+    # latent_attn_roofline.serve.py and mla_attn_roofline.serve.py read them
+    "attn/mla/mla_q_absorb", "attn/mla/mla_latent_attn", "attn/mla/mla_v_expand",
+    "attn/mla/mla_prefix_expand", "attn/mla/mla_chunk_attn", "kv_write/latent_write",
 )

@@ -129,7 +129,10 @@ def test_tiles_of_the_cells_expert_shapes(picker, shape):
 # kernels do
 FWD = [((2048, 768), (256, 256), False), ((3072, 1536), (256, 128), False),
        ((2048, 1792), (256, 256), False), ((2304, 1024), (256, 256), False),
-       ((3584, 1024), (256, 512), True)]
+       ((3584, 1024), (256, 512), True),
+       # sarvam-105b's held experts on the serve path (4096 x 2048): over the
+       # default stack at every tile too
+       ((4096, 2048), (256, 512), True)]
 
 
 @pytest.mark.parametrize("shape,want,asks", FWD, ids=[f"{d}x{i}" for (d, i), _, _ in FWD])
@@ -142,3 +145,21 @@ def test_forward_tiles_and_the_vmem_they_ask_for(shape, want, asks):
     need = fem._fwd_vmem(tm, ic, D)
     assert need <= (fem._VMEM_BUDGET if asks else fem._FWD_STACK)
     assert fem._fwd_vmem_limit(tm, ic, D) == ({"vmem_limit_bytes": fem._VMEM_LIMIT} if asks else {})
+
+
+def test_the_latent_decode_kernels_pages_at_the_sarvam_cells_shapes():
+    """ops/latent_attention.pages_per_step beside its kernel: 64 heads against
+    pages of 16 rows x 640 lanes (576 padded) in bfloat16. A decode step takes
+    1,024 positions a grid step, under the VMEM budget. The host's count of
+    the grid is the kernel's own arithmetic."""
+    import numpy as np
+
+    from automodel_tpu.ops import latent_attention as la
+
+    assert la.pages_per_step(16, 640, 512, 64, 2) == 64
+    assert la._step_bytes(64, 16, 640, 512, 64, 2) <= la._VMEM_BUDGET
+    # the cell at its fullest: 32 slots of 7,935 cached rows, a table of 544 blocks
+    lengths = np.full((32,), 7935)
+    assert la.grid_steps(lengths, 544, pages=64, block_size=16) == (32 * 9, 32 * 8)
+    assert la.context_rows(lengths) == 32 * 7936
+    assert la.grid_steps(np.zeros((32,), int), 544, pages=64, block_size=16) == (288, 32)

@@ -122,7 +122,10 @@ def test_every_scope_is_in_the_programs_op_names(programs):
     assert set(SCOPES) - set(program_trace.VOCABULARY) == {
         "conv", "kv_write/state_write", "attn/kda", "attn/kda/kda_conv", "attn/kda/kda_gate",
         "attn/kda/kda_chunk", "attn/kda/kda_norm", "attn/mla",
-        "norm/mhc", "norm/mhc/mhc_coeff", "norm/mhc/mhc_pre", "norm/mhc/mhc_post", "mtp"}
+        "norm/mhc", "norm/mhc/mhc_coeff", "norm/mhc/mhc_pre", "norm/mhc/mhc_post", "mtp",
+        # ... and the latent pool's segments (tests/test_sarvam_mla.py finds those)
+        "attn/mla/mla_q_absorb", "attn/mla/mla_latent_attn", "attn/mla/mla_v_expand",
+        "attn/mla/mla_prefix_expand", "attn/mla/mla_chunk_attn", "kv_write/latent_write"}
 
 
 @pytest.mark.parametrize("program,must_have", [

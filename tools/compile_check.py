@@ -119,7 +119,10 @@ def serve_programs(cfg, ctx):
                     sharding=Format(Layout(major_to_minor=tuple(range(a.ndim))), s),
                 ),
                 pool,
-                paged.pool_shardings(
+                # a latent pool (one side) is replicated, as place_pool does
+                paged.PagedKV(k=self.auto.mesh_ctx.replicated(), v=None)
+                if pool.latent
+                else paged.pool_shardings(
                     self.auto.mesh_ctx, pool.values_shape[3], self._quantized,
                     pool.state is not None,
                 ),
