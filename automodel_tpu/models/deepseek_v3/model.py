@@ -195,7 +195,9 @@ def mla_branch(
             )
         else:
             out = latent_attention.chunk_attend(
-                q_pass, q_rot, pool, w_kvb, cache_ctx.tables, cache_ctx.q_pos, **kw
+                q_pass, q_rot, pool, w_kvb, cache_ctx.tables, cache_ctx.q_pos,
+                interpret=cache_ctx.paged_interpret, gather=cache_ctx.paged_gather,
+                **kw,
             )
         out = out.reshape(B, S, N * vdim) @ ap["o_proj"]["kernel"].astype(x.dtype)
         return out, pool

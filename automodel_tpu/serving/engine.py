@@ -1129,6 +1129,36 @@ class ServingEngine:
             values.dtype.itemsize, self._quantized,
         )
 
+    @functools.cached_property
+    def _chunk_attn_blocks(self):
+        """The blocks of a latent layer's chunk kernel
+        (ops/latent_attention.chunk_blocks) from what the chunk program hands
+        it, or None where a chunk's attention is not that kernel: a K/V
+        layout, the gather backend, widths the kernel cannot tile."""
+        if not self._latent or self.decode_backend != "fused":
+            return None
+        mcfg = self.model.config
+        return latent_attention.chunk_blocks(
+            self.config.prefill_chunk, int(mcfg.num_heads), int(mcfg.qk_nope_head_dim),
+            int(mcfg.qk_rope_head_dim), int(mcfg.v_head_dim), self._tables.shape[1],
+            self.config.block_size, interpret=self._interpret,
+        )
+
+    def _chunk_attn_steps(self, start: int) -> dict:
+        """A latent layout's chunk at ``start``: its attention kernels' grid
+        steps and those that attend a live key block, all layers, by the
+        kernel's own arithmetic (0 and 0 where the chunk takes the loop)."""
+        if not self._latent:
+            return {}
+        grid = live = 0
+        if self._chunk_attn_blocks is not None:
+            grid, live = latent_attention.chunk_grid_steps(
+                [start], self.config.prefill_chunk, int(self.model.config.num_heads),
+                self._chunk_attn_blocks,
+            )
+        layers = len(self._layout)
+        return {"attn_grid_steps": layers * grid, "attn_live_steps": layers * live}
+
     def _expert_units_fn(self) -> Optional[Callable]:
         """``counts [L_moe, E]`` → the (live, grid) work units of the fused
         expert forward over a decode step's expert layers, or None where the
@@ -2416,7 +2446,8 @@ class ServingEngine:
             start = slot.prefill_pos
             real = min(chunk_len, p - start)
             with self._phase(
-                "prefill_dispatch", slot=b, pos=start, tokens=real
+                "prefill_dispatch", slot=b, pos=start, tokens=real,
+                **self._chunk_attn_steps(start),
             ) as dispatch:
                 ids = np.full((chunk_len,), pad, np.int32)
                 ids[:real] = slot.prompt[start : start + real]

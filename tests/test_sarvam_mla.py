@@ -9,6 +9,7 @@ it returned.
 """
 
 import dataclasses
+import functools
 import json
 import sys
 from pathlib import Path
@@ -179,10 +180,117 @@ def test_absorbed_is_expanded_on_the_same_rows(setup):
     kw = dict(layer=1, scale=cfg.mla_attn_scale, v_dim=v)
     absorbed = jax.jit(lambda *a: latent_attention.absorbed_attend(*a, gather=True, **kw))(
         qn[0][:, None], qr[0][:, None], pool, w, jnp.tile(tables, (4, 1)), start + jnp.arange(4))
-    expanded = jax.jit(lambda *a: latent_attention.chunk_attend(*a, kv_block=16, **kw))(
-        qn, qr, pool, w, tables, start)
-    np.testing.assert_allclose(np.asarray(absorbed)[:, 0], np.asarray(expanded)[0], atol=2e-5)
+    for path in ({"gather": True}, {"interpret": True}):  # the loop, the kernel
+        expanded = jax.jit(lambda *a: latent_attention.chunk_attend(*a, kv_block=16, **path, **kw))(
+            qn, qr, pool, w, tables, start)
+        np.testing.assert_allclose(np.asarray(absorbed)[:, 0], np.asarray(expanded)[0], atol=2e-5)
     assert np.abs(np.asarray(absorbed)).max() > 0.05
+
+
+# -- the chunk kernel against the loop --------------------------------------------
+
+KEYS = 16  # keys a block of either path in the cases below: two table entries of 8
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_case(B: int, N: int, nope: int, rope: int, v: int, width: int = 12, keys: int = KEYS):
+    """Random rows in a pool of 40 blocks, ``B`` sequences over tables of
+    ``width`` entries (12: 6 key blocks of 16), 8 queries; the kernel
+    interpreted and the loop, each jitted once with ``start`` (and the tables)
+    as arguments. Pool block 39 is NaN: no clean table names it."""
+    S, rank = 8, 24
+    k = jax.random.split(jax.random.key(11), 4)
+    rows = jax.random.normal(k[0], (2, 40, BLOCK, rank + rope), jnp.float32)
+    pool = jnp.zeros((2, 40, BLOCK, 128), jnp.float32).at[..., :rank + rope].set(rows)
+    pool = pool.at[:, 39].set(jnp.nan)
+    w = 0.2 * jax.random.normal(k[1], (rank, N * (nope + v)), jnp.float32)
+    qn = jax.random.normal(k[2], (B, S, N, nope), jnp.float32)
+    qr = jax.random.normal(k[3], (B, S, N, rope), jnp.float32)
+    tables = 1 + jnp.arange(B * width, dtype=jnp.int32).reshape(B, width)
+    kw = dict(layer=1, scale=0.3, v_dim=v, kv_block=keys)
+    kernel = jax.jit(lambda t, st: latent_attention.chunk_attend(
+        qn, qr, pool, w, t, st, interpret=True, **kw))
+    loop = jax.jit(lambda t, st: latent_attention.chunk_attend(
+        qn, qr, pool, w, t, st, gather=True, **kw))
+    return SimpleNamespace(kernel=kernel, loop=loop, tables=tables, S=S, width=width)
+
+
+@pytest.mark.parametrize("shape, starts", [
+    ((1, 4, 16, 8, 16), [0]),  # the first chunk: one live block, the causal mask in it
+    ((1, 4, 16, 8, 16), [5]),  # mid-block: the chunk straddles blocks 0 and 1
+    ((1, 4, 16, 8, 16), [16]),  # a block's edge: block 0 needs no mask, block 1 all of it
+    ((1, 4, 16, 8, 16), [88]),  # the table's last block
+    ((2, 4, 16, 8, 16), [3, 70]),  # two sequences, one live block and five
+    # whole lanes, two blocks of 128 keys: two heads a trip share a tile of rotary
+    # columns, the running max and sum 128 lanes wide
+    ((1, 2, 128, 64, 128, 32, 128), [150]),
+], ids=["start0", "mid-block", "block-edge", "last-block", "two-unequal", "whole-lanes"])
+def test_the_chunk_kernel_is_the_loop_on_the_same_rows(shape, starts):
+    case = _chunk_case(*shape)
+    start = jnp.asarray(starts, jnp.int32)
+    got, want = np.asarray(case.kernel(case.tables, start)), np.asarray(case.loop(case.tables, start))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert np.isfinite(got).all() and np.abs(want).max() > 0.05
+
+
+def test_the_chunk_kernel_never_reads_a_dead_block():
+    """Two sequences, one live key block and five: the expansion runs five
+    trips for both, so the first one's buffer holds whatever its table's dead
+    entries name. NaN rows there (and past the second one's live blocks) change
+    nothing: a dead step neither fetches nor attends."""
+    case = _chunk_case(2, 4, 16, 8, 16)
+    starts = np.asarray([3, 70])
+    start = jnp.asarray(starts, jnp.int32)
+    live = -(-(starts + case.S) // KEYS)  # key blocks that hold an attended position
+    entries = np.arange(case.width)[None, :] // (KEYS // BLOCK)
+    garbage = jnp.where(entries < live[:, None], case.tables, 39)
+    assert (np.asarray(garbage) == 39).sum() == (6 - 1 + 6 - 5) * 2
+    clean = np.asarray(case.kernel(case.tables, start))
+    np.testing.assert_array_equal(np.asarray(case.kernel(garbage, start)), clean)
+    assert np.isfinite(clean).all()
+    # the loop attends every block it reads: the same tables poison it
+    assert np.isnan(np.asarray(case.loop(garbage, start))).any()
+    # and the host's count of the kernel's steps is these live blocks
+    blocks = latent_attention.chunk_blocks(case.S, 4, 16, 8, 16, case.width, BLOCK,
+                                           interpret=True, kv_block=KEYS)
+    assert blocks == (4, KEYS, 6)
+    assert latent_attention.chunk_grid_steps(starts, case.S, 4, blocks) == (12, 6)
+
+
+def _walk(jaxpr, inside_kernel=False):
+    """(equation, whether a pallas_call holds it) over a jaxpr and what it nests."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside_kernel
+        held = inside_kernel or eqn.primitive.name == "pallas_call"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub, held)
+
+
+def test_the_chunk_program_holds_one_kernel_a_layer_and_no_score_array(setup):
+    """The chunk program with the kernel: one ``pallas_call`` a latent layer,
+    and outside them no float32 array with a (heads, queries, keys) extent;
+    the loop's program is the control that has them."""
+    N, keys = setup.model.config.num_heads, 14 * BLOCK  # the table's 14 entries are one block
+    pool = jax.eval_shape(lambda: paged.layout_pool(setup.layout, 1, 16, BLOCK, dtype=jnp.float32))
+
+    def program(**path):
+        return jax.make_jaxpr(lambda p, pool, t, toks: paged._fused_forward(
+            lambda pp, ids, **kw: setup.model(pp, ids, **kw), p, pool, t,
+            jnp.zeros((1,), jnp.int32), toks, jnp.ones((1,), bool), block_size=BLOCK,
+            **path)[0])(setup.params, pool, jax.ShapeDtypeStruct((1, 14), jnp.int32),
+                        jax.ShapeDtypeStruct((1, CHUNK), jnp.int32)).jaxpr
+
+    def scores(jaxpr):
+        return [v.aval.shape for eqn, held in _walk(jaxpr) if not held for v in eqn.outvars
+                if v.aval.dtype == jnp.float32 and v.aval.ndim >= 3
+                and all(d in v.aval.shape for d in (N, CHUNK, keys))]
+
+    kernel = program(interpret=True, gather=False)
+    calls = [eqn for eqn, _ in _walk(kernel) if eqn.primitive.name == "pallas_call"]
+    assert len(calls) == len(setup.layout) == 2
+    assert all(eqn.params["name"] == "latent_chunk_attention" for eqn in calls)
+    assert scores(kernel) == []
+    assert scores(program(interpret=False, gather=True))  # the loop writes them
 
 
 def test_eight_ranks_held_parts_and_one_shared_expert_are_the_uncut_layer(setup):
@@ -283,6 +391,55 @@ def test_the_engine_serves_it_and_reuses_a_prefix_over_latent_blocks(setup):
     assert again["prefix_hit_tokens"] == (PROMPT - 1) // BLOCK * BLOCK
     n = eng._account.n
     assert n["latent_context_rows"] > 0 and n["attn_grid_steps"] == 0  # the gather path has no grid
+
+
+def test_a_chunks_span_carries_the_kernels_grid_and_live_steps(setup, monkeypatch):
+    """``serve.prefill_dispatch`` of a latent engine: the chunk kernels' grid
+    steps and live steps of that chunk, every layer's, by the kernel's own host
+    arithmetic; 0 and 0 where the chunk takes the loop. The chunk program is a
+    stub: the counts are the host's, from the chunk's position alone."""
+    from automodel_tpu.serving import loop_account
+
+    spans = []
+
+    class Spy:
+        def __init__(self, name, **stats):
+            spans.append((name, stats))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(loop_account, "TraceAnnotation", Spy)
+    monkeypatch.setenv("AUTOMODEL_FLASH_INTERPRET", "1")
+    prompt = (3 + np.arange(1100) % 90).tolist()
+    seen = {}
+    for backend in ("fused", "gather"):
+        eng = _engine(setup.model, setup.params, decode_kernel=backend, max_seq_len=2048,
+                      num_blocks=300, slots=1)
+        vocab = setup.model.config.vocab_size
+        eng._chunk = lambda params, pool, *rest: (jnp.zeros((vocab,), jnp.float32), pool)
+        del spans[:]
+        eng.submit(prompt, request_id=backend, max_new_tokens=1)
+        (done,) = eng.run()
+        assert done["completion_reason"] == "length"
+        seen[backend] = [st for name, st in spans if name == "serve.prefill_dispatch"]
+        width = eng._tables.shape[1]
+    assert [st["pos"] for st in seen["fused"]] == list(range(0, 1100, CHUNK))
+    # a table over 2,048 positions and a chunk more = 5 key blocks of 512 (the last
+    # one part), the 4 heads one group, 2 layers
+    blocks = latent_attention.chunk_blocks(CHUNK, 4, 16, 8, 16, width, BLOCK, interpret=True)
+    assert blocks == (4, 512, 5)
+    for st in seen["fused"]:
+        grid, live = latent_attention.chunk_grid_steps([st["pos"]], CHUNK, 4, blocks)
+        assert (st["attn_grid_steps"], st["attn_live_steps"]) == (2 * grid, 2 * live)
+        assert st["attn_live_steps"] == 2 * (1 + (st["pos"] + CHUNK - 1) // 512)
+    assert {st["attn_grid_steps"] for st in seen["fused"]} == {10}
+    assert {st["attn_live_steps"] for st in seen["fused"]} == {2, 4, 6}
+    assert all(st["attn_grid_steps"] == st["attn_live_steps"] == 0 for st in seen["gather"])
+    assert len(seen["gather"]) == len(seen["fused"])
 
 
 def test_a_bounded_iteration_runs_the_oldest_admissions_chunks_first(setup):

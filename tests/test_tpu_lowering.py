@@ -241,6 +241,29 @@ def test_paged_decode_lowers(n, degrees, sq, int8, q_shape, pool_shape, nbseq, l
     assert _compile(functools.partial(paged_attend, mesh_ctx=ctx, **kw), *args) == 1
 
 
+def test_latent_chunk_attention_lowers_at_the_serve_cells_widths():
+    """sarvam-105b's chunk of 512 queries, 64 heads of 128 + 64 / 128 over a
+    latent pool of 640-lane rows and a table of 544 entries (8,192 positions and
+    a chunk): the expansion's buffer and ONE Mosaic call; heads of 16 lanes, which
+    the kernel cannot tile, take the loop (no call)."""
+    from automodel_tpu.ops import latent_attention
+
+    ctx = _tpu_ctx(1)
+    bf = jnp.bfloat16
+
+    def calls(N, nope, rope, v, rank):
+        args = [_sds(ctx, (1, 512, N, nope), bf), _sds(ctx, (1, 512, N, rope), bf),
+                _sds(ctx, (6, 20480, 16, 640), bf), _sds(ctx, (rank, N * (nope + v)), bf),
+                _sds(ctx, (1, 544), jnp.int32), _sds(ctx, (1,), jnp.int32)]
+        return _compile(functools.partial(
+            latent_attention.chunk_attend, layer=3, scale=0.135, v_dim=v), *args)
+
+    assert calls(64, 128, 64, 128, 512) == 1
+    assert latent_attention.chunk_blocks(512, 64, 128, 64, 128, 544, 16) == (8, 512, 17)
+    assert calls(4, 16, 8, 16, 24) == 0
+    assert latent_attention.chunk_blocks(512, 4, 16, 8, 16, 544, 16) is None
+
+
 def test_fused_linear_ce_is_three_vocabulary_products():
     """The train cell's loss at its widths (D 2048, V 151,936, bf16) over two
     1024-token chunks: differentiated, the chunk loop holds the logits, dH and
