@@ -192,8 +192,12 @@ def kda_block(cfg, backend, h, lp, norm_scale, segment_ids, constrain):
     """One KDA layer's mixer with its pre-norm and residual. Scope ``kda``
     (inside the caller's ``attn``) with the segments ``kda_conv``,
     ``kda_gate``, ``kda_chunk`` and ``kda_norm``; the projections are the
-    scope's own."""
-    B, S, D = h.shape
+    scope's own. Every array is ``[B, S, H * dh]`` (or ``[B, S, H]``), a
+    head's channels contiguous: the per-head RMS norm of ``o`` divides inside
+    the delta-rule kernels (``out_norm_eps``: the float32 tile, before the
+    one rounding), and ``kda_norm`` holds what is left of the gated norm, one
+    flat float32 pass ``o * scale * sigmoid(gate)`` rounded once. ``o`` is
+    rounded twice on its way to ``o_proj`` (the kernel's write, this pass)."""
     H, dh = cfg.kda_num_heads, cfg.kda_head_dim
     f32 = jnp.float32
     with jax.named_scope("kda"):
@@ -212,16 +216,18 @@ def kda_block(cfg, backend, h, lp, norm_scale, segment_ids, constrain):
             gate = proj("g_a_proj") @ lp["g_b_proj"]["kernel"].astype(x.dtype)
         with jax.named_scope("kda_chunk"):
             # q, k, v, g stay [B, S, H * dh] as the convs and the gate left
-            # them: the operator normalises q and k and forms beta k, beta v
-            # and the clamp a tile at a time (ops/delta_rule.py)
+            # them: the operator normalises q and k, forms beta k, beta v and
+            # the clamp, and divides a head's row of o by its root mean
+            # square, a tile at a time (ops/delta_rule.py)
             o = chunked_delta_rule(
-                q, k, v, g, beta, segment_ids=segment_ids,
+                q, k, v, g, beta, segment_ids=segment_ids, out_norm_eps=cfg.rms_eps,
                 platform=backend.platform, mesh_ctx=backend.mesh_ctx,
             )
         with jax.named_scope("kda_norm"):
-            o = rms_norm(o.reshape(B, S, H, dh), lp["o_norm"]["scale"], cfg.rms_eps)
-            o = o.reshape(B, S, H * dh).astype(f32) * jax.nn.sigmoid(gate.astype(f32))
-            o = o.astype(x.dtype)
+            # what is left of the gated norm: one flat pass. The scale is a
+            # [H * dh] row, so its gradient is a column sum of a flat array
+            scale = jnp.tile(lp["o_norm"]["scale"].astype(f32), H)
+            o = (o.astype(f32) * scale * jax.nn.sigmoid(gate.astype(f32))).astype(x.dtype)
         h = h + o @ lp["o_proj"]["kernel"].astype(x.dtype)
     return constrain(h, ("batch", "seq", None))
 

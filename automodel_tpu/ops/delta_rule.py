@@ -28,6 +28,22 @@ the raw operands: ``dq``, ``dk``, ``dv`` in the compute type, ``dg`` float32
 (zero where the clamp holds and on a first token) and ``dbeta``; ``beta k``,
 ``beta v``, the clamped ``g`` and their cotangents never reach HBM.
 
+**The epilogue, where a caller asks for it** (``out_norm_eps``: a float). A
+per-head RMS norm of ``o`` outside the operator needs ``[B, S, H, dv]``, the
+shape nothing here has (on the chip: float32 relayouts forward, recomputed
+and transposed, 22.5 ms a step of Kimi-Linear's four KDA layers at 16,384
+tokens; PERF.md section 6, PR 42 and PR 49), while a head's ``[C, dv]`` tile
+of ``o`` is in VMEM, in float32, when the body ends. So the body may end with
+``o = o * rsqrt(mean(o * o, axis=-1) + eps)`` on that tile, BEFORE its one
+rounding to the output type: a square, a lane sum over ``dv``, an ``rsqrt``
+and a multiply, no product. The norm's scale (a ``[dv]`` row the heads share)
+stays the caller's, as a flat ``[H * dv]`` multiply. The backward needs
+nothing written for it: it recomputes the body, the epilogue included, takes
+the cotangent of the NORMED output and differentiates both. A padded row is
+``0 * rsqrt(eps)`` = 0. ``None`` (the default, Qwen3-Next's: its gated norm
+multiplies by ``silu(z)`` in its model's own layout) leaves the body as it
+is.
+
 **The chunked form.** With ``G`` the cumulative sum of ``g`` inside a chunk
 of ``C`` tokens and ``u_t = beta_t (v_t - (Diag(exp g_t) S_{t-1})^T k_t)``::
 
@@ -259,7 +275,7 @@ _chunk_cumsum.defvjp(lambda g: (_tri_sum(g, reverse=False), None),
                      lambda _, d: (_tri_sum(d, reverse=True),))
 
 
-def _chunk_body(st0, q, k, v, g, beta, segc=None, segr=None):
+def _chunk_body(st0, q, k, v, g, beta, segc=None, segr=None, out_norm_eps=None):
     """One chunk of one head, from the operands as the projections left them.
     ``st0`` [dv, dk] float32: the state BEFORE the chunk, transposed (the decay
     then scales its columns); ``q, k`` [C, dk], ``v`` [C, dv]: raw, in the
@@ -268,6 +284,8 @@ def _chunk_body(st0, q, k, v, g, beta, segc=None, segr=None):
     document's first token, ``segr`` [1, C] int32: the resets seen so far; both
     None for one document. What only feeds the rule is formed here, in float32,
     and rounded to the compute type where the module docstring says.
+    ``out_norm_eps``: a float divides each row of ``o`` by its root mean square
+    (the epilogue; the state is the rule's own either way).
     -> (o [C, dv] float32, the state after the chunk [dv, dk] float32)."""
     cd, dk = q.dtype, q.shape[1]
     beta = beta.astype(F32)
@@ -279,7 +297,10 @@ def _chunk_body(st0, q, k, v, g, beta, segc=None, segr=None):
     if segc is not None:
         g = jnp.where((segc & 1) == 1, 0.0, g)
         segc = segc >> 1
-    return _chunk_rule(st0, qn, kn, kb, vb, jnp.broadcast_to(g, q.shape), segc, segr)
+    o, st1 = _chunk_rule(st0, qn, kn, kb, vb, jnp.broadcast_to(g, q.shape), segc, segr)
+    if out_norm_eps is not None:
+        o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + out_norm_eps)
+    return o, st1
 
 
 def _chunk_rule(st0, q, k, kb, vb, g, segc, segr):
@@ -332,10 +353,11 @@ def _chunk_rule(st0, q, k, kb, vb, g, segc, segr):
     return o, st1
 
 
-def _chunk_grads(st0, q, k, v, g, beta, segc, segr, do, dst1):
-    """The body recomputed and transposed: -> (dst0, dq, dk, dv, dg, dbeta),
-    each in its operand's shape and type."""
-    body = lambda *a: _chunk_body(*a, segc, segr)
+def _chunk_grads(st0, q, k, v, g, beta, segc, segr, do, dst1, out_norm_eps=None):
+    """The body recomputed and transposed, its epilogue with it (``do`` is the
+    cotangent of the output as the forward gave it): -> (dst0, dq, dk, dv, dg,
+    dbeta), each in its operand's shape and type."""
+    body = lambda *a: _chunk_body(*a, segc, segr, out_norm_eps)
     _, vjp = jax.vjp(body, st0, q, k, v, g, beta)
     return vjp((do, dst1))
 
@@ -365,7 +387,8 @@ def _add_head_row(ref, h, i, c, col):
     ref[pl.ds(h, 1), :] += jnp.sum(jnp.where(_diagonal(ref, i, c), col, 0.0), axis=0, keepdims=True)
 
 
-def _fwd_kernel(*refs, c: int, n_sub: int, hb: int, dk: int, dv: int, g_small: bool, has_seg: bool):
+def _fwd_kernel(*refs, c: int, n_sub: int, hb: int, dk: int, dv: int, g_small: bool, has_seg: bool,
+                out_norm_eps: Optional[float]):
     q, k, v, g, beta, *seg, o, states, st = refs
 
     @pl.when(pl.program_id(2) == 0)
@@ -380,7 +403,7 @@ def _fwd_kernel(*refs, c: int, n_sub: int, hb: int, dk: int, dv: int, g_small: b
             states[h, i] = st[h]
             out, st1 = _chunk_body(st[h], q[rows, kc], k[rows, kc], v[rows, vc],
                                    _head_column(g, h, i, c) if g_small else g[rows, kc],
-                                   _head_column(beta, h, i, c), *segs)
+                                   _head_column(beta, h, i, c), *segs, out_norm_eps=out_norm_eps)
             o[rows, vc] = out.astype(o.dtype)
             st[h] = st1
         return 0
@@ -388,7 +411,8 @@ def _fwd_kernel(*refs, c: int, n_sub: int, hb: int, dk: int, dv: int, g_small: b
     lax.fori_loop(0, n_sub, one, 0)
 
 
-def _bwd_kernel(*refs, c: int, n_sub: int, hb: int, dk: int, dv: int, g_small: bool, has_seg: bool):
+def _bwd_kernel(*refs, c: int, n_sub: int, hb: int, dk: int, dv: int, g_small: bool, has_seg: bool,
+                out_norm_eps: Optional[float]):
     q, k, v, g, beta, *seg, do, states, dq, dk_, dv_, dg, dbeta, dst = refs
 
     @pl.when(pl.program_id(2) == 0)
@@ -408,7 +432,7 @@ def _bwd_kernel(*refs, c: int, n_sub: int, hb: int, dk: int, dv: int, g_small: b
             dst0, gq, gk, gv, gg, gb = _chunk_grads(
                 states[h, i], q[rows, kc], k[rows, kc], v[rows, vc],
                 _head_column(g, h, i, c) if g_small else g[rows, kc], _head_column(beta, h, i, c),
-                *segs, do[rows, vc].astype(F32), dst[h])
+                *segs, do[rows, vc].astype(F32), dst[h], out_norm_eps=out_norm_eps)
             dq[rows, kc] = gq
             dk_[rows, kc] = gk
             dv_[rows, vc] = gv
@@ -439,8 +463,8 @@ def _from_groups(x):
     return x.transpose(0, 3, 1, 2).reshape(B, Sp, n * hb)
 
 
-def _kernel_call(kernel, operands, outputs, *, heads, c, dk, dv, g_small, has_seg, reverse,
-                 interpret, name):
+def _kernel_call(kernel, operands, outputs, *, heads, c, dk, dv, g_small, has_seg, out_norm_eps,
+                 reverse, interpret, name):
     """Grid: batch x groups of ``hb`` heads x steps of ``_CHUNKS_PER_STEP``
     chunks (walked from the last when ``reverse``). An operand or output is
     "k" (``[B, Sp, H * dk]``), "v" (``[B, Sp, H * dv]``), "h" (a value a head
@@ -471,7 +495,7 @@ def _kernel_call(kernel, operands, outputs, *, heads, c, dk, dv, g_small, has_se
     }
     return pl.pallas_call(
         functools.partial(kernel, c=c, n_sub=n_sub, hb=hb, dk=dk, dv=dv, g_small=g_small,
-                          has_seg=has_seg),
+                          has_seg=has_seg, out_norm_eps=out_norm_eps),
         grid=(B, heads // hb, n_steps),
         in_specs=[specs[kind] for kind, _ in operands],
         out_specs=[specs[kind] for kind, _ in outputs],
@@ -496,7 +520,7 @@ def _kernel_operands(q, k, v, g, beta, segc, segr, heads):
     return ops, dict(heads=heads, dk=dk, dv=dv, g_small=g_small, has_seg=bool(seg))
 
 
-def _kernel_fwd(q, k, v, g, beta, segc, segr, *, heads, c, interpret):
+def _kernel_fwd(q, k, v, g, beta, segc, segr, *, heads, c, out_norm_eps, interpret):
     """Flat operands ``[B, Sp, H * d]``, ``beta`` (and a scalar ``g``)
     ``[B, Sp, H]``, ``Sp`` a whole number of grid steps.
     -> (o [B, Sp, H * dv], boundary states [B, H, Sp / C, dv, dk])."""
@@ -506,16 +530,18 @@ def _kernel_fwd(q, k, v, g, beta, segc, segr, *, heads, c, interpret):
         _fwd_kernel, ops,
         [("v", jax.ShapeDtypeStruct(v.shape, v.dtype)),
          ("states", jax.ShapeDtypeStruct((B, heads, Sp // c, static["dv"], static["dk"]), F32))],
-        c=c, reverse=False, interpret=interpret, name="delta_rule_fwd", **static)
+        c=c, out_norm_eps=out_norm_eps, reverse=False, interpret=interpret, name="delta_rule_fwd",
+        **static)
 
 
-def _kernel_bwd(q, k, v, g, beta, segc, segr, do, states, *, heads, c, interpret):
+def _kernel_bwd(q, k, v, g, beta, segc, segr, do, states, *, heads, c, out_norm_eps, interpret):
     ops, static = _kernel_operands(q, k, v, g, beta, segc, segr, heads)
     like = lambda op, dt=None: (op[0], jax.ShapeDtypeStruct(op[1].shape, dt or op[1].dtype))
     dq, dk_, dv_, dg, dbeta = _kernel_call(
         _bwd_kernel, [*ops, ("v", do), ("states", states)],
         [like(ops[0]), like(ops[1]), like(ops[2]), like(ops[3], F32), like(ops[4], F32)],
-        c=c, reverse=True, interpret=interpret, name="delta_rule_bwd", **static)
+        c=c, out_norm_eps=out_norm_eps, reverse=True, interpret=interpret, name="delta_rule_bwd",
+        **static)
     return dq, dk_, dv_, _from_groups(dg) if static["g_small"] else dg, _from_groups(dbeta)
 
 
@@ -549,13 +575,13 @@ def _over_heads(fn, has_seg, n_tail=0):
     return jax.vmap(per_head, in_axes=(0,) * 6 + (seg, seg) + (0,) * n_tail)
 
 
-def _scan_fwd(q, k, v, g, beta, segc, segr, *, heads, c):
+def _scan_fwd(q, k, v, g, beta, segc, segr, *, heads, c, out_norm_eps):
     B, Sp, _ = q.shape
     dk, dv = q.shape[-1] // heads, v.shape[-1] // heads
     has_seg = segc is not None
     xs = tuple(_to_chunks(a, heads, c) for a in (q, k, v, g, beta))
     segs = _seg_chunks(segc, segr, c) if has_seg else None
-    batched = _over_heads(_chunk_body, has_seg)
+    batched = _over_heads(functools.partial(_chunk_body, out_norm_eps=out_norm_eps), has_seg)
 
     def step(st, x):
         ops, seg = x
@@ -567,12 +593,13 @@ def _scan_fwd(q, k, v, g, beta, segc, segr, *, heads, c):
     return _from_chunks(o).astype(v.dtype), states.transpose(1, 2, 0, 3, 4)
 
 
-def _scan_bwd(q, k, v, g, beta, segc, segr, do, states, *, heads, c):
+def _scan_bwd(q, k, v, g, beta, segc, segr, do, states, *, heads, c, out_norm_eps):
     has_seg = segc is not None
     xs = tuple(_to_chunks(a, heads, c) for a in (q, k, v, g, beta))
     segs = _seg_chunks(segc, segr, c) if has_seg else None
     dos = _to_chunks(do.astype(F32), heads, c)
-    batched = _over_heads(_chunk_grads, has_seg, n_tail=2)
+    batched = _over_heads(functools.partial(_chunk_grads, out_norm_eps=out_norm_eps), has_seg,
+                          n_tail=2)
 
     def step(dst, x):
         ops, seg, d_o, st0 = x
@@ -588,26 +615,28 @@ def _scan_bwd(q, k, v, g, beta, segc, segr, do, states, *, heads, c):
 # -- the custom_vjp over flat, padded operands -----------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def _chunked(q, k, v, g, beta, segc, segr, heads, c, mode):
-    return _chunked_fwd(q, k, v, g, beta, segc, segr, heads, c, mode)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+def _chunked(q, k, v, g, beta, segc, segr, heads, c, mode, out_norm_eps):
+    return _chunked_fwd(q, k, v, g, beta, segc, segr, heads, c, mode, out_norm_eps)[0]
 
 
-def _chunked_fwd(q, k, v, g, beta, segc, segr, heads, c, mode):
+def _chunked_fwd(q, k, v, g, beta, segc, segr, heads, c, mode, out_norm_eps):
+    static = dict(heads=heads, c=c, out_norm_eps=out_norm_eps)
     if mode == "scan":
-        o, states = _scan_fwd(q, k, v, g, beta, segc, segr, heads=heads, c=c)
+        o, states = _scan_fwd(q, k, v, g, beta, segc, segr, **static)
     else:
-        o, states = _kernel_fwd(q, k, v, g, beta, segc, segr, heads=heads, c=c,
+        o, states = _kernel_fwd(q, k, v, g, beta, segc, segr, **static,
                                 interpret=mode == "interpret")
     return o, (q, k, v, g, beta, segc, segr, states)
 
 
-def _chunked_bwd(heads, c, mode, res, do):
+def _chunked_bwd(heads, c, mode, out_norm_eps, res, do):
     *ops, segc, segr, states = res
+    static = dict(heads=heads, c=c, out_norm_eps=out_norm_eps)
     if mode == "scan":
-        grads = _scan_bwd(*ops, segc, segr, do, states, heads=heads, c=c)
+        grads = _scan_bwd(*ops, segc, segr, do, states, **static)
     else:
-        grads = _kernel_bwd(*ops, segc, segr, do, states, heads=heads, c=c,
+        grads = _kernel_bwd(*ops, segc, segr, do, states, **static,
                             interpret=mode == "interpret")
     return (*(d.astype(a.dtype) for d, a in zip(grads, ops)), None, None)
 
@@ -626,6 +655,7 @@ def chunked_delta_rule(
     beta: jnp.ndarray,  # [B, S, H]: write strength
     *,
     segment_ids: Optional[jnp.ndarray] = None,  # [B, S] packed-document ids
+    out_norm_eps: Optional[float] = None,  # a float: each head's output row over its RMS
     chunk_size: int = 128,
     platform: Optional[str] = None,
     mesh_ctx=None,
@@ -636,7 +666,14 @@ def chunked_delta_rule(
     a head (l2, ``eps`` 1e-6), scales ``q`` by ``dk ** -0.5``, forms ``beta k``
     and ``beta v`` and clamps ``g`` to [-10, 0] itself, a chunk's tile at a
     time (module docstring): no ``[B, S, H, d]`` array goes in or comes out,
-    and the gradients come back for the raw operands. The products take their
+    and the gradients come back for the raw operands. ``out_norm_eps``: a
+    float makes ``o`` each head's output row divided by its root mean square,
+    ``o * rsqrt(mean(o^2 over dv) + out_norm_eps)``, formed in float32 in the
+    chunk body's epilogue and rounded ONCE to ``v``'s type (the RMS norm of
+    ``o`` a head without its scale, which the caller multiplies in flat; the
+    gradients are those of the normed output); ``None`` gives the rule's own
+    ``o`` (Qwen3-Next's). It says which mathematics the caller wants, like
+    ``segment_ids``; it is no tuning knob. The products take their
     operands in ``q``'s type (bfloat16 in training, float32 for Qwen3-Next and
     in the CPU tests) and accumulate in float32; the norms, the state, the
     cumulative decay and the triangular solve are float32 whatever the
@@ -652,7 +689,7 @@ def chunked_delta_rule(
         raise ValueError(f"chunk_size {chunk_size} is not a power of two >= {SUB}")
 
     def block(q, k, v, g, beta, segment_ids=None):
-        return _delta_rule_block(q, k, v, g, beta, segment_ids, chunk_size, mode)
+        return _delta_rule_block(q, k, v, g, beta, segment_ids, chunk_size, mode, out_norm_eps)
 
     if mode == "scan" or kernel_axes(mesh_ctx) is None:
         return block(q, k, v, g, beta, segment_ids)
@@ -679,7 +716,7 @@ def _delta_shard_map(block, mesh_ctx, q, k, v, g, beta, segment_ids):
     return kernel_shard_map(mesh_ctx, block, tuple(specs), x)(*args)
 
 
-def _delta_rule_block(q, k, v, g, beta, segment_ids, c, mode):
+def _delta_rule_block(q, k, v, g, beta, segment_ids, c, mode, out_norm_eps):
     B, S, H = beta.shape
     cd = q.dtype
     step = c * (_CHUNKS_PER_STEP if mode != "scan" else 1)
@@ -697,5 +734,5 @@ def _delta_rule_block(q, k, v, g, beta, segment_ids, c, mode):
         segc = (2 * seg + starts.reshape(seg.shape)).reshape(B, Sp, 1)
         segr = seg.reshape(B, Sp // c, 1, c)
     o = _chunked(padded(q), padded(k.astype(cd)), padded(v.astype(cd)), padded(g), padded(beta),
-                 segc, segr, H, c, mode)
+                 segc, segr, H, c, mode, out_norm_eps)
     return o[:, :S].astype(v.dtype)

@@ -219,12 +219,13 @@ def test_bfloat16_operands_are_told_apart_by_the_tolerance():
     assert rel(low, jax.jit(reference)(*args)) > 20 * TOL
 
 
-def formed_outside(q, k, v, g, beta, segment_ids=None, c=128):
+def formed_outside(q, k, v, g, beta, segment_ids=None, c=128, rounded=True):
     """The operator as it stood before the kernels formed their own operands:
     ``l2norm``, ``round(k * beta)``, ``round(v * beta)``, the clamp and the
     zero on a first token as jnp passes over ``[B, S, H, d]`` arrays, then the
     same chunk rule (``_chunk_rule``) under a plain scan that autodiff walks.
-    ``S`` a whole number of chunks."""
+    ``S`` a whole number of chunks. ``rounded=False``: the rule's float32
+    output as it is before its one rounding to the compute type."""
     Bq, S, _ = q.shape
     cd = q.dtype
     qn = (l2norm(heads(q)) * DK**-0.5).astype(cd)
@@ -252,7 +253,8 @@ def formed_outside(q, k, v, g, beta, segment_ids=None, c=128):
 
     xs = tuple(chunks(a) for a in (qn, kn, kb, vb, g))
     _, o = jax.lax.scan(step, jnp.zeros((Bq, H, DV, DK), F32), (xs, seg))
-    return o.transpose(1, 0, 3, 2, 4).reshape(Bq, S, -1).astype(cd)
+    o = o.transpose(1, 0, 3, 2, 4).reshape(Bq, S, -1)
+    return o.astype(cd) if rounded else o
 
 
 # bfloat16 operands, both sides the same chunk rule on the same rounded
@@ -284,6 +286,63 @@ def test_bfloat16_rounding_points_are_those_of_the_formation_outside(mode, case)
     for name in NAMES:
         assert grads[name].dtype == args[NAMES.index(name)].dtype
         assert rel(grads[name], wants[name]) < TOL_BF16_GRADS, name
+
+
+# -- the epilogue: each head's output row over its root mean square -----------------------
+
+EPS = 1e-5
+
+
+def row_rms_normed(o, eps=EPS):
+    """What the epilogue replaces: the flat ``o`` taken to ``[B, S, H, dv]``
+    and each row divided by its root mean square, in float32."""
+    o4 = heads(o.astype(F32))
+    return (o4 * jax.lax.rsqrt(jnp.mean(o4 * o4, axis=-1, keepdims=True) + eps)).reshape(o.shape)
+
+
+@pytest.mark.parametrize("mode", ["interpret", "scan"])
+@pytest.mark.parametrize("packed_docs", [False, True], ids=["one_document", "packed"])
+@pytest.mark.parametrize("per_channel", [True, False], ids=["per_channel", "scalar"])
+def test_the_norm_epilogue_is_the_composition_it_replaces(mode, packed_docs, per_channel):
+    """``out_norm_eps`` against the operator without it followed by the row
+    norm in jnp, float32 operands, 200 tokens (the kernels pad them to 256,
+    the scan too): the output and all five gradients. The backward takes the
+    cotangent of the NORMED output and differentiates the epilogue with the
+    body it recomputes. Rows of ``o`` here have a mean square of 2e-7 to 6e-3
+    (median 2e-4): ``eps`` = 1e-5 leads in some rows and hardly counts in
+    others. Read: equal to the last bit (the same float32 operations on the
+    same numbers, inside the body or after it)."""
+    S = 200
+    args, w = operands(S, per_channel, 1.0, norms=(0.1, 30.0))
+    seg = packed(S)[1] if packed_docs else None
+    kw = dict(segment_ids=seg, interpret=mode == "interpret")
+    o, got = run(lambda *a: chunked_delta_rule(*a, out_norm_eps=EPS, **kw), args, w)
+    o_ref, want = run(lambda *a: row_rms_normed(chunked_delta_rule(*a, **kw)), args, w)
+    assert o.shape == o_ref.shape and o.dtype == F32
+    assert rel(o, o_ref) < TOL
+    for name in NAMES:
+        assert rel(got[name], want[name]) < TOL, name
+
+
+@pytest.mark.parametrize("mode", ["interpret", "scan"])
+@pytest.mark.parametrize("case", ["per_channel", "scalar", "packed"])
+def test_the_norm_epilogue_rounds_once(mode, case):
+    """bfloat16 operands: the epilogue divides the float32 tile and the kernel
+    rounds it, so the output is the rule's UNROUNDED float32 output, normed in
+    float32 and rounded once, to a last bit of bfloat16 (the composition it
+    replaces rounds ``o`` first and the normed rows again)."""
+    S = 256
+    (q, k, v, g, beta), _ = operands(S, case != "scalar", 1.0, norms=(0.1, 30.0))
+    seg = packed(S)[1] if case == "packed" else None
+    args = (*(a.astype(jnp.bfloat16) for a in (q, k, v)), g, beta)
+    got = jax.jit(lambda *a: chunked_delta_rule(*a, segment_ids=seg, out_norm_eps=EPS,
+                                                interpret=mode == "interpret"))(*args)
+    want = jax.jit(lambda *a: row_rms_normed(formed_outside(*a, seg, rounded=False))
+                   .astype(jnp.bfloat16))(*args)
+    assert got.dtype == jnp.bfloat16
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    # one last bit of a bfloat16 number is 2^-8 to 2^-7 of it
+    assert (np.abs(got - want) <= 2.0**-7 * np.abs(want)).all()
 
 
 @pytest.mark.parametrize("chunk", [64, 128])
@@ -356,17 +415,20 @@ def dot_generals(jaxpr) -> int:
                for eqn in jaxpr.eqns)
 
 
+@pytest.mark.parametrize("eps", [None, EPS], ids=["plain", "norm_epilogue"])
 @pytest.mark.parametrize("cd,forward,backward", [(jnp.bfloat16, 52, 87), (F32, 28, 59)],
                          ids=["bfloat16", "float32"])
-def test_products_a_chunk_head(cd, forward, backward):
+def test_products_a_chunk_head(cd, forward, backward, eps):
     """The kernels are bound by their count of 128 x 128 x 128 products (module
     docstring): at the cell's shapes a chunk-head is 52 forward (36 the
     inverse's twelve in three passes) and 87 backward: the body recomputed, its
     transpose, and 6 for the inverse's identity. ``jax.vjp`` let back into the
-    inverse reads 153 (float32 operands: 81)."""
+    inverse reads 153 (float32 operands: 81). The norm epilogue is a square, a
+    lane sum, an ``rsqrt`` and a multiply: it adds no product either way."""
     c, dk, dv = 128, 128, 128
     st0, rows = jnp.zeros((dv, dk), F32), jnp.zeros((c, dk), cd)
     args = (st0, rows, rows, jnp.zeros((c, dv), cd), jnp.zeros((c, dk), F32), jnp.zeros((c, 1), F32))
-    assert dot_generals(jax.make_jaxpr(_chunk_body)(*args).jaxpr) == forward
-    grads = lambda *a: _chunk_grads(*a[:6], None, None, *a[6:])
+    body = lambda *a: _chunk_body(*a, out_norm_eps=eps)
+    assert dot_generals(jax.make_jaxpr(body)(*args).jaxpr) == forward
+    grads = lambda *a: _chunk_grads(*a[:6], None, None, *a[6:], out_norm_eps=eps)
     assert dot_generals(jax.make_jaxpr(grads)(*args, jnp.zeros((c, dv), F32), st0).jaxpr) <= backward

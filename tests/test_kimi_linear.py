@@ -213,6 +213,70 @@ def test_state_dict_round_trip(setup):
                  params, back)
 
 
+def _kda_block_with_the_norm_outside(cfg, backend, h, lp, norm_scale):
+    """``kda_block`` as it stood until the per-head norm became the delta-rule
+    kernels' epilogue: the operator gives the raw ``o`` in the compute type,
+    ``rms_norm`` takes it a head at a time as ``[B, S, H, dh]`` and the gate
+    multiplies the flat result."""
+    from automodel_tpu.ops.delta_rule import chunked_delta_rule
+    from automodel_tpu.ops.norms import rms_norm
+    from automodel_tpu.ops.short_conv import causal_conv1d
+
+    B, S, _ = h.shape
+    H, dh = cfg.kda_num_heads, cfg.kda_head_dim
+    f32 = jnp.float32
+    x = rms_norm(h, norm_scale, cfg.rms_eps)
+    proj = lambda name: x @ lp[name]["kernel"].astype(x.dtype)
+    conv = lambda a, name: jax.nn.silu(causal_conv1d(a, lp[name]["weight"].astype(a.dtype), None))
+    q, k, v = (conv(proj(f"{n}_proj"), f"{n}_conv") for n in "qkv")
+    f = (proj("f_a_proj") @ lp["f_b_proj"]["kernel"].astype(x.dtype)).astype(f32) + lp["dt_bias"].astype(f32)
+    g = -jnp.repeat(jnp.exp(lp["A_log"].astype(f32)), dh) * jax.nn.softplus(f)
+    beta = jax.nn.sigmoid(proj("b_proj").astype(f32))
+    gate = proj("g_a_proj") @ lp["g_b_proj"]["kernel"].astype(x.dtype)
+    o = chunked_delta_rule(q, k, v, g, beta, platform=backend.platform, mesh_ctx=backend.mesh_ctx)
+    o = rms_norm(o.reshape(B, S, H, dh), lp["o_norm"]["scale"], cfg.rms_eps)
+    o = (o.reshape(B, S, H * dh).astype(f32) * jax.nn.sigmoid(gate.astype(f32))).astype(x.dtype)
+    return h + o @ lp["o_proj"]["kernel"].astype(x.dtype)
+
+
+def test_the_kda_block_is_the_block_with_its_norm_outside(setup):
+    """The mixer with ``out_norm_eps`` and a flat ``scale * sigmoid(gate)``
+    pass against the formulation it replaces, float32, the kernels in
+    interpret mode: the block's output and the gradient of every parameter
+    of the layer (``o_norm.scale`` among them: its gradient is now a column
+    sum of a flat array folded ``[H, dh] -> [dh]``) and of the input. The
+    weights' norm scales are 1 + 0.1 N(0, 1), so a dropped or misplaced scale
+    shows."""
+    from automodel_tpu.models.kimi_linear.model import kda_block
+
+    model, params = setup.model, setup.params
+    cfg, backend = model.config, model.backend
+    lp = jax.tree.map(lambda a: a[0], params["kda"])
+    scale = params["layers"]["input_norm"]["scale"][0]
+    assert float(jnp.abs(lp["o_norm"]["scale"] - 1.0).max()) > 0.05
+    h = jax.random.normal(jax.random.key(3), (2, 72, cfg.hidden_size), jnp.float32)
+    w = jax.random.normal(jax.random.key(4), h.shape, jnp.float32)
+
+    def run(block):
+        def weighed(h, lp, scale):
+            out = block(h, lp, scale)
+            return (out * w).sum(), out
+
+        with mock.patch.dict(os.environ, AUTOMODEL_DELTA_INTERPRET="1"):
+            return jax.device_get(jax.jit(jax.value_and_grad(weighed, argnums=(0, 1, 2), has_aux=True))(
+                h, lp, scale))
+
+    (_, got), got_grads = run(lambda h, lp, s: kda_block(cfg, backend, h, lp, s, None, lambda x, _: x))
+    (_, want), want_grads = run(lambda h, lp, s: _kda_block_with_the_norm_outside(cfg, backend, h, lp, s))
+    assert np.abs(got - want).max() / np.abs(want).max() < LOGITS_TOL
+    flat = lambda t: {W.path_name(p): g for p, g in jax.tree_util.tree_flatten_with_path(t)[0]}
+    got_grads, want_grads = flat(got_grads), flat(want_grads)
+    assert sorted(got_grads) == sorted(want_grads) and any("o_norm" in n for n in got_grads)
+    for name, w_ in want_grads.items():
+        err = float(np.linalg.norm(got_grads[name] - w_) / np.linalg.norm(w_))
+        assert err < GRAD_TOL, (name, err)
+
+
 def test_the_train_step_writes_both_mixers_scopes_and_the_counter(setup):
     from automodel_tpu.optim.builders import build_optimizer
     from automodel_tpu.training.train_state import TrainState
